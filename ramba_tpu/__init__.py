@@ -26,7 +26,7 @@ import numpy as _np
 
 from ramba_tpu import common  # noqa: F401  (env config; import first)
 
-common.setup_persistent_cache()
+common.setup_compile_cache()
 from ramba_tpu.core.fuser import flush, sync, stats as fuser_stats  # noqa: F401
 from ramba_tpu.core.masked import MaskedArray  # noqa: F401
 from ramba_tpu.core.ndarray import ndarray  # noqa: F401

@@ -321,7 +321,7 @@ def restore(path: str, target=None):
     if tgt is not None:
         try:
             with ocp.StandardCheckpointer() as ckptr:
-                meta = ckptr.metadata(apath)
+                meta = ckptr.metadata(apath).item_metadata.tree
         except Exception as e:
             raise CheckpointCorruptError(
                 f"checkpoint at {path!r} has unreadable metadata "

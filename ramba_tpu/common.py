@@ -100,88 +100,71 @@ rewrite_enabled = _env_flag("RAMBA_TPU_REWRITE", True)
 # Forced number of devices ("workers"); default = all visible devices.
 num_workers_env = os.environ.get("RAMBA_WORKERS", None)
 
-# Persistent compiled-kernel cache across processes (reference: RAMBA_CACHE
-# activates a Numba disk cache under ~/.ramba_numba_cache keyed by source
-# hash, /root/reference/ramba/ramba.py:177-246).  Here the compiled artifacts
-# are XLA executables, persisted via jax's compilation cache.  Set
-# RAMBA_CACHE=1 for the default location or RAMBA_CACHE=/some/dir.
-cache_env = os.environ.get("RAMBA_CACHE", None)
+# jax's persistent compilation cache: ONE directory, placed from outside.
+# JAX_COMPILATION_CACHE_DIR when set (jax reads the variable itself and no
+# code here or elsewhere points jax at another path), else
+# <checkout>/.jax_cache next to this package.  A second process finds the
+# first one's entries only in a directory that does not move, so it is
+# never under ~, a temporary name, a pid or a time.
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class CacheStatus(NamedTuple):
-    """Typed result of :func:`setup_persistent_cache` — init failure is
-    a reportable state, not a silent no-op."""
+    """Typed result of :func:`setup_compile_cache`."""
 
-    path: str | None   # resolved cache directory (None = disabled)
-    ok: bool           # every init step succeeded (True when disabled)
+    path: str          # directory jax's compilation cache lives in
+    source: str        # "env" (JAX_COMPILATION_CACHE_DIR) or "checkout"
+    ok: bool           # the directory exists and jax is configured for it
     error: str | None  # first failure, when ok is False
 
-    @property
-    def enabled(self) -> bool:
-        return self.path is not None
+
+def compile_cache_dir() -> str:
+    """The directory of jax's persistent compilation cache (see above).
+    Reads the live environment."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_CHECKOUT, ".jax_cache"))
+
+
+def setup_compile_cache() -> CacheStatus:
+    """Arm jax's on-disk executable cache at :func:`compile_cache_dir`,
+    caching every program regardless of compile time or size (the
+    reference caches every generated kernel,
+    /root/reference/ramba/ramba.py:177-246).  Emits a
+    ``compile.persist_init`` event so a trace records where a process
+    kept its cache."""
+    import jax
+
+    from_env = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    path = compile_cache_dir()
+    error = None
+    try:
+        os.makedirs(path, exist_ok=True)
+    except OSError as e:
+        error = f"{type(e).__name__}: {e}"
+    if not from_env:
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    status = CacheStatus(path, "env" if from_env else "checkout",
+                         error is None, error)
+    from ramba_tpu.observe import events as _events
+
+    _events.emit({"type": "compile.persist_init", **status._asdict()})
+    return status
 
 
 def persistent_cache_path() -> str | None:
-    """Resolve the RAMBA_CACHE directory (None when disabled).  Reads
-    the live environment so tests (and the compile/persist subsystem)
-    see runtime toggles, not the import-time snapshot."""
-    env = os.environ.get("RAMBA_CACHE", cache_env)
+    """Directory of the AOT lane (``compile/persist.py``), armed by
+    RAMBA_CACHE: a path, or a truthy value for ``ramba_aot`` inside
+    :func:`compile_cache_dir`.  None when unset or falsy.  RAMBA_CACHE
+    does not place jax's own cache.  Reads the live environment so tests
+    see runtime toggles."""
+    env = os.environ.get("RAMBA_CACHE")
     if not env or env in _FALSY:
         return None
     if env in _TRUTHY:
-        return os.path.expanduser("~/.ramba_tpu_xla_cache")
+        return os.path.join(compile_cache_dir(), "ramba_aot")
     return os.path.expanduser(env)
-
-
-def setup_persistent_cache() -> CacheStatus:
-    """Enable the on-disk XLA executable cache if RAMBA_CACHE is set.
-
-    Returns a :class:`CacheStatus`; emits a ``compile.persist_init``
-    event when the cache is enabled so traces record whether a process
-    actually armed its cache (a misconfigured dir used to be silently
-    ignored)."""
-    path = persistent_cache_path()
-    if path is None:
-        return CacheStatus(None, True, None)
-    error = None
-    try:
-        import jax
-
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # The reference caches every generated kernel regardless of
-        # compile time.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception as e:  # noqa: BLE001 — config failure must not kill import
-        error = f"{type(e).__name__}: {e}"
-    if error is None:
-        # jax initializes the persistent cache lazily on the *first*
-        # compile and latches that state — if anything compiled before
-        # RAMBA_CACHE was applied (cache dir None at the time), the new
-        # dir is silently ignored.  Force re-initialization so the dir
-        # takes effect mid-process.
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.reset_cache()
-        except Exception as e:  # noqa: BLE001 — reset is best-effort
-            error = f"reset_cache: {type(e).__name__}: {e}"
-    status = CacheStatus(path, error is None, error)
-    try:
-        from ramba_tpu.observe import events as _events
-
-        _events.emit({
-            "type": "compile.persist_init",
-            "path": status.path,
-            "ok": status.ok,
-            "error": status.error,
-        })
-    except Exception:  # noqa: BLE001 — observability must not break init
-        pass
-    return status
 
 
 def dprint(level: int, *args) -> None:

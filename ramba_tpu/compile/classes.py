@@ -290,18 +290,15 @@ def apply(plan: ClassPlan, leaf_vals: Sequence) -> list:
     out = list(leaf_vals)
     if plan.bucket <= plan.n:
         return out
-    import jax
     import jax.numpy as jnp
 
     pad = plan.bucket - plan.n
-    # allow_all: the pad runs eagerly, and under multi-process SPMD the
-    # leaves may not be fully addressable — every rank pads identically,
-    # so the op is SPMD-consistent by construction
-    with jax.spmd_mode("allow_all"):
-        for s in plan.pad_slots:
-            v = out[s]
-            widths = [(0, pad)] + [(0, 0)] * (getattr(v, "ndim", 1) - 1)
-            out[s] = jnp.pad(v, widths)
+    # the pad runs eagerly; under multi-process SPMD every rank pads
+    # identically, so the op is SPMD-consistent by construction
+    for s in plan.pad_slots:
+        v = out[s]
+        widths = [(0, pad)] + [(0, 0)] * (getattr(v, "ndim", 1) - 1)
+        out[s] = jnp.pad(v, widths)
     return out
 
 
@@ -312,10 +309,7 @@ def strip(plan: ClassPlan, outs: Sequence) -> tuple:
     full-rank operands), so the result is exact, not approximate."""
     if plan.bucket <= plan.n:
         return tuple(outs)
-    import jax
-
-    with jax.spmd_mode("allow_all"):
-        return tuple(o[: plan.n] for o in outs)
+    return tuple(o[: plan.n] for o in outs)
 
 
 def note_decision(fingerprint: str, plan: Optional[ClassPlan]) -> None:

@@ -1,15 +1,13 @@
 """First-class persistent executable cache (the ``RAMBA_CACHE`` dir).
 
-Promotes the JAX compilation cache from a fragile config side-effect
-(``common.setup_persistent_cache``) into a tested, ledger-accounted
-path, and adds an **AOT lane**: serialized ``jit(...).lower().compile()``
-executables for the top-K fingerprints, so a second process starts with
-near-zero compile wall — it deserializes executables instead of
-recompiling them.
+An **AOT lane** beside jax's persistent compilation cache: serialized
+``jit(...).lower().compile()`` executables for the top-K fingerprints, so
+a second process starts with near-zero compile wall — it deserializes
+executables instead of tracing, lowering and recompiling them.
 
-Layout under the cache directory (shared with JAX's own compilation
-cache, which ``common.setup_persistent_cache`` points at the same
-path)::
+Layout under the RAMBA_CACHE directory (jax's own compilation cache
+lives elsewhere, at ``common.compile_cache_dir()``, and is never moved
+or switched off from here)::
 
     <dir>/.ramba_cache          ownership marker (atomic init)
     <dir>/aot/<fp>-<sig>.aot    pickled (blob, in_tree, out_tree) triple
@@ -25,7 +23,7 @@ program recompiles (counted ``compile.persist_corrupt``; fault site
 counted here and surfaced through ``diagnostics.perf_report()`` and the
 ``ramba_compile_persist_*`` telemetry series.
 
-Set ``RAMBA_AOT=0`` to keep the JAX cache but disable the AOT lane.
+Set ``RAMBA_AOT=0`` to disable the AOT lane under an armed RAMBA_CACHE.
 """
 
 from __future__ import annotations
@@ -456,7 +454,17 @@ def store_entry(fp: str, sig: tuple, program_rec=None,
                 program_rec["instrs"], program_rec["n_leaves"],
                 program_rec["leaf_kinds"], program_rec["out_slots"])
             donate = program_rec["donate"]
-        fn = jax.jit(_fuser._build_callable(program), donate_argnums=donate)
+        run = _fuser._build_callable(program)
+
+        # Compiled under its own module name, so jax's persistent cache
+        # keys it apart from the demand compile of the same program that
+        # this process has usually just written there: the AOT compile
+        # is then a FRESH one (see below for why it must be).
+        def aot(*leaf_vals):
+            return run(*leaf_vals)
+
+        aot.__name__ = aot.__qualname__ = f"ramba_aot_{fp}"
+        fn = jax.jit(aot, donate_argnums=donate)
         vals = _example_vals(sig)
         shardings = (candidate or {}).get("shardings")
         if shardings:
@@ -468,16 +476,28 @@ def store_entry(fp: str, sig: tuple, program_rec=None,
                 if s is not None and hasattr(v, "shape") else v
                 for v, s in zip(vals, shardings)
             ]
-        # Compile fresh, bypassing JAX's persistent compilation cache: a
-        # cache-loaded executable serializes to a blob whose CPU kernel
-        # symbols are unresolvable in another process ("Symbols not
-        # found"), which would poison every warm start after the first.
-        prev_cache = jax.config.jax_enable_compilation_cache
-        jax.config.update("jax_enable_compilation_cache", False)
+        # Only a FRESH compile is stored.  An executable jax loaded from
+        # its own persistent cache serializes to a blob whose XLA:CPU
+        # kernel symbols do not resolve in another process ("Function
+        # ... not found"), which would poison every warm start after the
+        # first.  jax's cache is never moved or switched off for this: a
+        # hit (a re-store after an eviction, in a later process) is
+        # observed through jax.monitoring and the store skipped — jax's
+        # cache already spares that process the compile.
+        cache_hits = []
+
+        def on_event(event, **_kw):
+            if event == "/jax/compilation_cache/cache_hits":
+                cache_hits.append(event)
+
+        jax.monitoring.register_event_listener(on_event)
         try:
             compiled = fn.lower(*vals).compile()
         finally:
-            jax.config.update("jax_enable_compilation_cache", prev_cache)
+            jax.monitoring.unregister_event_listener(on_event)
+        if cache_hits:
+            _registry.inc("compile.persist_store_skipped_jax_cache")
+            return "skipped"
         from jax.experimental import serialize_executable as _se
 
         blob, in_tree, out_tree = _se.serialize(compiled)

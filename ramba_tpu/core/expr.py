@@ -26,15 +26,6 @@ import numpy as np
 # Nodes
 # ---------------------------------------------------------------------------
 
-# jax.typeof only exists in newer jax; jax.core.get_aval returns the same
-# ShapedArray (shape/dtype/weak_type) for concrete arrays on older releases.
-_typeof = getattr(jax, "typeof", None)
-if _typeof is None:
-
-    def _typeof(value):
-        return jax.core.get_aval(value)
-
-
 class Expr:
     """Base class. ``aval`` is a jax.ShapeDtypeStruct-like with shape/dtype."""
 
@@ -56,7 +47,7 @@ class Const(Expr):
 
     def __init__(self, value):
         self.value = value
-        self.aval = _typeof(value)
+        self.aval = jax.typeof(value)
 
 
 class Scalar(Expr):

@@ -949,13 +949,17 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
             pass
     bytes_in = sum(_nbytes(v) for v in leaf_vals)
     t0 = time.perf_counter()
-    if common.timing_level > 1 or _profile.enabled():
-        # label the dispatch in profiler traces (RAMBA_PROFILE_DIR /
-        # utils.timing.profiler_trace); off the hot path otherwise
-        with _profile.annotation(_program_label(program)):
+    # jax traces (first call, or a new shape under this key) inside the
+    # call below; kernels that choose a lowering while traced note it
+    # (registry.note_kernel) and the notes land on this flush's span
+    with _registry.collect_kernel_notes() as kernel_notes:
+        if common.timing_level > 1 or _profile.enabled():
+            # label the dispatch in profiler traces (RAMBA_PROFILE_DIR /
+            # utils.timing.profiler_trace); off the hot path otherwise
+            with _profile.annotation(_program_label(program)):
+                outs = fn(*leaf_vals)
+        else:
             outs = fn(*leaf_vals)
-    else:
-        outs = fn(*leaf_vals)
     dt = time.perf_counter() - t0
     sync_dt = None
     fence_dt = None
@@ -966,16 +970,14 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
     # fingerprint (deterministic — see attrib.fence_decision), so the
     # steady state stops paying the serialization tax on every flush.
     if _attrib.fence_decision(fp, span) or _ledger.sync_timing():
-        try:
-            jax.block_until_ready(outs)
-            fence_dt = time.perf_counter() - t0 - dt
-        except Exception:
-            fence_dt = None
-        if fence_dt is not None:
-            # the fence wait is observability's own cost: the device tail
-            # would have overlapped the host had we not blocked on it
-            _observer.add("fence", fence_dt)
-        if fence_dt is not None and _ledger.sync_timing():
+        # a device failure surfaces here (dispatch is asynchronous) and
+        # belongs to this attempt: let the ladder classify it
+        jax.block_until_ready(outs)
+        fence_dt = time.perf_counter() - t0 - dt
+        # the fence wait is observability's own cost: the device tail
+        # would have overlapped the host had we not blocked on it
+        _observer.add("fence", fence_dt)
+        if _ledger.sync_timing():
             # RAMBA_PERF=sync: a second, device-synchronized sample.
             sync_dt = dt + fence_dt
     if is_new:
@@ -1026,6 +1028,8 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
         if backend is not None:
             call["backend"] = backend
         span["calls"].append(call)
+        if kernel_notes:
+            span.setdefault("kernels", []).extend(kernel_notes)
     return outs
 
 
@@ -1137,10 +1141,7 @@ def _run_eager(program: _Program, leaf_vals, span: Optional[dict]):
     rung (eager dispatch is async) rather than at a later materialize."""
     _faults.check("eager")
     t0 = time.perf_counter()
-    # allow_all: eager ops on non-fully-addressable (multi-host) arrays
-    # are refused by default; this rung runs them op-by-op deliberately
-    with jax.spmd_mode("allow_all"):
-        outs = _build_callable(program)(*leaf_vals)
+    outs = _build_callable(program)(*leaf_vals)
     outs = jax.block_until_ready(outs)
     dt = time.perf_counter() - t0
     _ledger.record_execute(
@@ -1187,7 +1188,15 @@ def _run_host(program: _Program, leaf_vals, span: Optional[dict]):
         try:
             spec = _mesh.default_spec(o.shape, mesh)
             res.append(jax.device_put(o, NamedSharding(mesh, spec)))
-        except Exception:
+        except Exception as e:
+            # the mesh would not take the result back: it stays committed
+            # to the host CPU, and the timeline says so
+            _registry.inc("resilience.host_committed")
+            _events.emit({
+                "type": "degrade", "site": "flush",
+                "action": "host_committed", "shape": list(o.shape),
+                "error": f"{type(e).__name__}: {e}"[:300],
+            })
             res.append(o)
     dt = time.perf_counter() - t0
     _ledger.record_execute(

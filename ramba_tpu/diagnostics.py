@@ -304,7 +304,7 @@ def report(file=None) -> None:
         for ev in hs:
             bits = [f"{k}={ev[k]}" for k in
                     ("platform", "device_count", "outcome", "init_seconds",
-                     "selected_via", "error") if k in ev]
+                     "error") if k in ev]
             print("  " + " ".join(bits), file=file)
     rs = resilience_events()
     if rs:
@@ -391,10 +391,11 @@ def report(file=None) -> None:
         print(f"  flushes={attr['flushes']} {stages}"
               f" unattributed={attr['unattributed_s']:.4f}s"
               f" ({attr['unattributed_frac']:.1%})", file=file)
-        print(f"  device_kind={attr['device_kind'] or '?'}"
-              f" peaks={attr['peaks']['peak_gbps']:g}GB/s"
-              f"/{attr['peaks']['peak_tflops']:g}TFLOPs"
-              f" ({attr['peaks']['source']})", file=file)
+        peaks = attr["peaks"]
+        print(f"  device_kind={attr['device_kind'] or '?'} peaks="
+              + (f"{peaks['peak_gbps']:g}GB/s/{peaks['peak_tflops']:g}TFLOPs"
+                 f" ({peaks['source']})" if peaks
+                 else "unknown (no roofline)"), file=file)
         roofs = sorted(attr["rooflines"].items(),
                        key=lambda kv: kv[1]["frac_of_peak"], reverse=True)[:8]
         for fp, r in roofs:

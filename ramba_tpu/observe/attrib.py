@@ -114,10 +114,12 @@ _regressed: "set[str]" = set()
 _regressions = 0
 _atexit_armed = False
 
-# Peak table per device_kind substring (measured-spec ballpark, not
-# marketing sheets — the point is a stable denominator, override with
-# RAMBA_PEAKS_JSON for rigor).  Matched case-insensitively against
-# jax.devices()[0].device_kind; "default" is the CPU/interpret fallback.
+# Peak table per device_kind substring (published figures: Google Cloud
+# documentation, "TPU v5e" and the sibling pages), matched
+# case-insensitively against jax.devices()[0].device_kind — a v5e chip
+# reports "TPU v5 lite".  RAMBA_PEAKS_JSON adds or replaces entries.  A
+# device the table does not know has NO peaks: an absent roofline, never
+# one drawn against invented numbers.
 _BUILTIN_PEAKS = {
     "v5 lite": {"peak_gbps": 819.0, "peak_tflops": 197.0},
     "v5litepod": {"peak_gbps": 819.0, "peak_tflops": 197.0},
@@ -126,7 +128,6 @@ _BUILTIN_PEAKS = {
     "v4": {"peak_gbps": 1228.0, "peak_tflops": 275.0},
     "v3": {"peak_gbps": 900.0, "peak_tflops": 123.0},
     "v2": {"peak_gbps": 700.0, "peak_tflops": 45.0},
-    "default": {"peak_gbps": 50.0, "peak_tflops": 1.0},
 }
 
 
@@ -596,9 +597,10 @@ def device_kind() -> Optional[str]:
         return None
 
 
-def peak_table(kind: Optional[str] = None) -> dict:
+def peak_table(kind: Optional[str] = None) -> Optional[dict]:
     """Resolved ``{"peak_gbps", "peak_tflops", "source", "device_kind"}``
-    for ``kind`` (default: the live device)."""
+    for ``kind`` (default: the live device), or None when no table entry
+    matches it."""
     if kind is None:
         kind = device_kind()
     table = dict(_BUILTIN_PEAKS)
@@ -609,16 +611,17 @@ def peak_table(kind: Optional[str] = None) -> dict:
     low = (kind or "").lower()
     best = None
     for key, peaks in table.items():
-        if key == "default" or not isinstance(peaks, dict):
+        if not isinstance(peaks, dict):
             continue
         if key.lower() in low and (best is None or len(key) > len(best)):
             best = key
-    entry = table.get(best) if best else table.get("default", {})
-    entry = entry if isinstance(entry, dict) else {}
+    if best is None:
+        return None
+    entry = table[best]
     return {
         "peak_gbps": float(entry.get("peak_gbps") or 0.0),
         "peak_tflops": float(entry.get("peak_tflops") or 0.0),
-        "source": source if best else source + ":default",
+        "source": source,
         "device_kind": kind,
     }
 
@@ -687,6 +690,8 @@ def roofline_report(kernels: Optional[dict] = None,
         kernels = _ledger.snapshot().get("kernels", {})
     if peaks is None:
         peaks = peak_table()
+    if peaks is None:
+        return {}  # unknown device: no roofline
     out = {}
     for fp, k in kernels.items():
         flops = float(k.get("flops") or 0.0)
@@ -751,11 +756,14 @@ def attribution_report() -> dict:
         "flushes": flushes,
         "stage_seconds": _ordered(stage_totals),
         "unattributed_s": un,
-        "device_kind": peaks["device_kind"],
-        "peaks": {"peak_gbps": peaks["peak_gbps"],
-                  "peak_tflops": peaks["peak_tflops"],
-                  "source": peaks["source"]},
-        "rooflines": roofline_report(peaks=peaks),
+        "device_kind": device_kind(),
+        # None on a device the peak table does not know (e.g. the CPU
+        # backend): stages are still attributed, no roofline is drawn
+        "peaks": None if peaks is None else {
+            "peak_gbps": peaks["peak_gbps"],
+            "peak_tflops": peaks["peak_tflops"],
+            "source": peaks["source"]},
+        "rooflines": {} if peaks is None else roofline_report(peaks=peaks),
         "sentinel": sentinel_report(),
     }
     attributed = sum(stage_totals.values())

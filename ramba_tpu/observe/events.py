@@ -187,25 +187,17 @@ def _probe_rank():
     report single-process semantics WITHOUT initializing anything —
     calling ``jax.process_count()`` here would force single-process
     backend bring-up and poison a later ``distributed.initialize``."""
+    import jax
+    # private import, checked against the installed jax 0.9.0 (no public
+    # spelling exists); a move must fail loudly, not guess a rank
+    from jax._src import xla_bridge as _xb
+
+    if not (jax.distributed.is_initialized()
+            or _xb.backends_are_initialized()):
+        return 0, 1, False
     try:
-        import jax
-
-        try:
-            from jax._src import distributed as _jdist
-
-            if getattr(_jdist.global_state, "client", None) is not None:
-                return jax.process_index(), jax.process_count(), True
-        except Exception:
-            pass
-        try:
-            from jax._src import xla_bridge as _xb
-
-            if not _xb.backends_are_initialized():
-                return 0, 1, False
-        except Exception:
-            pass
         return jax.process_index(), jax.process_count(), True
-    except Exception:  # backend unavailable: single-process semantics
+    except RuntimeError:  # backend unavailable: single-process semantics
         return 0, 1, False
 
 

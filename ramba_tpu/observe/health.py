@@ -1,12 +1,10 @@
-"""TPU-health event source: platform selection, init probes, fallbacks.
+"""Backend-health event source: which platform a run actually executed on.
 
-Two rounds of benchmarking were lost to an opaque ``tpu_init_error`` string
-(BENCH_r05.json): the chip wedged, the run fell back to CPU, and nothing
-recorded when/why.  This module turns bring-up into first-class events in
-the same stream as flush spans:
+A run whose backend came up wrong must say so in the same stream as its
+flush spans, not in an opaque error string:
 
-* ``record()`` — explicit health record (bench.py calls it with its
-  subprocess-probe outcome and timings),
+* ``record()`` — explicit health record (``distributed.initialize`` files
+  its bring-up outcome and timings here),
 * ``record_mesh()`` — automatic record on the FIRST default-mesh creation
   (parallel/mesh.py), so every traced run carries at least one health line
   stating which platform actually executed.
@@ -27,11 +25,10 @@ def record(
     init_seconds: Optional[float] = None,
     outcome: str = "ok",
     error: Optional[str] = None,
-    selected_via: Optional[str] = None,
     **extra,
 ) -> dict:
-    """Emit one health event.  ``outcome``: "ok" | "fallback" | "error".
-    Returns the emitted event dict (bench.py folds it into its JSON line).
+    """Emit one health event.  ``outcome``: "ok" | "recovered" | "error".
+    Returns the emitted event dict.
     """
     ev = {"type": "health", "outcome": outcome}
     if platform is not None:
@@ -42,8 +39,6 @@ def record(
         ev["init_seconds"] = round(float(init_seconds), 4)
     if error:
         ev["error"] = str(error)[-800:]
-    if selected_via is not None:
-        ev["selected_via"] = selected_via
     ev.update(extra)
     registry.inc(f"health.{outcome}")
     return events.emit(ev)

@@ -19,6 +19,7 @@ XLA's profiler owns exact per-execution collective traffic.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import defaultdict
@@ -65,6 +66,40 @@ def inc(name: str, n: int = 1) -> None:
     """Increment a named counter (hot-path safe: one dict add)."""
     with lock:
         counters[name] += n
+
+
+# Which lowering a hand-written kernel took is decided while jax traces
+# the program (from shapes, dtypes, mesh and backend), so it is recorded
+# then: always as a counter, and on the flush span when the trace runs
+# inside a compiled call (fuser._execute_compiled collects the notes).
+_kernel_notes = threading.local()
+
+
+def note_kernel(kernel: str, path: str, interpret: bool = False) -> None:
+    """Record that ``kernel`` (e.g. ``"stencil"``) lowered through
+    ``path`` (``sharded`` / ``pallas_fast`` / ``pallas_padded`` / ``xla``
+    / a Pallas family name), and whether a Pallas kernel on that path
+    interprets instead of compiling for the chip."""
+    inc(f"{kernel}.path.{path}")
+    if interpret:
+        inc(f"{kernel}.interpret")
+    notes = getattr(_kernel_notes, "active", None)
+    if notes is not None:
+        notes.append({"kernel": kernel, "path": path,
+                      "interpret": bool(interpret)})
+
+
+@contextlib.contextmanager
+def collect_kernel_notes():
+    """Collect this thread's :func:`note_kernel` records made inside the
+    block (jax traces in the calling thread); yields the list."""
+    prev = getattr(_kernel_notes, "active", None)
+    notes: list = []
+    _kernel_notes.active = notes
+    try:
+        yield notes
+    finally:
+        _kernel_notes.active = prev
 
 
 def gauge(name: str, value) -> None:

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ramba_tpu.core.expr import MAPFN, OPS, _np_loop_dtypes
+from ramba_tpu.observe import registry as _registry
 from ramba_tpu.resilience import faults as _faults
 
 BACKEND_XLA = "xla"
@@ -102,7 +103,11 @@ def family_names() -> list:
 
 def interpret_mode() -> bool:
     """Pallas kernels interpret (and therefore run anywhere, including the
-    CPU tier-1 suite) whenever no TPU backend is present."""
+    CPU tier-1 suite) whenever jax's default backend is not ``"tpu"`` —
+    which is what a v5e reports (``device_kind`` "TPU v5 lite").  Every
+    lowering that consults this records its choice
+    (``registry.note_kernel``), so a run on the chip can assert that
+    nothing interpreted."""
     return jax.default_backend() != "tpu"
 
 
@@ -444,13 +449,15 @@ def _build_elemred(program) -> Callable:
                 (1, 1), reduce_meta[s][1]))
             out_specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
 
+        interpret = interpret_mode()
+        _registry.note_kernel("elemred", "pallas", interpret)
         results = pl.pallas_call(
             kernel,
             grid=(grid,),
             out_shape=out_shapes,
             in_specs=in_specs,
             out_specs=out_specs,
-            interpret=interpret_mode(),
+            interpret=interpret,
         )(*kernel_args)
         if not isinstance(results, (list, tuple)):
             results = (results,)
@@ -529,6 +536,8 @@ def _build_segred(program) -> Callable:
             def _accum():
                 out_ref[...] = comb_fn(out_ref[...], block)
 
+        interpret = interpret_mode()
+        _registry.note_kernel("segred", "pallas", interpret)
         partials = pl.pallas_call(
             kernel,
             grid=(grid,),
@@ -538,7 +547,7 @@ def _build_segred(program) -> Callable:
                 pl.BlockSpec((bh, LANES), lambda i: (i, 0)),
             ],
             out_specs=pl.BlockSpec((G, LANES), lambda i: (0, 0)),
-            interpret=interpret_mode(),
+            interpret=interpret,
         )(jnp.reshape(data, (rows, LANES)),
           jnp.reshape(labels, (rows, LANES)))
 

@@ -32,6 +32,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ramba_tpu.observe import registry as _registry
+from ramba_tpu.ops import pallas_backend as _pallas_backend
+
 _INTERPRET = os.environ.get("RAMBA_TPU_PALLAS_INTERPRET", "0") not in ("0", "")
 _ENABLED = os.environ.get("RAMBA_TPU_PALLAS", "1") not in ("0", "")
 
@@ -46,7 +49,7 @@ def available_local(arrs) -> bool:
     and the pallas_call sees purely local data."""
     if not _ENABLED:
         return False
-    if not (_INTERPRET or jax.default_backend() == "tpu"):
+    if _pallas_backend.interpret_mode() and not _INTERPRET:
         return False
     shapes = {a.shape for a in arrs}
     if len(shapes) != 1:
@@ -103,9 +106,11 @@ def run(func, lo, hi, slots, arrs, taps=8):
     kernel automatically falls back to ``interpret=True`` (rather than
     raising from an impossible Mosaic compile), so the CPU suite — and
     the autotune parity tests — exercise the same code path."""
-    interpret = _INTERPRET or jax.default_backend() != "tpu"
+    interpret = _INTERPRET or _pallas_backend.interpret_mode()
     if _fast_eligible(lo, hi, arrs):
+        _registry.note_kernel("stencil", "pallas_fast", interpret)
         return _run_fast(func, lo, hi, slots, arrs, taps, interpret)
+    _registry.note_kernel("stencil", "pallas_padded", interpret)
     return _run_padded(func, lo, hi, slots, arrs, taps, interpret)
 
 
@@ -180,9 +185,9 @@ def _run_fast(func, lo, hi, slots, arrs, taps, interpret=_INTERPRET):
                         )
                     # bh ≡ 0 (mod 8) and _RM == 8, so j*bh - _RM is 8-aligned;
                     # phrase it as (…)*8 + pl.multiple_of so Mosaic's prover
-                    # accepts the sublane-tiled HBM slice (BENCH_r02 failure:
-                    # "tile index in dimension 0 … divisible by the tiling
-                    # (8)" at bh=40 on the 8192x8192 bench shape).
+                    # accepts the sublane-tiled HBM slice (an earlier
+                    # Mosaic said: "tile index in dimension 0 … divisible by
+                    # the tiling (8)" at bh=40 on the 8192x8192 shape).
                     rs_mid = pl.multiple_of((j * (bh // 8) - 1) * 8, 8)
                     return pltpu.make_async_copy(
                         ins[k].at[pl.ds(rs_mid, slab_h)],
@@ -320,7 +325,7 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET):
         i = pl.program_id(0)
         for k in range(n_slabs):
             # bh is a static multiple of 8: expose that to Mosaic's
-            # divisibility prover (same class of failure as BENCH_r02)
+            # divisibility prover (same class of refusal as in _run_fast)
             rs = pl.multiple_of(i * (bh // 8) * 8, 8)
             cp = pltpu.make_async_copy(
                 ins[k].at[pl.ds(rs, slab_h), :], slabs[k], sem
@@ -385,6 +390,4 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET):
 # Registered kernel family: skeletons._eval_stencil (and anything else)
 # reaches this kernel through the backend registry rather than importing
 # this module's entry points ad hoc.
-from ramba_tpu.ops import pallas_backend as _pallas_backend  # noqa: E402
-
 _pallas_backend.register_family("stencil", available=available, run=run)

@@ -37,7 +37,6 @@ from jax.sharding import PartitionSpec as P
 from ramba_tpu import common
 from ramba_tpu.observe import registry as _registry
 from ramba_tpu.parallel import mesh as _mesh
-from ramba_tpu.utils import compat as _compat
 
 # Interior/halo overlap in the sharded path (off: single full-block eval)
 _OVERLAP = __import__("os").environ.get(
@@ -125,6 +124,7 @@ def run(func, lo, hi, slots, arrs, taps):
     """Evaluate the stencil over the mesh with explicit halo exchange
     (any rank).  Returns the full-shape result with border cells zeroed."""
     mesh = _mesh.get_mesh()
+    _registry.note_kernel("stencil", "sharded")
     x = arrs[0]
     shape = x.shape
     nd = len(shape)
@@ -172,6 +172,7 @@ def run(func, lo, hi, slots, arrs, taps):
             # The reference gets the analogous overlap from Numba prange
             # workers computing while ZMQ receives land (ramba.py:
             # 3549-3780); here the latency-hiding scheduler does it.
+            _registry.note_kernel("stencil", "xla")
             val = _overlapped_val(func, lo, hi, slots, blocks, exts,
                                   local_shape)
         else:
@@ -189,7 +190,7 @@ def run(func, lo, hi, slots, arrs, taps):
     spec = P(*(
         (e[0] if len(e) == 1 else tuple(e)) if e else None for e in ents
     ))
-    out = _compat.shard_map(
+    out = jax.shard_map(
         local, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
     )(*arrs)
     if padded_shape != shape:
@@ -256,6 +257,9 @@ def _local_stencil(func, lo, hi, slots, exts, taps, interior):
         try:
             full = stencil_pallas.run(func, lo, hi, slots, exts, taps)
             return jax.lax.slice(full, (top, left), (top + lh, left + lw))
-        except Exception:  # trace-time kernel failure: XLA local path
-            pass
+        except Exception as e:  # trace-time kernel failure: XLA local path
+            from ramba_tpu.skeletons import _stencil_degrade
+
+            _stencil_degrade("sharded pallas", "sharded xla", e)
+    _registry.note_kernel("stencil", "xla")
     return stencil_interior(func, lo, hi, slots, exts)

@@ -70,21 +70,20 @@ def initialize(
     )
     if not has_env:
         return  # single-host: nothing to do
-    try:
-        from jax._src import xla_bridge
+    # private import, checked against the installed jax 0.9.0 (no public
+    # spelling exists); if it moves, this must fail loudly, not skip the check
+    from jax._src import xla_bridge
 
-        if xla_bridge.backends_are_initialized():
-            # Backend already up (e.g. the process computed before calling
-            # initialize); too late to form a process group — stay
-            # single-controller.  The reference has the same
-            # initialize-at-import-or-never shape (common.py:683-758).
-            from ramba_tpu.common import dprint
+    if xla_bridge.backends_are_initialized():
+        # Backend already up (e.g. the process computed before calling
+        # initialize); too late to form a process group — stay
+        # single-controller.  The reference has the same
+        # initialize-at-import-or-never shape (common.py:683-758).
+        from ramba_tpu.common import dprint
 
-            dprint(1, "ramba_tpu.distributed.initialize: backend already "
-                      "initialized; staying single-process")
-            return
-    except ImportError:
-        pass
+        dprint(1, "ramba_tpu.distributed.initialize: backend already "
+                  "initialized; staying single-process")
+        return
     import time
 
     from ramba_tpu.observe import health as _health
@@ -94,20 +93,9 @@ def initialize(
     t0 = time.perf_counter()
     kw = _init_kwargs(kwargs)
 
-    # CPU multi-controller needs a cross-process collectives backend: with
-    # jax's default ("none") the group forms and compiles, then every
-    # cross-process computation fails at dispatch ("Multiprocess
-    # computations aren't implemented on the CPU backend").  Selecting
-    # gloo here — before the backend exists — makes bring-up on CPU
-    # clusters (and the 2-process CI legs) actually executable; TPU
-    # backends ignore it.
-    try:
-        from jax._src import xla_bridge as _xb
-
-        if _xb.CPU_COLLECTIVES_IMPLEMENTATION.value == "none":
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (ImportError, AttributeError):
-        pass  # older/newer jax without this option
+    # (CPU multi-controller needs a cross-process collectives backend;
+    # the installed jax defaults jax_cpu_collectives_implementation to
+    # "gloo", so there is nothing to select here.)
 
     def connect():
         _faults.check("init_connect")

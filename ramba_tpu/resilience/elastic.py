@@ -747,7 +747,7 @@ def resume(path, *, step: Optional[int] = None, mesh=None) -> Resumed:
     state_path = mgr.state_path(step)
     try:
         with ocp.StandardCheckpointer() as ckptr:
-            meta = ckptr.metadata(state_path)
+            meta = ckptr.metadata(state_path).item_metadata.tree
     except Exception as e:
         raise CheckpointCorruptError(
             f"checkpoint step {step} at {state_path!r} has unreadable "

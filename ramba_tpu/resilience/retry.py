@@ -5,8 +5,8 @@ fileio reads/writes, ``distributed.initialize``) funnels through
 :func:`call`, which:
 
 1. classifies each failure as ``retryable`` / ``degrade`` / ``oom`` /
-   ``fatal`` (:func:`classify`) — programming errors propagate unchanged
-   so existing error-path behavior is untouched; device-memory
+   ``fatal`` (:func:`classify`) — programming errors, and kernels the
+   chip's compiler refuses, propagate unchanged; device-memory
    exhaustion (``oom``) is pointless to retry identically and is handed
    to the degradation ladder, which evicts spill candidates
    (``memory.evict_for_oom``) before dropping a rung;
@@ -59,6 +59,33 @@ _OOM_MARKERS = (
     "RESOURCE_EXHAUSTED", "out of memory", "Out of memory", "OutOfMemory",
     "Resource exhausted",
 )
+# What the chip's compiler says when it refuses a hand-written kernel.
+# Both arrive under status codes the marker lists above would otherwise
+# claim: a Mosaic refusal is "INTERNAL: Mosaic failed to compile TPU
+# kernel: ...", and a kernel whose windows, scratch or stack overflow
+# VMEM is "RESOURCE_EXHAUSTED: Allocation (size=...) would exceed memory
+# (size=...) :: ... space=vmem ..." or "... Scoped allocation with size
+# 20.91M and limit 16.00M exceeded scoped vmem limit by 4.91M" (libtpu
+# 0.0.34 on a v5e, both seen in PR 21's chip runs).  They are
+# deterministic: backing off, evicting HBM arrays, or re-tracing the
+# same kernel on a lower rung cannot change the verdict, so they are
+# fatal and surface at once from the rung that compiled the kernel.
+_COMPILE_REFUSAL_MARKERS = (
+    "Mosaic failed to compile",
+    "Pallas encountered an internal verification error",
+    "exceeded scoped vmem limit",
+)
+_COMPILE_REFUSAL_TYPES = ("MosaicError", "VerificationError")
+
+
+def _is_compile_refusal(exc: BaseException, msg: str) -> bool:
+    if any(c.__name__ in _COMPILE_REFUSAL_TYPES for c in type(exc).__mro__):
+        return True
+    if any(marker in msg for marker in _COMPILE_REFUSAL_MARKERS):
+        return True
+    return "RESOURCE_EXHAUSTED" in msg and "vmem" in msg.lower()
+
+
 # I/O errors where a retry cannot possibly change the outcome.
 _FATAL_OS_ERRORS = (
     FileNotFoundError, IsADirectoryError, NotADirectoryError,
@@ -114,6 +141,8 @@ def classify(exc: BaseException) -> str:
     if isinstance(exc, (OSError, TimeoutError, ConnectionError)):
         return "retryable"
     msg = str(exc)
+    if _is_compile_refusal(exc, msg):
+        return "fatal"
     for marker in _OOM_MARKERS:
         if marker in msg:
             return "oom"

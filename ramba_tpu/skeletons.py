@@ -42,11 +42,11 @@ from ramba_tpu import common
 from ramba_tpu.core.expr import Const, Node, defop
 from ramba_tpu.core.fuser import sync as _sync
 from ramba_tpu.core.ndarray import ndarray
+from ramba_tpu.observe import events as _events
 from ramba_tpu.observe import registry as _registry
 from ramba_tpu.ops.creation import asarray
 from ramba_tpu.parallel import mesh as _mesh
 from ramba_tpu.resilience import memory as _gov_memory
-from ramba_tpu.utils import compat as _compat
 
 # ---------------------------------------------------------------------------
 # smap / smap_index
@@ -613,7 +613,7 @@ def _op_sreduce(static, mapped):
                          lambda a, b: _call_kernel(local_fn, a, b))
         return r[None]
 
-    partials = _compat.shard_map(
+    partials = jax.shard_map(
         local, mesh=mesh, in_specs=P(axes), out_specs=P(axes),
         check_vma=False,
     )(flat)
@@ -843,21 +843,34 @@ def call_stencil_body(func, build_args):
     return _combine_branches(leaves)
 
 
-def _eval_stencil(static, *arrs):
+def _stencil_degrade(frm: str, to: str, e: Exception) -> None:
+    """A stencil path raised while tracing and the next one takes over:
+    say so every time, on the degradation timeline and as a counter, so
+    no caller can time or check one path under another's name."""
     global _pallas_fallback_warned
+    _registry.inc("stencil.degraded")
+    _events.emit({
+        "type": "degrade", "site": "stencil", "action": "path",
+        "from": frm, "to": to,
+        "error": f"{type(e).__name__}: {e}"[:300],
+    })
+    if not _pallas_fallback_warned:
+        _pallas_fallback_warned = True
+        warnings.warn(
+            f"{frm} stencil path unavailable, using {to}: "
+            f"{type(e).__name__}: {e}"
+        )
+
+
+def _eval_stencil(static, *arrs):
     func, lo, hi, slots, taps = static
     from ramba_tpu.ops import stencil_sharded
 
     if stencil_sharded.eligible(lo, hi, arrs):
         try:
             return stencil_sharded.run(func, lo, hi, slots, arrs, taps)
-        except Exception as e:  # same fence as the pallas path below
-            if not _pallas_fallback_warned:
-                _pallas_fallback_warned = True
-                warnings.warn(
-                    f"sharded stencil path unavailable, using GSPMD "
-                    f"shifted-slice path: {type(e).__name__}: {e}"
-                )
+        except Exception as e:  # trace-time failure: next path, loudly
+            _stencil_degrade("sharded", "pallas/xla", e)
     if len(arrs[0].shape) == 2:
         from ramba_tpu.ops import pallas_backend
 
@@ -865,13 +878,9 @@ def _eval_stencil(static, *arrs):
         if fam is not None and fam.available(arrs):
             try:
                 return fam.run(func, lo, hi, slots, arrs, taps)
-            except Exception as e:  # fall back to the XLA path, but say so
-                if not _pallas_fallback_warned:
-                    _pallas_fallback_warned = True
-                    warnings.warn(
-                        f"pallas stencil kernel unavailable, using XLA "
-                        f"shifted-slice path: {type(e).__name__}: {e}"
-                    )
+            except Exception as e:  # trace-time failure: XLA path, loudly
+                _stencil_degrade("pallas", "xla", e)
+    _registry.note_kernel("stencil", "xla")
     shape = arrs[0].shape
     interior = tuple(
         s - (h - l) for s, l, h in zip(shape, lo, hi)
@@ -1079,7 +1088,7 @@ def _op_scumulative(static, x):
         return jnp.where(idx == 0, ys, fixed)
 
     spec = P(axes, *([None] * len(rest)))
-    out = _compat.shard_map(
+    out = jax.shard_map(
         per_shard, mesh=mesh, in_specs=spec, out_specs=spec,
         check_vma=False,
     )(xp)
@@ -1519,7 +1528,7 @@ def spmd(func, *args):
             outs.append(o)
         return tuple(outs)
 
-    outs = _compat.shard_map(
+    outs = jax.shard_map(
         inner, mesh=mesh, in_specs=tuple(specs), out_specs=tuple(specs),
         check_vma=False,
     )(*vals)

@@ -105,14 +105,12 @@ def drain_effect_errors() -> Exception | None:
     the token state.  jax's own ``block_until_ready`` skips its ``clear()``
     when a token raises, hence the explicit clear here.
     """
-    try:
-        # private API — can vanish or change shape on a jax upgrade;
-        # this is a best-effort debug helper, so degrade to a no-op
-        from jax._src import dispatch as _dispatch
+    # private API, checked against the installed jax 0.9.0 (no public
+    # spelling exists); a move must fail loudly, not turn the drain into a
+    # no-op that leaves the poisoned token for the next computation
+    from jax._src import dispatch as _dispatch
 
-        tokens = _dispatch.runtime_tokens
-    except (ImportError, AttributeError):
-        return None
+    tokens = _dispatch.runtime_tokens
     err: Exception | None = None
     try:
         tokens.block_until_ready()

@@ -6,7 +6,7 @@ versioned snapshot of its full diagnostics state every
 ``RAMBA_FLEET_INTERVAL_S`` seconds (ramba_tpu/observe/fleet.py).  This
 CLI is the reader side — run it anywhere the spool directory is visible
 (NFS mount, rsync target, the host itself); it never initializes an
-accelerator backend (JAX_PLATFORMS defaults to cpu below).
+accelerator backend (it holds itself to JAX_PLATFORMS=cpu below).
 
 Usage:
     python scripts/fleet_collector.py /srv/ramba-fleet
@@ -37,8 +37,9 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# reader-side process: never let the collector grab an accelerator
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# reader-side process: a chip belongs to one process at a time, and this
+# one must never be it — set, not defaulted
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 from ramba_tpu.observe import fleet  # noqa: E402
 

@@ -42,7 +42,14 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU-only harness, and it says so: replicas are separate processes and a
+# chip belongs to one process at a time, so this script (main) and every
+# replica it spawns (spawn_replica) are held to the CPU backend — set, not
+# defaulted, and not at import: bench.py and the tests import this module
+# from processes whose platform is not this module's to change.  Replicas
+# on chips, one chip each on a four-chip host, are ROADMAP D7's decision
+# cell, not something this launcher does.
+_PLATFORM = "cpu"
 
 
 def run_replica(args) -> int:
@@ -73,6 +80,7 @@ def spawn_replica(env_extra=None, timeout_s: float = 60.0):
     the READY marker (used by --demo, the suite leg, and tests)."""
     env = dict(os.environ)
     env.update(env_extra or {})
+    env["JAX_PLATFORMS"] = _PLATFORM
     proc = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--replica"],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
@@ -155,6 +163,7 @@ def main(argv=None) -> int:
     ap.add_argument("--demo", type=int, metavar="N", default=0,
                     help="spawn N replicas, route a demo workload, stop")
     args = ap.parse_args(argv)
+    os.environ["JAX_PLATFORMS"] = _PLATFORM
 
     if args.replica:
         return run_replica(args)

@@ -69,7 +69,6 @@ _METRIC_DIRECTION = {
     "reshard_peak_live_bytes": "lower",  # ledger peak during the reshard
     "live_reshape_ms": "lower",         # live mesh-reshape rung
     "checkpoint_reshape_ms": "lower",   # drain->checkpoint->resume fallback
-    "cold_start_ms": "lower",           # warm-process first-result wall
     "compile_hit_rate": "higher",       # bucketed shape-soak cache hits
     "bucket_pad_waste_frac": "lower",   # zero-padding overhead of pow2
     "attrib_unattributed_frac": "lower",  # waterfall residual share

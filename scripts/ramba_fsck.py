@@ -43,7 +43,9 @@ import os
 import sys
 from typing import List, Optional
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# offline checker: a chip belongs to one process at a time, and this one
+# must never be it — set, not defaulted
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:

@@ -79,22 +79,25 @@ def _resolve_peaks(args_peaks, extras: dict) -> dict:
         kind = extras.get("device_kind")
         low = (kind or "").lower()
         for key, entry in obj.items():
-            if key != "default" and key.lower() in low:
+            if key.lower() in low:
                 return {"peak_gbps": float(entry.get("peak_gbps") or 0.0),
                         "peak_tflops": float(entry.get("peak_tflops") or 0.0),
                         "source": f"--peaks:{key}",
                         "device_kind": kind}
-        entry = obj.get("default", {})
-        return {"peak_gbps": float(entry.get("peak_gbps") or 0.0),
-                "peak_tflops": float(entry.get("peak_tflops") or 0.0),
-                "source": "--peaks:default", "device_kind": kind}
+        raise ValueError(
+            f"--peaks has no entry matching device_kind {kind!r}")
     rec = extras.get("peaks")
     if isinstance(rec, dict) and (rec.get("peak_gbps")
                                   or rec.get("peak_tflops")):
         return {"peak_gbps": float(rec.get("peak_gbps") or 0.0),
                 "peak_tflops": float(rec.get("peak_tflops") or 0.0),
                 "source": "capture", "device_kind": extras.get("device_kind")}
-    return attrib.peak_table(extras.get("device_kind"))
+    peaks = attrib.peak_table(extras.get("device_kind"))
+    if peaks is None:
+        raise ValueError(
+            f"no peaks known for device_kind "
+            f"{extras.get('device_kind')!r}: pass --peaks")
+    return peaks
 
 
 def _device_seconds(entry: dict) -> tuple:

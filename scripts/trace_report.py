@@ -160,7 +160,7 @@ def report(path: str, events: list, top: int = 10, file=None) -> None:
     for h in health:
         bits = [f"{k}={h[k]}" for k in
                 ("platform", "device_count", "outcome", "init_seconds",
-                 "selected_via", "source") if k in h]
+                 "source") if k in h]
         print("health: " + " ".join(bits), file=file)
         if h.get("error"):
             print(f"  error: {h['error']}", file=file)

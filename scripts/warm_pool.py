@@ -12,9 +12,11 @@ compile class since PR 14) are ranked by arrival count, re-weighted by
 the live ledger when one exists, resolved against the persist cache's
 program skeletons, and submitted through ``CompilePipeline.submit_warm``
 — so warm compiles take round-robin turns with live traffic and are the
-first load shed under brownout (``serve.warm_shed``).  Exit status is 0
-even when individual warm-ups fail: a failed pre-compile is a lost
-opportunity, not an error.
+first load shed under brownout (``serve.warm_shed``).  The replay
+compiles in THIS process (no children), so it runs on whatever backend
+jax finds and holds it while it runs.  Exit status is 0 even when
+individual warm-ups fail: a failed pre-compile is a lost opportunity,
+not an error.
 """
 
 import argparse
@@ -41,11 +43,9 @@ def main(argv=None) -> int:
                     help="print the report as one JSON line")
     args = ap.parse_args(argv)
 
-    from ramba_tpu import common
     from ramba_tpu.compile import persist as _persist
     from ramba_tpu.compile import warmpool as _warmpool
 
-    common.setup_persistent_cache()
     _persist.reconfigure()
     if not _persist.armed():
         print("warm_pool: persist cache not armed (set RAMBA_CACHE); "

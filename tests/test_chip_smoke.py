@@ -1,0 +1,184 @@
+"""chip_smoke.py on the CPU mesh: each phase at toy size with the Pallas
+stencil kernel interpreting, the refusal to run without a TPU, a forced
+kernel fall-through that must FAIL its phase, and the classification of
+what the chip's compiler says when it refuses a kernel."""
+
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+import ramba_tpu as rt
+from ramba_tpu import skeletons
+from ramba_tpu.ops import pallas_backend, stencil_pallas
+from ramba_tpu.resilience import retry
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+pytestmark = pytest.mark.skipif(
+    jax.process_count() > 1,
+    reason="the smoke drives one controller, like a user's script")
+
+
+@pytest.fixture
+def interpreting(monkeypatch):
+    """The CPU mesh runs the Pallas stencil kernel in interpret mode, so
+    the phases take the kernel paths they take on the chip."""
+    monkeypatch.setattr(stencil_pallas, "_INTERPRET", True)
+    rt.random.seed(chip_smoke.SEED)
+
+
+def _paths(n):
+    return chip_smoke.expected_stencil_paths(n, len(jax.devices()))
+
+
+def test_semantics_phase(interpreting):
+    facts = chip_smoke.phase_semantics(rt, 1 << 14, interpret_ok=True)
+    assert facts["rungs"] == ["fused"]
+
+
+def test_distributed_phase(interpreting):
+    facts = chip_smoke.phase_distributed(rt, interpret_ok=True)
+    assert facts["rungs"] == ["fused"]
+    assert "xla" not in facts["stencil_paths"], facts
+
+
+def test_chain_phase(interpreting):
+    facts = chip_smoke.phase_chain(rt, 1 << 18, interpret_ok=True)
+    assert facts["chain_flushes"] == 1
+    assert abs(facts["sum"] - (1 << 18)) < 1e-3 * (1 << 18)
+
+
+@pytest.mark.parametrize("n", [256, 200])
+def test_stencil_phase(interpreting, n):
+    # 256 is lane-aligned (the fast kernel on one device), 200 is not
+    facts = chip_smoke.phase_stencil(rt, n, _paths(n), interpret_ok=True)
+    assert facts["path"] == "+".join(_paths(n))
+
+
+def test_stencil_sweeps_phase(interpreting):
+    facts = chip_smoke.phase_stencil_sweeps(rt, 128, 3, 4, _paths(128),
+                                            interpret_ok=True)
+    assert facts["rungs"] == ["fused"]
+
+
+def test_axpy_phase(interpreting):
+    assert chip_smoke.phase_axpy(rt, 1 << 18, interpret_ok=True)[
+        "rungs"] == ["fused"]
+
+
+def test_broadcast_phase(interpreting):
+    assert chip_smoke.phase_broadcast(rt, 512, interpret_ok=True)[
+        "rungs"] == ["fused"]
+
+
+def test_interpreted_kernel_fails_a_chip_phase(interpreting):
+    """On the chip nothing may interpret: without ``interpret_ok`` the
+    phase fails on the kernel's own record of having interpreted."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="interpret"):
+        chip_smoke.phase_stencil(rt, 136, _paths(136))
+
+
+def test_kernel_fall_through_fails_the_phase(interpreting, monkeypatch):
+    """The Pallas family's run raises: the stencil falls through to XLA
+    shifted slices and computes the right answer, and the phase FAILS on
+    the degrade event instead of passing on that answer."""
+    fam = pallas_backend.family("stencil")
+
+    def refuse(*a, **k):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(fam, "run", refuse)
+    monkeypatch.setattr(stencil_pallas, "run", refuse)
+    # the warning is once per process; the degrade event is every time
+    monkeypatch.setattr(skeletons, "_pallas_fallback_warned", False)
+    with pytest.warns(UserWarning, match="stencil path unavailable"):
+        with pytest.raises(chip_smoke.SmokeFailure) as ei:
+            chip_smoke.phase_stencil(rt, 264, _paths(264),
+                                     interpret_ok=True)
+    ev = rt.diagnostics.resilience_events(5)
+    assert any(e["type"] == "degrade" and e.get("site") == "stencil"
+               for e in ev), ev
+    assert "xla" in str(ei.value) or "degrade" in str(ei.value)
+
+
+def test_main_refuses_without_a_tpu(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       capture_output=True, text=True, timeout=120,
+                       cwd=tmp_path, env=env)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert "ok" not in r.stdout and "{" not in r.stdout, r.stdout
+
+
+def test_result_line_has_exactly_the_contract_keys():
+    import json
+
+    dev = jax.devices()[0]
+    for ok in (True, False):
+        got = json.loads(chip_smoke.result_line(ok, dev, len(jax.devices())))
+        assert got == {"ok": ok, "device": {
+            "platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}}
+
+
+class _XlaError(RuntimeError):
+    pass
+
+
+@pytest.mark.parametrize("msg", [
+    # the stencil kernel's own refusal under an earlier Mosaic
+    "INTERNAL: Mosaic failed to compile TPU kernel: Failed to prove that a "
+    "tile index in dimension 0 is divisible by the tiling (8).",
+    # libtpu 0.0.34 on a v5e, a 256 MiB input window (chip run, PR 21)
+    "RESOURCE_EXHAUSTED: Allocation (size=268435456) would exceed memory "
+    "(size=134217728) :: #allocation2 [shape = 'u8[268435456]{0}', "
+    "space=vmem, size = 0x10000000, tag = 'input window allocation for "
+    "operator input 0.'] :: tpu_custom_call.1",
+    # same chip, the fast stencil kernel at RAMBA_TPU_STENCIL_BH=64, 8192^2
+    "Scoped allocation with size 20.91M and limit 16.00M exceeded scoped "
+    "vmem limit by 4.91M. It should not be possible to run out of scoped "
+    "vmem",
+])
+def test_compile_refusals_are_fatal(msg):
+    assert retry.classify(_XlaError(msg)) == "fatal"
+
+
+def test_runtime_failures_keep_their_classes():
+    # an HBM allocation failure at run time (chip run, PR 21) is still oom
+    assert retry.classify(_XlaError(
+        "RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+        "allocate 7.45G. That was not possible. There are 867.18M free.; "
+        "(0x0x0_HBM0)")) == "oom"
+    assert retry.classify(_XlaError(
+        "INTERNAL: Failed to execute: stream error")) == "retryable"
+
+
+def test_compile_refusal_surfaces_from_the_fused_rung(monkeypatch):
+    """A refusal raised while the fused rung compiles is not retried, not
+    handed down the ladder and never reaches the host rung."""
+    from ramba_tpu.core import fuser
+
+    calls = []
+
+    def refuse(fn, program, leaf_vals, *a, **k):
+        calls.append(k.get("rung"))
+        raise _XlaError("INTERNAL: Mosaic failed to compile TPU kernel: no")
+
+    monkeypatch.setattr(fuser, "_execute_compiled", refuse)
+    a = rt.arange(4099) * 3.0
+    with pytest.raises(_XlaError, match="Mosaic failed"):
+        rt.sync()
+    assert calls == ["fused"], calls
+    ev = rt.diagnostics.resilience_events(5)
+    assert not any(e.get("action") in ("retry", "rung") and
+                   "Mosaic" in str(e.get("error")) for e in ev), ev
+    monkeypatch.undo()
+    del a
+    rt.sync()

@@ -348,45 +348,70 @@ class TestPersistCache:
         assert snap["candidates"] >= 1
 
 
+class TestAotStoresOnlyFreshCompiles:
+    def test_jax_cache_hit_is_not_serialized(self, tmp_path, monkeypatch):
+        """An executable jax loaded from its own persistent cache does not
+        serialize to a blob another process can load, so the AOT lane
+        skips it — observed through jax.monitoring, without switching
+        jax's cache off or moving it."""
+        import jax
+
+        monkeypatch.setenv("RAMBA_CACHE", str(tmp_path / "cache"))
+        persist.reconfigure()
+        with fuser._cache_lock:
+            fuser._compile_cache.clear()
+        base = np.arange(56, dtype=np.float32).reshape(7, 8)
+        np.asarray(rt.array(base) * 11.0 - 2.0)
+        real_compile = jax.stages.Lowered.compile
+
+        def compile_as_cache_hit(self, *a, **k):
+            jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+            return real_compile(self, *a, **k)
+
+        monkeypatch.setattr(jax.stages.Lowered, "compile",
+                            compile_as_cache_hit)
+        before = jax.config.jax_enable_compilation_cache
+        rep = persist.save_topk(4)
+        assert rep.get("stored", 0) == 0 and rep["skipped"] >= 1, rep
+        assert registry.get("compile.persist_store_skipped_jax_cache") >= 1
+        assert jax.config.jax_enable_compilation_cache == before
+
+
 class TestPersistInit:
     def test_cache_status_fields_and_event(self, tmp_path, monkeypatch):
+        """setup_compile_cache reports where jax's cache lives and says so
+        on the event stream; with the variable set it leaves the placing
+        to jax (which reads the variable itself at start-up)."""
         import jax
 
-        cache_dir = str(tmp_path / "xc")
-        monkeypatch.setenv("RAMBA_CACHE", cache_dir)
-        try:
-            st = common.setup_persistent_cache()
-            assert st.ok and st.enabled and st.path == cache_dir, st
-            ev = events.last(3, type="compile.persist_init")
-            assert ev and ev[-1]["path"] == cache_dir and ev[-1]["ok"]
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xc"))
+        st = common.setup_compile_cache()
+        assert st.ok and st.source == "env", st
+        assert st.path == str(tmp_path / "xc") and os.path.isdir(st.path)
+        assert jax.config.jax_compilation_cache_dir == before
+        ev = events.last(3, type="compile.persist_init")
+        assert ev and ev[-1]["path"] == st.path and ev[-1]["ok"]
 
-    def test_survives_clear_caches_and_reinit(self, tmp_path, monkeypatch):
-        """The PR-3 reset path: jax latches the persistent-cache state on
-        first compile; a re-init after ``jax.clear_caches()`` must land
-        compiled artifacts in the (re)configured dir."""
+    def test_unset_env_is_the_checkout(self, monkeypatch):
         import jax
 
-        cache_dir = str(tmp_path / "xc2")
-        monkeypatch.setenv("RAMBA_CACHE", cache_dir)
-        try:
-            st = common.setup_persistent_cache()
-            assert st.ok and st.path == cache_dir, st
-            jax.clear_caches()
-            st2 = common.setup_persistent_cache()
-            assert st2.ok and st2.path == cache_dir, st2
-            a = rt.arange(517.0)
-            np.asarray(rt.tanh(a) * 3.0 + a)
-            assert len(os.listdir(cache_dir)) >= 1
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        st = common.setup_compile_cache()
+        assert st.ok and st.source == "checkout", st
+        assert st.path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == st.path
 
-    def test_disabled_status_is_ok(self, monkeypatch):
-        monkeypatch.delenv("RAMBA_CACHE", raising=False)
-        monkeypatch.setattr(common, "cache_env", None)
-        st = common.setup_persistent_cache()
-        assert st.path is None and st.ok and not st.enabled
+    def test_ramba_cache_does_not_place_jax_cache(self, tmp_path,
+                                                  monkeypatch):
+        import jax
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("RAMBA_CACHE", str(tmp_path / "aot"))
+        st = common.setup_compile_cache()
+        assert st.path == os.path.join(REPO, ".jax_cache"), st
+        assert jax.config.jax_compilation_cache_dir == st.path
+        assert common.persistent_cache_path() == str(tmp_path / "aot")
 
 
 # ---------------------------------------------------------------------------
@@ -468,6 +493,7 @@ class TestWarmObservability:
 
 # argv: <phase>.  cold compiles + stores AOT entries; warm (same
 # RAMBA_CACHE) must answer from them with zero compiles in its ledger.
+# jax's own persistent cache is armed in both, where the environment says.
 _WARMSTART_CHILD = """
 import json
 import sys
@@ -477,7 +503,8 @@ from ramba_tpu import common
 from ramba_tpu.compile import classes, persist
 from ramba_tpu.observe import ledger
 assert classes.enabled(), 'RAMBA_COMPILE_CLASSES not armed'
-common.setup_persistent_cache()
+import jax
+assert jax.config.jax_compilation_cache_dir == common.compile_cache_dir()
 persist.reconfigure()
 assert persist.armed(), persist.snapshot()
 base = np.arange(48, dtype=np.float32).reshape(6, 8)
@@ -498,9 +525,13 @@ print(json.dumps({
 
 class TestWarmStart:
     def test_second_process_pays_zero_compiles(self, tmp_path):
+        # jax's own cache is placed (by the environment) in a directory
+        # that starts empty, so the cold process compiles fresh: the AOT
+        # lane stores only fresh compiles (persist.store_entry)
         env = dict(os.environ)
         env.update(JAX_PLATFORMS="cpu", RAMBA_COMPILE_CLASSES="pow2",
-                   RAMBA_CACHE=str(tmp_path / "cache"), PYTHONPATH=REPO)
+                   RAMBA_CACHE=str(tmp_path / "cache"), PYTHONPATH=REPO,
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
         for k in ("RAMBA_AOT", "RAMBA_FAULTS", "RAMBA_TRACE", "RAMBA_MEMO",
                   "RAMBA_VERIFY", "RAMBA_PERF", "RAMBA_TEST_PROCS"):
             env.pop(k, None)

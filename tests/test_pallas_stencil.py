@@ -51,8 +51,11 @@ class TestPallasStencil:
         import jax.numpy as jnp
 
         a = jnp.zeros((16, 16), jnp.float32)
-        # CPU without interpret mode: not available
-        assert not stencil_pallas.available([a])
+        # off the TPU without interpret mode: not available; on one chip
+        # (RAMBA_TEST_TPU=1) the kernel compiles and is
+        on_one_chip = (jax.default_backend() == "tpu"
+                       and len(jax.devices()) == 1)
+        assert stencil_pallas.available([a]) == on_one_chip
 
     def test_odd_sizes(self, interpret_mode):
         # non-multiple-of-128 width, non-multiple-of-block height

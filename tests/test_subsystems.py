@@ -336,39 +336,37 @@ class TestDistributed:
 
 
 class TestPersistentCache:
-    """Reference: RAMBA_CACHE Numba disk cache (ramba.py:177-246) — here the
-    XLA compilation cache persisted to disk."""
+    """Reference: RAMBA_CACHE Numba disk cache (ramba.py:177-246) — here
+    jax's persistent compilation cache, in ONE directory placed from
+    outside (a compile writing there is checked in fresh interpreters by
+    tests/test_compile_cache_dir.py; this suite runs with the cache off,
+    see conftest.py)."""
 
-    def test_cache_dir_created_and_populated(self, tmp_path, monkeypatch):
-        import jax
-
+    def test_one_directory_placed_by_the_environment(self, tmp_path,
+                                                     monkeypatch):
         from ramba_tpu import common
 
-        cache_dir = str(tmp_path / "xla_cache")
-        monkeypatch.setenv("RAMBA_CACHE", cache_dir)
-        status = common.setup_persistent_cache()
-        assert status.path == cache_dir and status.ok, status
-        assert status.enabled
-        assert os.path.isdir(cache_dir)
-        try:
-            # a fresh program structure so the executable is actually compiled
-            a = rt.arange(257.0)
-            b = rt.tanh(a) * 3.0 + rt.arange(257.0)
-            b.asarray()
-            rt.sync()
-            assert len(os.listdir(cache_dir)) >= 1
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert common.compile_cache_dir() == os.path.join(repo, ".jax_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "x"))
+        assert common.compile_cache_dir() == str(tmp_path / "x")
+        # RAMBA_CACHE never moves it
+        monkeypatch.setenv("RAMBA_CACHE", str(tmp_path / "aot"))
+        assert common.compile_cache_dir() == str(tmp_path / "x")
 
-    def test_disabled_by_default(self, monkeypatch):
+    def test_ramba_cache_arms_only_the_aot_lane(self, tmp_path, monkeypatch):
         from ramba_tpu import common
 
         monkeypatch.delenv("RAMBA_CACHE", raising=False)
-        monkeypatch.setattr(common, "cache_env", None)
-        status = common.setup_persistent_cache()
-        assert status.path is None and status.ok and not status.enabled
+        assert common.persistent_cache_path() is None
         monkeypatch.setenv("RAMBA_CACHE", "0")
-        assert common.setup_persistent_cache().path is None
+        assert common.persistent_cache_path() is None
+        monkeypatch.setenv("RAMBA_CACHE", str(tmp_path / "aot"))
+        assert common.persistent_cache_path() == str(tmp_path / "aot")
+        monkeypatch.setenv("RAMBA_CACHE", "1")
+        assert common.persistent_cache_path() == os.path.join(
+            common.compile_cache_dir(), "ramba_aot")
 
 
 class TestApiParity:
