@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ramba_tpu.observe import profile as _profile
+
 # ---------------------------------------------------------------------------
 # Nodes
 # ---------------------------------------------------------------------------
@@ -63,7 +65,8 @@ class Scalar(Expr):
 
     def __init__(self, value):
         self.value = value
-        self.aval = jax.eval_shape(lambda: jnp.asarray(value))
+        with _profile.span("dag.infer"):
+            self.aval = jax.eval_shape(lambda: jnp.asarray(value))
 
 
 class Node(Expr):
@@ -127,7 +130,10 @@ def infer_aval(op: str, static: tuple, arg_avals: Sequence[Any]) -> Any:
         hit = _aval_memo.get(key)
         if hit is not None:
             return hit
-    out = jax.eval_shape(lambda *a: fn(static, *a), *arg_avals)
+    # a miss: abstract evaluation at node construction, counted where it
+    # happens (``dag.infer.n`` misses, ``dag.infer.ns`` their time)
+    with _profile.span("dag.infer"):
+        out = jax.eval_shape(lambda *a: fn(static, *a), *arg_avals)
     if key is not None:
         if len(_aval_memo) > 8192:
             _aval_memo.clear()

@@ -915,8 +915,8 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
                       rung: str = "fused", donated: int = 0,
                       backend: Optional[str] = None):
     """Run one compiled program with the shared observability treatment:
-    RAMBA_SHOW_CODE dump on first compile, profiler TraceAnnotation at
-    RAMBA_TIMING>=2 or under RAMBA_PROFILE_DIR, first-call
+    RAMBA_SHOW_CODE dump on first compile, the fence under its profiler
+    annotation (``ramba.flush.fence``), first-call
     (trace+lower+XLA compile) vs steady-state timing attribution, a cost
     ledger record filed under ``fp`` (with the degradation ``rung`` this
     execution ran on), and — when ``span`` is given — a per-call child
@@ -953,13 +953,7 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
     # call below; kernels that choose a lowering while traced note it
     # (registry.note_kernel) and the notes land on this flush's span
     with _registry.collect_kernel_notes() as kernel_notes:
-        if common.timing_level > 1 or _profile.enabled():
-            # label the dispatch in profiler traces (RAMBA_PROFILE_DIR /
-            # utils.timing.profiler_trace); off the hot path otherwise
-            with _profile.annotation(_program_label(program)):
-                outs = fn(*leaf_vals)
-        else:
-            outs = fn(*leaf_vals)
+        outs = fn(*leaf_vals)
     dt = time.perf_counter() - t0
     sync_dt = None
     fence_dt = None
@@ -972,7 +966,8 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
     if _attrib.fence_decision(fp, span) or _ledger.sync_timing():
         # a device failure surfaces here (dispatch is asynchronous) and
         # belongs to this attempt: let the ladder classify it
-        jax.block_until_ready(outs)
+        with _profile.flush_annotation("fence", span):
+            jax.block_until_ready(outs)
         fence_dt = time.perf_counter() - t0 - dt
         # the fence wait is observability's own cost: the device tail
         # would have overlapped the host had we not blocked on it
@@ -1572,6 +1567,18 @@ def _flush_discard(work: "_FlushWork") -> None:
 def _flush_prepare(stream: FlushStream, roots: list,
                    extra: Sequence[Expr] = (), *,
                    detached: bool = False) -> Optional["_FlushWork"]:
+    """:func:`_flush_prepare_body` under ``ramba.flush.prepare``; the
+    label and trace id reach the annotation once the body has them."""
+    with _profile.flush_annotation("prepare") as ann:
+        work = _flush_prepare_body(stream, roots, extra, detached=detached)
+        if work is not None:
+            ann.set_metadata(**_profile.span_args(work.span))
+        return work
+
+
+def _flush_prepare_body(stream: FlushStream, roots: list,
+                        extra: Sequence[Expr], *,
+                        detached: bool) -> Optional["_FlushWork"]:
     """Stage 1 of a flush: rewrite + linearize, open the span, gather
     leaf values, take the donation census, emit the program event, pin
     the leaves, and run the RAMBA_VERIFY verifier.  Cheap relative to
@@ -1923,10 +1930,11 @@ def _finish_memo_hit(work: "_FlushWork") -> list:
     span["memo_hit"] = True
     span["out_bytes"] = sum(_nbytes(v) for v in outs)
     span["wall_s"] = round(time.perf_counter() - work.t_flush, 6)
-    _attrib.finalize_span(span, fp=work.fingerprint)
-    _events.emit(span)
-    _slo.observe_span(span)
-    _elastic.note_progress("flush")
+    with _profile.span("observe.tail"):
+        _attrib.finalize_span(span, fp=work.fingerprint)
+        _events.emit(span)
+        _slo.observe_span(span)
+        _elastic.note_progress("flush")
     return list(outs[len(work.roots):])
 
 
@@ -2011,8 +2019,7 @@ def _flush_dispatch_traced(work: "_FlushWork", *, coalesced: int = 0) -> list:
         _stages_pre = sum(span["stages"].get(k, 0.0) for k in
                           ("compile", "dispatch", "device_execute"))
         t_ladder = time.perf_counter()
-        with _profile.flush_annotation("ramba_flush:" + label,
-                                       trace_id=span.get("trace_id")):
+        with _profile.flush_annotation("run", span):
             with warnings.catch_warnings():
                 warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
                 if hedge_s is not None:
@@ -2110,15 +2117,19 @@ def _flush_dispatch_traced(work: "_FlushWork", *, coalesced: int = 0) -> list:
     )
     span["out_bytes"] = sum(_nbytes(v) for v in outs)
     span["wall_s"] = round(time.perf_counter() - work.t_flush, 6)
-    _attrib.add_stage(span, "write_back", time.perf_counter() - t_writeback)
-    _attrib.finalize_span(span, fp=work.fingerprint)
-    _events.emit(span)
-    # Slow-flush sentinel: compares this flush against the program's own
-    # rolling history and emits at most one slow_flush event (after the
-    # span, so the trace reads cause-then-verdict).
-    _ledger.observe_flush(span)
-    _slo.observe_span(span)
-    _elastic.note_progress("flush")
+    # the span is closed: what the observers do with it from here on is
+    # their own time, not the flush's
+    with _profile.span("observe.tail"):
+        _attrib.add_stage(span, "write_back",
+                          time.perf_counter() - t_writeback)
+        _attrib.finalize_span(span, fp=work.fingerprint)
+        _events.emit(span)
+        # Slow-flush sentinel: compares this flush against the program's
+        # own rolling history and emits at most one slow_flush event
+        # (after the span, so the trace reads cause-then-verdict).
+        _ledger.observe_flush(span)
+        _slo.observe_span(span)
+        _elastic.note_progress("flush")
     return list(outs[len(roots):])
 
 

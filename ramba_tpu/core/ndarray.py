@@ -31,6 +31,7 @@ from ramba_tpu import common
 from ramba_tpu.core import expr as E
 from ramba_tpu.core import fuser
 from ramba_tpu.core.expr import Const, Expr, Node, Scalar
+from ramba_tpu.observe import profile as _profile
 from ramba_tpu.parallel import mesh as _mesh
 
 _seq_counter = itertools.count()
@@ -342,14 +343,18 @@ class ndarray:
         from ramba_tpu.utils import timing as _timing
 
         v = self._value()
-        if not v.is_fully_addressable:
-            from jax.experimental import multihost_utils
-            from ramba_tpu.parallel import distributed as _distributed
+        # the read: device-to-host copy and NumPy's conversion (the flush
+        # under _value() keeps its own span)
+        with _profile.span("read"):
+            if not v.is_fully_addressable:
+                from jax.experimental import multihost_utils
+                from ramba_tpu.parallel import distributed as _distributed
 
-            out = np.asarray(multihost_utils.process_allgather(v, tiled=True))
-            _distributed.note_transfer("allgather", out.nbytes)
-        else:
-            out = np.asarray(v)
+                out = np.asarray(
+                    multihost_utils.process_allgather(v, tiled=True))
+                _distributed.note_transfer("allgather", out.nbytes)
+            else:
+                out = np.asarray(v)
         _timing.note_transfer("device_to_host", out.nbytes)
         return out
 

@@ -15,8 +15,12 @@ Environment variables:
   (``<path>.rank<i>`` per process under multi-controller SPMD).
 * ``RAMBA_TRACE_RING=<n>`` — in-memory ring size (default 256; the ring is
   always on, file output only when RAMBA_TRACE is set).
-* ``RAMBA_PROFILE_DIR=<dir>`` — capture a jax.profiler trace of every
-  flush, annotated by program label.
+* ``RAMBA_PROFILE_DIR=<dir>`` — capture a jax.profiler trace of the whole
+  process from the first flush on.  The annotations need no variable:
+  under any profiler session every flush shows as ``ramba.flush.prepare``
+  / ``.run`` / ``.fence`` (program label and span trace id as arguments)
+  and the counted work outside the span as ``ramba.dag.infer``,
+  ``ramba.read`` and ``ramba.observe.tail`` (``profile``).
 * ``RAMBA_PERF`` — ``1`` adds XLA cost_analysis capture per kernel and the
   ``kernels`` section in bench.py; ``sync`` also records synchronized
   execution timing.  The ledger itself is always on.
@@ -37,8 +41,6 @@ Environment variables:
 * ``RAMBA_TRACE_BUFFER=<n>`` — pending-line bound of the buffered trace
   writer (default 2048); overflow drops lines and counts
   ``events.write_dropped`` instead of blocking the flush path.
-* ``RAMBA_PROFILE=deep`` — flush TraceAnnotations carry the span's
-  trace id, joining profiler timelines to RAMBA_TRACE spans.
 * ``RAMBA_PEAKS_JSON`` — hardware-peak table override (inline JSON or a
   file path) for the roofline ledger.
 * ``RAMBA_BASELINE_DIR`` / ``RAMBA_PERF_DRIFT_FACTOR`` /

@@ -10,9 +10,10 @@ take"; this module answers the two questions the ledger cannot:
   folds the residual into ``unattributed_s`` so the stage durations plus
   the residual always reconcile with ``wall_s``.  Device time comes from
   a ``jax.block_until_ready`` fence after each compiled call (opt out
-  with ``RAMBA_ATTRIB=off``); under ``RAMBA_PROFILE=deep`` the same
-  spans are joined to XLA profiler traces via
-  ``jax.profiler.TraceAnnotation`` carrying the span's trace id.
+  with ``RAMBA_ATTRIB=off``); under any profiler session the same
+  spans are joined to XLA profiler traces via the
+  ``jax.profiler.TraceAnnotation``s of ``observe/profile.py``, which
+  carry the span's trace id.
 
   ``RAMBA_ATTRIB=sample:<N>`` fences 1-in-N calls **per kernel
   fingerprint** instead of every call: the decision is the

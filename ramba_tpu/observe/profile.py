@@ -1,44 +1,31 @@
-"""jax.profiler integration: RAMBA_PROFILE_DIR lines flushes up with xprof.
+"""jax.profiler integration: the program's names on the profiler's clock.
 
-With ``RAMBA_PROFILE_DIR=<dir>`` set, the first flush starts a
-``jax.profiler.trace`` into that directory (stopped atexit) and every flush
-dispatch runs inside a ``TraceAnnotation`` named by the fused program's
-label — so the Perfetto/TensorBoard timeline shows which ramba program each
-XLA module execution belongs to.  This supersedes the ad-hoc
-``RAMBA_TIMING>=2`` annotation previously buried in core/fuser.py (which
-still works: annotations engage when EITHER gate is on).
-
-``RAMBA_PROFILE=deep`` additionally joins the attribution plane
-(observe/attrib.py) to XLA profiler traces: every flush dispatch runs
-inside a ``TraceAnnotation`` that carries the span's trace id, so a
-Perfetto timeline row can be matched back to the exact flush span (and
-its stage waterfall) in the RAMBA_TRACE event stream.
+Every flush runs inside ``TraceAnnotation``s with stable names
+(``ramba.flush.prepare``, ``ramba.flush.run``, ``ramba.flush.fence``),
+each carrying the program's label and the span's trace id as arguments,
+and the work outside the flush span that :func:`span` wraps shows as
+``ramba.<name>``.  They engage under ANY profiler session: one the
+caller starts (``jax.profiler.start_trace``) or the whole-process one of
+``RAMBA_PROFILE_DIR=<dir>``, which the first flush starts and atexit
+stops.  With no session a ``TraceAnnotation`` is one atomic load, so
+nothing gates them.  The annotations carry no number the flush span
+lacks: the stage ledger (observe/attrib.py) stays the source of every
+stage metric, and a timeline row is matched back to its span by the
+``trace_id`` argument.
 """
 
 from __future__ import annotations
 
 import atexit
-import contextlib
 import os
+import time
+
+from jax.profiler import TraceAnnotation, start_trace, stop_trace
+
+from ramba_tpu.observe import registry
 
 _DIR = os.environ.get("RAMBA_PROFILE_DIR") or None
-_deep = (os.environ.get("RAMBA_PROFILE") or "").lower() == "deep"
 _started = False
-
-
-def enabled() -> bool:
-    return _DIR is not None
-
-
-def deep() -> bool:
-    return _deep
-
-
-def reconfigure() -> None:
-    """Re-read RAMBA_PROFILE_DIR / RAMBA_PROFILE (tests)."""
-    global _DIR, _deep
-    _DIR = os.environ.get("RAMBA_PROFILE_DIR") or None
-    _deep = (os.environ.get("RAMBA_PROFILE") or "").lower() == "deep"
 
 
 def ensure_started() -> None:
@@ -47,10 +34,8 @@ def ensure_started() -> None:
     if _DIR is None or _started:
         return
     _started = True
-    import jax.profiler as _prof
-
     os.makedirs(_DIR, exist_ok=True)
-    _prof.start_trace(_DIR)
+    start_trace(_DIR)
     atexit.register(_stop)
 
 
@@ -60,35 +45,51 @@ def _stop() -> None:
         return
     _started = False
     try:
-        import jax.profiler as _prof
-
-        _prof.stop_trace()
+        stop_trace()
     except Exception:  # interpreter teardown: best-effort
         pass
 
 
-def annotation(label: str):
-    """TraceAnnotation context when profiling (or RAMBA_TIMING>=2) is on;
-    a free nullcontext otherwise — safe on the per-flush hot path."""
-    from ramba_tpu import common
+def span_args(span) -> dict:
+    """The arguments a flush's annotations carry: the program's label and,
+    where the stream has one, the span's trace id."""
+    args = {}
+    if span is not None:
+        if span.get("label") is not None:
+            args["label"] = span["label"]
+        if span.get("trace_id") is not None:
+            args["trace_id"] = span["trace_id"]
+    return args
 
-    if _DIR is None and common.timing_level <= 1 and not _deep:
-        return contextlib.nullcontext()
-    import jax.profiler as _prof
 
-    return _prof.TraceAnnotation(label)
+def flush_annotation(stage: str, span=None):
+    """``ramba.flush.<stage>`` on the profiler's host line for the flush
+    that ``span`` records, with :func:`span_args` as arguments (the name
+    stays one per stage, so gaps add up over programs).  Free of any
+    gate: safe on the per-flush hot path."""
+    return TraceAnnotation("ramba.flush." + stage, **span_args(span))
 
 
-def flush_annotation(label: str, trace_id=None):
-    """Flush-dispatch annotation.  Under ``RAMBA_PROFILE=deep`` the
-    annotation carries the flush span's trace id as a TraceMe argument,
-    joining profiler timeline rows to RAMBA_TRACE spans; otherwise it
-    degrades to :func:`annotation` (free nullcontext when nothing is
-    profiling)."""
-    if not _deep:
-        return annotation(label)
-    import jax.profiler as _prof
+class span:
+    """Host work outside the flush span, counted where it happens: for
+    its duration ``ramba.<name>`` is open on the profiler's host line,
+    and on exit the elapsed nanoseconds go to the registry counter
+    ``<name>.ns`` and 1 to ``<name>.n``.  No event is emitted."""
 
-    if trace_id is not None:
-        return _prof.TraceAnnotation(label, trace_id=trace_id)
-    return _prof.TraceAnnotation(label)
+    __slots__ = ("name", "_ann", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        self._ann = TraceAnnotation("ramba." + self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        ns = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(*exc)
+        registry.inc(self.name + ".ns", ns)
+        registry.inc(self.name + ".n")
+        return False

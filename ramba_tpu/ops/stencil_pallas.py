@@ -275,6 +275,7 @@ def _run_fast(func, lo, hi, slots, arrs, taps, interpret=_INTERPRET):
             + [pltpu.SemaphoreType.DMA((2, n_slabs))]
         ),
         interpret=interpret,
+        name="ramba_stencil_fast",
     )(*arrs)
 
 
@@ -384,6 +385,7 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET):
             + [pltpu.SemaphoreType.DMA]
         ),
         interpret=interpret,
+        name="ramba_stencil_padded",
     )(*padded)
 
 

@@ -82,3 +82,39 @@ def driver_write(fn) -> None:
     from ramba_tpu.fileio import _driver_write_barrier
 
     _driver_write_barrier(fn)
+
+
+def profiled_host_lines(logdir, body) -> dict:
+    """Run ``body()`` under a ``jax.profiler`` session the CALLER starts
+    (no RAMBA_* variable involved) and return the trace's host lines:
+    ``{line name: [(name, start_ns, end_ns, stats)]}`` from the
+    ``xplane.pb``, TraceMe arguments as the ``stats`` dict."""
+    import glob
+    import os
+    import warnings
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files, "the profiler wrote no xplane.pb"
+    lines = {}
+    with warnings.catch_warnings():
+        # jaxlib's event_stats type warns once when it is first built
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in ProfileData.from_file(files[0]).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                lines.setdefault(line.name, []).extend(
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats)) for e in line.events)
+    return lines

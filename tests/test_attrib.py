@@ -16,7 +16,7 @@ the offline CLIs:
   flight-recorder incident under ``RAMBA_FAULTS=execute:delay:ms=150``,
   silence on a clean soak, baselines persisted/restored across
   processes via ``RAMBA_BASELINE_DIR``,
-* ``RAMBA_PROFILE=deep`` profiler-annotation smoke,
+* profiler annotations that carry the span's trace id (no variable set),
 * Prometheus series: stage totals + rooflines + regressions, and the
   compile-class/AOT satellite counters,
 * ``scripts/trace_report.py --attrib`` and ``scripts/roofline_report.py``
@@ -346,30 +346,42 @@ def test_baseline_persist_restore_across_processes(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# deep-mode profiler annotation
+# profiler annotations carry the span's trace id
 # ---------------------------------------------------------------------------
 
 
-def test_deep_profile_annotation_smoke():
-    with _env(RAMBA_PROFILE="deep"):
-        profile.reconfigure()
-        assert profile.deep()
-        import jax.profiler as _prof
+def test_flush_annotation_carries_trace_id(tmp_path):
+    """With no variable set, a profiler session sees each flush stage of a
+    traced session under its stable name with the span's trace id and
+    label as arguments: a timeline row joins back to its RAMBA_TRACE
+    span."""
+    import jax.profiler as _prof
 
-        ctx = profile.flush_annotation("ramba_flush:test",
-                                       trace_id="tr-0042")
-        assert isinstance(ctx, _prof.TraceAnnotation)
-        with ctx:
-            pass
-        _chain()  # a real flush dispatches under the annotation
-    profile.reconfigure()
-    assert not profile.deep()
-    if not os.environ.get("RAMBA_PROFILE_DIR"):
-        from ramba_tpu import common
+    from ramba_tpu import serve
+    from tests.helpers import profiled_host_lines
 
-        if common.timing_level <= 1:
-            assert isinstance(profile.flush_annotation("x"),
-                              type(contextlib.nullcontext()))
+    assert not os.environ.get("RAMBA_PROFILE")
+    ctx = profile.flush_annotation("run", {"label": "prog_x",
+                                           "trace_id": "tr-0042"})
+    assert isinstance(ctx, _prof.TraceAnnotation)
+    assert isinstance(profile.flush_annotation("fence"),
+                      _prof.TraceAnnotation)
+
+    def body():
+        with serve.Session(tenant="acme", trace_id="cafe000000000042") as s:
+            a = rt.arange(2711) * 2.0 + 1.0
+            s.flush(wait=True)
+            a.asarray()
+
+    lines = profiled_host_lines(tmp_path, body)
+    span = [e for e in events.ring if e.get("type") == "flush"
+            and e.get("trace_id") == "cafe000000000042"][-1]
+    for stage in ("prepare", "run", "fence"):
+        got = [stats for evs in lines.values() for name, _, _, stats in evs
+               if name == "ramba.flush." + stage
+               and stats.get("trace_id") == "cafe000000000042"]
+        assert got, f"no ramba.flush.{stage} with the session's trace id"
+        assert got[-1]["label"] == span["label"]
 
 
 # ---------------------------------------------------------------------------

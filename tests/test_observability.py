@@ -192,3 +192,145 @@ def test_disabled_trace_writes_no_file():
     _run_chain()
     assert len(events.ring) > n0 or events.ring.maxlen == len(events.ring)
     assert events._trace_file is None  # no sink ever opened
+
+
+# ---------------------------------------------------------------------------
+# the time outside the flush span: dag.infer, read, observe.tail
+# ---------------------------------------------------------------------------
+
+_OUTSIDE = ("dag.infer", "read", "observe.tail")
+
+
+def _outside_delta(before):
+    """{counter: movement} of the three spans' counter pairs."""
+    after = diagnostics.counters()
+    return {f"{n}.{k}": after.get(f"{n}.{k}", 0) - before.get(f"{n}.{k}", 0)
+            for n in _OUTSIDE for k in ("n", "ns")}
+
+
+def _prk_toy(iterations, n=64, r=2):
+    @rt.stencil
+    def star(a):
+        acc = None
+        for j in range(1, r + 1):
+            term = (1.0 / (2 * j * r)) * (a[0, j] - a[0, -j]
+                                          + a[j, 0] - a[-j, 0])
+            acc = term if acc is None else acc + term
+        return acc
+
+    i = rt.arange(n, dtype=np.float32)
+    A = i[:, None] + i[None, :]
+    B = rt.zeros((n, n), dtype=np.float32)
+    rt.sync()
+    before = diagnostics.counters()
+    for _ in range(iterations):
+        B += rt.sstencil(star, A)
+        A += 1.0
+    norm = float(rt.sum(abs(B))) / (n - 2 * r) ** 2
+    return norm, _outside_delta(before)
+
+
+def test_dag_infer_counts_stencil_misses():
+    """The stencil node's static holds the kernel's function, so its
+    inference never hits the memo: one miss an iteration at least, each
+    with its time."""
+    norm, moved = _prk_toy(3)
+    assert norm == pytest.approx(6.0, rel=1e-5)
+    assert moved["dag.infer.n"] >= 3
+    assert moved["dag.infer.ns"] > 0
+
+
+def test_dag_infer_memo_hits_move_nothing():
+    x = rt.arange(512)
+    rt.sync()
+
+    def chain():
+        return float(rt.sum(rt.sin(x) + x * x))
+
+    want = chain()  # fills the memo: every node of the repeat hits
+    before = diagnostics.counters()
+    assert chain() == want
+    moved = _outside_delta(before)
+    assert moved["dag.infer.n"] == 0 and moved["dag.infer.ns"] == 0
+    # ... while the read and the observers' tail of that solve did count
+    assert moved["read.n"] == 1 and moved["read.ns"] > 0
+    assert moved["observe.tail.n"] >= 1
+
+
+@pytest.mark.parametrize("read", [
+    lambda a: a.asarray(),
+    lambda a: float(a[3]),
+    lambda a: np.asarray(a),
+    lambda a: int(rt.sum(a)),
+], ids=["asarray", "float", "np.asarray", "int-of-sum"])
+def test_each_read_moves_read_n_by_one(read):
+    a = rt.arange(64) + 1
+    rt.sync()
+    before = diagnostics.counters()
+    read(a)
+    moved = _outside_delta(before)
+    assert moved["read.n"] == 1
+    assert moved["read.ns"] > 0
+
+
+def test_observe_tail_counts_flush_spans():
+    fuser.flush()
+    seen = []
+    events.add_tap(seen.append)
+    try:
+        before = diagnostics.counters()
+        a = rt.arange(256) * 2.0
+        rt.sync()
+        b = a + 1.0
+        float(rt.sum(b))
+        float(b[5])
+        moved = _outside_delta(before)
+    finally:
+        events.remove_tap(seen.append)
+    spans = [e for e in seen if e.get("type") == "flush"]
+    assert len(spans) >= 3
+    assert moved["observe.tail.n"] == len(spans)
+    assert moved["observe.tail.ns"] > 0
+    # the tail starts where the span's wall clock stops
+    assert all("wall_s" in s for s in spans)
+
+
+@pytest.mark.skipif(_MULTIPROC, reason="one profiler session per process")
+def test_annotations_reach_a_callers_profiler_session(tmp_path):
+    """With no RAMBA_* variable set, a session the caller starts sees the
+    program's names on the caller's host line, inside the caller's own
+    annotation: what the benchmark's traced stretch names gaps from."""
+    from tests.helpers import profiled_host_lines
+
+    for var in ("RAMBA_PROFILE_DIR", "RAMBA_PROFILE", "RAMBA_TIMING"):
+        assert not os.environ.get(var), var
+    x = rt.arange(512)
+    rt.sync()
+    float(rt.sum(x * 2.0))  # compiled outside the session
+
+    def body():
+        with _jax.profiler.TraceAnnotation("test_outer"):
+            float(rt.sum(x * 2.0))  # Scalar(2.0): an inference miss
+
+    lines = profiled_host_lines(tmp_path, body)
+    mine = [evs for evs in lines.values()
+            if any(name == "test_outer" for name, *_ in evs)]
+    assert len(mine) == 1, "the caller's host line carries its annotation"
+    outer = [e for e in mine[0] if e[0] == "test_outer"][0]
+    by_name = {}
+    for name, a, b, stats in mine[0]:
+        if name.startswith("ramba."):
+            assert outer[1] <= a and b <= outer[2], name
+            by_name.setdefault(name, []).append((a, b, stats))
+    for name in ("ramba.flush.prepare", "ramba.flush.run",
+                 "ramba.flush.fence", "ramba.read", "ramba.dag.infer",
+                 "ramba.observe.tail"):
+        assert name in by_name, (name, sorted(by_name))
+    # one stable name per stage; the program's label rides as an argument
+    label = diagnostics.last_flushes(1)[0]["label"]
+    for stage in ("prepare", "run", "fence"):
+        assert by_name[f"ramba.flush.{stage}"][-1][2].get("label") == label
+    # the fence sits inside the run, the read after the flush
+    run, fence = by_name["ramba.flush.run"][-1], by_name["ramba.flush.fence"][-1]
+    assert run[0] <= fence[0] and fence[1] <= run[1]
+    assert by_name["ramba.read"][-1][0] >= run[1]
