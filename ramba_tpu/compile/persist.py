@@ -196,7 +196,8 @@ class AotDispatcher:
     ``call_fallbacks``).  ``lower`` delegates to the fallback jit —
     ``_execute_compiled``/``capture_cost`` call it in guarded blocks."""
 
-    __slots__ = ("_loaded", "_sig", "_program", "_donate", "_fallback")
+    __slots__ = ("_loaded", "_sig", "_program", "_donate", "_fallback",
+                 "__weakref__")  # the fuser keeps kernel notes by weak key
 
     def __init__(self, loaded, sig, program, donate):
         self._loaded = loaded

@@ -16,6 +16,8 @@ plain Python functions over jax values; no source-string codegen, no eval().
 
 from __future__ import annotations
 
+import types
+import weakref
 from typing import Any, Callable, Sequence
 
 import jax
@@ -23,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ramba_tpu.observe import profile as _profile
+from ramba_tpu.observe import registry as _registry
 
 # ---------------------------------------------------------------------------
 # Nodes
@@ -59,14 +62,31 @@ class Scalar(Expr):
     the *value* of a scalar does not invalidate the compile cache — the analog
     of the reference pickling op operands separately from the generated source
     whose name is a hash of the code only (ramba.py:8260-8265,8286-8298).
+
+    The aval is ``jax.eval_shape(lambda: jnp.asarray(value))``.  For the
+    Python and NumPy number types it depends on ``type(value)`` and the
+    semantic fingerprint only, so it is abstractly evaluated on first sight
+    of a type (a miss, counted under ``dag.infer``) and read from a table
+    afterwards (``dag.infer.hit``): identical by construction, ``weak_type``
+    included.  A Python ``int`` that does not fit the default integer width
+    raises from ``jnp.asarray`` by value, so it is never tabled and goes on
+    taking the evaluation; so does a value of any other type.
     """
 
     __slots__ = ("value",)
 
     def __init__(self, value):
         self.value = value
-        with _profile.span("dag.infer"):
-            self.aval = jax.eval_shape(lambda: jnp.asarray(value))
+        key = _scalar_key(value)
+        aval = _scalar_avals.get(key) if key is not None else None
+        if aval is None:
+            with _profile.span("dag.infer"):
+                aval = jax.eval_shape(lambda: jnp.asarray(value))
+            if key is not None:
+                _scalar_avals[key] = aval
+        else:
+            _registry.inc("dag.infer.hit")
+        self.aval = aval
 
 
 class Node(Expr):
@@ -93,51 +113,119 @@ def as_expr(x: Any) -> Expr:
     raise TypeError(f"cannot lift {type(x)} into an expression")
 
 
+def semantic_fingerprint() -> tuple:
+    """Trace-time global configuration the OPS eval rules consult.  Anything
+    an eval rule reads while being traced MUST appear here: a program's key
+    and an inference memo key capture structure only, so two programs with
+    identical structure but different trace-time semantics — e.g. NEP-50
+    promotion in ``_np_loop_dtypes``, which keys off ``jax_enable_x64`` —
+    would otherwise share one compiled executable, or one inferred aval, and
+    silently reuse the wrong numerics (the collision the analyze
+    graph-hygiene rule detects)."""
+    return (bool(jax.config.jax_enable_x64),)
+
+
+# (type, semantic fingerprint) -> aval of a lifted scalar of that type
+_scalar_avals: dict = {}
+
+
+def _scalar_key(value):
+    """Key of ``Scalar``'s aval table, or None where the aval (or the
+    error) depends on more than the value's type."""
+    t = type(value)
+    if t is int:
+        # beyond the default integer width jnp.asarray raises, by value
+        half = 1 << (63 if jax.config.jax_enable_x64 else 31)
+        if not -half <= value < half:
+            return None
+    elif not (t is float or t is bool or t is complex
+              or issubclass(t, (np.number, np.bool_))):
+        return None
+    return t, semantic_fingerprint()
+
+
+# Value-keyed entries: (op, static, arg avals, fingerprint) -> aval.
 _aval_memo: dict = {}
+# Function-keyed entries: first function of the static (weakly) ->
+# {(op, static with a weak reference in each function's place, arg avals,
+# fingerprint): aval}.  An inner key holds no function, so an entry dies
+# with its first function; a later function at a recycled address is
+# another key (a dead reference equals only itself).
+_fn_aval_memo: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_MEMO_MAX = 8192
 
-_MEMO_SAFE_TYPES = (str, bytes, int, float, complex, bool, type(None), np.dtype)
+_MEMO_SAFE_TYPES = (str, bytes, int, float, complex, bool, type(None),
+                    np.dtype, np.generic)
 
 
-def _value_hashable(x) -> bool:
-    """True if ``x`` hashes by value (safe as a memo key component)."""
-    if isinstance(x, _MEMO_SAFE_TYPES) or isinstance(x, (np.generic,)):
-        return True
-    if isinstance(x, (tuple, frozenset)):
-        return all(_value_hashable(e) for e in x)
-    return False
+class _NoKey(Exception):
+    """The static holds an object the memo may neither key on nor keep."""
+
+
+def _static_key(x, funcs: list):
+    """``x`` as a memo key component: value-hashable members as they are,
+    each Python function replaced by a weak reference to it and appended
+    to ``funcs``.  Raises ``_NoKey`` for anything else: an identity-hashed
+    object (a ``_Lit``, a callable instance) can never hit and would be
+    pinned by its own key."""
+    if isinstance(x, _MEMO_SAFE_TYPES):
+        return x
+    if isinstance(x, tuple):
+        return tuple([_static_key(e, funcs) for e in x])
+    if type(x) is types.FunctionType:
+        funcs.append(x)
+        return weakref.ref(x)
+    if isinstance(x, frozenset) and all(
+            isinstance(e, _MEMO_SAFE_TYPES) for e in x):
+        return x
+    raise _NoKey
 
 
 def infer_aval(op: str, static: tuple, arg_avals: Sequence[Any]) -> Any:
     """Shape/dtype inference by abstract evaluation of the op's own eval rule —
     guarantees inference always matches execution (the reference instead
     duplicates shape/dtype logic in every ``DAGshape``-returning API function,
-    ramba.py:5133-5165).  Memoized: eval_shape costs ~1 ms, which would
-    otherwise dominate graph-build time for op-chain workloads."""
+    ramba.py:5133-5165).  Memoized: eval_shape costs ~1 ms for a map and the
+    whole kernel's trace (Pallas lowering included) for a stencil, which
+    would otherwise dominate graph-build time.
+
+    Keyed on the op, the static, the argument avals and
+    ``semantic_fingerprint()``.  A Python function in the static (a skeleton's
+    kernel, at any depth of its tuples) is keyed by identity and held weakly:
+    the same function object over the same avals is inferred once, a fresh
+    closure per call misses as before, and the memo never extends a
+    function's life.  Any other identity-hashed member (``_Lit`` literals,
+    callable objects) leaves the node without a key: it is inferred every
+    time and nothing of it is retained.
+
+    Assumed of a function: rebinding its closure cells or globals does not
+    change the *shape or dtype* of what it returns.  One that does is served
+    the old aval, exactly as the compile cache already serves it the old
+    executable (``program.key`` hashes the function by identity too).
+
+    A hit counts ``dag.infer.hit``; a miss ``dag.infer.n`` and its time
+    ``dag.infer.ns``."""
     fn = OPS[op]
+    funcs: list = []
     try:
-        key = (op, static, tuple(
-            (tuple(a.shape), str(a.dtype), bool(getattr(a, "weak_type", False)))
+        key = (op, _static_key(static, funcs), tuple(
+            (tuple(a.shape), a.dtype, bool(getattr(a, "weak_type", False)))
             for a in arg_avals
-        ))
-        hash(key)
-        if not _value_hashable(static):
-            # identity-hashed statics (closures, array literals) can never
-            # hit, and each miss would pin the object in the memo
-            key = None
-    except TypeError:
+        ), semantic_fingerprint())
+        memo = _fn_aval_memo.setdefault(funcs[0], {}) if funcs else _aval_memo
+        hit = memo.get(key)
+    except (TypeError, _NoKey):  # TypeError: a member that does not hash
         key = None
-    if key is not None:
-        hit = _aval_memo.get(key)
+    else:
         if hit is not None:
+            _registry.inc("dag.infer.hit")
             return hit
-    # a miss: abstract evaluation at node construction, counted where it
-    # happens (``dag.infer.n`` misses, ``dag.infer.ns`` their time)
     with _profile.span("dag.infer"):
         out = jax.eval_shape(lambda *a: fn(static, *a), *arg_avals)
     if key is not None:
-        if len(_aval_memo) > 8192:
-            _aval_memo.clear()
-        _aval_memo[key] = out
+        if len(memo) >= _MEMO_MAX:
+            memo.clear()
+        memo[key] = out
     return out
 
 

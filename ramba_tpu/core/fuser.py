@@ -57,7 +57,8 @@ from ramba_tpu.compile import classes as _classes
 from ramba_tpu.compile import persist as _persist
 from ramba_tpu.core import memo as _memo
 from ramba_tpu.core import plancache as _plancache
-from ramba_tpu.core.expr import Const, Expr, Node, Scalar, OPS
+from ramba_tpu.core.expr import (Const, Expr, Node, Scalar, OPS,
+                                 semantic_fingerprint as _semantic_fingerprint)
 from ramba_tpu.observe import attrib as _attrib
 from ramba_tpu.observe import events as _events
 from ramba_tpu.observe import fleet as _fleet
@@ -625,17 +626,6 @@ def _program_label(program: _Program) -> str:
     return "prog_" + hashlib.sha256(text.encode()).hexdigest()[:12]
 
 
-def _semantic_fingerprint() -> tuple:
-    """Trace-time global configuration the OPS eval rules consult.  Anything
-    an eval rule reads while being traced MUST appear here: ``program.key``
-    captures structure only, so two programs with identical structure but
-    different trace-time semantics — e.g. NEP-50 promotion in
-    ``expr._np_loop_dtypes``, which keys off ``jax_enable_x64`` — would
-    otherwise share one compiled executable and silently reuse the wrong
-    numerics (the collision the analyze graph-hygiene rule detects)."""
-    return (bool(jax.config.jax_enable_x64),)
-
-
 def _cache_key(program: _Program, donate_key: tuple,
                compile_class=None) -> tuple:
     """Full compile-cache key: structure + donation mask + the trace-time
@@ -910,6 +900,32 @@ def _run_chunked(program: _Program, leaf_vals, donate_idx: tuple,
                           rung="chunked")
 
 
+# compiled function -> {leaf signature: the kernel notes of the call that
+# traced it for that signature}.  Weak: an entry dies with its executable
+# (LRU eviction, mesh epoch).
+_traced_kernel_notes: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def _count_kernel_paths(fn, leaf_vals, kernel_notes: list) -> None:
+    """Keep ``<kernel>.path.<path>`` (and ``<kernel>.interpret``) counting
+    per flush, cache hit or not.  A call of ``fn`` that traced has counted
+    its kernels through ``registry.note_kernel``: its notes are kept with
+    ``fn`` under the leaves' signature (jit traces once per signature, and a
+    kernel may choose its path by shape).  A call that traced nothing
+    replays the notes of the trace it runs into the counters only."""
+    by_sig = _traced_kernel_notes.get(fn)
+    if by_sig is None and not kernel_notes:
+        return  # no kernel noted under this executable: the common case
+    sig = tuple(v.aval if isinstance(v, jax.Array) else type(v)
+                for v in leaf_vals)
+    if kernel_notes:
+        if by_sig is None:
+            by_sig = _traced_kernel_notes.setdefault(fn, {})
+        by_sig[sig] = tuple(kernel_notes)
+    else:
+        _registry.replay_kernel_notes(by_sig.get(sig, ()))
+
+
 def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
                       span: Optional[dict] = None, fp: Optional[str] = None,
                       rung: str = "fused", donated: int = 0,
@@ -951,9 +967,12 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
     t0 = time.perf_counter()
     # jax traces (first call, or a new shape under this key) inside the
     # call below; kernels that choose a lowering while traced note it
-    # (registry.note_kernel) and the notes land on this flush's span
+    # (registry.note_kernel): the notes land on this flush's span, and a
+    # later call that traces nothing counts them again, so the
+    # ``<kernel>.path.*`` counters move on every flush that runs the kernel
     with _registry.collect_kernel_notes() as kernel_notes:
         outs = fn(*leaf_vals)
+    _count_kernel_paths(fn, leaf_vals, kernel_notes)
     dt = time.perf_counter() - t0
     sync_dt = None
     fence_dt = None

@@ -70,29 +70,53 @@ def inc(name: str, n: int = 1) -> None:
 
 # Which lowering a hand-written kernel took is decided while jax traces
 # the program (from shapes, dtypes, mesh and backend), so it is recorded
-# then: always as a counter, and on the flush span when the trace runs
-# inside a compiled call (fuser._execute_compiled collects the notes).
+# then.  Two records with two lifetimes:
+# - the counters ``<kernel>.path.<path>`` (and ``<kernel>.interpret``)
+#   move once per flush that runs the kernel, cache hit or not: when the
+#   flush's compiled call traces, through note_kernel; when it does not,
+#   through replay_kernel_notes with the notes its trace left
+#   (fuser._count_kernel_paths).  They also move when node inference
+#   traces a kernel (a miss of expr.infer_aval: first sight of a function
+#   over given avals), which no flush span sees.
+# - the flush span's ``kernels`` list holds the notes only when the
+#   flush itself traced (fuser._execute_compiled collects them).
 _kernel_notes = threading.local()
+
+
+def _count_kernel(kernel: str, path: str, interpret: bool) -> None:
+    inc(f"{kernel}.path.{path}")
+    if interpret:
+        inc(f"{kernel}.interpret")
 
 
 def note_kernel(kernel: str, path: str, interpret: bool = False) -> None:
     """Record that ``kernel`` (e.g. ``"stencil"``) lowered through
     ``path`` (``sharded`` / ``pallas_fast`` / ``pallas_padded`` / ``xla``
     / a Pallas family name), and whether a Pallas kernel on that path
-    interprets instead of compiling for the chip."""
-    inc(f"{kernel}.path.{path}")
-    if interpret:
-        inc(f"{kernel}.interpret")
+    interprets instead of compiling for the chip.  Called while jax
+    traces: counts the path, and leaves a note with the enclosing
+    :func:`collect_kernel_notes`, if any."""
+    _count_kernel(kernel, path, interpret)
     notes = getattr(_kernel_notes, "active", None)
     if notes is not None:
         notes.append({"kernel": kernel, "path": path,
                       "interpret": bool(interpret)})
 
 
+def replay_kernel_notes(notes) -> None:
+    """Count the kernels of ``notes`` (as :func:`collect_kernel_notes`
+    yielded them when the program was traced) for a call of the compiled
+    program that traced nothing: counters only, never a new note."""
+    for note in notes:
+        _count_kernel(note["kernel"], note["path"], note["interpret"])
+
+
 @contextlib.contextmanager
 def collect_kernel_notes():
     """Collect this thread's :func:`note_kernel` records made inside the
-    block (jax traces in the calling thread); yields the list."""
+    block (jax traces in the calling thread); yields the list.  Empty
+    after a call that hit jax's trace cache: the notes are per trace, the
+    counters per flush."""
     prev = getattr(_kernel_notes, "active", None)
     notes: list = []
     _kernel_notes.active = notes

@@ -84,6 +84,21 @@ def driver_write(fn) -> None:
     _driver_write_barrier(fn)
 
 
+def prk_star_kernel(r=2):
+    """A fresh function object of the PRK star stencil of radius ``r``
+    (weights 1/(2jr), so one sweep of ``i + j`` adds 2 to the norm a
+    point); wrap it with ``rt.stencil``."""
+    def star(a):
+        acc = None
+        for j in range(1, r + 1):
+            term = (1.0 / (2 * j * r)) * (a[0, j] - a[0, -j]
+                                          + a[j, 0] - a[-j, 0])
+            acc = term if acc is None else acc + term
+        return acc
+
+    return star
+
+
 def profiled_host_lines(logdir, body) -> dict:
     """Run ``body()`` under a ``jax.profiler`` session the CALLER starts
     (no RAMBA_* variable involved) and return the trace's host lines:

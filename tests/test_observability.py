@@ -25,7 +25,7 @@ import pytest
 import jax as _jax
 import ramba_tpu as rt
 from ramba_tpu import common, diagnostics
-from ramba_tpu.core import fuser
+from ramba_tpu.core import expr, fuser
 from ramba_tpu.observe import events
 
 _MULTIPROC = _jax.process_count() > 1
@@ -195,7 +195,9 @@ def test_disabled_trace_writes_no_file():
 
 
 # ---------------------------------------------------------------------------
-# the time outside the flush span: dag.infer, read, observe.tail
+# the time outside the flush span: dag.infer (misses only: a node whose
+# function and avals were seen before is not inferred again), read,
+# observe.tail
 # ---------------------------------------------------------------------------
 
 _OUTSIDE = ("dag.infer", "read", "observe.tail")
@@ -208,16 +210,7 @@ def _outside_delta(before):
             for n in _OUTSIDE for k in ("n", "ns")}
 
 
-def _prk_toy(iterations, n=64, r=2):
-    @rt.stencil
-    def star(a):
-        acc = None
-        for j in range(1, r + 1):
-            term = (1.0 / (2 * j * r)) * (a[0, j] - a[0, -j]
-                                          + a[j, 0] - a[-j, 0])
-            acc = term if acc is None else acc + term
-        return acc
-
+def _prk_toy(star, iterations, n=64, r=2):
     i = rt.arange(n, dtype=np.float32)
     A = i[:, None] + i[None, :]
     B = rt.zeros((n, n), dtype=np.float32)
@@ -231,13 +224,26 @@ def _prk_toy(iterations, n=64, r=2):
 
 
 def test_dag_infer_counts_stencil_misses():
-    """The stencil node's static holds the kernel's function, so its
-    inference never hits the memo: one miss an iteration at least, each
-    with its time."""
-    norm, moved = _prk_toy(3)
+    from tests.helpers import prk_star_kernel
+
+    """The stencil node's static holds the kernel's function, which the
+    memo keys by identity: the first sight of a kernel over given avals
+    misses, with its time; every repeat hits, however many iterations."""
+    star = rt.stencil(prk_star_kernel())
+    hits0 = diagnostics.counters().get("dag.infer.hit", 0)
+    norm, moved = _prk_toy(star, 3)
     assert norm == pytest.approx(6.0, rel=1e-5)
-    assert moved["dag.infer.n"] >= 3
-    assert moved["dag.infer.ns"] > 0
+    assert moved["dag.infer.n"] >= 1  # the kernel; the loop's other nodes
+    assert moved["dag.infer.ns"] > 0  # too where no test built them before
+    norm, moved = _prk_toy(star, 5)
+    assert norm == pytest.approx(10.0, rel=1e-5)
+    assert moved["dag.infer.n"] == 0 and moved["dag.infer.ns"] == 0
+    assert diagnostics.counters()["dag.infer.hit"] - hits0 >= 5 + 5 + 5
+    # another function object over the same avals: one miss, not one an
+    # iteration
+    norm, moved = _prk_toy(rt.stencil(prk_star_kernel()), 4)
+    assert norm == pytest.approx(8.0, rel=1e-5)
+    assert moved["dag.infer.n"] == 1 and moved["dag.infer.ns"] > 0
 
 
 def test_dag_infer_memo_hits_move_nothing():
@@ -304,13 +310,15 @@ def test_annotations_reach_a_callers_profiler_session(tmp_path):
 
     for var in ("RAMBA_PROFILE_DIR", "RAMBA_PROFILE", "RAMBA_TIMING"):
         assert not os.environ.get(var), var
-    x = rt.arange(512)
+    x = rt.arange(509)
     rt.sync()
     float(rt.sum(x * 2.0))  # compiled outside the session
 
     def body():
         with _jax.profiler.TraceAnnotation("test_outer"):
-            float(rt.sum(x * 2.0))  # Scalar(2.0): an inference miss
+            expr.infer_aval(  # a shape no test builds: an inference miss
+                "reshape", ((509, 1, 1, 1),), [x.read_expr().aval])
+            float(rt.sum(x * 2.0))
 
     lines = profiled_host_lines(tmp_path, body)
     mine = [evs for evs in lines.values()
