@@ -344,6 +344,7 @@ def _save_program(fp, program, donate_key, sig, compile_class) -> None:
         "n_leaves": program.n_leaves,
         "leaf_kinds": tuple(program.leaf_kinds),
         "out_slots": tuple(program.out_slots),
+        "live_cuts": tuple(program.live_cuts),
         "donate": tuple(donate_key),
         "sig": sig,
         "compile_class": compile_class,
@@ -453,7 +454,8 @@ def store_entry(fp: str, sig: tuple, program_rec=None,
         else:
             program = _fuser._Program(
                 program_rec["instrs"], program_rec["n_leaves"],
-                program_rec["leaf_kinds"], program_rec["out_slots"])
+                program_rec["leaf_kinds"], program_rec["out_slots"],
+                program_rec.get("live_cuts", ()))
             donate = program_rec["donate"]
         run = _fuser._build_callable(program)
 

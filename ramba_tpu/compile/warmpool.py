@@ -83,7 +83,8 @@ def _make_thunk(fp: str, rec: dict):
         from ramba_tpu.core import fuser as _fuser
 
         program = _fuser._Program(rec["instrs"], rec["n_leaves"],
-                                  rec["leaf_kinds"], rec["out_slots"])
+                                  rec["leaf_kinds"], rec["out_slots"],
+                                  rec.get("live_cuts", ()))
         vals = _persist._example_vals(rec["sig"])
         fn, _is_new, _fp, _backend = _fuser._get_compiled(
             program, tuple(rec["donate"]), leaf_vals=vals,

@@ -499,17 +499,26 @@ class _Program:
     leaf arguments and later slots index prior instruction results.  Holding
     no jax.Array references makes the program safe to retain in the compile
     cache without pinning HBM.
+
+    ``live_cuts`` (empty for a program as linearized) are the instruction
+    indices at which a live group ends: ``_build_callable`` holds the
+    values live there behind an ``optimization_barrier``.  They are part
+    of ``key``, so every cache keyed on it tells the two forms apart.
     """
 
-    __slots__ = ("instrs", "n_leaves", "leaf_kinds", "out_slots", "key",
-                 "key_hash")
+    __slots__ = ("instrs", "n_leaves", "leaf_kinds", "out_slots",
+                 "live_cuts", "key", "key_hash")
 
-    def __init__(self, instrs, n_leaves, leaf_kinds, out_slots):
+    def __init__(self, instrs, n_leaves, leaf_kinds, out_slots,
+                 live_cuts=()):
         self.instrs = instrs
         self.n_leaves = n_leaves
         self.leaf_kinds = leaf_kinds
         self.out_slots = tuple(out_slots)
+        self.live_cuts = tuple(live_cuts)
         self.key = (tuple(instrs), n_leaves, leaf_kinds, self.out_slots)
+        if self.live_cuts:
+            self.key += (("live_cuts",) + self.live_cuts,)
         # Hashed at linearize time (the key is part of the capture
         # product) so prepare-side caches keyed on the program pay an
         # O(1) cached hash instead of re-walking the instrs tuple; -1
@@ -518,6 +527,10 @@ class _Program:
             self.key_hash = hash(self.key)
         except TypeError:
             self.key_hash = -1
+
+    @property
+    def live_groups(self) -> int:
+        return len(self.live_cuts) + 1
 
 
 def _linearize(roots: Sequence[Expr]):
@@ -576,6 +589,8 @@ def _linearize(roots: Sequence[Expr]):
 
 
 def _build_callable(program: _Program):
+    if program.live_cuts:
+        return _build_grouped_callable(program)
     instrs = program.instrs
     n_leaves = program.n_leaves
     out_slots = program.out_slots
@@ -583,6 +598,41 @@ def _build_callable(program: _Program):
     def run(*leaf_vals):
         vals = list(leaf_vals)
         for op, static, argslots in instrs:
+            vals.append(OPS[op](static, *(vals[s] for s in argslots)))
+        return tuple(vals[s] for s in out_slots)
+
+    return run
+
+
+def _build_grouped_callable(program: _Program):
+    """The straight-line callable with a bounded live set: at each of
+    ``program.live_cuts`` every value that is live there (made before the
+    cut, read after it or returned; Python scalars apart) goes through
+    ONE ``jax.lax.optimization_barrier``.  XLA may then neither start
+    the next group's work before this group's results exist nor fuse
+    across the cut, so what it holds at once is one group's temporaries
+    and not the whole program's.  Still one jitted program: one
+    executable, one dispatch, the same donation."""
+    instrs = program.instrs
+    n_leaves = program.n_leaves
+    out_slots = program.out_slots
+    last_use = _last_use_map(program)
+    held_at = {}
+    for cut in program.live_cuts:
+        top = n_leaves + cut
+        held_at[cut] = tuple(
+            s for s in range(top)
+            if last_use.get(s, 0) >= top
+            and (s >= n_leaves or program.leaf_kinds[s] == "C"))
+
+    def run(*leaf_vals):
+        vals = list(leaf_vals)
+        for i, (op, static, argslots) in enumerate(instrs):
+            held = held_at.get(i)
+            if held:
+                kept = jax.lax.optimization_barrier([vals[s] for s in held])
+                for s, v in zip(held, kept):
+                    vals[s] = v
             vals.append(OPS[op](static, *(vals[s] for s in argslots)))
         return tuple(vals[s] for s in out_slots)
 
@@ -780,6 +830,89 @@ def _byte_segment_end(instrs, n_leaves, start: int, slot_bytes: dict,
         seg_bytes += cost
         end += 1
     return end
+
+
+def _live_order(program: _Program, slot_bytes: dict) -> list:
+    """A topological order of ``program``'s instructions that keeps few
+    values live: depth-first from the outputs, at every node the operand
+    whose sub-DAG makes the most bytes first (Sethi and Ullman's rule,
+    with the bytes of the distinct instructions below a node for its
+    register need).  ``_linearize`` walks root by root, so a root that is
+    an input of the others (PRK's ten ``A += 1``) is laid down whole
+    before its first reader; this order interleaves it."""
+    instrs, n_leaves = program.instrs, program.n_leaves
+    below = []  # bit j set: instruction j is in the sub-DAG
+    for i, (_op, _st, args) in enumerate(instrs):
+        m = 1 << i
+        for s in args:
+            if s >= n_leaves:
+                m |= below[s - n_leaves]
+        below.append(m)
+    out_bytes = [slot_bytes.get(n_leaves + i, 0) for i in range(len(instrs))]
+    weight = [sum(b for j, b in enumerate(out_bytes[:i + 1]) if m >> j & 1)
+              for i, m in enumerate(below)]
+
+    def heaviest_first(slots):
+        idx = {s - n_leaves for s in slots if s >= n_leaves}
+        return sorted(idx, key=lambda i: (-weight[i], i))
+
+    order, seen = [], set()
+    stack = [(i, False) for i in reversed(heaviest_first(program.out_slots))]
+    while stack:
+        i, done = stack.pop()
+        if done:
+            order.append(i)
+        elif i not in seen:
+            seen.add(i)
+            stack.append((i, True))
+            stack.extend((j, False) for j in
+                         reversed(heaviest_first(instrs[i][2])))
+    return order
+
+
+def _live_grouped(program: _Program, leaf_avals,
+                  groups: int) -> Optional[_Program]:
+    """``program`` in :func:`_live_order`, cut by the byte segmenter into
+    ``groups`` live groups of even estimated bytes (the smallest per-group
+    target that needs no more; where equal sizes let no target give just
+    that many, the next target down: never fewer while a cut is left), as
+    a program whose callable holds the values live at each cut behind a
+    barrier.  Same leaves, same outputs in the same order, so the
+    donation mask carries over.  None when the segmenter finds no cut."""
+    from ramba_tpu.analyze import rules as _rules
+
+    n_leaves = program.n_leaves
+    slot_bytes = _rules.slot_nbytes(program, leaf_avals)
+    order = _live_order(program, slot_bytes)
+    new_slot = {n_leaves + i: n_leaves + k for k, i in enumerate(order)}
+    instrs = tuple(
+        (op, st, tuple(new_slot.get(s, s) for s in args))
+        for op, st, args in (program.instrs[i] for i in order))
+    slot_bytes = {new_slot.get(s, s): b for s, b in slot_bytes.items()}
+
+    def cuts_for(target):
+        cuts, start = [], 0
+        while start < len(instrs):
+            start = _byte_segment_end(instrs, n_leaves, start, slot_bytes,
+                                      target, 0)
+            cuts.append(start)
+        return cuts[:-1]
+
+    lo, hi = 1, max(1, sum(slot_bytes.values()))  # one group fits in hi
+    while lo < hi:
+        mid = (lo + hi) // 2
+        if len(cuts_for(mid)) < groups:
+            hi = mid
+        else:
+            lo = mid + 1
+    cuts = cuts_for(hi)
+    if len(cuts) + 1 < groups and hi > 1:
+        cuts = cuts_for(hi - 1)
+    if not cuts:
+        return None
+    return _Program(instrs, n_leaves, program.leaf_kinds,
+                    [new_slot.get(s, s) for s in program.out_slots],
+                    live_cuts=cuts)
 
 
 def _iter_segments(program: _Program, last_use: dict,
@@ -2020,9 +2153,12 @@ def _flush_dispatch_traced(work: "_FlushWork", *, coalesced: int = 0) -> list:
         if work.detached:
             _revalidate_donation(work)
         t_admit = time.perf_counter()
-        route_chunked = _memory.admit(program, leaf_vals, work.donate_key,
-                                      span, tenant=stream.tenant,
-                                      quota=stream.quota_bytes)
+        # admission may hand back the program with its live set grouped
+        # (same leaves, outputs and donation): every rung runs that one
+        route_chunked, program = _memory.admit(
+            program, leaf_vals, work.donate_key, span,
+            tenant=stream.tenant, quota=stream.quota_bytes)
+        span["live_groups"] = program.live_groups
         _attrib.add_stage(span, "admit", time.perf_counter() - t_admit)
         # Hedged dispatch: when RAMBA_HEDGE_FACTOR is set and the program
         # is effect-certified pure with no donation, a dispatch running

@@ -8,14 +8,21 @@ instead of crashing the program:
     fused  →  split  →  chunked  →  eager  →  host
 
 * **fused**: the normal path — one jit-compiled program (possibly
-  auto-segmented by ``RAMBA_TPU_MAX_PROGRAM_INSTRS``).
+  auto-segmented by ``RAMBA_TPU_MAX_PROGRAM_INSTRS``).  Where admission
+  estimates the program over the HBM watermark as it stands and under it
+  with its live set bounded, this rung runs that form: the same one
+  program, reordered and cut into live groups with the values live at
+  each cut behind an ``optimization_barrier`` (``memory._fit_live_groups``,
+  ``fuser._live_grouped``).  Nothing has failed and nothing is degraded:
+  one executable, one dispatch, the same donation.
 * **split**: the same program re-run through the segmented executor with
   a halved segment size and no leaf donation — smaller XLA programs,
   smaller peak live set.
 * **chunked**: the segmented executor bounded by estimated live *bytes*
   per segment (``fuser._run_chunked`` / ``resilience.memory``) — the
   memory-pressure rung.  Admission control can also start the ladder
-  here directly, before anything has failed.
+  here directly, before anything has failed: for a program that is over
+  the watermark even in live groups and after eviction.
 * **eager**: per-op dispatch with no jit at all.
 * **host**: the whole program interpreted on the CPU backend (device →
   host fallback as a first-class path; only offered single-controller).

@@ -28,9 +28,18 @@ communication" (arXiv:2112.01075) applied to the fuser:
   ``analyze.rules.estimate_peak_bytes``; ``RAMBA_HBM_ESTIMATE=analytic``
   forces the latter).  If ``live + peak`` crosses the watermark
   (``RAMBA_HBM_WATERMARK``, default 0.9 of budget) the governor first
-  evicts spill candidates, then — if still over — routes the flush to the
-  ``chunked`` rung (byte-bounded segments, see ``fuser._run_chunked``)
-  instead of letting it OOM.
+  asks whether the same program fits with its live set bounded
+  (:func:`_fit_live_groups`: ``fuser._live_grouped`` reorders it to keep
+  few values live and cuts it with the byte segmenter into as few live
+  groups as bring its estimate under the watermark less what is resident
+  and not an argument; the values live at each cut go through an
+  ``optimization_barrier``).  That program is estimated the same way and,
+  when it fits, is what :func:`admit` hands back: still ONE jitted
+  program on the ``fused`` rung with the same donation, admitted with
+  ``ok: true`` and no ``watermark`` event.  Only a program that is still
+  over when grouped is evicted for, and then — if still over — routed to
+  the ``chunked`` rung (byte-bounded segments, one executable each, see
+  ``fuser._run_chunked``) instead of letting it OOM.
 * **OOM recovery** — ``retry.classify`` marks real and injected
   ``RESOURCE_EXHAUSTED`` as the distinct ``oom`` class; the ladder calls
   :func:`evict_for_oom` before dropping a rung, so recovery is
@@ -39,7 +48,9 @@ communication" (arXiv:2112.01075) applied to the fuser:
 Everything observable lands on the observe stream: ``memory``-type
 watermark/evict/spill/restore/admit events and the gauges
 ``memory.live_bytes``, ``memory.spilled_bytes``, ``memory.evictions``,
-``memory.admission_rejects``.
+``memory.admission_rejects``, and the counter ``memory.live_grouped``
+(one per flush admitted in live groups; the ``admit`` event and the
+flush span carry the count as ``live_groups``).
 
 Implementation note: expression nodes are normally immutable; the one
 sanctioned mutation in the codebase is the governor swapping a
@@ -567,8 +578,10 @@ def _leaf_avals(leaf_vals) -> list:
 
 def _xla_estimate(program, avals) -> Optional[int]:
     """XLA's own numbers via an AOT lowering (the ``analyze_pending``
-    pattern): argument + output + temp sizes.  Returns None when the
-    backend reports nothing usable (CPU typically reports zeros)."""
+    pattern): argument + output + temp sizes, of the callable the fused
+    rung would jit for ``program`` (barriers and all, when it carries
+    ``live_cuts``).  Returns None when the backend reports nothing usable
+    (CPU typically reports zeros)."""
     import jax
 
     from ramba_tpu.core import fuser as _fuser
@@ -584,6 +597,11 @@ def _xla_estimate(program, avals) -> Optional[int]:
     return total if total > 0 else None
 
 
+def _est_key(program, avals, donate) -> tuple:
+    return (program.key, tuple(donate),
+            tuple((tuple(a.shape), str(a.dtype)) for a in avals))
+
+
 def estimate_program_bytes(program, leaf_vals, donate=()) -> int:
     """Peak device footprint estimate for one linearized program.
 
@@ -594,8 +612,7 @@ def estimate_program_bytes(program, leaf_vals, donate=()) -> int:
     nothing (CPU) or ``RAMBA_HBM_ESTIMATE=analytic`` forces determinism.
     """
     avals = _leaf_avals(leaf_vals)
-    fp = (program.key, tuple(donate),
-          tuple((tuple(a.shape), str(a.dtype)) for a in avals))
+    fp = _est_key(program, avals, donate)
     cached = _est_memo.get(fp)
     if cached is not None:
         return cached
@@ -641,14 +658,63 @@ def _resident_overlap(leaf_vals, tenant: Optional[str] = None) -> int:
     return resident
 
 
+#: Lowerings one admission may spend looking for a grouping that fits:
+#: each is a whole compile of the program.
+_GROUPED_LOWERINGS = 3
+
+
+def _fit_live_groups(program, leaf_vals, donate_key, est: int, room: int):
+    """The same program with its live set bounded (``fuser._live_grouped``)
+    in as few groups as bring its estimate under ``room``, given that as
+    it stands it needs ``est``.  Returns ``(grouped program, its
+    estimate)``, or None when no grouping found fits; memoized beside
+    the estimates, so a steady flush pays a lookup.
+
+    The first count is the share of the room the program overflows by;
+    after a grouping that is still over, the two readings give the part
+    of the estimate that grouping does not shrink (arguments, outputs,
+    the largest single step) and the part that falls as 1/groups, and so
+    the next count, or the verdict that none can fit."""
+    from ramba_tpu.core import fuser as _fuser
+
+    if room <= 0 or len(program.instrs) < 2:
+        return None
+    avals = _leaf_avals(leaf_vals)
+    memo_key = ("live_groups", room) + _est_key(program, avals, donate_key)
+    if memo_key in _est_memo:
+        return _est_memo[memo_key]
+    groups = max(2, -(-est // room))
+    found, n = None, 1
+    for _ in range(_GROUPED_LOWERINGS):
+        grouped = _fuser._live_grouped(program, avals, groups)
+        if grouped is None or grouped.live_groups <= n:
+            break  # no cut left to make
+        n = grouped.live_groups
+        est_n = estimate_program_bytes(grouped, leaf_vals, donate_key)
+        if est_n <= room:
+            found = (grouped, est_n)
+            break
+        shrinks = (est - est_n) * n // (n - 1)
+        fixed = est - shrinks
+        if shrinks <= 0 or fixed >= room:
+            break
+        groups = max(n + 1, -(-shrinks // (room - fixed)))
+    if len(_est_memo) >= _EST_MEMO_MAX:
+        _est_memo.clear()
+    _est_memo[memo_key] = found
+    return found
+
+
 def _admit_budget(program, leaf_vals, donate_key,
-                  span: Optional[dict] = None) -> bool:
+                  span: Optional[dict] = None):
     """The global-budget admission leg (historical ``admit`` body).
-    Returns True to route chunked.  No-op (False) when no budget is
-    known."""
+    Returns ``(route chunked, the program the fused rung runs)``: the
+    program as it stands when its estimate is under the watermark (and
+    when no budget is known: a no-op), else the same program with its
+    live set grouped when that fits, else evict, then route chunked."""
     budget = budget_bytes()
     if budget is None:
-        return False
+        return False, program
     wm = watermark_bytes(budget) or budget
     est = estimate_program_bytes(program, leaf_vals, donate_key)
     # ledger.live already counts this flush's resident leaves; the program
@@ -656,6 +722,16 @@ def _admit_budget(program, leaf_vals, donate_key,
     # are not double-billed.
     resident = _resident_overlap(leaf_vals)
     other = max(0, ledger.live_bytes - resident)
+    if other + est > wm:
+        # before anything is evicted: grouping costs a few array passes,
+        # a spill costs a trip to the host
+        fit = _fit_live_groups(program, leaf_vals, donate_key, est,
+                               wm - other)
+        if fit is not None:
+            if span is not None:
+                span["mem_peak_est_ungrouped"] = est
+            program, est = fit
+            _registry.inc("memory.live_grouped")
     projected = other + est
     if span is not None:
         span["mem_live_bytes"] = ledger.live_bytes
@@ -665,10 +741,10 @@ def _admit_budget(program, leaf_vals, donate_key,
         "type": "memory", "action": "admit", "est_bytes": est,
         "live_bytes": ledger.live_bytes, "projected_bytes": projected,
         "watermark_bytes": wm, "budget_bytes": budget,
-        "ok": projected <= wm,
+        "ok": projected <= wm, "live_groups": program.live_groups,
     })
     if projected <= wm:
-        return False
+        return False, program
     _events.emit({
         "type": "memory", "action": "watermark",
         "over_bytes": projected - wm, "watermark_bytes": wm,
@@ -677,7 +753,7 @@ def _admit_budget(program, leaf_vals, donate_key,
     if projected - freed <= wm:
         if span is not None:
             span["admission"] = "evicted"
-        return False
+        return False, program
     _registry.inc("memory.admission_rejects")
     _registry.gauge("memory.admission_rejects.last_over_bytes",
                     projected - freed - wm)
@@ -688,7 +764,7 @@ def _admit_budget(program, leaf_vals, donate_key,
     })
     if span is not None:
         span["admission"] = "chunked"
-    return True
+    return True, program
 
 
 def _admit_tenant(program, leaf_vals, donate_key, span: Optional[dict],
@@ -725,14 +801,18 @@ def _admit_tenant(program, leaf_vals, donate_key, span: Optional[dict],
 
 def admit(program, leaf_vals, donate_key, span: Optional[dict] = None, *,
           tenant: Optional[str] = None,
-          quota: Optional[int] = None) -> bool:
-    """Pre-flush admission check.  Returns True when the flush should be
-    routed to the ``chunked`` rung — it does not fit under the global
-    watermark even after eviction, OR it would push ``tenant`` past its
-    serving ``quota`` even after evicting that tenant's own cold arrays;
-    False admits the fused path.  The global leg is a no-op (False) when
-    no budget is known; the tenant leg runs whenever a quota is given."""
-    route = _admit_budget(program, leaf_vals, donate_key, span)
+          quota: Optional[int] = None):
+    """Pre-flush admission check.  Returns ``(route, program)``.
+    ``route`` is True when the flush should be routed to the ``chunked``
+    rung — it does not fit under the global watermark, grouped or after
+    eviction, OR it would push ``tenant`` past its serving ``quota`` even
+    after evicting that tenant's own cold arrays; False admits the fused
+    path, for ``program``: the one handed in, or its live-grouped form
+    where only that fits under the watermark.  The global leg is a no-op
+    when no budget is known; the tenant leg runs whenever a quota is
+    given."""
+    asked = program
+    route, program = _admit_budget(program, leaf_vals, donate_key, span)
     if tenant is not None and quota:
         if _admit_tenant(program, leaf_vals, donate_key, span, tenant,
                          int(quota)):
@@ -748,7 +828,16 @@ def admit(program, leaf_vals, donate_key, span: Optional[dict] = None, *,
         if agreed and not route and span is not None:
             span["admission"] = "coherent"
         route = agreed
-    return route
+        # the cuts are program structure too: every rank runs as many
+        # groups as the rank that needs the most
+        groups = _coherence.agree("memory:live_groups",
+                                  program.live_groups, reduce="max")
+        if groups > program.live_groups:
+            from ramba_tpu.core import fuser as _fuser
+
+            program = _fuser._live_grouped(
+                asked, _leaf_avals(leaf_vals), groups) or program
+    return route, program
 
 
 _OOM_BYTES_RE = re.compile(r"(\d{4,})\s*bytes|[Aa]llocating\s+(\d+)")
