@@ -89,18 +89,24 @@ def _count_kernel(kernel: str, path: str, interpret: bool) -> None:
         inc(f"{kernel}.interpret")
 
 
-def note_kernel(kernel: str, path: str, interpret: bool = False) -> None:
+def note_kernel(kernel: str, path: str, interpret: bool = False, *,
+                block_rows=None, grid=None, vmem_limit_bytes=None) -> None:
     """Record that ``kernel`` (e.g. ``"stencil"``) lowered through
     ``path`` (``sharded`` / ``pallas_fast`` / ``pallas_padded`` / ``xla``
     / a Pallas family name), and whether a Pallas kernel on that path
     interprets instead of compiling for the chip.  Called while jax
     traces: counts the path, and leaves a note with the enclosing
-    :func:`collect_kernel_notes`, if any."""
+    :func:`collect_kernel_notes`, if any.  A kernel that sizes its own
+    blocks says what it chose (``block_rows``, ``grid``,
+    ``vmem_limit_bytes``): kept on the note, not counted."""
     _count_kernel(kernel, path, interpret)
     notes = getattr(_kernel_notes, "active", None)
     if notes is not None:
-        notes.append({"kernel": kernel, "path": path,
-                      "interpret": bool(interpret)})
+        note = {"kernel": kernel, "path": path, "interpret": bool(interpret)}
+        chose = {"block_rows": block_rows, "grid": grid,
+                 "vmem_limit_bytes": vmem_limit_bytes}
+        note.update((k, int(v)) for k, v in chose.items() if v is not None)
+        notes.append(note)
 
 
 def replay_kernel_notes(notes) -> None:

@@ -10,11 +10,14 @@ kernel for the hot case: 2-D float stencils on a single TPU chip.
 Design (pallas_guide.md patterns):
 
 * The input is zero-padded by the stencil halo and the lane dimension is
-  rounded up to 128.  The kernel grid walks row slabs; each instance DMAs
-  its slab (rows + halo) from HBM into a VMEM scratch buffer, then evaluates
-  the user's kernel function over *statically shifted* in-VMEM slices — the
-  same trace-the-user-function approach as the XLA path, so arbitrary
-  (including nonlinear) stencil bodies work.
+  rounded up to 128.  The kernel grid walks row slabs; each instance waits
+  for its slab (rows + halo), whose DMA from HBM into one of two VMEM
+  scratch buffers the instance before it started, starts the next slab's,
+  then evaluates the user's kernel function over *statically shifted*
+  in-VMEM slices — the same trace-the-user-function approach as the XLA
+  path, so arbitrary (including nonlinear) stencil bodies work.
+* The padded path sizes its row block from the VMEM it asks Mosaic for
+  (``_padded_block``), and says what it chose on its kernel note.
 * Output blocks are plain VMEM BlockSpecs; borders are zeroed afterwards to
   match sstencil's semantics (the reference writes only indices whose full
   neighborhood is in range).
@@ -38,9 +41,24 @@ from ramba_tpu.ops import pallas_backend as _pallas_backend
 _INTERPRET = os.environ.get("RAMBA_TPU_PALLAS_INTERPRET", "0") not in ("0", "")
 _ENABLED = os.environ.get("RAMBA_TPU_PALLAS", "1") not in ("0", "")
 
-# VMEM working-set budget for slabs + output block (bytes); a v5e core has
-# ~16 MB of VMEM and the runtime needs headroom for double-buffered output.
+# The fast path's VMEM working set (slabs + output block), inside the
+# 16 MiB a kernel gets when it asks for no limit of its own.
 _VMEM_BUDGET = 8 << 20
+
+# The padded path asks for its own limit (_padded_block): at most
+# _VMEM_SHARE of a core's VMEM, which jax names where a chip is attached
+# (pltpu.get_tpu_info); the v5e's 128 MiB (the chip's own refusal says
+# "would exceed memory (size=134217728)", tests/test_chip_smoke.py) stands
+# in where none is.  _VMEM_SLACK is Mosaic's internal scratch beside what
+# the estimate counts.
+_V5E_VMEM = 128 << 20
+_VMEM_SHARE = 0.75
+_VMEM_SLACK = 2 << 20
+# Rows the padded kernel's body evaluates at once, where VMEM allows: the
+# knee of scripts/tpu_stencil_sweep.py on the chip (a 15000^2 f32 sweep:
+# 16 rows 4.85 ms, 32 4.04, 64 3.66, 128 3.50, for 1.7, 3.1, 6.4 and 15 s
+# of Mosaic; PERF.md section 6, PR 27).
+_BLOCK_ROWS = 64
 
 
 def available_local(arrs) -> bool:
@@ -76,10 +94,6 @@ def available(arrs) -> bool:
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
-
-# Fast-path block height (rows per grid step); sweepable for tuning.
-_BH = int(os.environ.get("RAMBA_TPU_STENCIL_BH", "0") or 0)
-
 # Margins of the fast path's VMEM slabs.  RM rows / CM cols of each slab
 # hold halo (or don't-care garbage at the array edges, masked out of the
 # output); 8 and 128 are the TPU sublane/lane tile sizes, which keeps every
@@ -100,21 +114,25 @@ def _fast_eligible(lo, hi, arrs) -> bool:
     )
 
 
-def run(func, lo, hi, slots, arrs, taps=8):
+def run(func, lo, hi, slots, arrs, taps=8, *, _block_rows=None):
     """Evaluate the stencil with a Pallas kernel.  Returns the full-shape
     result with border cells zeroed (sstencil semantics).  Off-TPU the
     kernel automatically falls back to ``interpret=True`` (rather than
     raising from an impossible Mosaic compile), so the CPU suite — and
-    the autotune parity tests — exercise the same code path."""
+    the autotune parity tests — exercise the same code path.
+    ``_block_rows`` is scripts/tpu_stencil_sweep.py's: a candidate block
+    height in place of the derived one."""
     interpret = _INTERPRET or _pallas_backend.interpret_mode()
     if _fast_eligible(lo, hi, arrs):
         _registry.note_kernel("stencil", "pallas_fast", interpret)
-        return _run_fast(func, lo, hi, slots, arrs, taps, interpret)
-    _registry.note_kernel("stencil", "pallas_padded", interpret)
-    return _run_padded(func, lo, hi, slots, arrs, taps, interpret)
+        return _run_fast(func, lo, hi, slots, arrs, taps, interpret,
+                         _block_rows)
+    return _run_padded(func, lo, hi, slots, arrs, taps, interpret,
+                       _block_rows)
 
 
-def _run_fast(func, lo, hi, slots, arrs, taps, interpret=_INTERPRET):
+def _run_fast(func, lo, hi, slots, arrs, taps, interpret=_INTERPRET,
+              block_rows=None):
     """Tiled kernel for aligned shapes: no host-visible padding pass and
     double-buffered HBM->VMEM slab DMA (compute on block i overlaps the
     fetch of block i+1 — the pipelining the reference gets from Numba's
@@ -140,10 +158,10 @@ def _run_fast(func, lo, hi, slots, arrs, taps, interpret=_INTERPRET):
     itemsize = np.dtype(dtype).itemsize
 
     Wi = W + 2 * _CM
-    if _BH:
-        # clamp the override: blocks below _RM rows or off 8-row alignment
+    if block_rows:
+        # clamp a candidate: blocks below _RM rows or off 8-row alignment
         # would put the mid-block DMA start (j*bh - _RM) out of bounds
-        bh = max(_RM, _round_up(_BH, 8))
+        bh = max(_RM, _round_up(block_rows, 8))
     else:
         # VMEM: 2 slabs per input + pipelined out block + ~4 live tap temps.
         rowcost = itemsize * (n_slabs * 2 * Wi + 6 * W)
@@ -279,8 +297,58 @@ def _run_fast(func, lo, hi, slots, arrs, taps, interpret=_INTERPRET):
     )(*arrs)
 
 
-def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET):
-    """General-shape path: halo-pad the input and walk row slabs."""
+def _vmem_cap() -> int:
+    """The most VMEM the padded kernel asks for: a share of one
+    TensorCore's, read from jax where a chip is attached."""
+    if _pallas_backend.interpret_mode():
+        return int(_V5E_VMEM * _VMEM_SHARE)
+    from jax.experimental.pallas import tpu as pltpu
+
+    return int(pltpu.get_tpu_info().vmem_capacity_bytes * _VMEM_SHARE)
+
+
+def _padded_widths(W, halo_c):
+    """(Wo, Wi): lane widths of the output block and of the padded input."""
+    Wo = _round_up(max(W, 128), 128)
+    return Wo, _round_up(Wo + halo_c, 128)
+
+
+def _padded_vmem_bytes(bh, W, itemsize, n_slabs, taps, halo):
+    """What a block of ``bh`` rows asks of VMEM: per input two slabs and
+    the value of the one being read, the output block Pallas
+    double-buffers, and on Mosaic's stack one (bh, Wo) temporary per
+    shifted read plus three of the arithmetic (its refusals read 6 to
+    6.5 of them at 8 taps: PERF.md section 6, PR 27)."""
+    halo_r, halo_c = halo
+    Wo, Wi = _padded_widths(W, halo_c)
+    slab_h = _round_up(bh + halo_r, 8)
+    return itemsize * (3 * n_slabs * slab_h * Wi
+                       + (max(taps, 1) + 5) * bh * Wo) + _VMEM_SLACK
+
+
+def _padded_block(H, W, itemsize, n_slabs, taps, halo):
+    """(rows per block, vmem_limit_bytes) of the padded kernel over an
+    ``(H, W)`` array: ``n_slabs`` inputs, ``taps`` shifted reads,
+    ``halo = (top + bottom, left + right)``.  The body's cost per row
+    falls with the rows it evaluates at once (each unaligned read of bh
+    rows touches bh/8 + 1 sublane tiles) and its temporaries and Mosaic's
+    compile time rise with them: _BLOCK_ROWS where VMEM allows, fewer
+    where the array is wide, never under the 8-row tile."""
+    cap = _vmem_cap()
+
+    def need(bh):
+        return _padded_vmem_bytes(bh, W, itemsize, n_slabs, taps, halo)
+
+    bh = min(_BLOCK_ROWS, _round_up(H, 8))
+    while bh > 8 and need(bh) > cap:
+        bh -= 8
+    return bh, min(cap, need(bh))
+
+
+def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET,
+                block_rows=None):
+    """General-shape path: halo-pad the input and walk row slabs, the
+    fetch of slab i+1 under the compute of slab i."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -290,23 +358,26 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET):
     top, left = -lo[0], -lo[1]
     bottom, right = hi[0], hi[1]
     halo_r = top + bottom
-
-    Wo = _round_up(max(W, 128), 128)
-    Wi = _round_up(Wo + left + right, 128)
-
-    # Rows per output block within the VMEM budget.  Mosaic materializes
-    # one (bh, Wo) temporary per shifted-slice read on its VMEM stack, so
-    # the working set is ~ (taps + double-buffered out) output-width blocks
-    # plus the input slabs.
-    itemsize = np.dtype(dtype).itemsize
     n_slabs = len(arrs)
-    denom = itemsize * (n_slabs * Wi + (max(taps, 1) + 3) * Wo)
-    bh = max(8, min(512, (_VMEM_BUDGET // denom - halo_r) // 8 * 8))
+
+    Wo, Wi = _padded_widths(W, left + right)
+    if block_rows:
+        # the sweep's candidate, under the cap itself
+        bh, vmem_limit = _round_up(block_rows, 8), _vmem_cap()
+    else:
+        bh, vmem_limit = _padded_block(
+            H, W, np.dtype(dtype).itemsize, n_slabs, taps,
+            (halo_r, left + right))
     grid = -(-H // bh)
     Ho = grid * bh
+    _registry.note_kernel("stencil", "pallas_padded", interpret,
+                          block_rows=bh, grid=grid,
+                          vmem_limit_bytes=vmem_limit)
 
     # Mosaic requires HBM slices 8-aligned in the sublane dim: round the
     # slab height up and pad the input tail to cover the extra rows read.
+    # The input stays padded to Ho + halo + extra rows, so every block's
+    # copy is the one static (slab_h, Wi) shape at row i*bh.
     slab_h = _round_up(bh + halo_r, 8)
     extra = slab_h - (bh + halo_r)
 
@@ -318,34 +389,59 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET):
     padded = [pad(a) for a in arrs]
 
     def _kernel_body(*refs):
-        # refs: n_slabs HBM inputs, out_ref, n_slabs VMEM scratch, 1 sem
+        # refs: n_slabs HBM inputs, out_ref, n_slabs (2, slab_h, Wi) VMEM
+        # slabs, one (2, n_slabs) array of DMA semaphores
         ins = refs[:n_slabs]
         out_ref = refs[n_slabs]
         slabs = refs[n_slabs + 1: 2 * n_slabs + 1]
-        sem = refs[-1]
+        sems = refs[-1]
         i = pl.program_id(0)
-        for k in range(n_slabs):
+        cur = jax.lax.rem(i, jnp.asarray(2, i.dtype))
+
+        def copies(j, b):
             # bh is a static multiple of 8: expose that to Mosaic's
             # divisibility prover (same class of refusal as in _run_fast)
-            rs = pl.multiple_of(i * (bh // 8) * 8, 8)
-            cp = pltpu.make_async_copy(
-                ins[k].at[pl.ds(rs, slab_h), :], slabs[k], sem
-            )
-            cp.start()
+            rs = pl.multiple_of(j * (bh // 8) * 8, 8)
+            return [
+                pltpu.make_async_copy(
+                    ins[k].at[pl.ds(rs, slab_h), :], slabs[k].at[b],
+                    sems.at[b, k])
+                for k in range(n_slabs)
+            ]
+
+        @pl.when(i == 0)
+        def _():
+            for cp in copies(i, cur):
+                cp.start()
+
+        if grid > 1:
+            @pl.when(i + 1 < grid)
+            def _():
+                for cp in copies(i + 1, 1 - cur):
+                    cp.start()
+
+        for cp in copies(i, cur):
             cp.wait()
 
         from ramba_tpu.skeletons import _KVal, call_stencil_body
 
         class _Shift:
+            """Shifted reads as slices of the slab's value, loaded once:
+            as fast on the chip as slicing the ref per read, and half
+            the Mosaic compile (PERF.md section 6, PR 27)."""
+
             def __init__(self, ref, wrap_vals):
                 self.ref = ref
                 self.wrap_vals = wrap_vals
+                self.slab = None
 
             def __getitem__(self, off):
                 if not isinstance(off, tuple):
                     off = (off,)
                 di, dj = off
-                piece = self.ref[
+                if self.slab is None:
+                    self.slab = self.ref[cur]
+                piece = self.slab[
                     top + di: top + di + bh, left + dj: left + dj + Wo
                 ]
                 return _KVal(piece) if self.wrap_vals else piece
@@ -381,9 +477,10 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET):
         out_specs=pl.BlockSpec((bh, Wo), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=(
-            [pltpu.VMEM((slab_h, Wi), dtype)] * n_slabs
-            + [pltpu.SemaphoreType.DMA]
+            [pltpu.VMEM((2, slab_h, Wi), dtype)] * n_slabs
+            + [pltpu.SemaphoreType.DMA((2, n_slabs))]
         ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name="ramba_stencil_padded",
     )(*padded)

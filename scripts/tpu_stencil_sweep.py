@@ -1,21 +1,32 @@
 """Pallas stencil block-height sweep on the chip (ROADMAP S4).
 
-PRK star stencil r=2 at 8192^2 f32, one configuration per FRESH process
-(the structure-keyed compile cache and leftover HBM buffers make
-in-process config toggling invalid), run in sequence by this parent, which
-never imports jax: a chip belongs to one process at a time.  Sweeps
-RAMBA_TPU_STENCIL_BH x {auto, 64, 128, 256, 512} plus the XLA
-shifted-slice path (RAMBA_TPU_PALLAS=0) and a bf16-input variant (half the
-HBM traffic).  Each worker refuses to run off the TPU and checks, from the
-flush span, that the stencil took the path its configuration names.
+PRK star stencil r=2, one FRESH process per configuration (a shape, a
+dtype and the path it has to take), run in sequence by this parent, which
+never imports jax: a chip belongs to one process at a time.  Inside a
+process every candidate height is its own jitted chain of sweeps, the
+kernel called directly (``stencil_pallas.run``) with the candidate handed
+over through the kernel's private keyword; ``None`` is the height the
+kernel derives, which is what ships.  The configurations:
+
+    8192^2  f32, bf16   pallas_fast    derived, 16, 32
+    8192^2  f32         xla            the shifted-slice path
+    15000^2, 13504^2, 15004^2  f32  pallas_padded  derived, 16, 32, 64, 128
+
+(the three padded shapes are what the benchmark's star cells hand the
+kernel per chip).  Each worker refuses to run off the TPU and checks, from
+the kernel's own note, that the stencil took the path its configuration
+names, at the height asked for.
 
 Run it through the chip tool, one call:
 
     python scripts/tpu_stencil_sweep.py
 
 Prints one JSON object, also written to chiprun_out/stencil_sweep.json;
-exits non-zero if any configuration failed.  Times are host-clock walls of
-a 30-sweep chain ending in a scalar fetch, on the device the JSON names.
+exits non-zero if any candidate failed.  ``kernel_ms`` is the device time
+of the Pallas custom calls of one sweep, from a profiler trace of one
+chain; ``device_ms`` that of every op of the sweep (the padded path's
+``pad`` with it); ``wall_ms`` the host's clock over the chain, median of
+5; all on the device the JSON names.
 """
 
 from __future__ import annotations
@@ -29,106 +40,156 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _WORKER_SRC = r"""
-import json, os, sys, time
+import glob, json, os, sys, tempfile, time
 
-sys.path.insert(0, os.environ["RAMBA_SWEEP_REPO"])
+cfg = json.loads(sys.argv[1])
+sys.path.insert(0, cfg["repo"])
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 dev = jax.devices()[0]
 if dev.platform != "tpu":
     sys.exit(f"stencil sweep: no TPU (first device is {dev.platform})")
 import ramba_tpu as rt
-
-dtype = os.environ.get("RAMBA_SWEEP_DTYPE", "float32")
-want_path = os.environ["RAMBA_SWEEP_PATH"]
+from ramba_tpu import skeletons
+from ramba_tpu.observe import registry
+from ramba_tpu.ops import stencil_pallas
 
 @rt.stencil
 def star2(a):
     return (0.25 * (a[0, 1] + a[0, -1] + a[1, 0] + a[-1, 0])
             + 0.125 * (a[0, 2] + a[0, -2] + a[2, 0] + a[-2, 0]))
 
-sn = 8192
-x = rt.fromarray(np.random.RandomState(0).rand(sn, sn).astype(dtype))
-rt.sync()
-sk = 30
+sn, dtype, want_path, sk = cfg["n"], cfg["dtype"], cfg["path"], 10
+slots = (("arr", 0),)
+lo, hi, taps = star2.neighborhood(slots)
+x = jnp.asarray(np.random.RandomState(0).rand(sn, sn), dtype=dtype)
 
-def chain():
-    y = x
-    for _ in range(sk):
-        y = rt.sstencil(star2, y)
-    s = rt.sum(y)
-    t0 = time.perf_counter()
-    float(s)
-    return time.perf_counter() - t0
+def device_ns(tdir):
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(tdir, "plugins/profile/*/*.xplane.pb"))
+    every = kernel = 0
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:TPU:0"):
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    for e in line.events:
+                        every += e.duration_ns
+                        if "custom-call(" in e.name:
+                            kernel += e.duration_ns
+    return every, kernel
 
-chain()  # trace + compile
-span = rt.diagnostics.last_flushes(1)[0]
-paths = sorted({k["path"] for k in span.get("kernels", ())})
-assert paths == [want_path], (paths, want_path)
-assert "degraded" not in span and not rt.diagnostics.resilience_events()
-walls = sorted(chain() / sk for _ in range(5))
-wall = walls[len(walls) // 2]
-print(json.dumps({
-    "per_iter_ms_median": wall * 1e3, "samples": len(walls),
-    "per_iter_ms_all": [w * 1e3 for w in walls],
-    "mflops": 13 * (sn - 4) * (sn - 4) / wall / 1e6,
-    "gb_per_s": 2 * sn * sn * np.dtype(dtype).itemsize / wall / 1e9,
-    "path": paths[0], "device_kind": dev.device_kind,
-    "device_count": len(jax.devices()),
-}), flush=True)
+def sweep(y, rows):
+    if want_path == "xla":
+        v = skeletons.stencil_interior(star2.func, lo, hi, slots, [y])
+        return jnp.zeros_like(y).at[2:-2, 2:-2].set(v)
+    return stencil_pallas.run(star2.func, lo, hi, slots, [y], taps,
+                              _block_rows=rows)
+
+for rows in cfg["rows"]:
+    row = {"block_rows_asked": rows}
+    try:
+        def chain(y, rows=rows):
+            for _ in range(sk):
+                y = sweep(y, rows)
+            return y
+        t0 = time.perf_counter()
+        with registry.collect_kernel_notes() as notes:
+            run = jax.jit(chain).lower(x).compile()
+        row["compile_s"] = time.perf_counter() - t0
+        if want_path != "xla":
+            assert {n["path"] for n in notes} == {want_path}, notes
+            assert not any(n["interpret"] for n in notes), notes
+            row.update({k: notes[0][k] for k in
+                        ("block_rows", "grid", "vmem_limit_bytes")
+                        if k in notes[0]})
+            assert rows is None or row.get("block_rows", rows) == rows, row
+        jax.block_until_ready(run(x))
+        walls = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(x))
+            walls.append((time.perf_counter() - t0) / sk * 1e3)
+        with tempfile.TemporaryDirectory() as tdir:
+            with jax.profiler.trace(tdir):
+                jax.block_until_ready(run(x))
+            every, kernel = device_ns(tdir)
+        itemsize = np.dtype(dtype).itemsize
+        per = (kernel or every) / sk / 1e9
+        row.update({
+            "wall_ms": sorted(walls)[2], "device_ms": every / sk / 1e6,
+            "kernel_ms": kernel / sk / 1e6,
+            "gpoints_per_s": sn * sn / per / 1e9,
+            "gb_per_s": 2 * sn * sn * itemsize / per / 1e9,
+        })
+    except Exception as e:
+        lines = f"{type(e).__name__}: {e}".splitlines()
+        row["error"] = " | ".join(lines)[:600]
+    print(json.dumps(row), flush=True)
+print(json.dumps({"device_kind": dev.device_kind,
+                  "device_count": len(jax.devices())}), flush=True)
 """
 
+_PADDED_ROWS = [None, 16, 32, 64, 128]
 CONFIGS = [
-    ("bh_auto", "pallas_fast", {}),
-    ("bh_64", "pallas_fast", {"RAMBA_TPU_STENCIL_BH": "64"}),
-    ("bh_128", "pallas_fast", {"RAMBA_TPU_STENCIL_BH": "128"}),
-    ("bh_256", "pallas_fast", {"RAMBA_TPU_STENCIL_BH": "256"}),
-    ("bh_512", "pallas_fast", {"RAMBA_TPU_STENCIL_BH": "512"}),
-    ("xla_path", "xla", {"RAMBA_TPU_PALLAS": "0"}),
-    ("bf16_auto", "pallas_fast", {"RAMBA_SWEEP_DTYPE": "bfloat16"}),
+    ("fast_8192", {"n": 8192, "dtype": "float32", "path": "pallas_fast",
+                   "rows": [None, 16, 32]}),
+    ("fast_8192_bf16", {"n": 8192, "dtype": "bfloat16",
+                        "path": "pallas_fast", "rows": [None]}),
+    ("xla_8192", {"n": 8192, "dtype": "float32", "path": "xla",
+                  "rows": [None]}),
+    ("padded_15000", {"n": 15000, "dtype": "float32",
+                      "path": "pallas_padded", "rows": _PADDED_ROWS}),
+    ("padded_13504", {"n": 13504, "dtype": "float32",
+                      "path": "pallas_padded", "rows": _PADDED_ROWS}),
+    ("padded_15004", {"n": 15004, "dtype": "float32",
+                      "path": "pallas_padded", "rows": _PADDED_ROWS}),
 ]
 
 
-def _run(path, env_extra, timeout_s):
-    env = dict(os.environ, RAMBA_SWEEP_REPO=REPO, RAMBA_SWEEP_PATH=path,
-               **env_extra)
+def _run(cfg, timeout_s):
+    arg = json.dumps(dict(cfg, repo=REPO))
     try:
-        r = subprocess.run([sys.executable, "-c", _WORKER_SRC],
-                           capture_output=True, text=True,
-                           timeout=timeout_s, env=env)
+        r = subprocess.run([sys.executable, "-c", _WORKER_SRC, arg],
+                           capture_output=True, text=True, timeout=timeout_s)
     except subprocess.TimeoutExpired:
         return {"error": f"timed out after {timeout_s:.0f}s"}
-    if r.returncode == 0:
-        return json.loads(r.stdout.strip().splitlines()[-1])
+    rows = [json.loads(ln) for ln in r.stdout.splitlines()
+            if ln.startswith("{")]
+    if r.returncode == 0 and rows:
+        return dict(rows[-1], candidates=rows[:-1])
     # the line that names the exception, and what follows it
     lines = ((r.stderr or "") + (r.stdout or "")).strip().splitlines()
     named = [i for i, ln in enumerate(lines) if "Error" in ln.split(":")[0]]
     tail = lines[named[-1]:] if named else lines[-3:]
-    return {"error": f"rc={r.returncode} " + " | ".join(tail)[:1200]}
+    return {"error": f"rc={r.returncode} " + " | ".join(tail)[:1200],
+            "candidates": rows}
 
 
-def main() -> int:
-    per_cfg = float(os.environ.get("RAMBA_SWEEP_CFG_TIMEOUT", "600"))
+def main(argv) -> int:
+    """``argv``: names of configurations to run (default: all)."""
+    per_cfg = float(os.environ.get("RAMBA_SWEEP_CFG_TIMEOUT", "900"))
+    unknown = set(argv) - {name for name, _ in CONFIGS}
+    if unknown:
+        sys.exit(f"stencil sweep: no configuration named {sorted(unknown)}")
     out = {"configs": {},
            "recorded_at": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())}
-    for name, path, env in CONFIGS:
-        out["configs"][name] = _run(path, env, per_cfg)
-        print(f"{name}: {out['configs'][name]}", file=sys.stderr, flush=True)
-    scored = {k: v["mflops"] for k, v in out["configs"].items()
-              if "mflops" in v and not k.startswith("bf16")}
-    if scored:
-        best = max(scored, key=scored.get)
-        out["best"] = {"config": best, "mflops": scored[best]}
-    failed = sorted(k for k, v in out["configs"].items() if "error" in v)
-    out["failed"] = failed
+    for name, cfg in CONFIGS:
+        if argv and name not in argv:
+            continue
+        got = out["configs"][name] = dict(_run(cfg, per_cfg), **cfg)
+        print(f"{name}: {got}", file=sys.stderr, flush=True)
+    out["failed"] = sorted(
+        name for name, got in out["configs"].items()
+        if "error" in got or any("error" in c for c in got["candidates"]))
     os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
     with open(os.path.join(REPO, "chiprun_out", "stencil_sweep.json"),
               "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
-    return 1 if failed else 0
+    return 1 if out["failed"] else 0
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))

@@ -141,7 +141,7 @@ class _XlaError(RuntimeError):
     "(size=134217728) :: #allocation2 [shape = 'u8[268435456]{0}', "
     "space=vmem, size = 0x10000000, tag = 'input window allocation for "
     "operator input 0.'] :: tpu_custom_call.1",
-    # same chip, the fast stencil kernel at RAMBA_TPU_STENCIL_BH=64, 8192^2
+    # same chip, the fast stencil kernel at a 64-row block, 8192^2 (PR 21)
     "Scoped allocation with size 20.91M and limit 16.00M exceeded scoped "
     "vmem limit by 4.91M. It should not be possible to run out of scoped "
     "vmem",

@@ -185,6 +185,14 @@ class TestShardedStencil:
         x = np.random.RandomState(7).rand(48, 64).astype(np.float32)
         out = rt.sstencil(_star2(), rt.fromarray(x)).asarray()
         np.testing.assert_allclose(out, _star2_numpy(x), rtol=1e-5, atol=1e-6)
+        # the flush that traced says what the kernel chose for the local,
+        # halo-extended block, beside the sharded note
+        notes = rt.diagnostics.last_flushes(1)[0]["kernels"]
+        assert {k["path"] for k in notes} == {"sharded", "pallas_padded"}
+        for k in notes:
+            if k["path"] == "pallas_padded":
+                assert k["block_rows"] % 8 == 0 and k["grid"] >= 1
+                assert k["vmem_limit_bytes"] > 0
 
 
 class TestShardedStencilND:
