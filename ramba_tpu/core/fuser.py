@@ -1072,8 +1072,8 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
     record in the flush span.  Used by both the monolithic and segmented
     flush paths so the two can never drift."""
     # Attribution clock starts at call entry — BEFORE the fault hooks — so
-    # an injected execute delay lands in the sentinel's device window
-    # exactly like a real device slowdown.
+    # an injected execute delay lands in the span's compile/dispatch
+    # stage exactly like a real host slowdown.
     t_call = time.perf_counter()
     _faults.check("execute", instrs=len(program.instrs))
     _faults.check("oom", instrs=len(program.instrs))
@@ -1111,11 +1111,8 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
     fence_dt = None
     # Cheap device fence: dt above stays the dispatch-time measurement
     # every existing consumer sees; the fence window is the on-device
-    # tail the stage ledger files as device_execute.  Under
-    # RAMBA_ATTRIB=sample:<N> the fence fires 1-in-N calls per
-    # fingerprint (deterministic — see attrib.fence_decision), so the
-    # steady state stops paying the serialization tax on every flush.
-    if _attrib.fence_decision(fp, span) or _ledger.sync_timing():
+    # tail the stage ledger files as device_execute.
+    if _attrib.fence_enabled() or _ledger.sync_timing():
         # a device failure surfaces here (dispatch is asynchronous) and
         # belongs to this attempt: let the ladder classify it
         with _profile.flush_annotation("fence", span):
@@ -1144,20 +1141,6 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
             donated=donated, sync_seconds=sync_dt,
             tenant=current_tenant(), backend=backend,
         )
-        if fence_dt is not None and not is_new:
-            # steady-state fenced window (entry through fence) feeds the
-            # roofline device-time estimate and the drift sentinel
-            _attrib.record_device(fp, _program_label(program),
-                                  time.perf_counter() - t_call,
-                                  backend=backend)
-        elif fence_dt is None and not is_new and _attrib.sampling():
-            # unfenced sampled call: carry the rolling fenced p50 as an
-            # estimate on the span (display-only — never a stage, the
-            # device tail genuinely overlaps the host here)
-            est = _attrib.estimated_device_s(fp)
-            if est is not None and span is not None:
-                span["device_est_s"] = round(
-                    span.get("device_est_s", 0.0) + est, 6)
     if span is not None:
         if is_new:
             # first call pays trace+lower+XLA compile; the pre-call
@@ -2098,7 +2081,7 @@ def _flush_dispatch(work: "_FlushWork", *, coalesced: int = 0) -> list:
 
     The whole stage runs inside the flush span's trace scope, so every
     event emitted underneath — degrade rungs, memory admissions/rejects,
-    watchdog stalls, barrier spans, slow_flush verdicts — is auto-stamped
+    watchdog stalls, barrier spans — is auto-stamped
     as a child of this flush (observe/telemetry.py)."""
     span = work.span
     with _telemetry.span_scope(span.get("trace_id"), span.get("span_id")):
@@ -2279,10 +2262,7 @@ def _flush_dispatch_traced(work: "_FlushWork", *, coalesced: int = 0) -> list:
                           time.perf_counter() - t_writeback)
         _attrib.finalize_span(span, fp=work.fingerprint)
         _events.emit(span)
-        # Slow-flush sentinel: compares this flush against the program's
-        # own rolling history and emits at most one slow_flush event
-        # (after the span, so the trace reads cause-then-verdict).
-        _ledger.observe_flush(span)
+        _ledger.record_flush_wall(span)
         _slo.observe_span(span)
         _elastic.note_progress("flush")
     return list(outs[len(roots):])

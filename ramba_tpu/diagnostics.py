@@ -40,10 +40,10 @@ def identity() -> dict:
     ``schema_version`` versions the contract the rest of the snapshot
     follows.  Stamped onto every snapshot, flight-recorder dump, and
     fleet spool file so federated tooling can join/dedup replicas."""
+    # never force the jax import from the observability plane
+    jax = sys.modules.get("jax")
     try:
-        from ramba_tpu.observe import attrib as _attrib
-
-        kind = _attrib.device_kind()
+        kind = jax.devices()[0].device_kind if jax is not None else None
     except Exception:
         kind = None
     rank, nprocs = _events.rank_info()
@@ -100,8 +100,7 @@ def perf_report() -> dict:
     (count/total/min/max/p50/p95), bytes in/out, cache hit/miss/evict,
     per-degradation-rung execution counts, XLA cost_analysis flops and
     bytes-accessed when captured — plus per-program flush wall-time
-    windows and the slow-flush sentinel tally.  This is the capture
-    format ``scripts/perf_diff.py`` compares.  When the backend
+    windows.  When the backend
     autotuner is active (or has latched decisions), an ``autotune``
     section reports its mode, decision table, and race overhead.  When
     compile classes or the persistent AOT cache are in play, a
@@ -358,8 +357,6 @@ def report(file=None) -> None:
             if k.get("flops") is not None:
                 line += f" flops={k['flops']:.3g}"
             print(line, file=file)
-        if perf["slow_flushes"]:
-            print(f"  slow flushes: {perf['slow_flushes']}", file=file)
     comp = perf.get("compile")
     if comp:
         print("-- compile --", file=file)
@@ -391,33 +388,6 @@ def report(file=None) -> None:
         print(f"  flushes={attr['flushes']} {stages}"
               f" unattributed={attr['unattributed_s']:.4f}s"
               f" ({attr['unattributed_frac']:.1%})", file=file)
-        peaks = attr["peaks"]
-        print(f"  device_kind={attr['device_kind'] or '?'} peaks="
-              + (f"{peaks['peak_gbps']:g}GB/s/{peaks['peak_tflops']:g}TFLOPs"
-                 f" ({peaks['source']})" if peaks
-                 else "unknown (no roofline)"), file=file)
-        roofs = sorted(attr["rooflines"].items(),
-                       key=lambda kv: kv[1]["frac_of_peak"], reverse=True)[:8]
-        for fp, r in roofs:
-            print(f"  {fp} {r['label']:<18s} {r['bound']:<9s}"
-                  f" peak={r['frac_of_peak']:.2%}"
-                  f" bw={r['achieved_gb_per_s']:g}GB/s"
-                  f" fl={r['achieved_tflops']:g}TFLOPs"
-                  f" dev_p50={r['device_p50_s']:.6f}s"
-                  f" ({r['device_time_source']})", file=file)
-        sen = attr["sentinel"]
-        if sen["regressions"] or sen["baselines"]:
-            print(f"  sentinel baselines={sen['baselines']}"
-                  f" regressions={sen['regressions']}"
-                  f" factor={sen['drift_factor']:g}", file=file)
-        samp = attr.get("sampling")
-        if samp:
-            fenced = sum(len(d.get("fenced_seqs", []))
-                         for d in samp.get("fingerprints", {}).values())
-            calls = sum(d.get("calls", 0)
-                        for d in samp.get("fingerprints", {}).values())
-            print(f"  sampling 1-in-{samp['sample_every']}"
-                  f" fenced={fenced}/{calls} calls", file=file)
     obs = observer_report()
     if obs.get("components"):
         print("-- observer tax --", file=file)

@@ -6,8 +6,8 @@ rebuild's production posture on top of those seeds: every flush emits a
 structured span (``events``), every subsystem increments named counters in
 one registry (``registry``), hardware bring-up lands health records in the
 same stream (``health``), every compiled kernel accumulates a cost ledger
-entry feeding a slow-flush sentinel (``ledger``), and ``RAMBA_PROFILE_DIR``
-lines the whole thing up with jax.profiler/Perfetto traces (``profile``).
+entry (``ledger``), and ``RAMBA_PROFILE_DIR`` lines the whole thing up
+with jax.profiler/Perfetto traces (``profile``).
 
 Environment variables:
 
@@ -21,31 +21,21 @@ Environment variables:
   / ``.run`` / ``.fence`` (program label and span trace id as arguments)
   and the counted work outside the span as ``ramba.dag.infer``,
   ``ramba.read`` and ``ramba.observe.tail`` (``profile``).
-* ``RAMBA_PERF`` — ``1`` adds XLA cost_analysis capture per kernel and the
-  ``kernels`` section in bench.py; ``sync`` also records synchronized
-  execution timing.  The ledger itself is always on.
-* ``RAMBA_SLOW_FLUSH_FACTOR`` / ``RAMBA_SLOW_FLUSH_MIN_SAMPLES`` /
-  ``RAMBA_PERF_WINDOW`` — slow-flush sentinel tuning (see ``ledger``).
+* ``RAMBA_PERF`` — ``1`` adds XLA cost_analysis capture per kernel;
+  ``sync`` also records synchronized execution timing.  The ledger
+  itself is always on.
+* ``RAMBA_PERF_WINDOW`` — length of the ledger's rolling windows
+  (see ``ledger``).
 * ``RAMBA_ATTRIB=off`` — disable the always-on ``block_until_ready``
-  device fence the stage waterfalls and rooflines use (``attrib``).
-* ``RAMBA_ATTRIB=sample:<N>`` — fence only 1-in-N flushes per kernel
-  fingerprint (deterministic: the fingerprint's flush sequence number,
-  never RNG, so SPMD ranks fence in lockstep); unfenced flushes carry
-  ``device_source:"estimated"`` from the rolling fenced p50, rooflines
-  and sentinels consume fenced samples only.
+  device fence the stage waterfalls use (``attrib``).
 * ``RAMBA_TRACE_SAMPLE=<N>`` — head-sample the JSONL trace file to
   1-in-N trace chains (the in-memory ring stays full-fidelity); chains
-  that end in an incident (slow_flush, flush_error, shed, degrade,
-  stall, integrity, slo_breach, perf_regression) retroactively flush
-  their buffered span chain — the tail latch (``events``).
+  that end in an incident (flush_error, shed, degrade, stall,
+  integrity, slo_breach) retroactively flush their buffered span
+  chain — the tail latch (``events``).
 * ``RAMBA_TRACE_BUFFER=<n>`` — pending-line bound of the buffered trace
   writer (default 2048); overflow drops lines and counts
   ``events.write_dropped`` instead of blocking the flush path.
-* ``RAMBA_PEAKS_JSON`` — hardware-peak table override (inline JSON or a
-  file path) for the roofline ledger.
-* ``RAMBA_BASELINE_DIR`` / ``RAMBA_PERF_DRIFT_FACTOR`` /
-  ``RAMBA_PERF_DRIFT_MIN_SAMPLES`` — perf-regression sentinel: persisted
-  per-kernel device-time baselines and the drift trip point.
 * ``RAMBA_FLEET_DIR`` — fleet snapshot spool: publish an atomic versioned
   ``diagnostics.snapshot()`` document to ``<dir>/<host>-<pid>-<rank>.json``
   every ``RAMBA_FLEET_INTERVAL_S`` seconds (default 5); the collector in
@@ -55,8 +45,8 @@ Environment variables:
 
 Every observability code path self-accounts its own wall time in
 ``observer`` (the observer-tax ledger): exported as
-``ramba_observer_seconds_total{component}`` and gated in bench/perf_diff
-as ``observer_tax_frac`` (< 2 % of flush wall at ``sample:16``).
+``ramba_observer_seconds_total{component}`` and
+``ramba_observer_tax_frac``.
 
 Public read API lives in ``ramba_tpu.diagnostics`` (``perf_report()`` for
 the ledger, including the ``attribution`` section); the fleet-level read

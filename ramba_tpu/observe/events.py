@@ -29,12 +29,11 @@ full-fidelity, but the file lane head-samples 1-in-N *traces* — the
 verdict is a deterministic hash of the ``trace_id`` (identical on every
 rank), so a sampled-out trace is sampled out everywhere.  Sampled-out
 events park in a bounded per-trace buffer; if the chain later hits an
-incident (``TAIL_TRIGGERS``: slow_flush / flush_error / shed / degrade /
-stall / integrity / slo_breach / perf_regression) the buffer is
-retroactively flushed and the trace latched in — incidents are always
-fully traced, steady-state traffic costs 1/N the bytes.  A rotated
-buffer leaves a ``trace_gap`` marker so trace_report can tell a
-sampling gap from a genuine orphan.
+incident (``TAIL_TRIGGERS``: flush_error / shed / degrade / stall /
+integrity / slo_breach) the buffer is retroactively flushed and the
+trace latched in — incidents are always fully traced, steady-state
+traffic costs 1/N the bytes.  A rotated buffer leaves a ``trace_gap``
+marker so trace_report can tell a sampling gap from a genuine orphan.
 
 Two injection points keep this module import-light while letting the
 telemetry plane (observe/telemetry.py) see every event:
@@ -96,8 +95,8 @@ _PENDING_MAX = _env_int("RAMBA_TRACE_BUFFER", 2048)
 
 # -- tail-based retention ----------------------------------------------------
 # Incident types that latch a sampled-out trace into the file lane.
-TAIL_TRIGGERS = ("slow_flush", "flush_error", "shed", "degrade", "stall",
-                 "integrity", "slo_breach", "perf_regression")
+TAIL_TRIGGERS = ("flush_error", "shed", "degrade", "stall", "integrity",
+                 "slo_breach")
 _trace_sample = _env_int("RAMBA_TRACE_SAMPLE", 1)
 _TAIL_SPANS = 64        # buffered events per sampled-out trace
 _TAIL_TRACES_MAX = 256  # distinct sampled-out traces buffered at once

@@ -1,7 +1,7 @@
 """Fleet observability federation: snapshot spool, collector, health model.
 
 Every observability surface below this module is process-local — the
-counters registry, the kernel/roofline ledger, SLO histograms, the
+counters registry, the kernel ledger, SLO histograms, the
 overload plane, heartbeat liveness all describe ONE process.  A serving
 fleet of N replicas is N blind silos until something federates them.
 This module is that something, in three pieces:
@@ -44,8 +44,7 @@ The health dict is exactly the input the ROADMAP-3 router consumes:
 "counts": {...}, "fleet_state": worst}``.  :func:`rollup` aggregates the
 same spool into fleet-level numbers: merged per-tenant SLO percentiles
 (fixed-bucket histograms merge by addition — ``slo.merge_summaries``),
-fleet goodput, a cross-replica memo/compile/AOT hit-rate comparison, and
-the fleet's worst kernels by roofline fraction-of-peak.
+fleet goodput and a cross-replica memo/compile/AOT hit-rate comparison.
 
 **Prometheus federation.**  :func:`render` emits the fleet rollup in
 text exposition format with a ``replica`` label on every per-replica
@@ -455,9 +454,7 @@ def rollup(directory: Optional[str] = None,
       reconciliation invariant the fleet suite leg asserts),
     * ``caches``: per-replica memo / jit-cache / persistent-AOT hit
       rates side by side — one replica compiling what the others serve
-      from cache is the federated-warm-start smell,
-    * ``rooflines``: the fleet's worst kernels by fraction-of-peak with
-      the replica that reported them.
+      from cache is the federated-warm-start smell.
     """
     d = directory or fleet_dir()
     _h, docs = _ingest(d, _load_entries(d), now=now)
@@ -531,25 +528,9 @@ def _rollup_of(d: Optional[str], docs: dict) -> dict:
         row["aot_misses"] = int(persist.get("misses", 0))
         caches[rep] = row
 
-    # -- worst rooflines -----------------------------------------------------
-    worst = []
-    for rep, doc in docs.items():
-        roofs = (doc.get("diagnostics", {}).get("perf", {})
-                 .get("attribution", {}).get("rooflines", {}) or {})
-        for fp, row in roofs.items():
-            frac = row.get("frac_of_peak")
-            if isinstance(frac, (int, float)):
-                worst.append({
-                    "replica": rep, "fingerprint": fp,
-                    "label": row.get("label", "?"),
-                    "bound": row.get("bound", "?"),
-                    "frac_of_peak": frac,
-                })
-    worst.sort(key=lambda r: r["frac_of_peak"])
-
     return {"dir": d, "replicas": sorted(docs),
             "slo": slo_merged, "goodput": goodput,
-            "caches": caches, "rooflines": worst[:16]}
+            "caches": caches}
 
 
 # ---------------------------------------------------------------------------

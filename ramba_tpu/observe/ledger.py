@@ -1,9 +1,8 @@
-"""Per-compiled-kernel cost ledger + slow-flush sentinel (`ramba-perf`).
+"""Per-compiled-kernel cost ledger (`ramba-perf`).
 
 The flush span stream (observe/events.py) records *that* a flush happened
 and what it cost in aggregate; this module attributes cost to the unit
-users actually pay for — the compiled kernel — and guards each kernel's
-trajectory against its own history:
+users actually pay for — the compiled kernel:
 
 * **Ledger.**  Every compile-cache interaction and every execution in
   ``core/fuser.py`` (all rungs: fused/split/chunked/eager/host) lands in
@@ -22,30 +21,20 @@ trajectory against its own history:
   additionally records ``block_until_ready``-synchronized samples in a
   separate rolling window — device time, at the cost of serializing
   dispatch.
-* **Slow-flush sentinel.**  Each flush's wall time feeds a rolling
-  window per flush program; once a program has
-  ``RAMBA_SLOW_FLUSH_MIN_SAMPLES`` samples, a flush slower than
-  ``RAMBA_SLOW_FLUSH_FACTOR`` x the rolling p50 emits ONE ``slow_flush``
-  event (kernel label, rung, bytes, compile-vs-execute attribution) on
-  the observability stream.  Deterministic trigger for tests: the
-  ``delay:ms=<n>`` fault mode (resilience/faults.py).
+* **Flush walls.**  Each flush's wall time feeds a rolling window per
+  flush program and per (program, rung): the history the hedged
+  dispatch and the deadline-aware ladder (serve/overload.py) size their
+  thresholds from.  Nothing here compares a flush with that history.
 
 Environment:
 
 * ``RAMBA_PERF`` — unset/0: ledger on, cost_analysis off (default);
-  ``1``/``on``: + capture XLA cost_analysis per new kernel and emit the
-  ``kernels`` section in bench.py; ``sync``: all of that + synchronized
-  execution timing.
-* ``RAMBA_SLOW_FLUSH_FACTOR`` — sentinel threshold multiplier (default
-  4.0; <= 0 disables the sentinel).
-* ``RAMBA_SLOW_FLUSH_MIN_SAMPLES`` — samples before the sentinel may
-  fire for a program (default 5).
+  ``1``/``on``: + capture XLA cost_analysis per new kernel; ``sync``:
+  all of that + synchronized execution timing.
 * ``RAMBA_PERF_WINDOW`` — rolling-window length (default 64).
 
-Read APIs: ``snapshot()`` here, ``ramba_tpu.diagnostics.perf_report()``,
-the ``kernels`` section of ``bench.py``'s JSON line, and offline
-``scripts/perf_diff.py`` which compares two captures and fails CI on
-per-kernel regressions.
+Read APIs: ``snapshot()`` here and
+``ramba_tpu.diagnostics.perf_report()``.
 """
 
 from __future__ import annotations
@@ -83,33 +72,21 @@ def _parse_mode(v: Optional[str]) -> str:
 
 
 _mode = ""
-_slow_factor = 4.0
+# flush walls a program needs before flush_quantile/rung_quantile answer
 _min_samples = 5
 _window = 64
 
 
 def reconfigure(*, mode: Optional[str] = None,
-                factor: Optional[float] = None,
                 min_samples: Optional[int] = None,
                 window: Optional[int] = None) -> None:
     """Reload configuration from the environment, with explicit keyword
     overrides (tests).  Existing rolling windows keep their old length;
     only windows created after a ``window`` change use the new one."""
-    global _mode, _slow_factor, _min_samples, _window
+    global _mode, _min_samples, _window
     _mode = _parse_mode(os.environ.get("RAMBA_PERF")) if mode is None \
         else _parse_mode(mode)
-    try:
-        _slow_factor = float(
-            os.environ.get("RAMBA_SLOW_FLUSH_FACTOR", "4.0") or 4.0
-        ) if factor is None else float(factor)
-    except ValueError:
-        _slow_factor = 4.0
-    try:
-        _min_samples = int(
-            os.environ.get("RAMBA_SLOW_FLUSH_MIN_SAMPLES", "5") or 5
-        ) if min_samples is None else int(min_samples)
-    except ValueError:
-        _min_samples = 5
+    _min_samples = 5 if min_samples is None else int(min_samples)
     try:
         _window = max(4, int(
             os.environ.get("RAMBA_PERF_WINDOW", "64") or 64
@@ -187,11 +164,10 @@ class _Rolling:
 
 def _token(x) -> str:
     """Canonical serialization of one cache-key element: stable across
-    processes (no ``id()``-bearing reprs), so two SPMD ranks — or two
-    runs being diffed by scripts/perf_diff.py — fingerprint the same
-    program identically.  Plain values serialize by repr; anything that
-    could embed a memory address (closures in statics, array objects)
-    degrades to its type/qualname."""
+    processes (no ``id()``-bearing reprs), so two SPMD ranks fingerprint
+    the same program identically.  Plain values serialize by repr;
+    anything that could embed a memory address (closures in statics,
+    array objects) degrades to its type/qualname."""
     if x is None or isinstance(x, (bool, int, float, str, bytes)):
         return repr(x)
     if isinstance(x, (tuple, list)):
@@ -351,7 +327,7 @@ class KernelEntry:
 
 _kernels: "dict[str, KernelEntry]" = {}
 
-# flush-program label -> rolling wall-time window (sentinel state)
+# flush-program label -> rolling wall-time window (hedge threshold)
 _flush_walls: "dict[str, _Rolling]" = {}
 
 # per-(label, rung) flush walls: the overload plane's deadline-aware
@@ -359,7 +335,6 @@ _flush_walls: "dict[str, _Rolling]" = {}
 # budget" — a question the label-level window cannot answer once a
 # program has degraded even once (its window then mixes rung costs)
 _rung_walls: "dict[tuple, _Rolling]" = {}
-_slow_flushes = 0
 
 
 def _entry(fp: str, label: Optional[str] = None, instrs: int = 0,
@@ -458,9 +433,9 @@ def record_execute(fp: str, label: str, instrs: int, rung: str,
 
     First calls (``is_new``) pay jit trace + lower + XLA compile and are
     accounted as compile wall time, NOT as execution samples — mixing
-    them in would poison the steady-state percentiles the sentinel and
-    perf_diff compare against.  ``tenant`` (a serving session's identity)
-    accumulates a per-tenant execution count on the entry.  ``backend``
+    them in would poison the steady-state percentiles.  ``tenant`` (a
+    serving session's identity) accumulates a per-tenant execution
+    count on the entry.  ``backend``
     (a lowering backend name, ``xla``/``pallas``) additionally records
     the sample in that backend's slice — the per-fingerprint evidence
     ``core/autotune.py`` races on.  Compiles inherit the ambient
@@ -589,13 +564,11 @@ def capture_cost(fp: str, fn, leaf_vals,
         pass
 
 
-def observe_flush(span: dict) -> Optional[dict]:
-    """Feed one finished flush span into the sentinel.  Emits (and
-    returns) at most ONE ``slow_flush`` event when this flush's wall
-    time exceeds ``RAMBA_SLOW_FLUSH_FACTOR`` x the program's rolling p50
-    — compared against history BEFORE this sample joins the window, so
-    one slow flush cannot mask the next."""
-    global _slow_flushes
+def record_flush_wall(span: dict) -> None:
+    """File one finished flush span's ``wall_s`` under its program label
+    and under its (label, rung): the rolling windows
+    :func:`flush_quantile` (hedge threshold) and :func:`rung_quantile`
+    (deadline-aware ladder) answer from."""
     label = span.get("label", "?")
     wall = float(span.get("wall_s", 0.0) or 0.0)
     t_obs = _time.perf_counter()
@@ -603,12 +576,6 @@ def observe_flush(span: dict) -> Optional[dict]:
         win = _flush_walls.get(label)
         if win is None:
             win = _flush_walls[label] = _Rolling()
-        fire_p50 = None
-        if _slow_factor > 0 and win.count >= _min_samples:
-            p50 = win.quantile(0.50)
-            if p50 and wall > _slow_factor * p50:
-                _slow_flushes += 1
-                fire_p50 = (p50, win.count)
         win.add(wall)
         rkey = (label, span.get("degraded") or "fused")
         rwin = _rung_walls.get(rkey)
@@ -616,56 +583,11 @@ def observe_flush(span: dict) -> Optional[dict]:
             rwin = _rung_walls[rkey] = _Rolling()
         rwin.add(wall)
     _observer.add("ledger", _time.perf_counter() - t_obs)
-    fired = None
-    if fire_p50 is not None:
-        p50, samples = fire_p50
-        _registry.inc("perf.slow_flush")
-        ev = {
-            "type": "slow_flush",
-            "label": label,
-            "rung": span.get("degraded", "fused"),
-            "wall_s": round(wall, 6),
-            "p50_s": round(p50, 6),
-            "slowdown": round(wall / p50, 2),
-            "factor": _slow_factor,
-            "samples": samples,
-            "instrs": span.get("instrs"),
-            "bytes_in": span.get("leaf_bytes"),
-            "bytes_out": span.get("out_bytes"),
-            "compile_s": span.get("compile_s"),
-            "execute_s": span.get("execute_s"),
-            "cache": span.get("cache"),
-        }
-        # serving attribution: the sentinel names the tenant whose flush
-        # blew past its program's history
-        if span.get("tenant") is not None:
-            ev["tenant"] = span["tenant"]
-        # trace join: carry the flush's trace id so the tail-retention
-        # latch (observe/events.py) keys on the incident's own chain even
-        # when the sentinel runs outside the dispatch span scope
-        if span.get("trace_id") is not None:
-            ev["trace_id"] = span["trace_id"]
-        # incident explainer: diff this flush's waterfall against its
-        # fingerprint's rolling per-stage baselines and name the
-        # dominant divergent stage.  Lazy import — attrib imports this
-        # module at the top level.
-        try:
-            from ramba_tpu.observe import attrib as _attrib
-
-            why = _attrib.explain(span)
-            if why is not None:
-                ev["why"] = why["text"]
-                ev["why_stage"] = why["stage"]
-                ev["why_verdict"] = why["verdict"]
-        except Exception:
-            pass
-        fired = _events.emit(ev)
-    return fired
 
 
 def flush_quantile(label: str, q: float) -> Optional[float]:
     """Rolling flush-wall quantile for ``label``, or None below the
-    slow-flush sample floor — the hedged-dispatch trigger reads p95
+    sample floor — the hedged-dispatch trigger reads p95
     here, so hedging stays off until real history exists."""
     with _lock:
         win = _flush_walls.get(label)
@@ -687,15 +609,11 @@ def rung_quantile(label: str, rung: str, q: float) -> Optional[float]:
 
 def snapshot() -> dict:
     """JSON-serializable ledger dump — the payload behind
-    ``diagnostics.perf_report()``, bench.py's ``kernels`` section, and
-    ``scripts/perf_diff.py`` captures."""
+    ``diagnostics.perf_report()``."""
     with _lock:
         return {
             "mode": _mode or "off",
-            "slow_flush_factor": _slow_factor,
-            "slow_flush_min_samples": _min_samples,
             "window": _window,
-            "slow_flushes": _slow_flushes,
             "kernels": {fp: e.summary() for fp, e in _kernels.items()},
             "flushes": {label: w.summary()
                         for label, w in _flush_walls.items()},
@@ -711,13 +629,11 @@ def kernel_keys() -> list:
 
 def reset() -> None:
     """Drop all accumulated state (tests/benchmarks)."""
-    global _slow_flushes
     with _lock:
         _kernels.clear()
         _flush_walls.clear()
         _rung_walls.clear()
         _fp_memo.clear()
-        _slow_flushes = 0
 
 
 reconfigure()

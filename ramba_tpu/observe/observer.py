@@ -7,9 +7,7 @@ telemetry renders walk every store.  This module is the plane's own
 bill: each observability code path self-accounts its wall seconds into a
 per-component ledger, exported as ``ramba_observer_seconds_total
 {component}`` plus a single ``observer_tax_frac`` — observer seconds
-over total attributed flush wall — that bench.py captures and
-``scripts/perf_diff.py`` gates (the acceptance bar is < 2% of flush
-wall at ``RAMBA_ATTRIB=sample:16``).
+over total attributed flush wall.
 
 Components (what each window covers):
 
@@ -18,15 +16,15 @@ Components (what each window covers):
 * ``fence``     — ``block_until_ready`` wall beyond the dispatch tail
                   (the device time attribution pays to observe).
 * ``ledger``    — kernel-ledger bookkeeping (``record_execute``,
-                  ``observe_flush`` minus any event emit, which
+                  ``record_flush_wall``; the ``compile`` event's emit
                   self-accounts under ``events``).
 * ``telemetry`` — one Prometheus ``render()``.
 * ``fleet``     — one fleet snapshot ``publish()``.
 * ``flight``    — one flight-recorder dump.
 
 Windows may nest (an emit inside a publish bills both components), so
-the total is a slight over-count — fine for a tax that must stay under
-2%: the bound errs against us, never for us.
+the total is a slight over-count: the bound errs against us, never
+for us.
 
 Import-light by design: stdlib only at module scope, so every other
 observe/ module (including events.py at the bottom of the import DAG)

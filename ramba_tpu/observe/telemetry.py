@@ -29,9 +29,9 @@ textfile atomically (tmp + ``os.replace``) every
 on hosts where opening a port is not an option.  Both can run at once.
 
 **Incident flight recorder.**  When ``RAMBA_FLIGHT_DIR`` is set, a tap
-on the event stream watches for incident events — ``slow_flush``,
-``stall`` (RankStallError), ``slo_breach``, ``flush_error``
-(quarantine), and oom-class memory eviction — and dumps the bounded
+on the event stream watches for incident events — ``stall``
+(RankStallError), ``slo_breach``, ``flush_error`` (quarantine),
+``integrity`` and oom-class memory eviction — and dumps the bounded
 event ring plus a full ``diagnostics.snapshot()`` (stamped with the
 process-identity block) to one JSON file per triggering event, named by
 the event's ``seq`` so the dump is exactly once per incident and sorts
@@ -115,8 +115,7 @@ _events.set_context_provider(_context_fields)
 # ---------------------------------------------------------------------------
 
 #: Event types that constitute an incident (each occurrence = one dump).
-FLIGHT_TRIGGERS = ("slow_flush", "stall", "slo_breach", "flush_error",
-                   "perf_regression", "integrity")
+FLIGHT_TRIGGERS = ("stall", "slo_breach", "flush_error", "integrity")
 
 _flight_lock = threading.Lock()
 _flight_dumps = 0
@@ -338,7 +337,6 @@ def _counter_series(fams: _Families, snap: dict, gauge_names) -> None:
 
 def _ledger_series(fams: _Families) -> None:
     snap = _ledger.snapshot()
-    fams.add("ramba_slow_flushes_total", "counter", snap.get("slow_flushes", 0))
     for fp, e in snap.get("kernels", {}).items():
         lab = {"fingerprint": fp, "label": e.get("label", "?")}
         ex = e.get("exec", {})
@@ -488,25 +486,12 @@ def _attrib_series(fams: _Families) -> None:
                  {"stage": stage})
     fams.add("ramba_stage_unattributed_seconds_total", "counter",
              rep.get("unattributed_s", 0.0))
-    sentinel = rep.get("sentinel", {})
-    fams.add("ramba_perf_regressions_total", "counter",
-             sentinel.get("regressions", 0))
-    fams.add("ramba_perf_baselines", "gauge", sentinel.get("baselines", 0))
-    for fp, row in sorted(rep.get("rooflines", {}).items()):
-        labels = {"fingerprint": fp, "label": row.get("label", "?"),
-                  "bound": row.get("bound", "?")}
-        fams.add("ramba_roofline_frac_of_peak", "gauge",
-                 row.get("frac_of_peak", 0.0), labels)
-        fams.add("ramba_roofline_achieved_gb_per_s", "gauge",
-                 row.get("achieved_gb_per_s", 0.0), labels)
-        fams.add("ramba_roofline_achieved_tflops", "gauge",
-                 row.get("achieved_tflops", 0.0), labels)
 
 
 def _observer_series(fams: _Families) -> None:
     """The observability plane's own bill (observe/observer.py): wall
     seconds per component plus the tax as a fraction of attributed
-    flush wall — the number perf_diff gates below 2%."""
+    flush wall."""
     snap = _observer.snapshot()
     comps = snap.get("components") or {}
     if not comps:

@@ -19,8 +19,8 @@ specs.  Modes:
   rank sees the same pattern and the ranks stay in collective lockstep.
 * ``delay:ms=<n>`` sleep ``n`` milliseconds at every check of the site
   and then continue — no exception.  This simulates slowness rather
-  than failure (a deterministic trigger for the slow-flush sentinel in
-  observe/ledger.py): ``RAMBA_FAULTS='execute:delay:ms=200'`` makes
+  than failure (a deterministic signal for the stage ledger, the SLO
+  histograms and the hedge): ``RAMBA_FAULTS='execute:delay:ms=200'`` makes
   every flush's execute step 200 ms slower without perturbing results.
 * ``hang:ms=<n>`` like ``delay`` but semantically a *stall*: the check
   sleeps long enough to trip the elastic watchdog

@@ -37,9 +37,9 @@ Environment:
   docs/index.md "Overload control & deadlines".
 
 Everything a session does lands on the existing observability surface
-with a ``tenant`` tag: flush spans and degrade/flush_error/slow_flush
-events, ``serve.tenant.<t>.*`` counters, per-tenant execution counts in
-the kernel cost ledger, and per-tenant resident bytes in the memory
+with a ``tenant`` tag: flush spans and degrade/flush_error events,
+``serve.tenant.<t>.*`` counters, per-tenant execution counts in the
+kernel cost ledger, and per-tenant resident bytes in the memory
 snapshot — ``diagnostics.report()`` renders the rollup.
 """
 

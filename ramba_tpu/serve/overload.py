@@ -38,7 +38,7 @@ peaks.  This module is the piece that decides *what not to run*:
   after a cooldown the breaker goes half-open and admits exactly one
   probe flush, whose outcome closes or re-opens it.
 * **Hedged dispatch** — when a dispatch exceeds ``RAMBA_HEDGE_FACTOR``
-  × its program's rolling p95 (the slow-flush sentinel's window), a
+  × its program's rolling p95 (``ledger.flush_quantile``), a
   second attempt races the first — but only for programs the effect
   certifier (``analyze/effects.py``) proves pure and donation-free, so
   the loser can be abandoned without a donation hazard.  The loser is

@@ -18,7 +18,7 @@ Usage:
 One-shot by default: prints the replica health table (state, reason,
 snapshot age, publish seq) and the fleet rollup (merged per-tenant SLO
 percentiles, goodput totals with per-replica rows, cache hit-rate
-comparison, worst rooflines).  ``--json`` emits the same as one JSON
+comparison).  ``--json`` emits the same as one JSON
 object.  ``--prom PATH`` writes the fleet Prometheus textfile atomically
 (``-`` prints the exposition to stdout).  ``--watch N`` repeats every N
 seconds until interrupted — the poor operator's dashboard.
@@ -98,10 +98,6 @@ def print_report(directory: str, file=None, polled=None) -> int:
             print(f"  {rep:<32s} jit={jit:<5s} memo={memo:<5s} "
                   f"aot={row['aot_hits']}/{row['aot_hits'] + row['aot_misses']}",
                   file=file)
-    for r in roll["rooflines"][:8]:
-        print(f"roofline {r['label']:<18s} {r['bound']}-bound "
-              f"{r['frac_of_peak']:.1%} of peak  "
-              f"replica={r['replica']}", file=file)
     return _EXIT[h["fleet_state"]]
 
 

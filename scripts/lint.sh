@@ -88,7 +88,7 @@ rm -rf "$ftd"
 
 if command -v ruff >/dev/null 2>&1; then
     echo "== lint.sh: ruff =="
-    ruff check ramba_tpu tests scripts bench.py || rc=1
+    ruff check ramba_tpu tests scripts || rc=1
 else
     echo "== lint.sh: ruff not installed, skipping =="
 fi

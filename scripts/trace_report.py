@@ -23,8 +23,7 @@ Prints, per input:
   * the elastic lifecycle timeline (watchdog stalls, drains,
     checkpoints, resumes, heartbeat misses) plus a per-rank heartbeat
     liveness summary that flags gaps wider than 2x the beacon interval
-    — the offline signature of a wedged rank,
-  * slow_flush sentinel events (observe/ledger.py), and
+    — the offline signature of a wedged rank, and
   * the top programs by cumulative wall time.
 
 ``--merge-ranks`` switches to a cross-rank view: per-rank files are
@@ -59,16 +58,9 @@ STAGE_ORDER = ("trace", "prepare", "verify", "queue_wait", "coalesce",
 
 def _stage_sig(flush: dict) -> str:
     """Order-stable stage signature of one flush span ('' when the span
-    predates the stage ledger).  A span with ``device_source:
-    "estimated"`` skipped its device fence by SAMPLING POLICY
-    (RAMBA_ATTRIB=sample:<N>), not by behavior — normalize it as if the
-    fence had fired, so estimated-vs-fenced never reads as a rank
-    divergence while a genuinely missing fence still does."""
+    predates the stage ledger)."""
     st = flush.get("stages") or {}
-    estimated = (flush.get("device_source") == "estimated")
-    return ",".join(
-        k for k in STAGE_ORDER
-        if k in st or (estimated and k == "device_execute"))
+    return ",".join(k for k in STAGE_ORDER if k in st)
 
 
 def _discover(path: str) -> list:
@@ -169,7 +161,6 @@ def report(path: str, events: list, top: int = 10, file=None) -> None:
     _memory_timeline(events, file=file)
     _lifecycle_timeline(events, file=file)
     _findings_summary(events, file=file)
-    _slow_flush_summary(events, file=file)
 
     flushes = [e for e in events if e.get("type") == "flush"]
     if not flushes:
@@ -337,29 +328,6 @@ def _findings_summary(events: list, file=None) -> None:
         per.items(), key=lambda kv: (sev_rank.get(kv[0][1], 3), kv[0][0])
     ):
         print(f"  {rule:<20s} {sev:<9s} {n:>5d}  {sample}", file=file)
-
-
-def _slow_flush_summary(events: list, file=None, cap: int = 20) -> None:
-    """slow_flush sentinel events (observe/ledger.py): flushes that blew
-    past RAMBA_SLOW_FLUSH_FACTOR x their program's rolling p50, with the
-    rung they ran on and compile-vs-execute attribution."""
-    file = file or sys.stdout
-    slow = [e for e in events if e.get("type") == "slow_flush"]
-    if not slow:
-        return
-    print(f"slow flushes ({len(slow)}):", file=file)
-    for e in slow[:cap]:
-        print(
-            f"  {e.get('label', '?'):<18s} rung={e.get('rung', '?'):<8s}"
-            f" wall={e.get('wall_s', 0):.4f}s"
-            f" p50={e.get('p50_s', 0):.4f}s x{e.get('slowdown', 0)}"
-            f" compile={e.get('compile_s', 0)}s"
-            f" execute={e.get('execute_s', 0)}s"
-            f" cache={e.get('cache', '?')}",
-            file=file,
-        )
-    if len(slow) > cap:
-        print(f"  ... and {len(slow) - cap} more", file=file)
 
 
 def _degradation_timeline(events: list, file=None, cap: int = 50) -> None:
@@ -594,9 +562,6 @@ def _merge_line(e: dict) -> str:
     if t == "degrade":
         return (f"degrade   {e.get('site', '?')} {e.get('action', '?')}"
                 f" {e.get('from', '')}->{e.get('to', '')}")
-    if t == "slow_flush":
-        return (f"slow_flush {e.get('label', '?')}"
-                f" rung={e.get('rung', '?')} x{e.get('slowdown', '?')}")
     if t == "cache_evict":
         return f"cache_evict {e.get('key', '?')}"
     if t == "flush_error":
@@ -795,7 +760,7 @@ def merge_report(path: str, per_rank: dict, file=None, cap: int = 80) -> None:
 
     def noteworthy(e: dict) -> bool:
         t = e.get("type")
-        if t in ("fault", "degrade", "slow_flush", "cache_evict",
+        if t in ("fault", "degrade", "cache_evict",
                  "flush_error", "health", "serve_coalesce", "stall",
                  "lifecycle", "coherence", "reshard", "shed", "breaker",
                  "hedge", "brownout", "redirect", "heal", "migrate",
@@ -945,16 +910,6 @@ def attrib_report(path: str, events: list, top: int = 10,
             if h50 > 0:
                 line += f" ({m50 / h50:.1f}x)"
         print(line, file=file)
-    # sampled attribution (RAMBA_ATTRIB=sample:<N>): estimated spans
-    # carry a rolling fenced p50 instead of a measured device window
-    estimated = [e for e in flushes
-                 if e.get("device_source") == "estimated"]
-    if estimated:
-        fenced = sum(1 for e in flushes
-                     if e.get("device_source") == "fenced")
-        print(f"sampled attribution: {fenced} fenced / "
-              f"{len(estimated)} estimated span(s) "
-              "(device_est_s = rolling fenced p50)", file=file)
     recent = flushes[-8:]
     print(f"recent flushes (last {len(recent)}):", file=file)
     for e in recent:
@@ -963,15 +918,9 @@ def attrib_report(path: str, events: list, top: int = 10,
         u = u if isinstance(u, (int, float)) else 0.0
         rung = e.get("degraded", "fused")
         plan = f" plan={e['plan_cache']}" if e.get("plan_cache") else ""
-        dev = ""
-        if e.get("device_source") == "estimated":
-            est = e.get("device_est_s")
-            dev = (f" dev~{est:.4f}s(est)"
-                   if isinstance(est, (int, float))
-                   else " dev=?(est,no fenced history)")
-        print(f"  {e.get('label', '?')} [{rung}]{plan} wall={wall:.4f}s{dev}  "
+        print(f"  {e.get('label', '?')} [{rung}]{plan} wall={wall:.4f}s  "
               + _waterfall(e["stages"], wall, u), file=file)
-    # incident explainer verdicts (stamped by the sentinels — see
+    # incident explainer verdicts (stamped by observe/slo.py — see
     # observe/attrib.py explain()): why each incident's flush diverged
     whys = [e for e in events if e.get("why")]
     if whys:
@@ -1003,8 +952,8 @@ def trace_chain(trace_id: str, per_rank: dict, file=None) -> int:
     input streams (SPMD ranks, or fleet replicas when the input was a
     directory) and re-threaded by span parentage: the ``serve_session``
     root, then each flush span in time order, with that span's child
-    events (degrade rungs, stalls, memory admissions, slow_flush
-    verdicts, barrier spans) indented beneath it — the end-to-end story
+    events (degrade rungs, stalls, memory admissions, barrier spans)
+    indented beneath it — the end-to-end story
     of one request, even when its pieces executed on different processes
     and interleaved with thousands of unrelated events.  A child whose
     ``parent_span`` resolves to NO span in the inputs is an orphaned

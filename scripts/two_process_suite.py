@@ -49,12 +49,11 @@ compiled different programs — and then runs
 prove the cross-rank merged timeline works end to end.
 
 ``--attrib-leg`` runs the critical-path-attribution acceptance leg
-(observe/attrib.py): the same 2-rank topology under ``RAMBA_PERF=1``
-with a pinned ``RAMBA_PEAKS_JSON``; each rank asserts its stage sums
-(plus the unattributed residual) reconcile with span wall time, then
-prints its lockstep per-flush stage signatures and per-fingerprint
-roofline boundedness classes.  The runner asserts both marker streams
-are IDENTICAL across ranks and that ``trace_report.py --attrib`` (stage
+(observe/attrib.py): the same 2-rank topology under ``RAMBA_PERF=1``;
+each rank asserts its stage sums (plus the unattributed residual)
+reconcile with span wall time, then prints its lockstep per-flush stage
+signatures.  The runner asserts the marker stream is IDENTICAL across
+ranks and that ``trace_report.py --attrib`` (stage
 waterfall) and ``--merge-ranks`` (per-rank stage columns, no
 divergence) both build from the traces.
 
@@ -193,22 +192,14 @@ counts on both ranks).  The runner compares the per-rank class-decision
 tables within and across phases and the persist hit counts across
 ranks.
 
-``--sampling-leg`` runs the self-metering-observability acceptance leg
-(PR 20): two ranks under ``RAMBA_ATTRIB=sample:4`` +
-``RAMBA_TRACE_SAMPLE=4`` with a rank-skewed ``execute:delay`` fault.
-The fence verdict is the fingerprint's flush sequence number (never
-RNG, never timing), so both ranks must fence the IDENTICAL sequence
-numbers per fingerprint and classify every roofline identically even
-while rank 1 runs 40 ms slower per execute — a timing-derived sampler
-would skew here and desync the collective schedule.  Steady-state
-sessions use deterministic trace ids whose sha256 verdict keeps
-exactly 5 of 48 chains in the file lane (>= 4x volume drop by
-construction); one seeded slow flush on a sampled-OUT trace must trip
-the sentinel on both ranks and the tail latch must retroactively
-replay that trace's full buffered chain into the file.  The runner
-compares fence/roofline markers across ranks, asserts zero stalls and
-zero local-fallback rounds, and greps each rank's trace file for the
-latched chain and the steady-state volume ratio.
+``--sampling-leg`` runs the trace-retention acceptance leg (PR 20):
+two ranks under ``RAMBA_TRACE_SAMPLE=4`` with a rank-skewed
+``execute:delay`` fault.  Steady-state sessions use deterministic trace
+ids whose sha256 verdict keeps exactly 5 of 48 chains in the file lane
+(>= 4x volume drop by construction), the same 5 on both ranks even
+while rank 1 runs 40 ms slower per execute.  The runner asserts zero
+stalls and zero local-fallback rounds and greps each rank's trace file
+for the steady-state volume ratio.
 """
 
 from __future__ import annotations
@@ -315,12 +306,10 @@ print('PERF_LEG_KEYS rank=%d %s' % (rank, ','.join(keys)))
 
 
 # SPMD workload for the attribution leg: each rank runs the same flush
-# sequence, then prints (a) the per-flush stage signatures in lockstep
-# order and (b) the per-fingerprint roofline boundedness classes.  Both
-# must be identical across ranks: stage stamping is deterministic control
-# flow and classification is pure math over rank-agreed cost models and
-# a pinned peak table.  Each rank also checks that its stage sums plus
-# the unattributed residual reconcile with span wall time.
+# sequence, then prints the per-flush stage signatures in lockstep
+# order.  They must be identical across ranks: stage stamping is
+# deterministic control flow.  Each rank also checks that its stage sums
+# plus the unattributed residual reconcile with span wall time.
 # argv: <rank> <coordinator>.
 _ATTRIB_WORKLOAD = """
 import sys
@@ -352,23 +341,14 @@ for f in diagnostics.last_flushes(50):
     assert abs(tot - wall) <= max(0.05 * wall, 1e-3), (wall, tot, st)
     sigs.append(f.get('label', '?') + ':' + ','.join(order))
 assert sigs, diagnostics.last_flushes(5)
-rep = diagnostics.perf_report()
-roofs = (rep.get('attribution') or {}).get('rooflines') or {}
-assert roofs, rep.get('attribution')
-roofmark = ','.join('%s=%s' % (fp, roofs[fp]['bound'])
-                    for fp in sorted(roofs))
 print('ATTRIB_LEG_STAGES rank=%d %s' % (rank, ';'.join(sigs)))
-print('ATTRIB_LEG_ROOFS rank=%d %s' % (rank, roofmark))
 """
 
 
 # SPMD workload for the sampling leg: 48 steady-state serving sessions
-# with deterministic trace ids under RAMBA_ATTRIB=sample:4 +
-# RAMBA_TRACE_SAMPLE=4, then one seeded slow flush on a sampled-OUT
-# trace.  The fence decisions (per-fingerprint flush sequence numbers)
-# and roofline bounds are printed for the runner to compare across
-# ranks; the rank-skewed env fault makes rank 1 slower per execute, so
-# any timing dependence in the sampler would diverge the markers.
+# with deterministic trace ids under RAMBA_TRACE_SAMPLE=4.  The
+# rank-skewed env fault makes rank 1 slower per execute; the head-sampling
+# verdict is a hash of the trace id, so both ranks keep the same chains.
 # argv: <rank> <coordinator>.
 _SAMPLING_WORKLOAD = """
 import sys
@@ -380,10 +360,8 @@ distributed.initialize(coordinator_address=coord, num_processes=2,
 import jax
 assert jax.process_count() == 2, jax.process_count()
 import ramba_tpu as rt
-from ramba_tpu import diagnostics, serve
-from ramba_tpu.observe import attrib, events, registry
-from ramba_tpu.resilience import faults
-assert attrib.fence_enabled() and attrib.sample_every() == 4
+from ramba_tpu import serve
+from ramba_tpu.observe import events
 assert events.trace_sample_every() == 4
 # steady state: one-flush sessions with deterministic trace ids; the
 # sha256 head-sampling verdict keeps exactly 5 of these 48 chains
@@ -397,47 +375,13 @@ for tid in tids:
         x = float(np.asarray(a).sum())
 exp = float((np.arange(2048) * 2.0 + 1.0).sum())
 assert abs(x - exp) <= 1e-5 * abs(exp), (x, exp)
-# seeded slow flush on a sampled-OUT trace: warm the program's rolling
-# p50, then delay one execute on BOTH ranks (faults.active suspends the
-# rank-skew env plan) -> the sentinel fires and the tail latch must
-# replay the whole buffered chain into the file lane
-assert not events.trace_sampled_in('slow-0')
-with serve.Session(trace_id='slow-0') as s:
-    for _ in range(6):
-        b = rt.sqrt(rt.arange(4099) + 1.0)
-        float(np.asarray(b).sum())
-    # 1500 ms: the SPMD gather collective drags rank 1's 40 ms skew into
-    # every flush's wall (~55 ms p50), so the seed must clear 8x THAT
-    with faults.active('execute:delay:ms=1500'):
-        b = rt.sqrt(rt.arange(4099) + 1.0)
-        float(np.asarray(b).sum())
 rt.sync()
-slow = events.last(0, type='slow_flush')
-assert slow, 'seeded slow flush never tripped the sentinel'
-assert slow[-1].get('trace_id') == 'slow-0', slow[-1]
 ring = events.snapshot_ring()
 stalls = sum(1 for e in ring if e.get('type') == 'stall')
 local = sum(1 for e in ring if e.get('type') == 'coherence'
             and e.get('outcome') == 'local')
-est = sum(1 for e in ring if e.get('type') == 'flush'
-          and e.get('device_source') == 'estimated')
-fen = sum(1 for e in ring if e.get('type') == 'flush'
-          and e.get('device_source') == 'fenced')
-rep = diagnostics.perf_report()
-roofs = (rep.get('attribution') or {}).get('rooflines') or {}
-assert roofs, rep.get('attribution')
-samp = attrib.sampling_report()
-fences = ';'.join(
-    '%s:%s/%d' % (fp, ','.join(str(q) for q in d['fenced_seqs']),
-                  d['calls'])
-    for fp, d in sorted(samp['fingerprints'].items()))
-roofmark = ','.join('%s=%s' % (fp, roofs[fp]['bound'])
-                    for fp in sorted(roofs))
-print('SAMPLING_LEG_FENCES rank=%d %s' % (rank, fences))
-print('SAMPLING_LEG_ROOFS rank=%d %s' % (rank, roofmark))
-print('SAMPLING_LEG_HEALTH rank=%d stalls=%d local=%d est=%d fenced=%d '
-      'latched=%d' % (rank, stalls, local, est, fen,
-                      registry.get('events.tail_latched')))
+print('SAMPLING_LEG_HEALTH rank=%d stalls=%d local=%d'
+      % (rank, stalls, local))
 """
 
 
@@ -2090,10 +2034,9 @@ def run_perf_leg() -> int:
 
 
 def run_attrib_leg() -> int:
-    """Two ranks under RAMBA_PERF=1 + a pinned peak table; both must
-    stamp lockstep stage signatures, classify every shared fingerprint
-    identically on the roofline, and reconcile stage sums with span
-    wall; the stage waterfall and merged stage columns must build."""
+    """Two ranks under RAMBA_PERF=1; both must stamp lockstep stage
+    signatures and reconcile stage sums with span wall; the stage
+    waterfall and merged stage columns must build."""
     with socket.socket() as s:
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
@@ -2107,17 +2050,12 @@ def run_attrib_leg() -> int:
         env["PYTHONPATH"] = REPO
         for k in ("RAMBA_TEST_PROCS", "RAMBA_TEST_PROC_ID",
                   "RAMBA_TEST_COORD", "RAMBA_TEST_SHARED_TMP",
-                  "RAMBA_PROFILE_DIR", "RAMBA_FAULTS", "RAMBA_HBM_BUDGET",
-                  "RAMBA_BASELINE_DIR"):
+                  "RAMBA_PROFILE_DIR", "RAMBA_FAULTS", "RAMBA_HBM_BUDGET"):
             env.pop(k, None)
         env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         env["RAMBA_PERF"] = "1"
         env["RAMBA_TRACE"] = trace_base
-        # same denominators on both ranks: classification must agree by
-        # construction, not by both hosts happening to probe alike
-        env["RAMBA_PEAKS_JSON"] = (
-            '{"cpu": {"peak_gbps": 100.0, "peak_tflops": 1.0}}')
         log = open(os.path.join(basetemp, f"rank{rank}.log"), "w")
         logs.append(log)
         procs.append(subprocess.Popen(
@@ -2142,8 +2080,7 @@ def run_attrib_leg() -> int:
 
     ok = all(rc == 0 for rc in rcs)
 
-    marks = {"ATTRIB_LEG_STAGES": [None, None],
-             "ATTRIB_LEG_ROOFS": [None, None]}
+    marks = {"ATTRIB_LEG_STAGES": [None, None]}
     for rank in range(2):
         path = os.path.join(basetemp, f"rank{rank}.log")
         with open(path) as f:
@@ -2163,9 +2100,8 @@ def run_attrib_leg() -> int:
             ok = False
     if ok:
         nflush = len((marks["ATTRIB_LEG_STAGES"][0] or "").split(";"))
-        nroof = len((marks["ATTRIB_LEG_ROOFS"][0] or "").split(","))
         print(f"attrib leg: {nflush} lockstep stage signature(s), "
-              f"{nroof} roofline class(es), identical on both ranks")
+              f"identical on both ranks")
 
     # The stage waterfall and the merged stage columns must build from
     # the per-rank traces with no rank divergence.
@@ -2202,12 +2138,10 @@ def run_attrib_leg() -> int:
 
 
 def run_sampling_leg() -> int:
-    """Two ranks under RAMBA_ATTRIB=sample:4 + RAMBA_TRACE_SAMPLE=4 with
-    a rank-skewed execute:delay fault; the fence sequence numbers and
-    roofline bounds must be identical across ranks (the sampler is
-    count-derived, never timing-derived), the tail latch must replay the
-    seeded slow flush's full chain into each rank's file, and steady-
-    state file volume must drop >= 4x."""
+    """Two ranks under RAMBA_TRACE_SAMPLE=4 with a rank-skewed
+    execute:delay fault; both ranks stay clean under the skew and
+    steady-state file volume must drop >= 4x, to the same hash-selected
+    chains on both."""
     import json
 
     with socket.socket() as s:
@@ -2224,23 +2158,16 @@ def run_sampling_leg() -> int:
         for k in ("RAMBA_TEST_PROCS", "RAMBA_TEST_PROC_ID",
                   "RAMBA_TEST_COORD", "RAMBA_TEST_SHARED_TMP",
                   "RAMBA_PROFILE_DIR", "RAMBA_HBM_BUDGET",
-                  "RAMBA_BASELINE_DIR", "RAMBA_SLO_P95_MS"):
+                  "RAMBA_SLO_P95_MS"):
             env.pop(k, None)
         env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         env["RAMBA_PERF"] = "1"
         env["RAMBA_TRACE"] = trace_base
-        env["RAMBA_ATTRIB"] = "sample:4"
         env["RAMBA_TRACE_SAMPLE"] = "4"
-        # slack against scheduler hiccups on the un-delayed rank: only
-        # the seeded 400 ms flush (>= 10x any p50 here) may trip
-        env["RAMBA_SLOW_FLUSH_FACTOR"] = "8"
         # rank-skewed slowness: same env on BOTH ranks (the per-site
         # call counter must advance everywhere), fires on rank 1 only
         env["RAMBA_FAULTS"] = "execute:delay:ms=40:rank=1"
-        # same denominators on both ranks (see attrib leg)
-        env["RAMBA_PEAKS_JSON"] = (
-            '{"cpu": {"peak_gbps": 100.0, "peak_tflops": 1.0}}')
         log = open(os.path.join(basetemp, f"rank{rank}.log"), "w")
         logs.append(log)
         procs.append(subprocess.Popen(
@@ -2265,9 +2192,7 @@ def run_sampling_leg() -> int:
 
     ok = all(rc == 0 for rc in rcs)
 
-    marks = {"SAMPLING_LEG_FENCES": [None, None],
-             "SAMPLING_LEG_ROOFS": [None, None],
-             "SAMPLING_LEG_HEALTH": [None, None]}
+    marks = {"SAMPLING_LEG_HEALTH": [None, None]}
     for rank in range(2):
         path = os.path.join(basetemp, f"rank{rank}.log")
         with open(path) as f:
@@ -2281,14 +2206,6 @@ def run_sampling_leg() -> int:
         print(f"--- sampling leg rank {rank} rc={rcs[rank]} ({path}) ---")
         print("\n".join(tail[-(4 if ok else 40):]))
 
-    # lockstep proof: identical fence sequence numbers per fingerprint
-    # and identical roofline bounds, despite the rank-1 delay skew
-    for key in ("SAMPLING_LEG_FENCES", "SAMPLING_LEG_ROOFS"):
-        vals = marks[key]
-        if ok and vals[0] != vals[1]:
-            print(f"sampling leg: FAIL ({key} diverges: "
-                  f"r0={vals[0]} r1={vals[1]})")
-            ok = False
     if ok:
         for rank in range(2):
             fields = dict(kv.split("=") for kv
@@ -2297,22 +2214,13 @@ def run_sampling_leg() -> int:
                 print(f"sampling leg: FAIL (rank {rank} not clean under "
                       f"skew: {fields})")
                 ok = False
-            if int(fields["est"]) <= 0 or int(fields["fenced"]) <= 0:
-                print(f"sampling leg: FAIL (rank {rank} missing "
-                      f"estimated/fenced spans: {fields})")
-                ok = False
-            if int(fields["latched"]) < 1:
-                print(f"sampling leg: FAIL (rank {rank} tail latch never "
-                      f"fired: {fields})")
-                ok = False
 
-    # file-lane checks per rank: exactly the 5 hash-selected steady
-    # chains on disk (9.6x volume drop), plus the latched slow-0 chain
-    # in full (6 warm flushes + the slow one + the incident line)
+    # file-lane check per rank: exactly the 5 hash-selected steady
+    # chains on disk (9.6x volume drop)
     if ok:
         for rank in range(2):
             fpath = f"{trace_base}.rank{rank}"
-            steady_ids, slow_flushes, slow_incident = set(), 0, 0
+            steady_ids = set()
             try:
                 with open(fpath) as f:
                     for line in f:
@@ -2323,11 +2231,6 @@ def run_sampling_leg() -> int:
                         tid = e.get("trace_id") or ""
                         if tid.startswith("steady-"):
                             steady_ids.add(tid)
-                        if tid == "slow-0":
-                            if e.get("type") == "flush":
-                                slow_flushes += 1
-                            elif e.get("type") == "slow_flush":
-                                slow_incident += 1
             except OSError as exc:
                 print(f"sampling leg: FAIL (rank {rank} trace file: {exc})")
                 ok = False
@@ -2337,15 +2240,9 @@ def run_sampling_leg() -> int:
                       f"steady chains on disk, expected the 5 hash-selected "
                       f"ones: {sorted(steady_ids)})")
                 ok = False
-            if slow_flushes < 7 or slow_incident < 1:
-                print(f"sampling leg: FAIL (rank {rank}: latched chain "
-                      f"incomplete — {slow_flushes} flush spans, "
-                      f"{slow_incident} slow_flush line(s))")
-                ok = False
             if ok:
                 print(f"sampling leg rank {rank}: 5/48 steady chains on "
-                      f"disk (9.6x drop), slow-0 chain replayed "
-                      f"({slow_flushes} spans + incident)")
+                      f"disk (9.6x drop)")
 
     print(f"two-process sampling leg: {'OK' if ok else 'FAIL'}")
     if ok:

@@ -79,7 +79,7 @@ def test_no_code_points_jax_at_another_directory():
     """One resolver: the only config.update of jax_compilation_cache_dir
     in the tree is common.setup_compile_cache's."""
     sources = [os.path.join(REPO, f)
-               for f in ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+               for f in ("chip_smoke.py", "__graft_entry__.py")]
     for root in ("ramba_tpu", "scripts", "examples"):
         for dp, _dn, files in os.walk(os.path.join(REPO, root)):
             sources += [os.path.join(dp, f) for f in files

@@ -268,28 +268,20 @@ def test_rollup_merges_slo_histograms_exactly(tmp_path):
     assert merged["sum_s"] == pytest.approx(want["sum_s"])
 
 
-def test_rollup_cache_and_roofline_comparison(tmp_path):
+def test_rollup_cache_comparison(tmp_path):
     _doc(str(tmp_path), replica="warm-1-0", now=NOW,
          counters={"fuser.cache_hit": 9, "fuser.cache_miss": 1},
          diagnostics_extra={"perf": {
-             "compile": {"persist": {"hits": 5, "misses": 0}},
-             "attribution": {"rooflines": {
-                 "fp1": {"label": "prog_a", "bound": "memory",
-                         "frac_of_peak": 0.8}}}}})
+             "compile": {"persist": {"hits": 5, "misses": 0}}}})
     _doc(str(tmp_path), replica="cold-2-0", now=NOW,
          counters={"fuser.cache_hit": 1, "fuser.cache_miss": 9},
          diagnostics_extra={"perf": {
-             "compile": {"persist": {"hits": 0, "misses": 5}},
-             "attribution": {"rooflines": {
-                 "fp1": {"label": "prog_a", "bound": "memory",
-                         "frac_of_peak": 0.1}}}}})
+             "compile": {"persist": {"hits": 0, "misses": 5}}}})
     roll = fleet.rollup(str(tmp_path), now=NOW)
     assert roll["caches"]["warm-1-0"]["jit_hit_rate"] == pytest.approx(0.9)
     assert roll["caches"]["cold-2-0"]["jit_hit_rate"] == pytest.approx(0.1)
     assert roll["caches"]["warm-1-0"]["aot_hits"] == 5
-    worst = roll["rooflines"]
-    assert worst[0]["replica"] == "cold-2-0"  # worst first
-    assert worst[0]["frac_of_peak"] == pytest.approx(0.1)
+    assert roll["caches"]["cold-2-0"]["aot_misses"] == 5
 
 
 # -- Prometheus federation ---------------------------------------------------

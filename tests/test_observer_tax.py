@@ -1,16 +1,9 @@
 """Self-metering observability plane (PR 20).
 
 Covers ``ramba_tpu.observe.observer`` (the observer-tax ledger),
-sampled attribution (``RAMBA_ATTRIB=sample:<N>``), tail-based trace
-retention (``RAMBA_TRACE_SAMPLE``), the buffered JSONL writer, and the
-incident explainer:
+tail-based trace retention (``RAMBA_TRACE_SAMPLE``), the buffered JSONL
+writer, and the incident explainer:
 
-* fence sampling is a pure function of the fingerprint's flush sequence
-  number — deterministic, replayable, independent per fingerprint, and
-  the fence stays *armed* (``fence_enabled()``) under sampling,
-* unfenced flushes carry ``device_source:"estimated"`` with a
-  ``device_est_s`` stand-in from the rolling fenced p50, and never a
-  ``device_execute`` stage,
 * the file lane head-samples 1-in-N traces by a deterministic trace-id
   hash; an incident retroactively latches the chain (tail latch), a
   rotated buffer leaves a ``trace_gap`` marker,
@@ -19,12 +12,10 @@ incident explainer:
   ``events.ring_dropped``,
 * the explainer names the dominant divergent stage with an
   operator-facing verdict for >= 3 distinct dominant-stage scenarios,
-  and the ``slow_flush`` sentinel stamps it onto the event,
-* ``scripts/trace_report.py`` treats estimated-vs-fenced as NOT a rank
-  divergence and renders sampled-out gaps instead of ORPHANED.
+* ``scripts/trace_report.py`` renders the explainer's verdicts and
+  sampled-out gaps instead of ORPHANED.
 """
 
-import contextlib
 import json
 import os
 import subprocess
@@ -33,7 +24,6 @@ import sys
 import ramba_tpu as rt
 from ramba_tpu import diagnostics
 from ramba_tpu.observe import attrib, events, observer, registry, telemetry
-from ramba_tpu.resilience import faults
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -43,145 +33,8 @@ def _chain(n=2711):
     return float(rt.sum(a))
 
 
-@contextlib.contextmanager
-def _env(**kv):
-    saved = {k: os.environ.get(k) for k in kv}
-    for k, v in kv.items():
-        if v is None:
-            os.environ.pop(k, None)
-        else:
-            os.environ[k] = v
-    try:
-        yield
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
 def _counter(name):
     return registry.snapshot()["counters"].get(name, 0)
-
-
-# ---------------------------------------------------------------------------
-# deterministic fence sampling
-# ---------------------------------------------------------------------------
-
-
-def test_sample_env_parse_keeps_fence_armed():
-    with _env(RAMBA_ATTRIB="sample:4"):
-        attrib.reconfigure()
-        try:
-            assert attrib.fence_enabled()  # armed, just not every call
-            assert attrib.sampling()
-            assert attrib.sample_every() == 4
-        finally:
-            pass
-    attrib.reconfigure()
-    assert not attrib.sampling() and attrib.sample_every() == 1
-
-
-def test_fence_decision_deterministic_and_replayable():
-    with _env(RAMBA_ATTRIB="sample:4"):
-        attrib.reconfigure()
-        attrib.reset()
-        try:
-            fp = "ab" * 6
-            dec = [attrib.fence_decision(fp) for _ in range(9)]
-            assert dec == [True, False, False, False,
-                           True, False, False, False, True]
-            # independent counter per fingerprint: a fresh fp starts at
-            # seq 0, which is ALWAYS fenced (cold kernels get a sample)
-            assert attrib.fence_decision("cd" * 6) is True
-            rep = attrib.sampling_report()
-            assert rep["sample_every"] == 4 and rep["enabled"]
-            assert rep["fingerprints"][fp]["calls"] == 9
-            assert rep["fingerprints"][fp]["fenced_seqs"] == [0, 4, 8]
-            # replay after reset is bit-identical: the verdict is a pure
-            # function of call order, never RNG, never timing — the
-            # property that keeps SPMD ranks in lockstep
-            attrib.reset()
-            assert [attrib.fence_decision(fp) for _ in range(9)] == dec
-        finally:
-            attrib.reset()
-    attrib.reconfigure()
-
-
-def test_fence_decision_stamps_device_source():
-    with _env(RAMBA_ATTRIB="sample:2"):
-        attrib.reconfigure()
-        attrib.reset()
-        try:
-            fp = "ee" * 6
-            s0, s1 = {}, {}
-            assert attrib.fence_decision(fp, s0) is True
-            assert attrib.fence_decision(fp, s1) is False
-            assert s0["device_source"] == "fenced" and s0["fence_seq"] == 0
-            assert s1["device_source"] == "estimated" and s1["fence_seq"] == 1
-            # a segmented flush with any fenced segment reads as fenced
-            attrib.fence_decision(fp, s1)
-            assert s1["device_source"] == "fenced"
-        finally:
-            attrib.reset()
-    attrib.reconfigure()
-
-
-def test_fence_decision_off_and_always_modes():
-    with _env(RAMBA_ATTRIB="off"):
-        attrib.reconfigure()
-        assert attrib.fence_decision("ab" * 6) is False
-    with _env(RAMBA_ATTRIB=None):
-        attrib.reconfigure()
-        # always-on: every call fences, no sequence bookkeeping
-        assert all(attrib.fence_decision("ab" * 6) for _ in range(3))
-        assert not attrib.sampling()
-    attrib.reconfigure()
-
-
-def test_estimated_device_source_on_real_flushes():
-    with _env(RAMBA_ATTRIB="sample:2", RAMBA_PERF="1"):
-        attrib.reconfigure()
-        attrib.reset()
-        try:
-            for _ in range(6):
-                _chain(3301)
-            spans = [s for s in diagnostics.last_flushes(6)
-                     if s.get("device_source")]
-            srcs = {s["device_source"] for s in spans}
-            assert {"fenced", "estimated"} <= srcs, spans
-            for s in spans:
-                if s["device_source"] == "estimated":
-                    # the estimate is display-only: never a stage (the
-                    # device tail genuinely overlaps the host unfenced)
-                    assert "device_execute" not in s.get("stages", {}), s
-                    assert s.get("fence_seq") is not None
-            # once a fenced steady-state sample exists, unfenced flushes
-            # carry the rolling fenced p50 as device_est_s
-            est = [s for s in spans if s["device_source"] == "estimated"
-                   and s.get("device_est_s") is not None]
-            assert est, spans
-            for s in est:
-                assert s["device_est_s"] > 0
-            # the report carries the sampling block under sampling mode
-            rep = attrib.attribution_report()
-            assert rep["sampling"]["sample_every"] == 2
-            assert rep["sampling"]["fingerprints"]
-        finally:
-            attrib.reset()
-    attrib.reconfigure()
-
-
-def test_estimated_device_s_needs_fenced_history():
-    attrib.reset()
-    assert attrib.estimated_device_s("99" * 6) is None
-    assert attrib.estimated_device_s(None) is None
-    attrib.record_device("99" * 6, "prog_x", 0.004)
-    attrib.record_device("99" * 6, "prog_x", 0.006)
-    est = attrib.estimated_device_s("99" * 6)
-    assert est is not None and 0.004 <= est <= 0.006
-    attrib.reset()
 
 
 # ---------------------------------------------------------------------------
@@ -225,13 +78,13 @@ def test_tail_latch_replays_buffered_chain(tmp_path):
         assert _counter("events.tail_buffered") == b0 + 3
         # incident: the chain is latched and replayed IN ORDER ahead of
         # the incident line
-        events.emit({"type": "slow_flush", "label": "prog_t",
+        events.emit({"type": "slo_breach", "label": "prog_t",
                      "trace_id": tid_out})
         events.sync()
         chain = [e for e in _read_jsonl(path)
                  if e.get("trace_id") == tid_out]
         assert [e.get("i") for e in chain[:3]] == [0, 1, 2]
-        assert chain[3]["type"] == "slow_flush"
+        assert chain[3]["type"] == "slo_breach"
         assert _counter("events.tail_latched") == l0 + 1
         # later events of a latched trace write through unsampled
         events.emit({"type": "flush", "label": "prog_t", "i": 7,
@@ -257,7 +110,7 @@ def test_tail_buffer_rotation_leaves_gap_marker(tmp_path):
         for i in range(n):
             events.emit({"type": "flush", "label": "prog_g", "i": i,
                          "trace_id": tid})
-        events.emit({"type": "slow_flush", "label": "prog_g",
+        events.emit({"type": "slo_breach", "label": "prog_g",
                      "trace_id": tid})
         events.sync()
         evs = [e for e in _read_jsonl(path) if e.get("trace_id") == tid]
@@ -464,34 +317,8 @@ def test_explainer_silent_without_divergence_or_history():
         attrib.reset()
 
 
-def test_slow_flush_event_carries_why_verdict():
-    attrib.reset()
-    with _env(RAMBA_SLOW_FLUSH_FACTOR="4", RAMBA_PERF="1"):
-        from ramba_tpu.observe import ledger
-        ledger.reconfigure()
-        try:
-            for _ in range(6):
-                _chain(4201)
-            base = len(events.last(0, type="slow_flush"))
-            with faults.active("execute:delay:ms=200"):
-                _chain(4201)
-            evs = events.last(0, type="slow_flush")
-            assert len(evs) == base + 1, evs[-2:]
-            ev = evs[-1]
-            # the explainer stamped the sentinel event with its verdict
-            assert ev.get("why") and ev.get("why_stage") in (
-                attrib.STAGES + ("unattributed",))
-            assert ev.get("why_verdict") == attrib._EXPLAIN_VERDICTS[
-                ev["why_stage"]]
-            assert ev["why"].endswith(ev["why_verdict"])
-        finally:
-            attrib.reset()
-    from ramba_tpu.observe import ledger
-    ledger.reconfigure()
-
-
 # ---------------------------------------------------------------------------
-# trace_report: estimated spans + sampled-out gaps
+# trace_report: explainer verdicts + sampled-out gaps
 # ---------------------------------------------------------------------------
 
 
@@ -509,60 +336,19 @@ def _trace_report(*args):
     )
 
 
-def test_merge_ranks_estimated_is_not_divergence(tmp_path):
-    base = tmp_path / "m.jsonl"
-    # rank 0 fenced (full waterfall), rank 1 sampled out at the same
-    # flush index: no device_execute stage, but device_source says why
-    _write_jsonl(f"{base}.rank0", [
-        {"type": "flush", "label": "prog_a", "ts": 10.1, "seq": 1,
-         "rank": 0, "wall_s": 0.01, "cache": "hit",
-         "device_source": "fenced", "unattributed_s": 0.001,
-         "stages": {"prepare": 0.002, "dispatch": 0.003,
-                    "device_execute": 0.004}},
-    ])
-    _write_jsonl(f"{base}.rank1", [
-        {"type": "flush", "label": "prog_a", "ts": 10.1, "seq": 1,
-         "rank": 1, "wall_s": 0.01, "cache": "hit",
-         "device_source": "estimated", "device_est_s": 0.004,
-         "unattributed_s": 0.005,
-         "stages": {"prepare": 0.002, "dispatch": 0.003}},
-    ])
-    r = _trace_report(str(base), "--merge-ranks")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "rank divergence: none" in r.stdout
-    # ...but a genuinely MISSING fence (no device_source alibi) at the
-    # same index still flags — sampling must not mask real skew
-    _write_jsonl(f"{base}.rank1", [
-        {"type": "flush", "label": "prog_a", "ts": 10.1, "seq": 1,
-         "rank": 1, "wall_s": 0.01, "cache": "hit",
-         "unattributed_s": 0.005,
-         "stages": {"prepare": 0.002, "dispatch": 0.003}},
-    ])
-    r2 = _trace_report(str(base), "--merge-ranks")
-    assert r2.returncode == 0, r2.stdout + r2.stderr
-    assert "rank divergence at flush #0" in r2.stdout
-
-
-def test_attrib_report_renders_estimated_spans(tmp_path):
+def test_attrib_report_renders_explainer_verdicts(tmp_path):
     path = tmp_path / "t.jsonl"
     _write_jsonl(path, [
         {"type": "flush", "label": "prog_a", "ts": 1.0, "seq": 1,
          "wall_s": 0.01, "unattributed_s": 0.001,
-         "device_source": "fenced",
          "stages": {"prepare": 0.002, "dispatch": 0.003,
                     "device_execute": 0.004}},
-        {"type": "flush", "label": "prog_a", "ts": 1.1, "seq": 2,
-         "wall_s": 0.01, "unattributed_s": 0.005,
-         "device_source": "estimated", "device_est_s": 0.0042,
-         "stages": {"prepare": 0.002, "dispatch": 0.003}},
-        {"type": "slow_flush", "label": "prog_a", "ts": 1.2, "seq": 3,
+        {"type": "slo_breach", "label": "prog_a", "ts": 1.2, "seq": 2,
          "why": "queue_wait 12.0x baseline -> overload",
          "why_stage": "queue_wait", "why_verdict": "overload"},
     ])
     r = _trace_report(str(path), "--attrib")
     assert r.returncode == 0, r.stdout + r.stderr
-    assert "sampled attribution: 1 fenced / 1 estimated" in r.stdout
-    assert "(est)" in r.stdout
     assert "incident explainer verdicts" in r.stdout
     assert "queue_wait 12.0x baseline -> overload" in r.stdout
 

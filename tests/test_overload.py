@@ -150,8 +150,8 @@ def test_deadline_rung_pruning_and_exhaustion():
     skipped; when nothing fits the ladder sheds with stage='ladder'."""
     ledger.reconfigure(min_samples=3)
     for _ in range(4):
-        ledger.observe_flush({"label": "L", "wall_s": 10.0})
-        ledger.observe_flush({"label": "L", "degraded": "split",
+        ledger.record_flush_wall({"label": "L", "wall_s": 10.0})
+        ledger.record_flush_wall({"label": "L", "degraded": "split",
                               "wall_s": 0.001})
     assert ledger.rung_quantile("L", "fused", 0.5) == 10.0
     assert ledger.rung_quantile("L", "split", 0.5) == 0.001
@@ -484,7 +484,7 @@ def test_hedge_threshold_gates(monkeypatch):
     # pure + history -> threshold = factor * p95
     ledger.reconfigure(min_samples=3)
     for _ in range(4):
-        ledger.observe_flush({"label": "HL", "wall_s": 0.1})
+        ledger.record_flush_wall({"label": "HL", "wall_s": 0.1})
     assert overload.hedge_threshold("HL", _P(), ()) == pytest.approx(0.2)
     # no history -> off
     assert overload.hedge_threshold("nohist", _P(), ()) is None

@@ -1,4 +1,4 @@
-"""Kernel cost ledger, slow-flush sentinel, and perf tooling (ramba-perf).
+"""Kernel cost ledger and trace tooling (ramba-perf).
 
 Covers ``ramba_tpu.observe.ledger`` + the fuser hooks + the offline CLIs:
 
@@ -8,13 +8,9 @@ Covers ``ramba_tpu.observe.ledger`` + the fuser hooks + the offline CLIs:
 * ledger accumulation through real flushes (compile vs execute
   attribution, cache hit/miss, rung counts, bytes),
 * true-LRU compile cache with ``fuser.cache_evict`` counter + event,
-* the slow-flush sentinel firing exactly once per offending flush under
-  an injected ``delay:ms=`` fault,
-* the ``delay:ms=<n>`` RAMBA_FAULTS grammar itself,
-* ``scripts/perf_diff.py`` verdicts on synthetic captures,
+* the ``delay:ms=<n>`` RAMBA_FAULTS grammar,
 * ``scripts/trace_report.py --merge-ranks`` over hand-built multi-rank
-  JSONL (including a truncated final line), and slow_flush visibility in
-  the single-file report,
+  JSONL (including a truncated final line),
 * ``observe.events`` rank re-probing (no permanent ``(0, 1)`` cache
   before distributed bring-up).
 """
@@ -116,7 +112,7 @@ def test_ledger_accumulates_compile_and_exec():
     assert k["cache"]["misses"] >= 1 and k["cache"]["hits"] >= 1
     assert k["bytes_out"] > 0
     assert k["rungs"]["fused"] >= 2
-    # per-program flush wall windows feed the sentinel
+    # per-program flush wall windows (the hedge threshold reads them)
     assert rep["flushes"]
     win = list(rep["flushes"].values())[0]
     assert win["count"] >= 2 and win["p50_s"] > 0.0
@@ -230,7 +226,7 @@ def test_program_fix_point_construction():
 
 
 # ---------------------------------------------------------------------------
-# delay fault grammar + slow-flush sentinel
+# delay fault grammar
 # ---------------------------------------------------------------------------
 
 
@@ -261,129 +257,8 @@ def test_delay_fault_sleeps_without_raising():
     assert ev["kind"] == "delay" and ev["ms"] == 40.0
 
 
-def test_slow_flush_sentinel_fires_once_per_offending_flush():
-    fuser.flush()
-    ledger.reconfigure(min_samples=3, factor=5.0)
-    try:
-        for _ in range(4):  # build the rolling baseline
-            _chain()
-        base = len(events.last(0, type="slow_flush"))
-        with faults.active("execute:delay:ms=150"):
-            _chain()
-        assert len(events.last(0, type="slow_flush")) == base + 1
-        with faults.active("execute:delay:ms=150"):
-            _chain()  # a second offending flush fires exactly once more
-        assert len(events.last(0, type="slow_flush")) == base + 2
-        ev = events.last(1, type="slow_flush")[-1]
-        for k in ("label", "rung", "wall_s", "p50_s", "slowdown",
-                  "bytes_in", "bytes_out", "compile_s", "execute_s",
-                  "cache"):
-            assert k in ev, f"slow_flush missing {k!r}"
-        assert ev["label"].startswith("prog_")
-        assert ev["rung"] == "fused"
-        assert ev["wall_s"] > ev["p50_s"] * 5.0
-        assert diagnostics.counters().get("perf.slow_flush", 0) >= 2
-        assert diagnostics.perf_report()["slow_flushes"] >= 2
-    finally:
-        ledger.reconfigure()
-
-
-def test_sentinel_quiet_on_healthy_flushes_and_disabled_by_factor():
-    fuser.flush()
-    ledger.reconfigure(min_samples=3, factor=5.0)
-    try:
-        base = len(events.last(0, type="slow_flush"))
-        for _ in range(6):
-            _chain()
-        assert len(events.last(0, type="slow_flush")) == base
-        # factor <= 0 disables the sentinel even for a glacial flush
-        ledger.reconfigure(min_samples=3, factor=0.0)
-        with faults.active("execute:delay:ms=150"):
-            _chain()
-        assert len(events.last(0, type="slow_flush")) == base
-    finally:
-        ledger.reconfigure()
-
-
 # ---------------------------------------------------------------------------
-# perf_diff CLI on synthetic captures
-# ---------------------------------------------------------------------------
-
-
-def _capture(p50: float, value: float = 2.0) -> dict:
-    return {
-        "value": value,
-        "kernels": {
-            "abc123def456": {
-                "label": "prog_synthetic",
-                "exec": {"count": 10, "p50_s": p50, "total_s": p50 * 10},
-                "compile_s": 0.4,
-            },
-        },
-    }
-
-
-def _run_perf_diff(tmp_path, old: dict, new: dict, *extra):
-    f_old = tmp_path / "old.json"
-    f_new = tmp_path / "new.json"
-    f_old.write_text(json.dumps(old))
-    f_new.write_text(json.dumps(new))
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "perf_diff.py"),
-         str(f_old), str(f_new), *extra],
-        capture_output=True, text=True,
-    )
-
-
-def test_perf_diff_identical_captures_pass(tmp_path):
-    r = _run_perf_diff(tmp_path, _capture(0.01), _capture(0.01))
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "verdict: ok" in r.stdout
-
-
-def test_perf_diff_flags_2x_kernel_slowdown(tmp_path):
-    r = _run_perf_diff(tmp_path, _capture(0.01), _capture(0.025))
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "REGRESSION" in r.stdout
-    assert "abc123def456" in r.stdout
-    # --json mode carries the same verdict machine-readably
-    rj = _run_perf_diff(tmp_path, _capture(0.01), _capture(0.025), "--json")
-    assert rj.returncode == 1
-    verdict = json.loads(rj.stdout)
-    assert verdict["verdict"] == "regressed"
-    assert verdict["regressions"][0]["ratio"] == pytest.approx(2.5)
-
-
-def test_perf_diff_improvement_and_metric_direction(tmp_path):
-    r = _run_perf_diff(tmp_path, _capture(0.03), _capture(0.01))
-    assert r.returncode == 0
-    assert "improved" in r.stdout
-    # headline scalar regression (value = chain wall, lower is better)
-    r2 = _run_perf_diff(tmp_path, _capture(0.01, value=2.0),
-                        _capture(0.01, value=5.0))
-    assert r2.returncode == 1
-    assert "value" in r2.stdout
-
-
-def test_perf_diff_usage_errors(tmp_path):
-    # baseline without a kernels/metrics section
-    f = tmp_path / "empty.json"
-    f.write_text(json.dumps({"n": 1}))
-    g = tmp_path / "new.json"
-    g.write_text(json.dumps(_capture(0.01)))
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "perf_diff.py"),
-         str(f), str(g)],
-        capture_output=True, text=True,
-    )
-    assert r.returncode == 2
-    r2 = _run_perf_diff(tmp_path, _capture(0.01), _capture(0.01),
-                        "--threshold", "0.9")
-    assert r2.returncode == 2
-
-
-# ---------------------------------------------------------------------------
-# trace_report: --merge-ranks + slow_flush visibility
+# trace_report: --merge-ranks
 # ---------------------------------------------------------------------------
 
 
@@ -413,8 +288,8 @@ def test_trace_report_merge_ranks(tmp_path):
          "rank": 1, "wall_s": 0.01, "cache": "miss"},
         {"type": "flush", "label": "prog_b", "ts": 200.25, "seq": 3,
          "rank": 1, "wall_s": 0.3, "degraded": "chunked", "cache": "hit"},
-        {"type": "slow_flush", "label": "prog_b", "rung": "chunked",
-         "slowdown": 30.0, "wall_s": 0.3, "p50_s": 0.01,
+        {"type": "degrade", "site": "flush", "action": "degrade",
+         "from": "fused", "to": "chunked",
          "ts": 200.26, "seq": 4, "rank": 1},
     ]
     _write_rank_file(f"{base}.rank0", r0)
@@ -433,7 +308,7 @@ def test_trace_report_merge_ranks(tmp_path):
     # rank 1 degraded to chunked while rank 0 stayed fused at flush #1
     assert "rank divergence at flush #1" in r.stdout
     assert "r0=prog_b/fused" in r.stdout and "r1=prog_b/chunked" in r.stdout
-    assert "slow_flush" in r.stdout
+    assert "degrade   flush degrade fused->chunked" in r.stdout
     # the truncated final line warns to stderr without crashing the merge
     assert "unparseable" in r.stderr
 
@@ -454,25 +329,6 @@ def test_trace_report_merge_ranks_lockstep(tmp_path):
     )
     assert r.returncode == 0, r.stdout + r.stderr
     assert "rank divergence: none" in r.stdout
-
-
-def test_trace_report_single_file_shows_slow_flush(tmp_path):
-    path = tmp_path / "s.jsonl"
-    _write_rank_file(path, [
-        {"type": "flush", "label": "prog_a", "ts": 1.0, "seq": 1,
-         "wall_s": 0.5, "cache": "hit"},
-        {"type": "slow_flush", "label": "prog_a", "rung": "fused",
-         "wall_s": 0.5, "p50_s": 0.01, "slowdown": 50.0, "compile_s": 0.0,
-         "execute_s": 0.4, "cache": "hit", "ts": 1.5, "seq": 2},
-    ])
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "trace_report.py"),
-         str(path)],
-        capture_output=True, text=True,
-    )
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "slow flushes (1):" in r.stdout
-    assert "rung=fused" in r.stdout
 
 
 # ---------------------------------------------------------------------------

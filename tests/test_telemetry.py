@@ -334,31 +334,23 @@ def test_explicit_trace_id_joins_existing_trace():
 
 @spmd_skip
 def test_child_events_inherit_trace_via_dispatch_scope():
-    """Events emitted inside the dispatch (slow_flush here, same
-    mechanism as degrade/stall/memory) are auto-stamped with the flush
-    span's trace context — no per-site wiring."""
-    os.environ["RAMBA_SLOW_FLUSH_FACTOR"] = "2"
-    os.environ["RAMBA_SLOW_FLUSH_MIN_SAMPLES"] = "2"
-    from ramba_tpu.observe import ledger as _ledger
-    _ledger.reconfigure()
-    try:
-        faults.configure("dispatch:delay:ms=150:after=3")
-        with serve.Session(tenant="acme") as s:
-            for i in range(5):
-                a = rt.ones((32,)) + float(i)
-                s.flush(wait=True)
-                a.asarray()
-        slow = [e for e in events.ring if e.get("type") == "slow_flush"]
-        assert slow, "seeded delay must trip the sentinel"
-        assert slow[-1].get("trace_id") == s.trace_id
-        # parent is the flush span, not the session root
-        spans = {e.get("span_id") for e in events.ring
-                 if e.get("type") == "flush"}
-        assert slow[-1].get("parent_span") in spans
-    finally:
-        del os.environ["RAMBA_SLOW_FLUSH_FACTOR"]
-        del os.environ["RAMBA_SLOW_FLUSH_MIN_SAMPLES"]
-        _ledger.reconfigure()
+    """Events emitted inside the dispatch (an injected fault's record
+    here, same mechanism as degrade/stall/memory) are auto-stamped with
+    the flush span's trace context — no per-site wiring."""
+    faults.configure("dispatch:delay:ms=5:after=3")
+    with serve.Session(tenant="acme") as s:
+        for i in range(5):
+            a = rt.ones((32,)) + float(i)
+            s.flush(wait=True)
+            a.asarray()
+    seeded = [e for e in events.ring if e.get("type") == "fault"
+              and e.get("site") == "dispatch"]
+    assert seeded, "the seeded delay must leave its fault record"
+    assert seeded[-1].get("trace_id") == s.trace_id
+    # parent is the flush span, not the session root
+    spans = {e.get("span_id") for e in events.ring
+             if e.get("type") == "flush"}
+    assert seeded[-1].get("parent_span") in spans
 
 
 @spmd_skip
@@ -416,34 +408,32 @@ def test_e2e_slo_observed_per_ticket():
 
 @spmd_skip
 def test_flight_recorder_exactly_once_per_incident(tmp_path, monkeypatch):
-    """A seeded one-shot stall-class fault produces exactly ONE incident
-    event and exactly ONE dump — the sentinel fires once and the
+    """A seeded one-shot fatal fault produces exactly ONE incident
+    event (the quarantine's ``flush_error``) and exactly ONE dump — the
     recorder maps incidents 1:1 to files."""
     fd = tmp_path / "flight"
     monkeypatch.setenv("RAMBA_FLIGHT_DIR", str(fd))
-    monkeypatch.setenv("RAMBA_SLOW_FLUSH_FACTOR", "2")
-    monkeypatch.setenv("RAMBA_SLOW_FLUSH_MIN_SAMPLES", "2")
-    from ramba_tpu.observe import ledger as _ledger
-    _ledger.reconfigure()
     telemetry.flight_reset()
-    try:
-        faults.configure("dispatch:delay:ms=200:after=3")
-        for i in range(6):
-            a = rt.ones((32,)) + float(i)
-            a.asarray()
-        dumps = sorted(glob.glob(str(fd / "flight_*.json")))
-        assert len(dumps) == 1, dumps
-        rec = json.loads(open(dumps[0]).read())
-        assert rec["incident"]["type"] == "slow_flush"
-        assert rec["events"], "ring included"
-        assert "captured_at" in rec["diagnostics"]
-        assert rec["identity"]["pid"] == os.getpid()
-        assert rec["identity"]["schema_version"] == diagnostics.SCHEMA_VERSION
-        assert os.path.basename(dumps[0]).startswith(
-            f"flight_{rec['incident']['seq']:06d}_")
-        assert registry.get("telemetry.flight_dumps") == 1
-    finally:
-        _ledger.reconfigure()
+    fuser.flush()
+    for i in range(3):
+        (rt.ones((32,)) + float(i)).asarray()
+    fuser._compile_cache.clear()
+    with faults.inject("compile", "once", kind="fatal"):
+        with pytest.raises(faults.InjectedFault):
+            (rt.ones((32,)) + 7.0).asarray()
+    for i in range(2):
+        (rt.ones((32,)) + float(i)).asarray()
+    dumps = sorted(glob.glob(str(fd / "flight_*.json")))
+    assert len(dumps) == 1, dumps
+    rec = json.loads(open(dumps[0]).read())
+    assert rec["incident"]["type"] == "flush_error"
+    assert rec["events"], "ring included"
+    assert "captured_at" in rec["diagnostics"]
+    assert rec["identity"]["pid"] == os.getpid()
+    assert rec["identity"]["schema_version"] == diagnostics.SCHEMA_VERSION
+    assert os.path.basename(dumps[0]).startswith(
+        f"flight_{rec['incident']['seq']:06d}_")
+    assert registry.get("telemetry.flight_dumps") == 1
 
 
 @spmd_skip
