@@ -83,14 +83,18 @@ def inc(name: str, n: int = 1) -> None:
 _kernel_notes = threading.local()
 
 
-def _count_kernel(kernel: str, path: str, interpret: bool) -> None:
+def _count_kernel(kernel: str, path: str, interpret: bool,
+                  operand_copy: int = 0) -> None:
     inc(f"{kernel}.path.{path}")
     if interpret:
         inc(f"{kernel}.interpret")
+    if operand_copy:
+        inc(f"{kernel}.operand_copy", operand_copy)
 
 
 def note_kernel(kernel: str, path: str, interpret: bool = False, *,
-                block_rows=None, grid=None, vmem_limit_bytes=None) -> None:
+                block_rows=None, grid=None, vmem_limit_bytes=None,
+                halo=None, operand_copy: int = 0) -> None:
     """Record that ``kernel`` (e.g. ``"stencil"``) lowered through
     ``path`` (``sharded`` / ``pallas_fast`` / ``pallas_padded`` / ``xla``
     / a Pallas family name), and whether a Pallas kernel on that path
@@ -98,14 +102,21 @@ def note_kernel(kernel: str, path: str, interpret: bool = False, *,
     traces: counts the path, and leaves a note with the enclosing
     :func:`collect_kernel_notes`, if any.  A kernel that sizes its own
     blocks says what it chose (``block_rows``, ``grid``,
-    ``vmem_limit_bytes``): kept on the note, not counted."""
-    _count_kernel(kernel, path, interpret)
+    ``vmem_limit_bytes``, and ``halo``: where it reads its halo from):
+    kept on the note, not counted.  ``operand_copy`` is how many of its
+    operands reach the kernel through an array-sized copy XLA makes:
+    counted as ``<kernel>.operand_copy`` and kept on the note where it is
+    not 0, so that a replay counts it again."""
+    _count_kernel(kernel, path, interpret, operand_copy)
     notes = getattr(_kernel_notes, "active", None)
     if notes is not None:
         note = {"kernel": kernel, "path": path, "interpret": bool(interpret)}
         chose = {"block_rows": block_rows, "grid": grid,
-                 "vmem_limit_bytes": vmem_limit_bytes}
+                 "vmem_limit_bytes": vmem_limit_bytes,
+                 "operand_copy": operand_copy or None}
         note.update((k, int(v)) for k, v in chose.items() if v is not None)
+        if halo is not None:
+            note["halo"] = halo
         notes.append(note)
 
 
@@ -114,7 +125,8 @@ def replay_kernel_notes(notes) -> None:
     yielded them when the program was traced) for a call of the compiled
     program that traced nothing: counters only, never a new note."""
     for note in notes:
-        _count_kernel(note["kernel"], note["path"], note["interpret"])
+        _count_kernel(note["kernel"], note["path"], note["interpret"],
+                      note.get("operand_copy", 0))
 
 
 @contextlib.contextmanager

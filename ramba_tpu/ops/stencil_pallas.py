@@ -9,26 +9,38 @@ kernel for the hot case: 2-D float stencils on a single TPU chip.
 
 Design (pallas_guide.md patterns):
 
-* The input is zero-padded by the stencil halo and the lane dimension is
-  rounded up to 128.  The kernel grid walks row slabs; each instance waits
-  for its slab (rows + halo), whose DMA from HBM into one of two VMEM
-  scratch buffers the instance before it started, starts the next slab's,
-  then evaluates the user's kernel function over *statically shifted*
-  in-VMEM slices — the same trace-the-user-function approach as the XLA
-  path, so arbitrary (including nonlinear) stencil bodies work.
-* The padded path sizes its row block from the VMEM it asks Mosaic for
-  (``_padded_block``), and says what it chose on its kernel note.
-* Output blocks are plain VMEM BlockSpecs; borders are zeroed afterwards to
-  match sstencil's semantics (the reference writes only indices whose full
-  neighborhood is in range).
+* The kernel grid walks row blocks.  Each instance waits for its slab (the
+  block's rows plus a margin of one tile of rows above and below and one
+  tile of lanes left and right), whose DMAs from HBM into one of two VMEM
+  scratch buffers the instance before it started, starts the next
+  slab's, then evaluates the user's kernel function over *statically
+  shifted* in-VMEM slices — the same trace-the-user-function approach as
+  the XLA path, so arbitrary (including nonlinear) stencil bodies work.
+* Every slab is fetched from the arrays that already hold the data: the
+  operand as it lies in HBM, never a padded copy of it.  The general
+  path (``_run_padded``; the name is the benchmark's, it pads nothing)
+  copies the operand's whole tiles itself and takes what is not
+  tile-aligned, its ragged last lane and row tile, and the halo strips a
+  neighbouring shard sent, as tile-wide operands XLA lays out
+  (``_tail_operands``).  It sizes its row block from the VMEM it asks
+  Mosaic for (``_padded_block``) and says what it chose on its kernel
+  note: ``block_rows``, ``grid``, ``vmem_limit_bytes``, and ``halo``:
+  ``"edge"`` (the array's own edge: margins no copy wrote are masked) or
+  ``"strips"`` (operands).  ``stencil.operand_copy`` counts the operands
+  that still travel in an array-sized XLA copy: one with no whole tile.
+* Output blocks are plain VMEM BlockSpecs; the stencil border is zeroed
+  in the kernel by a select, to match sstencil's semantics (the reference
+  writes only indices whose full neighborhood is in range).
 
 Multi-chip stencils run through ops/stencil_sharded.py (shard_map +
 explicit ppermute halo exchange), which calls back into this kernel on
-each shard's halo-extended local block via ``available_local``/``run``.
+each shard's local block, its four received halo strips beside it, via
+``available_local``/``run(..., halos=)``.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -63,8 +75,8 @@ _BLOCK_ROWS = 64
 
 def available_local(arrs) -> bool:
     """Kernel eligibility for already-local (per-shard) blocks — used from
-    inside stencil_sharded's shard_map, where halo exchange has happened
-    and the pallas_call sees purely local data."""
+    inside stencil_sharded's shard_map, where the pallas_call sees purely
+    local data."""
     if not _ENABLED:
         return False
     if _pallas_backend.interpret_mode() and not _INTERPRET:
@@ -114,21 +126,23 @@ def _fast_eligible(lo, hi, arrs) -> bool:
     )
 
 
-def run(func, lo, hi, slots, arrs, taps=8, *, _block_rows=None):
+def run(func, lo, hi, slots, arrs, taps=8, *, halos=None, _block_rows=None):
     """Evaluate the stencil with a Pallas kernel.  Returns the full-shape
-    result with border cells zeroed (sstencil semantics).  Off-TPU the
-    kernel automatically falls back to ``interpret=True`` (rather than
-    raising from an impossible Mosaic compile), so the CPU suite — and
-    the autotune parity tests — exercise the same code path.
+    result with border cells zeroed (sstencil semantics); with ``halos``
+    (per input the ``(west, east, north, south)`` strips its neighbours
+    sent: ``_tail_operands``) every cell of the result, unmasked.  Off-TPU
+    the kernel automatically falls back to ``interpret=True`` (rather
+    than raising from an impossible Mosaic compile), so the CPU suite —
+    and the autotune parity tests — exercise the same code path.
     ``_block_rows`` is scripts/tpu_stencil_sweep.py's: a candidate block
     height in place of the derived one."""
     interpret = _INTERPRET or _pallas_backend.interpret_mode()
-    if _fast_eligible(lo, hi, arrs):
+    if halos is None and _fast_eligible(lo, hi, arrs):
         _registry.note_kernel("stencil", "pallas_fast", interpret)
         return _run_fast(func, lo, hi, slots, arrs, taps, interpret,
                          _block_rows)
     return _run_padded(func, lo, hi, slots, arrs, taps, interpret,
-                       _block_rows)
+                       _block_rows, halos)
 
 
 def _run_fast(func, lo, hi, slots, arrs, taps, interpret=_INTERPRET,
@@ -307,121 +321,286 @@ def _vmem_cap() -> int:
     return int(pltpu.get_tpu_info().vmem_capacity_bytes * _VMEM_SHARE)
 
 
-def _padded_widths(W, halo_c):
-    """(Wo, Wi): lane widths of the output block and of the padded input."""
+def _margins(lo, hi, itemsize):
+    """(top, bottom, left, right) margins of the padded kernel's slab: each
+    halo rounded up to the tile (8 rows of 32 bits, 128 lanes), so that
+    every copy into the slab starts and ends on a tile."""
+    sub = 32 // itemsize
+    return (_round_up(-lo[0], sub), _round_up(hi[0], sub),
+            _round_up(-lo[1], 128), _round_up(hi[1], 128))
+
+
+def _padded_vmem_bytes(bh, W, itemsize, n_slabs, taps, margins):
+    """What a block of ``bh`` rows asks of VMEM: per input two slabs
+    (the block and its margins) and the value of the one being read, the
+    output block Pallas double-buffers, and on Mosaic's stack one (bh, Wo)
+    temporary per shifted read plus three of the arithmetic (its refusals
+    read 6 to 6.5 of them at 8 taps: PERF.md section 6, PR 27)."""
+    mt, mb, ml, mr = margins
     Wo = _round_up(max(W, 128), 128)
-    return Wo, _round_up(Wo + halo_c, 128)
-
-
-def _padded_vmem_bytes(bh, W, itemsize, n_slabs, taps, halo):
-    """What a block of ``bh`` rows asks of VMEM: per input two slabs and
-    the value of the one being read, the output block Pallas
-    double-buffers, and on Mosaic's stack one (bh, Wo) temporary per
-    shifted read plus three of the arithmetic (its refusals read 6 to
-    6.5 of them at 8 taps: PERF.md section 6, PR 27)."""
-    halo_r, halo_c = halo
-    Wo, Wi = _padded_widths(W, halo_c)
-    slab_h = _round_up(bh + halo_r, 8)
-    return itemsize * (3 * n_slabs * slab_h * Wi
+    return itemsize * (3 * n_slabs * (bh + mt + mb) * (ml + Wo + mr)
                        + (max(taps, 1) + 5) * bh * Wo) + _VMEM_SLACK
 
 
-def _padded_block(H, W, itemsize, n_slabs, taps, halo):
+def _padded_block(H, W, itemsize, n_slabs, taps, margins):
     """(rows per block, vmem_limit_bytes) of the padded kernel over an
     ``(H, W)`` array: ``n_slabs`` inputs, ``taps`` shifted reads,
-    ``halo = (top + bottom, left + right)``.  The body's cost per row
+    ``margins`` as ``_margins`` gives them.  The body's cost per row
     falls with the rows it evaluates at once (each unaligned read of bh
     rows touches bh/8 + 1 sublane tiles) and its temporaries and Mosaic's
     compile time rise with them: _BLOCK_ROWS where VMEM allows, fewer
-    where the array is wide, never under the 8-row tile."""
+    where the array is wide, never under the sublane tile."""
     cap = _vmem_cap()
+    sub = 32 // itemsize
 
     def need(bh):
-        return _padded_vmem_bytes(bh, W, itemsize, n_slabs, taps, halo)
+        return _padded_vmem_bytes(bh, W, itemsize, n_slabs, taps, margins)
 
-    bh = min(_BLOCK_ROWS, _round_up(H, 8))
-    while bh > 8 and need(bh) > cap:
-        bh -= 8
+    bh = min(_BLOCK_ROWS, _round_up(H, sub))
+    while bh > sub and need(bh) > cap:
+        bh -= sub
     return bh, min(cap, need(bh))
 
 
+def _cat(parts, axis):
+    parts = [p for p in parts if p is not None]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
+
+
+def _tail_operands(x, strips, lo, hi, margins, sub):
+    """The small arrays the padded kernel reads beside ``x`` itself, each
+    ``None`` or laid out so that its copy into the slab is tile-aligned:
+
+    * west ``(H, left margin)``: the west halo in its last lanes;
+    * east ``(H, k*128)``: ``x``'s ragged last lane tile, then the east
+      halo, at the lanes they take in the slab after ``x``'s whole tiles;
+    * north ``(top margin, Wi)``: the north halo, corners included, in its
+      last rows, in the slab's lanes;
+    * south ``(k*sub, Wi)``: ``x``'s ragged last row tile (with its west
+      and east halo), then the south halo, in the slab's lanes.
+
+    ``strips`` is ``None`` (no halo: the array's own edge) or the raw
+    ``(west (H, left), east (H, right), north (top, left + W + right),
+    south (bottom, left + W + right))``, ``None`` where the width is 0.
+    XLA builds them: a tile-wide strip each, never a copy of ``x``."""
+    H, W = x.shape
+    mt, _, ml, mr = margins
+    top, left = -lo[0], -lo[1]
+    w, e, n, s = strips or (None,) * 4
+    Hf, Wf = H // sub * sub, W // 128 * 128
+    Wi = ml + _round_up(max(W, 128), 128) + mr
+
+    def lanes(a, at):
+        """``a`` at lane ``at`` of a slab-wide row strip."""
+        return jnp.pad(a, ((0, 0), (at, Wi - at - a.shape[1])))
+
+    west = None if w is None else jnp.pad(w, ((0, 0), (ml - left, 0)))
+    east = None
+    if W > Wf or e is not None:
+        east = _cat([x[:, Wf:] if W > Wf else None, e], 1)
+        east = jnp.pad(
+            east, ((0, 0), (0, _round_up(east.shape[1], 128) - east.shape[1])))
+    at = ml - left if strips else ml
+    north = None
+    if n is not None:
+        north = jnp.pad(lanes(n, at), ((mt - top, 0), (0, 0)))
+    south = None
+    if H > Hf or s is not None:
+        tail = None
+        if H > Hf:
+            tail = _cat([None if w is None else w[Hf:], x[Hf:],
+                         None if e is None else e[Hf:]], 1)
+        south = lanes(_cat([tail, s], 0), at)
+        south = jnp.pad(
+            south, ((0, _round_up(south.shape[0], sub) - south.shape[0]),
+                    (0, 0)))
+    return west, east, north, south
+
+
 def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET,
-                block_rows=None):
-    """General-shape path: halo-pad the input and walk row slabs, the
-    fetch of slab i+1 under the compute of slab i."""
+                block_rows=None, halos=None):
+    """General-shape path: walk row blocks, the fetch of block i+1 under
+    the compute of block i, every fetch straight from the arrays that hold
+    the data: no padded copy of an operand is made (``_padded_call``).
+    Sizes the block, notes what it chose, and calls the kernel through one
+    jitted function per (kernel function, neighbourhood, block): a program
+    that runs the same stencil ten times traces and lowers it once."""
+    x = arrs[0]
+    H, W = x.shape
+    itemsize = np.dtype(x.dtype).itemsize
+    sub = 32 // itemsize
+    if block_rows:
+        # the sweep's candidate, under the cap itself
+        bh, vmem_limit = _round_up(block_rows, sub), _vmem_cap()
+    else:
+        bh, vmem_limit = _padded_block(H, W, itemsize, len(arrs), taps,
+                                       _margins(lo, hi, itemsize))
+    # an operand with no whole tile reaches the kernel through its tails
+    # alone: the one shape an XLA-made copy still serves
+    _registry.note_kernel("stencil", "pallas_padded", interpret,
+                          block_rows=bh, grid=-(-H // bh),
+                          vmem_limit_bytes=vmem_limit,
+                          halo="strips" if halos else "edge",
+                          operand_copy=0 if H >= sub and W >= 128
+                          else len(arrs))
+    call = _padded_jit(func, tuple(lo), tuple(hi), tuple(slots), interpret,
+                       bh, vmem_limit)
+    return call(list(arrs), halos)
+
+
+@functools.lru_cache(maxsize=64)
+def _padded_jit(*static):
+    """``_padded_call`` under these statics, jitted: jax traces it once per
+    operand shapes and lowers it once per program, however many times the
+    program calls it (PRK's ten iterations; PERF.md section 6, PR 29)."""
+    def ramba_stencil(arrs, halos):
+        return _padded_call(*static, arrs, halos)
+
+    return jax.jit(ramba_stencil)
+
+
+def _padded_call(func, lo, hi, slots, interpret, bh, vmem_limit, arrs, halos):
+    """The padded kernel over ``arrs`` at ``bh`` rows a block.
+
+    Layout: each input has two VMEM slabs of ``bh`` rows plus the row
+    margins, ``W`` rounded up to the lane tile plus the lane margins
+    (``_margins``: the halo rounded up to the tile, so operand row ``r``,
+    column ``c`` of block ``i`` lies at slab row ``mt + r - i*bh``, lane
+    ``ml + c``, and every copy is tile-aligned).  A middle block is one
+    dynamic copy of ``slab_h`` rows of the operand's whole lane tiles; a
+    block that reaches over the first or the last row (``edge``) has its
+    own static, clipped copy shapes.  What is not tile-aligned in the
+    operand itself, its ragged last lane tile and last row tile, comes
+    from the tail operands (``_tail_operands``), and so do the halo
+    strips where ``halos`` gives them (one ``(west, east, north, south)``
+    per input: stencil_sharded's received strips).  Without ``halos`` the
+    halo is the array's own edge: slab cells that no copy wrote hold stale
+    VMEM and are read only by cells the ``valid`` select zeroes (sstencil
+    writes only cells whose full neighbourhood is in range).  With them
+    every cell of the output has its neighbourhood and the caller owns the
+    masking."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     x = arrs[0]
     H, W = x.shape
     dtype = x.dtype
+    itemsize = np.dtype(dtype).itemsize
+    sub = 32 // itemsize
     top, left = -lo[0], -lo[1]
     bottom, right = hi[0], hi[1]
-    halo_r = top + bottom
     n_slabs = len(arrs)
+    margins = mt, mb, ml, _ = _margins(lo, hi, itemsize)
 
-    Wo, Wi = _padded_widths(W, left + right)
-    if block_rows:
-        # the sweep's candidate, under the cap itself
-        bh, vmem_limit = _round_up(block_rows, 8), _vmem_cap()
-    else:
-        bh, vmem_limit = _padded_block(
-            H, W, np.dtype(dtype).itemsize, n_slabs, taps,
-            (halo_r, left + right))
+    Wo = _round_up(max(W, 128), 128)
+    Wi = ml + Wo + margins[3]
+    Hf, Wf = H // sub * sub, W // 128 * 128
     grid = -(-H // bh)
-    Ho = grid * bh
-    _registry.note_kernel("stencil", "pallas_padded", interpret,
-                          block_rows=bh, grid=grid,
-                          vmem_limit_bytes=vmem_limit)
+    slab_h = bh + mt + mb
 
-    # Mosaic requires HBM slices 8-aligned in the sublane dim: round the
-    # slab height up and pad the input tail to cover the extra rows read.
-    # The input stays padded to Ho + halo + extra rows, so every block's
-    # copy is the one static (slab_h, Wi) shape at row i*bh.
-    slab_h = _round_up(bh + halo_r, 8)
-    extra = slab_h - (bh + halo_r)
+    # operands: per input the array, then the tails it has
+    tails = [_tail_operands(a, halos[k] if halos else None, lo, hi, margins,
+                            sub) for k, a in enumerate(arrs)]
+    operands, where = [], []
+    for a, ts in zip(arrs, tails):
+        idx = []
+        for t in (a, *ts):
+            idx.append(None if t is None else len(operands))
+            if t is not None:
+                operands.append(t)
+        where.append(idx)
+    _, east0, _, south0 = tails[0]
+    ew = 0 if east0 is None else east0.shape[1]
+    sh = 0 if south0 is None else south0.shape[0]
 
-    def pad(a):
-        return jnp.pad(
-            a, ((top, Ho - H + bottom + extra), (left, Wi - W - left)),
-        )
-
-    padded = [pad(a) for a in arrs]
+    # blocks whose slab reaches above row 0 or below the last whole row tile
+    first_tail = max(0, (Hf - mb) // bh)
+    n_head = min(grid, -(-mt // bh))
+    edge = sorted(set(range(n_head)) | set(range(first_tail, grid)))
+    n_ops = len(operands)
 
     def _kernel_body(*refs):
-        # refs: n_slabs HBM inputs, out_ref, n_slabs (2, slab_h, Wi) VMEM
-        # slabs, one (2, n_slabs) array of DMA semaphores
-        ins = refs[:n_slabs]
-        out_ref = refs[n_slabs]
-        slabs = refs[n_slabs + 1: 2 * n_slabs + 1]
+        # refs: the HBM operands, out_ref, n_slabs (2, slab_h, Wi) VMEM
+        # slabs, one (2, 5 * n_slabs) array of DMA semaphores
+        out_ref = refs[n_ops]
+        slabs = refs[n_ops + 1: n_ops + 1 + n_slabs]
         sems = refs[-1]
         i = pl.program_id(0)
         cur = jax.lax.rem(i, jnp.asarray(2, i.dtype))
 
         def copies(j, b):
-            # bh is a static multiple of 8: expose that to Mosaic's
-            # divisibility prover (same class of refusal as in _run_fast)
-            rs = pl.multiple_of(j * (bh // 8) * 8, 8)
-            return [
-                pltpu.make_async_copy(
-                    ins[k].at[pl.ds(rs, slab_h), :], slabs[k].at[b],
-                    sems.at[b, k])
-                for k in range(n_slabs)
-            ]
+            """The copies that fill buffer ``b`` with block ``j``: ``j`` a
+            Python int for an edge block, traced for a middle one.  The
+            wait mirrors the start: the same list, so the semaphores' byte
+            counts match."""
+            if isinstance(j, int):
+                start = j * bh - mt  # operand row of slab row 0
+                r0 = max(0, start)
+                L, d0 = min(Hf, start + slab_h) - r0, r0 - start
+            else:
+                # bh and mt are static multiples of the sublane tile:
+                # expose that to Mosaic's divisibility prover (same class
+                # of refusal as in _run_fast)
+                start = None
+                r0 = pl.multiple_of((j * (bh // sub) - mt // sub) * sub, sub)
+                L, d0 = slab_h, 0
+            cps = []
+            for k in range(n_slabs):
+                xi, wi, ei, ni, si = where[k]
+
+                def cp(src, dst, c):
+                    cps.append(pltpu.make_async_copy(
+                        src, dst, sems.at[b, 5 * k + c]))
+
+                slab = slabs[k]
+                if L > 0:
+                    rows, drows = pl.ds(r0, L), pl.ds(d0, L)
+                    if Wf:
+                        cp(refs[xi].at[rows, pl.ds(0, Wf)],
+                           slab.at[b, drows, pl.ds(ml, Wf)], 0)
+                    if wi is not None:
+                        cp(refs[wi].at[rows], slab.at[b, drows, pl.ds(0, ml)],
+                           1)
+                    if ei is not None:
+                        cp(refs[ei].at[rows],
+                           slab.at[b, drows, pl.ds(ml + Wf, ew)], 2)
+                if start is None:
+                    continue
+                if ni is not None and start < 0:
+                    cp(refs[ni].at[pl.ds(mt + start, -start)],
+                       slab.at[b, pl.ds(0, -start)], 3)
+                n = min(sh, start + slab_h - Hf)
+                if si is not None and n > 0:
+                    cp(refs[si].at[pl.ds(0, n)],
+                       slab.at[b, pl.ds(Hf - start, n)], 4)
+            return cps
+
+        def each_copy(j, b, act):
+            """``act`` on the copies of block ``j`` (traced): one static
+            branch per edge block, one for all the middle ones."""
+            for e in edge:
+                @pl.when(j == e)
+                def _(e=e):
+                    for c in copies(e, b):
+                        act(c)
+
+            if n_head < first_tail:
+                @pl.when((j >= n_head) & (j < first_tail))
+                def _():
+                    for c in copies(j, b):
+                        act(c)
 
         @pl.when(i == 0)
         def _():
-            for cp in copies(i, cur):
-                cp.start()
+            for c in copies(0, 0):
+                c.start()
 
         if grid > 1:
             @pl.when(i + 1 < grid)
             def _():
-                for cp in copies(i + 1, 1 - cur):
-                    cp.start()
+                each_copy(i + 1, 1 - cur, lambda c: c.start())
 
-        for cp in copies(i, cur):
-            cp.wait()
+        each_copy(i, cur, lambda c: c.wait())
 
         from ramba_tpu.skeletons import _KVal, call_stencil_body
 
@@ -442,7 +621,7 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET,
                 if self.slab is None:
                     self.slab = self.ref[cur]
                 piece = self.slab[
-                    top + di: top + di + bh, left + dj: left + dj + Wo
+                    mt + di: mt + di + bh, ml + dj: ml + dj + Wo
                 ]
                 return _KVal(piece) if self.wrap_vals else piece
 
@@ -458,32 +637,37 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET,
             return call_args
 
         val = call_stencil_body(func, build_args).astype(dtype)
-        # zero the stencil border in-kernel (cells whose neighborhood
-        # leaves the valid array) — saves a full masking pass afterwards
-        gr = jax.lax.broadcasted_iota(jnp.int32, (bh, Wo), 0) + i * bh
-        gc = jax.lax.broadcasted_iota(jnp.int32, (bh, Wo), 1)
-        valid = (gr >= top) & (gr < H - bottom) & (gc >= left) & (gc < W - right)
-        out_ref[:] = jnp.where(valid, val, jnp.zeros((), dtype))
+        if not halos:
+            # zero the stencil border in-kernel (cells whose neighbourhood
+            # leaves the array, which read the margins no copy wrote): a
+            # select, never a multiply, because stale VMEM may hold NaN
+            gr = jax.lax.broadcasted_iota(jnp.int32, (bh, Wo), 0) + i * bh
+            gc = jax.lax.broadcasted_iota(jnp.int32, (bh, Wo), 1)
+            valid = ((gr >= top) & (gr < H - bottom)
+                     & (gc >= left) & (gc < W - right))
+            val = jnp.where(valid, val, jnp.zeros((), dtype))
+        out_ref[:] = val
 
     # out_shape is the exact result shape: pallas clips partial edge
-    # blocks, and the kernel masks the stencil border itself, so no
+    # blocks, and the kernel masks the stencil border itself (the array's
+    # own; a shard's caller masks by global coordinates), so no
     # post-processing pass is needed.  The NumPy-ufunc retry and branch
     # auto-lowering happen inside the kernel body (call_stencil_body).
     return pl.pallas_call(
         _kernel_body,
         grid=(grid,),
         out_shape=jax.ShapeDtypeStruct((H, W), dtype),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_slabs,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_ops,
         out_specs=pl.BlockSpec((bh, Wo), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=(
             [pltpu.VMEM((2, slab_h, Wi), dtype)] * n_slabs
-            + [pltpu.SemaphoreType.DMA((2, n_slabs))]
+            + [pltpu.SemaphoreType.DMA((2, 5 * n_slabs))]
         ),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name="ramba_stencil_padded",
-    )(*padded)
+    )(*operands)
 
 
 # Registered kernel family: skeletons._eval_stencil (and anything else)

@@ -12,11 +12,14 @@ shard
 1. exchanges halo columns with its left/right neighbors via
    ``lax.ppermute`` (nearest-neighbor ICI traffic, width = the probed
    stencil radius — no full all-gather of the operand),
-2. exchanges halo rows of the column-extended block (so corner halos ride
-   along for free),
-3. evaluates the stencil over the extended block — through the Pallas
-   kernel on TPU (ops/stencil_pallas.py) or XLA shifted slices elsewhere —
-   producing every local output cell, and
+2. exchanges halo rows, the received column strips' corners joined to
+   them (so corner halos ride along for free),
+3. evaluates the stencil, producing every local output cell: on TPU (2-D)
+   through the Pallas kernel (ops/stencil_pallas.py), which takes the
+   local block as it lies and the four received strips as operands of
+   their own, so nothing array-sized is made between the resident block
+   and the kernel; elsewhere (any rank) through XLA shifted slices over
+   the block with its halos joined on (``_exchange``), and
 4. masks cells whose *global* neighborhood leaves the array (sstencil
    writes only fully-in-range indices; borders are zero).
 
@@ -84,47 +87,71 @@ def eligible(lo, hi, arrs) -> bool:
     return True
 
 
+def _from_neighbour(send, axes_names, nshards, step):
+    """``send`` as the shard ``step`` (+1: the one below, -1: the one
+    above) along the (possibly multi-name) mesh axis group sent it.  End
+    shards receive zeros (masked out of the output downstream)."""
+    if nshards <= 1:
+        return jnp.zeros_like(send)
+    perm = [(i, i + step) for i in range(nshards) if 0 <= i + step < nshards]
+    # trace-time estimate: every non-end shard ships one halo slab
+    _registry.inc(
+        "stencil.halo_bytes_est",
+        len(perm) * math.prod(send.shape) * send.dtype.itemsize,
+    )
+    return jax.lax.ppermute(send, axes_names, perm)
+
+
 def _exchange(x, axis, axes_names, nshards, lo_amt, hi_amt):
     """Extend ``x`` along ``axis`` with halo slabs from the neighboring
-    shards over the (possibly multi-name) mesh axis group.  End shards
-    receive zeros (masked out of the output downstream)."""
+    shards: a copy of ``x`` with the slabs joined on.  The XLA paths' and
+    ``halo()``'s; the Pallas kernel takes the strips as they are
+    (``_halo_strips``)."""
     parts = []
     if lo_amt:
         send = jax.lax.slice_in_dim(
             x, x.shape[axis] - lo_amt, x.shape[axis], axis=axis
         )
-        if nshards > 1:
-            perm = [(i, i + 1) for i in range(nshards - 1)]
-            # trace-time estimate: every non-end shard ships one halo slab
-            _registry.inc(
-                "stencil.halo_bytes_est",
-                len(perm) * math.prod(send.shape) * send.dtype.itemsize,
-            )
-            parts.append(jax.lax.ppermute(send, axes_names, perm))
-        else:
-            parts.append(jnp.zeros_like(send))
+        parts.append(_from_neighbour(send, axes_names, nshards, 1))
     parts.append(x)
     if hi_amt:
         send = jax.lax.slice_in_dim(x, 0, hi_amt, axis=axis)
-        if nshards > 1:
-            perm = [(i, i - 1) for i in range(1, nshards)]
-            _registry.inc(
-                "stencil.halo_bytes_est",
-                len(perm) * math.prod(send.shape) * send.dtype.itemsize,
-            )
-            parts.append(jax.lax.ppermute(send, axes_names, perm))
-        else:
-            parts.append(jnp.zeros_like(send))
+        parts.append(_from_neighbour(send, axes_names, nshards, -1))
     if len(parts) == 1:
         return x
     return jnp.concatenate(parts, axis=axis)
+
+
+def _halo_strips(b, ents, counts, los, his):
+    """The halo of the 2-D local block ``b`` as four strips, ``None`` where
+    the stencil reaches nowhere: west ``(lh, left)``, east ``(lh, right)``,
+    north ``(top, left + lw + right)`` and south ``(bottom, ...)``.  The
+    edge columns go first; the edge rows travel with the received column
+    strips' corners joined to them (three arrays a few rows high), so a
+    stencil that reads its corners gets them.  No copy of ``b``."""
+    lh, lw = b.shape
+    (top, left), (bottom, right) = los, his
+    west = east = north = south = None
+    if left:
+        west = _from_neighbour(b[:, lw - left:], ents[1], counts[1], 1)
+    if right:
+        east = _from_neighbour(b[:, :right], ents[1], counts[1], -1)
+
+    def rows(r0, r1):
+        return jnp.concatenate(
+            [p[r0:r1] for p in (west, b, east) if p is not None], axis=1)
+
+    if top:
+        north = _from_neighbour(rows(lh - top, lh), ents[0], counts[0], 1)
+    if bottom:
+        south = _from_neighbour(rows(0, bottom), ents[0], counts[0], -1)
+    return west, east, north, south
 
 
 def run(func, lo, hi, slots, arrs, taps):
     """Evaluate the stencil over the mesh with explicit halo exchange
     (any rank).  Returns the full-shape result with border cells zeroed."""
     mesh = _mesh.get_mesh()
-    _registry.note_kernel("stencil", "sharded")
     x = arrs[0]
     shape = x.shape
     nd = len(shape)
@@ -136,48 +163,64 @@ def run(func, lo, hi, slots, arrs, taps):
         for d in range(nd)
     ]
 
-    # pad to shard-divisible global shape (garbage cells are masked)
+    # pad to shard-divisible global shape (garbage cells are masked): the
+    # one array-sized copy this path still makes, so it is counted
     padded_shape = tuple(-(-shape[d] // counts[d]) * counts[d]
                          for d in range(nd))
+    _registry.note_kernel(
+        "stencil", "sharded",
+        operand_copy=len(arrs) if padded_shape != shape else 0)
     if padded_shape != shape:
         pads = tuple((0, p - s) for p, s in zip(padded_shape, shape))
         arrs = [jnp.pad(a, pads) for a in arrs]
     local_shape = tuple(p // c for p, c in zip(padded_shape, counts))
 
     def local(*blocks):
-        # halo exchange dim by dim, last dim first; each later exchange
-        # sends the already-extended block, so corner halos ride along
-        exts = []
-        for b in blocks:
-            e = b
-            for d in range(nd - 1, -1, -1):
-                e = _exchange(e, d, ents[d], counts[d], los[d], his[d])
-            exts.append(e)
-
         from ramba_tpu.ops import stencil_pallas
+        from ramba_tpu.skeletons import _stencil_degrade, stencil_interior
 
-        inner = tuple(
-            local_shape[d] - (los[d] + his[d]) for d in range(nd)
-        )
-        if (
-            _OVERLAP
-            and nd == 2
-            and all(i > 0 for i in inner)
-            and (any(los) or any(his))
-            and not stencil_pallas.available_local(exts)
-        ):
-            # overlapped schedule: the interior strip depends only on the
-            # local block, so XLA runs it concurrently with the (async)
-            # halo collective-permutes; border strips wait on the halos.
-            # The reference gets the analogous overlap from Numba prange
-            # workers computing while ZMQ receives land (ramba.py:
-            # 3549-3780); here the latency-hiding scheduler does it.
+        val = None
+        if nd == 2 and stencil_pallas.available_local(blocks):
+            # the kernel reads the block where it lies and its halo from
+            # the strips as they were received: nothing array-sized is
+            # made between the resident block and the kernel's VMEM
+            try:
+                val = stencil_pallas.run(
+                    func, lo, hi, slots, blocks, taps,
+                    halos=[_halo_strips(b, ents, counts, los, his)
+                           for b in blocks])
+            except Exception as e:  # trace-time kernel failure: XLA path
+                _stencil_degrade("sharded pallas", "sharded xla", e)
+        if val is None:
+            # halo exchange dim by dim, last dim first; each later exchange
+            # sends the already-extended block, so corner halos ride along
+            exts = []
+            for b in blocks:
+                e = b
+                for d in range(nd - 1, -1, -1):
+                    e = _exchange(e, d, ents[d], counts[d], los[d], his[d])
+                exts.append(e)
             _registry.note_kernel("stencil", "xla")
-            val = _overlapped_val(func, lo, hi, slots, blocks, exts,
-                                  local_shape)
-        else:
-            val = _local_stencil(func, lo, hi, slots, exts, taps,
-                                 local_shape)
+            inner = tuple(
+                local_shape[d] - (los[d] + his[d]) for d in range(nd)
+            )
+            if (
+                _OVERLAP
+                and nd == 2
+                and all(i > 0 for i in inner)
+                and (any(los) or any(his))
+            ):
+                # overlapped schedule: the interior strip depends only on
+                # the local block, so XLA runs it concurrently with the
+                # (async) halo collective-permutes; border strips wait on
+                # the halos.  The reference gets the analogous overlap
+                # from Numba prange workers computing while ZMQ receives
+                # land (ramba.py:3549-3780); here the latency-hiding
+                # scheduler does it.
+                val = _overlapped_val(func, lo, hi, slots, blocks, exts,
+                                      local_shape)
+            else:
+                val = stencil_interior(func, lo, hi, slots, exts)
         valid = None
         for d in range(nd):
             off = (jax.lax.axis_index(ents[d]) if ents[d] else 0) \
@@ -242,24 +285,3 @@ def _overlapped_val(func, lo, hi, slots, blocks, exts, shape):
     if bottom:
         rows.append(strip(lh - bottom, lh, 0, lw))
     return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=0)
-
-
-def _local_stencil(func, lo, hi, slots, exts, taps, interior):
-    """Stencil over a halo-extended local block; returns the local-shape
-    interior values (no masking — the caller owns global-coordinate
-    masking).  Any rank; the Pallas kernel serves the 2-D case on TPU."""
-    from ramba_tpu.ops import stencil_pallas
-    from ramba_tpu.skeletons import stencil_interior
-
-    if len(interior) == 2 and stencil_pallas.available_local(exts):
-        top, left = -lo[0], -lo[1]
-        lh, lw = interior
-        try:
-            full = stencil_pallas.run(func, lo, hi, slots, exts, taps)
-            return jax.lax.slice(full, (top, left), (top + lh, left + lw))
-        except Exception as e:  # trace-time kernel failure: XLA local path
-            from ramba_tpu.skeletons import _stencil_degrade
-
-            _stencil_degrade("sharded pallas", "sharded xla", e)
-    _registry.note_kernel("stencil", "xla")
-    return stencil_interior(func, lo, hi, slots, exts)

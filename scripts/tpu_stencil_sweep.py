@@ -10,12 +10,17 @@ kernel derives, which is what ships.  The configurations:
 
     8192^2  f32, bf16   pallas_fast    derived, 16, 32
     8192^2  f32         xla            the shifted-slice path
-    15000^2, 13504^2, 15004^2  f32  pallas_padded  derived, 16, 32, 64, 128
+    15000^2, 13500^2  f32  pallas_padded, halo "edge"    derived, 32, 64
+    15000^2, 13500^2  f32  pallas_padded, halo "strips"  derived, 32, 64
 
-(the three padded shapes are what the benchmark's star cells hand the
-kernel per chip).  Each worker refuses to run off the TPU and checks, from
-the kernel's own note, that the stencil took the path its configuration
-names, at the height asked for.
+(the padded shapes are what the benchmark's star cells hand the kernel per
+chip: the array's own edge on one chip, the block and its four received
+strips on four).  Each worker refuses to run off the TPU, checks from the
+kernel's own note that the stencil took the path and the halo its
+configuration names, at the height asked for, and compares one sweep with
+``skeletons.stencil_interior`` (``equal``: to the bit).  ``to_beat_ms`` is
+what the kernel that padded its operand cost a sweep at that shape, the
+copies that fed it included (ledger, PR 28): the table's line to beat.
 
 Run it through the chip tool, one call:
 
@@ -24,9 +29,9 @@ Run it through the chip tool, one call:
 Prints one JSON object, also written to chiprun_out/stencil_sweep.json;
 exits non-zero if any candidate failed.  ``kernel_ms`` is the device time
 of the Pallas custom calls of one sweep, from a profiler trace of one
-chain; ``device_ms`` that of every op of the sweep (the padded path's
-``pad`` with it); ``wall_ms`` the host's clock over the chain, median of
-5; all on the device the JSON names.
+chain; ``device_ms`` that of every op of the sweep (the tails XLA builds
+for the padded path with it); ``wall_ms`` the host's clock over the
+chain, median of 5; all on the device the JSON names.
 """
 
 from __future__ import annotations
@@ -64,7 +69,24 @@ def star2(a):
 sn, dtype, want_path, sk = cfg["n"], cfg["dtype"], cfg["path"], 10
 slots = (("arr", 0),)
 lo, hi, taps = star2.neighborhood(slots)
-x = jnp.asarray(np.random.RandomState(0).rand(sn, sn), dtype=dtype)
+rs = np.random.RandomState(0)
+x = jnp.asarray(rs.rand(sn, sn), dtype=dtype)
+halos = None
+if cfg.get("halo") == "strips":
+    # what four chips hand the kernel: the block and its received strips
+    (t, l), (b, r) = (-lo[0], -lo[1]), hi
+    halos = [tuple(jnp.asarray(rs.rand(*shp), dtype=dtype) for shp in
+                   ((sn, l), (sn, r), (t, l + sn + r), (b, l + sn + r)))]
+
+def reference(y):
+    v = skeletons.stencil_interior
+    if halos is None:
+        return jnp.zeros_like(y).at[2:-2, 2:-2].set(
+            v(star2.func, lo, hi, slots, [y]))
+    w, e, n, s = halos[0]
+    ext = jnp.concatenate(
+        [n, jnp.concatenate([w, y, e], axis=1), s], axis=0)
+    return v(star2.func, lo, hi, slots, [ext])
 
 def device_ns(tdir):
     from jax.profiler import ProfileData
@@ -82,10 +104,9 @@ def device_ns(tdir):
 
 def sweep(y, rows):
     if want_path == "xla":
-        v = skeletons.stencil_interior(star2.func, lo, hi, slots, [y])
-        return jnp.zeros_like(y).at[2:-2, 2:-2].set(v)
+        return reference(y)
     return stencil_pallas.run(star2.func, lo, hi, slots, [y], taps,
-                              _block_rows=rows)
+                              halos=halos, _block_rows=rows)
 
 for rows in cfg["rows"]:
     row = {"block_rows_asked": rows}
@@ -102,9 +123,17 @@ for rows in cfg["rows"]:
             assert {n["path"] for n in notes} == {want_path}, notes
             assert not any(n["interpret"] for n in notes), notes
             row.update({k: notes[0][k] for k in
-                        ("block_rows", "grid", "vmem_limit_bytes")
-                        if k in notes[0]})
+                        ("block_rows", "grid", "vmem_limit_bytes", "halo",
+                         "operand_copy") if k in notes[0]})
             assert rows is None or row.get("block_rows", rows) == rows, row
+            assert row.get("halo") == cfg.get("halo"), row
+            got = jax.jit(lambda y, rows=rows: sweep(y, rows))(x)
+            diff = jnp.abs(got.astype(jnp.float32)
+                           - jax.jit(reference)(x).astype(jnp.float32))
+            row["max_abs_diff"] = float(jnp.max(diff))
+            row["equal"] = row["max_abs_diff"] == 0.0
+            del got, diff
+            assert row["max_abs_diff"] < 1e-5, row
         jax.block_until_ready(run(x))
         walls = []
         for _ in range(5):
@@ -131,7 +160,7 @@ print(json.dumps({"device_kind": dev.device_kind,
                   "device_count": len(jax.devices())}), flush=True)
 """
 
-_PADDED_ROWS = [None, 16, 32, 64, 128]
+_PADDED_ROWS = [None, 32, 64]
 CONFIGS = [
     ("fast_8192", {"n": 8192, "dtype": "float32", "path": "pallas_fast",
                    "rows": [None, 16, 32]}),
@@ -139,12 +168,22 @@ CONFIGS = [
                         "path": "pallas_fast", "rows": [None]}),
     ("xla_8192", {"n": 8192, "dtype": "float32", "path": "xla",
                   "rows": [None]}),
-    ("padded_15000", {"n": 15000, "dtype": "float32",
+    ("padded_15000", {"n": 15000, "dtype": "float32", "halo": "edge",
+                      "path": "pallas_padded", "rows": _PADDED_ROWS,
+                      "to_beat_ms": {"star2: kernel 3.828 + pad 2.969":
+                                     6.797}}),
+    ("padded_13500", {"n": 13500, "dtype": "float32", "halo": "edge",
                       "path": "pallas_padded", "rows": _PADDED_ROWS}),
-    ("padded_13504", {"n": 13504, "dtype": "float32",
-                      "path": "pallas_padded", "rows": _PADDED_ROWS}),
-    ("padded_15004", {"n": 15004, "dtype": "float32",
-                      "path": "pallas_padded", "rows": _PADDED_ROWS}),
+    ("strips_15000", {
+        "n": 15000, "dtype": "float32", "halo": "strips",
+        "path": "pallas_padded", "rows": _PADDED_ROWS,
+        "to_beat_ms": {"star2-30000-x4: kernel 3.829 + pad 2.971 + "
+                       "concatenate 6.352": 13.152}}),
+    ("strips_13500", {
+        "n": 13500, "dtype": "float32", "halo": "strips",
+        "path": "pallas_padded", "rows": _PADDED_ROWS,
+        "to_beat_ms": {"star2-x4: kernel 3.095 + pad 2.400 + "
+                       "concatenate 5.056": 10.551}}),
 ]
 
 
@@ -188,6 +227,16 @@ def main(argv) -> int:
               "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
+    print(f"{'configuration':<16}{'rows':>6}{'kernel_ms':>11}{'device_ms':>11}"
+          f"{'equal':>7}  to beat (ledger, PR 28)", file=sys.stderr)
+    for name, got in out["configs"].items():
+        beat = "; ".join(f"{v} ms ({k})"
+                         for k, v in got.get("to_beat_ms", {}).items())
+        for c in got.get("candidates", ()):
+            print(f"{name:<16}{c.get('block_rows', '-'):>6}"
+                  f"{c.get('kernel_ms', float('nan')):>11.3f}"
+                  f"{c.get('device_ms', float('nan')):>11.3f}"
+                  f"{str(c.get('equal', '-')):>7}  {beat}", file=sys.stderr)
     return 1 if out["failed"] else 0
 
 

@@ -115,6 +115,43 @@ def _skew(a):
     return a[-3, 0] + a[0, 5] - a[1, -1]
 
 
+def _far(a):
+    # halos wider than one tile: 9 rows up, 130 lanes right
+    return a[-9, 0] - a[0, 130] + a[2, -1]
+
+
+def _box(a):
+    # reads its corners
+    return (a[-1, -1] + 2 * a[-1, 0] + 3 * a[-1, 1] + 4 * a[0, -1]
+            + 5 * a[0, 0] + 6 * a[0, 1] + 7 * a[1, -1] + 8 * a[1, 0]
+            + 9 * a[1, 1])
+
+
+_KERNELS = {"mix": _mix, "skew": _skew, "far": _far, "box": _box}
+
+
+def _kernel(which):
+    return _prk_star2() if which == "star2" else rt.stencil(_KERNELS[which])
+
+
+def _data(rs, shape, which):
+    """Random operand; whole numbers for the nine-tap box, whose sum is
+    then exact in any order (XLA and the interpreter contract differently)."""
+    return rs.randint(0, 16, shape) if which == "box" else rs.rand(*shape)
+
+
+def _bordered(st, lo, hi, slots, arrs):
+    """sstencil's result by the XLA shifted-slice path: zero border."""
+    from ramba_tpu import skeletons
+
+    shape = arrs[0].shape
+    interior = np.asarray(
+        skeletons.stencil_interior(st.func, lo, hi, slots, arrs))
+    want = np.zeros(shape, dtype=interior.dtype)
+    want[-lo[0]:shape[0] - hi[0], -lo[1]:shape[1] - hi[1]] = interior
+    return want
+
+
 #: (shape, dtype, kernel, candidate rows or None for the derived height,
 #: grid expected)
 _PADDED_CASES = {
@@ -126,6 +163,16 @@ _PADDED_CASES = {
     "grid4-bf16": ((52, 130), "bfloat16", "star2", 16, 4),
     "grid3-asymmetric": ((45, 150), "float32", "skew", 16, 3),
     "grid2-derived-tall": ((100, 140), "float32", "skew", None, 2),
+    # toy images of the benchmark's 13500^2 (H % 8 = 4, W % 128 = 60) and
+    # 15000^2 (W % 128 = 24): ragged in rows and lanes together
+    "toy-13500": ((108, 188), "float32", "star2", 16, 7),
+    "toy-15000": ((120, 152), "float32", "star2", 16, 8),
+    "toy-13500-bf16": ((108, 188), "bfloat16", "star2", 32, 4),
+    "last-block-shorter-than-halo": ((33, 140), "float32", "star2", 16, 3),
+    "last-block-one-row-grid6": ((41, 140), "float32", "star2", 8, 6),
+    "halo-wider-than-a-tile": ((60, 400), "float32", "far", 8, 8),
+    "no-whole-row-tile": ((5, 300), "float32", "box", None, 1),
+    "box-two-blocks": ((44, 260), "float32", "box", 24, 2),
 }
 
 
@@ -137,15 +184,13 @@ class TestPaddedKernel:
     def test_matches_stencil_interior_exactly(self, case):
         import jax.numpy as jnp
 
-        from ramba_tpu import skeletons
         from ramba_tpu.observe import registry
 
         shape, dtype, which, rows, want_grid = _PADDED_CASES[case]
-        st = {"star2": _prk_star2(), "mix": rt.stencil(_mix),
-              "skew": rt.stencil(_skew)}[which]
+        st = _kernel(which)
         rs = np.random.RandomState(len(case))
         n_in = 2 if which == "mix" else 1
-        arrs = [jnp.asarray(rs.rand(*shape), dtype=dtype)
+        arrs = [jnp.asarray(_data(rs, shape, which), dtype=dtype)
                 for _ in range(n_in)]
         slots = tuple(("arr", k) for k in range(n_in))
         lo, hi, taps = st.neighborhood(slots)
@@ -157,15 +202,129 @@ class TestPaddedKernel:
         assert note["grid"] == want_grid == -(-shape[0] // note["block_rows"])
         assert rows is None or note["block_rows"] == rows
         assert 0 < note["vmem_limit_bytes"] <= stencil_pallas._vmem_cap()
-        want = np.zeros(shape, dtype=got.dtype)
-        interior = np.asarray(
-            skeletons.stencil_interior(st.func, lo, hi, slots, arrs))
-        want[-lo[0]:shape[0] - hi[0], -lo[1]:shape[1] - hi[1]] = interior
+        assert note["halo"] == "edge"
+        # only an operand with no whole tile travels in an XLA-made copy
+        whole = shape[0] >= 32 // arrs[0].dtype.itemsize and shape[1] >= 128
+        assert note.get("operand_copy", 0) == (0 if whole else n_in)
         assert got.dtype == arrs[0].dtype and got.shape == shape
-        np.testing.assert_array_equal(np.asarray(got), want)
+        np.testing.assert_array_equal(
+            np.asarray(got), _bordered(st, lo, hi, slots, arrs))
+
+    @pytest.mark.parametrize("case", [
+        "toy-13500", "toy-15000", "last-block-shorter-than-halo",
+        "last-block-one-row-grid6", "grid3-two-inputs",
+        "halo-wider-than-a-tile"])
+    def test_stale_slab_never_reaches_the_result(self, case):
+        """The TPU interpreter with every scratch buffer NaN to begin with
+        (and reads out of bounds refused): slab cells that no copy wrote
+        are read only by cells the select zeroes."""
+        import jax.numpy as jnp
+        from jax.experimental.pallas import tpu as pltpu
+
+        shape, dtype, which, rows, _ = _PADDED_CASES[case]
+        st = _kernel(which)
+        n_in = 2 if which == "mix" else 1
+        rs = np.random.RandomState(7)
+        arrs = [jnp.asarray(rs.rand(*shape), dtype=dtype)
+                for _ in range(n_in)]
+        slots = tuple(("arr", k) for k in range(n_in))
+        lo, hi, taps = st.neighborhood(slots)
+        got = np.asarray(stencil_pallas._run_padded(
+            st.func, lo, hi, slots, arrs, taps,
+            pltpu.InterpretParams(uninitialized_memory="nan"), rows))
+        assert not np.isnan(got).any()
+        np.testing.assert_array_equal(
+            got, _bordered(st, lo, hi, slots, arrs))
+
+    def test_nan_at_the_arrays_edge_leaves_the_border_zero(self):
+        import jax.numpy as jnp
+
+        st = _prk_star2()
+        x = np.random.RandomState(3).rand(108, 188).astype(np.float32)
+        x[:2] = x[-2:] = np.nan
+        x[:, :2] = x[:, -2:] = np.nan
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+        got = np.asarray(stencil_pallas._run_padded(
+            st.func, lo, hi, slots, [jnp.asarray(x)], taps, True, 16))
+        border = np.ones(x.shape, bool)
+        border[2:-2, 2:-2] = False
+        assert (got[border] == 0).all()
+        np.testing.assert_array_equal(
+            got, _bordered(st, lo, hi, slots, [jnp.asarray(x)]))
+
+    def test_no_operand_sized_pad_or_concatenate_is_traced(self):
+        """The program of one PRK sweep holds no ``pad`` and no
+        ``concatenate`` as large as the operand: the tails are a tile wide."""
+        import jax
+        import jax.numpy as jnp
+
+        st = _prk_star2()
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+        shape = (1080, 1880)
+        jaxpr = jax.make_jaxpr(lambda a: stencil_pallas._run_padded(
+            st.func, lo, hi, slots, [a], taps, True))(
+                jnp.zeros(shape, jnp.float32))
+        sizes = _copy_sizes(jaxpr.jaxpr)
+        assert sizes and max(sizes) <= shape[0] * shape[1] // 8, sizes
 
 
-_STAR_SHAPES = [(15000, 15000), (13504, 13504), (15004, 15004)]
+def test_ten_calls_of_one_stencil_trace_the_kernel_once(monkeypatch):
+    """A program that runs the same stencil ten times (PRK's solve) builds
+    the kernel once: the calls share one jitted function, so jax lowers
+    one program for the ten."""
+    import jax
+    import jax.numpy as jnp
+
+    built = []
+    real = stencil_pallas._padded_call
+    monkeypatch.setattr(stencil_pallas, "_padded_call",
+                        lambda *a: built.append(a[5]) or real(*a))
+    stencil_pallas._padded_jit.cache_clear()
+    st = _prk_star2()
+    slots = (("arr", 0),)
+    lo, hi, taps = st.neighborhood(slots)
+
+    def solve(a, b):
+        for _ in range(10):
+            b = b + stencil_pallas._run_padded(st.func, lo, hi, slots, [a],
+                                               taps, True, 16)
+            a = a + 1
+        return a, b
+
+    z = jnp.zeros((108, 188), jnp.float32)
+    try:
+        jaxpr = jax.make_jaxpr(solve)(z, z)
+    finally:
+        stencil_pallas._padded_jit.cache_clear()
+    assert built == [16]
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name in ("pjit", "jit")
+             and e.params.get("name") == "ramba_stencil"]
+    assert len(calls) == 10
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+
+
+def _copy_sizes(jaxpr):
+    """Elements of every ``pad`` / ``concatenate`` output in ``jaxpr`` and
+    the programs nested in it (a kernel's own body aside)."""
+    import jax
+
+    sizes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("pad", "concatenate"):
+            sizes.append(int(np.prod(eqn.outvars[0].aval.shape)))
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            sizes.extend(_copy_sizes(sub))
+    return sizes
+
+
+# what the benchmark's star cells hand the kernel per chip: the arrays as
+# they lie, no longer the halo-extended 13504^2 and 15004^2
+_STAR_SHAPES = [(15000, 15000), (13500, 13500), (30000, 30000)]
+_STAR_MARGINS = (8, 8, 128, 128)
 
 
 class TestPaddedBlock:
@@ -173,9 +332,11 @@ class TestPaddedBlock:
 
     @pytest.mark.parametrize("shape", _STAR_SHAPES, ids=str)
     def test_benchmark_shapes(self, shape):
-        args = (*shape, 4, 1, 8, (4, 4))
+        args = (*shape, 4, 1, 8, _STAR_MARGINS)
+        assert stencil_pallas._margins((-2, -2), (2, 2), 4) == _STAR_MARGINS
         bh, limit = stencil_pallas._padded_block(*args)
-        assert bh >= 32 and bh % 8 == 0
+        # 64 rows but on the one-chip 30000^2 of PERF.md section 7
+        assert bh == (64 if shape[1] < 30000 else 48)
         assert stencil_pallas._padded_vmem_bytes(bh, *args[1:]) <= limit
         assert limit <= stencil_pallas._vmem_cap()
 
@@ -195,7 +356,7 @@ class TestPaddedBlock:
             a = dict(base, **{vary: v})
             bh, limit = stencil_pallas._padded_block(
                 a["H"], a["W"], a["itemsize"], a["n_slabs"], a["taps"],
-                (4, 4))
+                _STAR_MARGINS)
             assert bh % 8 == 0 and 8 <= bh <= max(8, -(-a["H"] // 8) * 8)
             assert 0 < limit <= cap
             heights.append(bh)

@@ -195,6 +195,163 @@ class TestShardedStencil:
                 assert k["vmem_limit_bytes"] > 0
 
 
+def _box(a):
+    # reads its corners: the strips' corners have to arrive
+    return (a[-1, -1] + 2 * a[-1, 0] + 3 * a[-1, 1] + 4 * a[0, -1]
+            + 5 * a[0, 0] + 6 * a[0, 1] + 7 * a[1, -1] + 8 * a[1, 0]
+            + 9 * a[1, 1])
+
+
+def _far_corner(a):
+    return a[-3, -2] - a[2, 5] + a[0, 0]
+
+
+_MESHES = {"2x2": ((2, 2), ("d0", "d1")), "1x4": ((4,), ("d0",)),
+           "2x4": ((2, 4), ("d0", "d1"))}
+
+
+@pytest.fixture
+def on_mesh(monkeypatch):
+    """Install a mesh of the first devices, by name; the Pallas kernel
+    interprets, so the sharded path hands it the strips."""
+    from jax.sharding import Mesh
+
+    monkeypatch.setattr(stencil_pallas, "_INTERPRET", True)
+    monkeypatch.setattr(stencil_pallas, "_ENABLED", True)
+    old = _mesh.get_mesh()
+
+    def install(name):
+        dims, names = _MESHES[name]
+        n = int(np.prod(dims))
+        if len(jax.devices()) < n:
+            pytest.skip(f"needs {n} devices")
+        _mesh.set_mesh(Mesh(np.array(jax.devices()[:n]).reshape(dims), names))
+
+    yield install
+    _mesh.set_mesh(old)
+
+
+class TestStripsKernel:
+    """The kernel fed its halo as strips (``halos=``), inside shard_map:
+    against the one-device kernel, bit for bit."""
+
+    @pytest.mark.parametrize("mesh", sorted(_MESHES))
+    @pytest.mark.parametrize("which,shape", [
+        ("star2", (216, 376)),   # local 108 x 188 on 2x2: ragged
+        ("box", (216, 376)),
+        ("box", (48, 1024)),      # 1x4 splits the lanes
+        ("far", (90, 300)),      # asymmetric, uneven split
+    ])
+    def test_matches_one_device_bit_for_bit(self, on_mesh, mesh, which,
+                                            shape):
+        from ramba_tpu.observe import registry
+
+        st = {"star2": _star2(), "box": rt.stencil(_box),
+              "far": rt.stencil(_far_corner)}[which]
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+        # whole numbers: every sum exact, whatever order it is made in
+        x = jnp.asarray(np.random.RandomState(5).randint(0, 16, shape),
+                        jnp.float32)
+        want = np.asarray(stencil_pallas.run(st.func, lo, hi, slots, [x],
+                                             taps))
+        on_mesh(mesh)
+        assert stencil_sharded.eligible(lo, hi, [x])
+        with registry.collect_kernel_notes() as notes:
+            got = jax.jit(lambda a: stencil_sharded.run(
+                st.func, lo, hi, slots, [a], taps))(x)
+        assert [n["path"] for n in notes] == ["sharded", "pallas_padded"]
+        assert notes[1]["halo"] == "strips" and notes[1]["interpret"]
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+    def test_two_inputs_get_their_own_strips(self, on_mesh):
+        @rt.stencil
+        def mix(a, b):
+            return a[0, -1] + 2 * b[-1, 1] + 3 * b[1, 0]
+
+        slots = (("arr", 0), ("arr", 1))
+        lo, hi, taps = mix.neighborhood(slots)
+        rs = np.random.RandomState(6)
+        xs = [jnp.asarray(rs.randint(0, 16, (100, 280)), jnp.float32)
+              for _ in range(2)]
+        want = np.asarray(stencil_pallas.run(mix.func, lo, hi, slots, xs,
+                                             taps))
+        on_mesh("2x2")
+        got = jax.jit(lambda a, b: stencil_sharded.run(
+            mix.func, lo, hi, slots, [a, b], taps))(*xs)
+        np.testing.assert_array_equal(np.asarray(got), want)
+
+    def test_stale_slab_never_reaches_the_result(self, on_mesh, monkeypatch):
+        """Every scratch buffer NaN to begin with: with strips the kernel
+        masks nothing, so each cell's whole neighbourhood has to have been
+        copied in."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        st = rt.stencil(_box)
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+        x = jnp.asarray(np.random.RandomState(8).randint(0, 16, (216, 376)),
+                        jnp.float32)
+        want = np.asarray(stencil_pallas.run(st.func, lo, hi, slots, [x],
+                                             taps))
+        real = stencil_pallas._run_padded
+        monkeypatch.setattr(
+            stencil_pallas, "_run_padded",
+            lambda *a: real(*a[:6], pltpu.InterpretParams(
+                uninitialized_memory="nan"), 16, a[8]))
+        on_mesh("2x2")
+        got = np.asarray(jax.jit(lambda a: stencil_sharded.run(
+            st.func, lo, hi, slots, [a], taps))(x))
+        assert not np.isnan(got).any()
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("mesh", ["2x2", "1x4"])
+    def test_prk_iteration_copies_no_block(self, on_mesh, mesh):
+        """One PRK iteration on the mesh, traced: no ``pad`` and no
+        ``concatenate`` as large as the local block, and the halos still
+        travel by ``ppermute``."""
+        from tests.test_pallas_stencil import _copy_sizes
+
+        on_mesh(mesh)
+        st = _star2()
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+        n = 2160
+
+        def iteration(a, b):
+            b = b + stencil_sharded.run(st.func, lo, hi, slots, [a], taps)
+            return a + 1, b
+
+        z = jnp.zeros((n, n), jnp.float32)
+        jaxpr = jax.make_jaxpr(iteration)(z, z)
+        assert "ppermute" in str(jaxpr)
+        sizes = _copy_sizes(jaxpr.jaxpr)
+        assert sizes and max(sizes) <= n * n // 4 // 8, sizes
+
+    def test_uneven_split_counts_its_copy(self, on_mesh):
+        """A shape the mesh does not divide is padded whole before the
+        shard_map: the one array-sized copy left, and it is counted."""
+        from ramba_tpu.observe import registry
+
+        on_mesh("2x2")
+        st = _star2()
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+
+        def copies(shape):
+            with registry.collect_kernel_notes() as notes:
+                jax.make_jaxpr(lambda a: stencil_sharded.run(
+                    st.func, lo, hi, slots, [a], taps))(
+                        jnp.zeros(shape, jnp.float32))
+            return sum(n.get("operand_copy", 0) for n in notes)
+
+        before = registry.get("stencil.operand_copy")
+        assert copies((400, 600)) == 0
+        assert registry.get("stencil.operand_copy") == before
+        assert copies((401, 600)) == 1
+        assert registry.get("stencil.operand_copy") == before + 1
+
+
 class TestShardedStencilND:
     """Explicit ppermute halos generalize to 1-D and 3-D stencils."""
 
