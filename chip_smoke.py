@@ -409,6 +409,81 @@ def _random_f32(rt, shape):
     return x
 
 
+def phase_groupby(rt, days, grid, groups=366, interpret_ok=False):
+    """The Xarray day-of-year pattern over a small cube on the live
+    mesh, against NumPy: the mean and the max of every day of the year
+    (the sorted chunked walk: on several devices each walks its own rows
+    and the partials are combined, which is where the scatter it replaced
+    miscompiled), and the RMS of the anomalies (one device: the walk
+    again, nothing stored; several: a take and a reduce, GSPMD's).  The
+    cube is a flush's result on a ragged grid, so on one chip it lies
+    row-major where the compiler would have put time last
+    (``core/layouts.py``).  Then the two corners of the walk no cell
+    stands on: twelve groups of thousands of narrow rows (each chunk one
+    gather), and the minimum on the eager rung, where every op meets the
+    pinned cube without a jit around it."""
+    from ramba_tpu.resilience import faults
+
+    labels = (numpy.arange(days) % 365 + (numpy.arange(days) // 1461)
+              ).astype(numpy.int32) % groups
+    rng = numpy.random.default_rng(SEED)
+    x = rt.fromarray(rng.random((days,) + tuple(grid),
+                                dtype=numpy.float32)) * 1.0
+    narrow = rt.fromarray(rng.random((days * 16, 8), dtype=numpy.float32))
+    months = (numpy.arange(days * 16) // 61 % 12).astype(numpy.int32)
+    rt.sync()
+    _require_sharded(rt, x, "groupby operand")
+    with Recorder(rt) as rec:
+        def run():
+            g = x.groupby(0, labels, groups)
+            clim = g.mean()
+            rms = float((((g - clim) ** 2).mean()) ** 0.5)
+            return clim, g.max(), rms
+
+        (clim, top, rms), first = _timed(run)
+        got, got_top = _host(clim), _host(top)
+        (_, _, again), second = _timed(run)
+        by_month = _host(narrow.groupby(0, months, 12).sum())
+    rec.require_clean(interpret_ok=interpret_ok)
+    fetches = sorted({k["fetch"] for f in rec.flushes
+                      for k in f.get("kernels", ()) if k["kernel"] == "segment"})
+    _require(fetches == ["gather", "slices"], f"segment fetches {fetches}")
+    nn = _host(narrow).astype(numpy.float64)
+    want_month = numpy.stack([nn[months == m].sum(0) for m in range(12)])
+    _require(numpy.allclose(by_month, want_month, rtol=2e-5, atol=0),
+             "the monthly sums differ from NumPy")
+    with Recorder(rt) as low, faults.inject("compile", "always"):
+        bottom = _host(x.groupby(0, labels, groups).min())
+    _require(low.rungs() == ["eager"], f"forced off the jit: {low.rungs()}")
+    xn = _host(x).astype(numpy.float64)
+    want = numpy.full((groups,) + tuple(grid), numpy.nan)
+    want_top = numpy.full(want.shape, -numpy.inf)
+    for g in range(groups):
+        members = xn[labels == g]
+        if len(members):
+            want[g], want_top[g] = members.mean(0), members.max(0)
+    want_rms = float(numpy.sqrt(numpy.mean((xn - want[labels]) ** 2)))
+    full = ~numpy.isnan(want[:, 0, 0])
+    err = float(numpy.max(numpy.abs(got[full] - want[full])))
+    _require(numpy.isnan(got[~full]).all(), "an empty day has a mean")
+    _require(err <= _tol(numpy.float32), f"clim off NumPy by {err:.3e}")
+    _require(numpy.array_equal(got_top, want_top.astype(numpy.float32)),
+             "the maxima differ from NumPy")
+    want_bottom = numpy.stack([xn[labels == g].min(0, initial=numpy.inf)
+                               for g in range(groups)])
+    _require(numpy.array_equal(bottom, want_bottom.astype(numpy.float32)),
+             "the minima on the eager rung differ from NumPy")
+    _require(abs(rms - want_rms) <= 1e-5 * want_rms and again == rms,
+             f"rms {rms!r}, {again!r}, NumPy {want_rms!r}")
+    paths = sorted(k[len("segment.path."):] for k, v in rec.counters.items()
+                   if k.startswith("segment.path.") and v > 0)
+    _require("walk_reduce" in paths, f"segment paths {paths}")
+    return {"flushes": len(rec.flushes), "rungs": rec.rungs(),
+            "segment_paths": paths, "fetches": fetches, "max_abs_err": err,
+            "rms": rms, "first_s": first, "second_s": second,
+            "layout": str(getattr(x._value(), "format", None))}
+
+
 def _star2(rt):
     """The PRK star stencil r=2 (13 flops per interior point)."""
     @rt.stencil
@@ -669,6 +744,7 @@ def main() -> int:
         ("semantics", lambda: _need(phase_semantics(rt, 1 << 28),
                                     "peak_growth_bytes")),
         ("distributed", lambda: phase_distributed(rt)),
+        ("groupby 366 days", lambda: phase_groupby(rt, 2928, (60, 380))),
         ("chain+reductions", lambda: phase_chain(rt, 1_000_000_000)),
         ("stencil 8192^2", lambda: phase_stencil(
             rt, 8192, expected_stencil_paths(8192, ndev))),

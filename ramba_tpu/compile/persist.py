@@ -208,14 +208,11 @@ class AotDispatcher:
 
     def _jit(self):
         if self._fallback is None:
-            import jax
-
             from ramba_tpu.core import fuser as _fuser
+            from ramba_tpu.core import layouts as _layouts
 
-            self._fallback = jax.jit(
-                _fuser._build_callable(self._program),
-                donate_argnums=self._donate,
-            )
+            self._fallback = _layouts.RowMajorJit(
+                _fuser._build_callable(self._program), self._donate)
         return self._fallback
 
     def __call__(self, *leaf_vals):
@@ -287,6 +284,18 @@ def lookup(fp: str, leaf_vals: Sequence, program, donate_key):
             os.unlink(path)
         except OSError:
             pass
+        return None
+    import jax
+
+    from ramba_tpu.core import layouts as _layouts
+
+    if _layouts.pins(jax.tree_util.tree_leaves(loaded.out_info)):
+        # an entry from before layouts were pinned: a deserialized
+        # executable cannot say how its results lie (core/layouts.py)
+        os.unlink(path)
+        with _lock:
+            stats["misses"] += 1
+        _registry.inc("compile.persist_miss")
         return None
     writer = payload.get("writer")
     cross = bool(writer) and writer != _writer_identity()
@@ -467,7 +476,9 @@ def store_entry(fp: str, sig: tuple, program_rec=None,
             return run(*leaf_vals)
 
         aot.__name__ = aot.__qualname__ = f"ramba_aot_{fp}"
-        fn = jax.jit(aot, donate_argnums=donate)
+        from ramba_tpu.core import layouts as _layouts
+
+        fn = _layouts.RowMajorJit(aot, donate)
         vals = _example_vals(sig)
         shardings = (candidate or {}).get("shardings")
         if shardings:
@@ -479,6 +490,11 @@ def store_entry(fp: str, sig: tuple, program_rec=None,
                 if s is not None and hasattr(v, "shape") else v
                 for v, s in zip(vals, shardings)
             ]
+        if fn.pins(*vals):
+            # compiled in every process: a deserialized executable cannot
+            # say how its results lie (core/layouts.py)
+            _registry.inc("compile.persist_store_skipped_pinned")
+            return "skipped"
         # Only a FRESH compile is stored.  An executable jax loaded from
         # its own persistent cache serializes to a blob whose XLA:CPU
         # kernel symbols do not resolve in another process ("Function

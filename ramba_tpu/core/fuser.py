@@ -55,6 +55,7 @@ import jax
 from ramba_tpu import common
 from ramba_tpu.compile import classes as _classes
 from ramba_tpu.compile import persist as _persist
+from ramba_tpu.core import layouts as _layouts
 from ramba_tpu.core import memo as _memo
 from ramba_tpu.core import plancache as _plancache
 from ramba_tpu.core.expr import (Const, Expr, Node, Scalar, OPS,
@@ -771,9 +772,8 @@ def _get_compiled(program: _Program, donate_key: tuple,
                 _ledger.record_cache(fp, "miss")
                 return aot, False, fp, backend
         _faults.check("compile", instrs=len(program.instrs))
-        fn = jax.jit(build if build is not None
-                     else _build_callable(program),
-                     donate_argnums=donate_key)
+        fn = _layouts.RowMajorJit(build if build is not None
+                                  else _build_callable(program), donate_key)
         _compile_cache[cache_key] = fn
         with _stats_lock:
             stats["compiles"] += 1
@@ -2342,7 +2342,7 @@ def analyze_pending() -> Optional[dict]:
             ma = seen_keys.get(ak)
             if ma is None:
                 compiled = (
-                    jax.jit(_build_callable(seg_prog))
+                    _layouts.RowMajorJit(_build_callable(seg_prog))
                     .lower(*seg_avals)
                     .compile()
                 )
@@ -2360,7 +2360,8 @@ def analyze_pending() -> Optional[dict]:
             out["segments"] += 1
         out.update(peak)
         return out
-    compiled = jax.jit(_build_callable(program)).lower(*avals).compile()
+    compiled = _layouts.RowMajorJit(
+        _build_callable(program)).lower(*avals).compile()
     ma = compiled.memory_analysis()
     for name in ("temp_size_in_bytes", "argument_size_in_bytes",
                  "output_size_in_bytes", "generated_code_size_in_bytes"):

@@ -13,8 +13,13 @@ with direct implementations:
   for g])`` (the xarray ``groupby().mean()`` expansion) becomes ONE segment
   reduction instead of k gathers + k reductions + a stack.
 * ``rewrite_concatenate_binop_getitem`` (:4680-4789) — ``concatenate([
-  x[:, idx_g] ∘ m[g] for g])`` (the xarray anomaly pattern) becomes two
-  gathers + one fused elementwise op.
+  x[:, idx_g] ∘ m[g] for g])`` (the xarray anomaly pattern) becomes the
+  node of the direct ``gb ∘ m`` (a take of ``m`` by label under one fused
+  elementwise op), re-ordered by one gather where the groups are not in
+  place.
+* ``rewrite_reduce_group_broadcast`` — a reduction over everything of such
+  an expression becomes ``segment_mapreduce``: the broadcast operand is
+  never stored (ramba_tpu/groupby.py).
 
 Rules run bottom-up once per flush (core/fuser.py); a rule returns a
 replacement Node or None.  All matching is defensive: any structural
@@ -167,11 +172,10 @@ def rewrite_stack_reduce_advindex(node: Node):
         labels[idx] = g
     if np.any(labels < 0):
         return None
-    out = Node(
-        "segment_reduce",
-        (kind, len(groups), dim),
-        [base, Const(_to_device(labels.astype(np.int32)))],
-    )
+    from ramba_tpu.groupby import segment_node
+
+    out = segment_node(base, Const(_to_device(labels.astype(np.int32))),
+                       kind, len(groups), dim)
     # segment_reduce leaves groups on `dim`; stack puts them on stack_axis.
     if stack_axis != dim:
         out = Node("moveaxis", (dim, stack_axis), [out])
@@ -256,6 +260,21 @@ def rewrite_concat_binop_getitem(node: Node):
         ("i", 0) if q == dim else ("s", None, None, None)
         for q in range(x_ndim)
     )
+    n = base.aval.shape[dim]
+    if (not newaxis_form and m_dim == dim and len(m_shape) == x_ndim
+            and np.array_equal(np.sort(cat_idx), np.arange(n))):
+        # the groups cover every position once: the direct call's node
+        # (``gb <op> m``, RambaGroupby._binop), then in the groups' order
+        from ramba_tpu.groupby import broadcast_node
+
+        labels = np.empty((n,), np.int32)
+        labels[cat_idx] = pos_group
+        out = broadcast_node(fname, base, m_base, Const(_to_device(labels)),
+                             dim, swapped)
+        if np.array_equal(cat_idx, np.arange(n)):
+            return out
+        return Node("getitem_adv", (enc, (dim,)),
+                    [out, Const(_to_device(cat_idx))])
     gathered_x = Node(
         "getitem_adv", (enc, (dim,)),
         [base, Const(_to_device(cat_idx))],
@@ -382,10 +401,22 @@ def rewrite_align_operand_layouts(node: Node):
     return Node(node.op, node.static, new_args, aval=node.aval)
 
 
+def rewrite_reduce_group_broadcast(node: Node):
+    """reduce(elementwise(x, take(m, labels))) over everything ->
+    segment_mapreduce: the group-broadcast operand is never stored
+    (groupby.fuse_broadcast_reduce says when)."""
+    if node.op != "reduce":
+        return None
+    from ramba_tpu.groupby import fuse_broadcast_reduce
+
+    return fuse_broadcast_reduce(node)
+
+
 RULES = [
     rewrite_arange_reshape,
     rewrite_stack_reduce_advindex,
     rewrite_concat_binop_getitem,
+    rewrite_reduce_group_broadcast,
     rewrite_align_operand_layouts,
 ]
 

@@ -94,7 +94,7 @@ def _count_kernel(kernel: str, path: str, interpret: bool,
 
 def note_kernel(kernel: str, path: str, interpret: bool = False, *,
                 block_rows=None, grid=None, vmem_limit_bytes=None,
-                halo=None, operand_copy: int = 0) -> None:
+                halo=None, operand_copy: int = 0, **chose) -> None:
     """Record that ``kernel`` (e.g. ``"stencil"``) lowered through
     ``path`` (``sharded`` / ``pallas_fast`` / ``pallas_padded`` / ``xla``
     / a Pallas family name), and whether a Pallas kernel on that path
@@ -106,17 +106,21 @@ def note_kernel(kernel: str, path: str, interpret: bool = False, *,
     kept on the note, not counted.  ``operand_copy`` is how many of its
     operands reach the kernel through an array-sized copy XLA makes:
     counted as ``<kernel>.operand_copy`` and kept on the note where it is
-    not 0, so that a replay counts it again."""
+    not 0, so that a replay counts it again.  Any further keyword is a
+    plain value a lowering chose for itself (the segment walk's ``groups``,
+    ``chunk_rows``, ``chunks``, ``fetch``, ``sharded``): kept on the note
+    as given."""
     _count_kernel(kernel, path, interpret, operand_copy)
     notes = getattr(_kernel_notes, "active", None)
     if notes is not None:
         note = {"kernel": kernel, "path": path, "interpret": bool(interpret)}
-        chose = {"block_rows": block_rows, "grid": grid,
+        sized = {"block_rows": block_rows, "grid": grid,
                  "vmem_limit_bytes": vmem_limit_bytes,
                  "operand_copy": operand_copy or None}
-        note.update((k, int(v)) for k, v in chose.items() if v is not None)
+        note.update((k, int(v)) for k, v in sized.items() if v is not None)
         if halo is not None:
             note["halo"] = halo
+        note.update(chose)
         notes.append(note)
 
 

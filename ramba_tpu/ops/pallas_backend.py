@@ -13,8 +13,10 @@ user.  Three kernel families:
   reduction outputs accumulate **on chip** across sequential grid steps
   (TPU grids execute in order on a core, so a constant-index output block
   is a legal accumulator).
-* **segred** — the masked segment reductions behind ``groupby.py``
-  (``sum``/``prod``/``min``/``max``/``count`` over 1-D data): per grid step
+* **segred** — masked segment reductions (``sum``/``prod``/``min``/``max``/
+  ``count`` over 1-D data, at most 64 groups; work groups x data), the
+  form ``groupby.py`` had before its sorted chunked walk and reachable
+  only through the autotune race (ROADMAP D6): per grid step
   the kernel unrolls the (small, static) group count, reduces each group's
   masked lanes, and accumulates ``(num_groups, 128)`` lane partials on
   chip; the cross-lane combine happens outside the kernel.

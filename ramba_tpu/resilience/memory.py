@@ -564,13 +564,18 @@ def _leaf_avals(leaf_vals) -> list:
     avals = []
     for v in leaf_vals:
         if _is_device_array(v):
-            try:
-                avals.append(
-                    jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=v.sharding)
-                )
-                continue
-            except Exception:
-                pass
+            # with the array's own layout where the backend has layouts:
+            # the estimate is of the program a call would run
+            for place in ("format", "sharding"):
+                try:
+                    avals.append(jax.ShapeDtypeStruct(
+                        v.shape, v.dtype, sharding=getattr(v, place)))
+                    break
+                except Exception:
+                    continue
+            else:
+                avals.append(jax.ShapeDtypeStruct(v.shape, v.dtype))
+            continue
         a = np.asarray(v)
         avals.append(jax.ShapeDtypeStruct(a.shape, a.dtype))
     return avals
@@ -586,7 +591,10 @@ def _xla_estimate(program, avals) -> Optional[int]:
 
     from ramba_tpu.core import fuser as _fuser
 
-    compiled = jax.jit(_fuser._build_callable(program)).lower(*avals).compile()
+    from ramba_tpu.core import layouts as _layouts
+
+    compiled = _layouts.RowMajorJit(
+        _fuser._build_callable(program)).lower(*avals).compile()
     ma = compiled.memory_analysis()
     total = 0
     for name in ("argument_size_in_bytes", "output_size_in_bytes",

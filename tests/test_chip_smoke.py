@@ -48,6 +48,12 @@ def test_distributed_phase(interpreting):
     assert "xla" not in facts["stencil_paths"], facts
 
 
+def test_groupby_phase(interpreting):
+    facts = chip_smoke.phase_groupby(rt, 1504, (6, 20), interpret_ok=True)
+    assert facts["rungs"] == ["fused"]
+    assert "walk_reduce" in facts["segment_paths"]
+
+
 def test_chain_phase(interpreting):
     facts = chip_smoke.phase_chain(rt, 1 << 18, interpret_ok=True)
     assert facts["chain_flushes"] == 1

@@ -1,0 +1,134 @@
+"""The day-of-year flush at the benchmark's size, compiled for a described
+v5e with no chip attached (on-chip-measurement guide, section 2.3), and the
+fact about the chip's compiler that ``ramba_tpu/core/layouts.py`` answers:
+left alone it lays a (time, 721, 1440) cube out with TIME minor, so a walk
+along time costs a copy of the cube.  Nothing here runs on a TPU and nothing
+printed is a time.  The only tier-1 file that loads the TPU's compiler: keep
+such tests here."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from ramba_tpu import groupby  # noqa: F401  (registers the segment ops)
+from ramba_tpu.core import layouts
+from ramba_tpu.core.expr import OPS
+from ramba_tpu.parallel import mesh as rmesh
+
+GRID, G = (721, 1440), 366
+WATERMARK = 0.9 * 16909336064  # resilience/memory.py's share of a v5e's HBM
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def one_chip(topo):
+    """The program's mesh held to the described chip."""
+    before = rmesh.get_mesh()
+    rmesh.set_mesh(Mesh(np.array(topo.devices[:1]), ("d0",)))
+    yield SingleDeviceSharding(topo.devices[0])
+    rmesh.set_mesh(before)
+
+
+def flush(cube, labels):
+    """One solve of ``doy-clim`` as the fuser linearizes it."""
+    anomaly = ("mean", 0, G, ("full", "group"),
+               (("subtract", (("a", 0), ("a", 1))),
+                ("multiply", (("t", 0), ("t", 0)))))
+    clim = OPS["segment_reduce"](("mean", G, 0), cube, labels)
+    return clim, OPS["segment_mapreduce"](anomaly, labels, cube, clim)
+
+
+def shapes(days, one_chip, layout=None):
+    where = one_chip
+    if layout is not None:
+        from jax.experimental.layout import Format, Layout
+        where = Format(Layout(major_to_minor=layout), one_chip)
+    return (jax.ShapeDtypeStruct((days,) + GRID, jnp.float32, sharding=where),
+            jax.ShapeDtypeStruct((days,), jnp.int32, sharding=one_chip))
+
+
+def total(compiled):
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("shape,dtype,keep", [
+    ((2922, 721, 1440), "float32", True),    # 7.7 % of padding
+    ((366, 721, 1440), "float32", True),
+    ((1000, 3, 3), "float32", False),        # row-major tiles: x 114
+    ((40, 9, 20), "float32", False),
+    ((15000, 15000), "float32", False),      # rank two: the compiler's
+    ((1000000,), "float32", False),
+    ((64, 512, 1024), "bfloat16", True),
+    ((), "float32", False),
+])
+def test_which_results_stay_row_major(shape, dtype, keep):
+    assert layouts.keeps_row_major(
+        jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))) is keep
+
+
+def test_several_devices_leave_the_layout_to_the_compiler():
+    if len(jax.devices()) == 1:
+        pytest.skip("tier-1's mesh has eight devices")
+    fn = layouts.RowMajorJit(lambda a: (a + 1.0,))
+    x = jnp.zeros((4, 128, 256), jnp.float32)
+    assert fn._jit_for((x,)) is fn._plain
+    assert float(fn(x)[0].sum()) == 4 * 128 * 256
+
+
+def test_left_alone_the_compiler_puts_time_last_and_copies_the_cube(one_chip):
+    """The cube's tiles cover (lon, time), which pad 0.3 %, not (lat,
+    lon), which pad 7.7 %, and the climatology's likewise; the walk then
+    costs one time-major copy of the cube, and a fourth year is over the
+    15.22 GB watermark (PERF.md section 6, PR 30)."""
+    c = jax.jit(flush).lower(*shapes(1096, one_chip)).compile()
+    (cube, _), _ = c.input_formats
+    assert cube.layout.major_to_minor == (1, 2, 0)
+    assert c.output_formats[0].layout.major_to_minor == (1, 2, 0)
+    copy = 1096 * 728 * 1536 * 4  # time-major, as the chip tiles it
+    assert copy < c.memory_analysis().temp_size_in_bytes < 1.4 * copy
+    assert total(c) < WATERMARK
+    four = jax.jit(flush).lower(*shapes(1461, one_chip)).compile()
+    assert total(four) > WATERMARK
+
+
+def test_the_system_keeps_the_cube_row_major(one_chip):
+    def cube(s):
+        out = s
+        for d, n in enumerate((64,) + GRID):
+            shape = [n if i == d else 1 for i in range(3)]
+            out = out + jnp.arange(n, dtype=jnp.float32).reshape(shape)
+        return (out,)
+
+    s = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    assert jax.jit(cube).lower(s).compile().output_formats[
+        0].layout.major_to_minor != (0, 1, 2)
+    pinned = layouts.RowMajorJit(cube).lower(s).compile()
+    assert pinned.output_formats[0].layout.major_to_minor == (0, 1, 2)
+
+
+def test_the_flush_at_the_cells_size_stores_nothing_of_the_cube(one_chip):
+    """One solve of ``doy-clim`` at 8 years on the row-major cube: the two
+    walks, no copy of the operand, under the watermark."""
+    compiled = layouts.RowMajorJit(flush).lower(
+        *shapes(2922, one_chip, (0, 1, 2))).compile()
+    assert compiled.output_formats[0].layout.major_to_minor == (0, 1, 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    assert 14.6e9 < total(compiled) < WATERMARK
+    assert compiled.as_text().count(" while(") == 2
