@@ -582,6 +582,56 @@ def phase_stencil_sweeps(rt, n, sweeps, jacobi_iters, expect,
             "iterate_first_s": first, "jacobi_first_s": second}
 
 
+def phase_prk_scalars(rt, n, iters, expect, interpret_ok=False):
+    """Two PRK solves (``B += stencil(A); A += 1.0``, ``iters`` times in
+    one flush, then the norm): in the second no scalar operand crosses to
+    the device.  Each ``1.0`` is found resident (``dispatch.scalar.hit``)
+    and none is put (``dispatch.scalar.put``); a solve as slow as the
+    first would say the executable copies the resident arrays instead of
+    taking them where they lie."""
+    @rt.stencil
+    def star(a):  # PRK's weights, r = 2: one sweep of i + j adds 2
+        return (0.25 * (a[0, 1] - a[0, -1] + a[1, 0] - a[-1, 0])
+                + 0.125 * (a[0, 2] - a[0, -2] + a[2, 0] - a[-2, 0]))
+
+    with Recorder(rt) as rec:
+        i = rt.arange(n, dtype=numpy.float32)
+        A = i[:, None] + i[None, :]
+        B = rt.zeros((n, n), dtype=numpy.float32)
+        rt.sync()
+
+        def solve():
+            nonlocal A, B
+            for _ in range(iters):
+                B += rt.sstencil(star, A)
+                A += 1.0
+            return float(rt.sum(abs(B))) / (n - 4) ** 2
+
+        with Recorder(rt) as r1:
+            norm1, first = _timed(solve)
+        with Recorder(rt) as r2:
+            norm2, second = _timed(solve)
+        for T, norm in ((iters, norm1), (2 * iters, norm2)):
+            _require(abs(norm - 2.0 * T) <= 1e-4 * 2.0 * T,
+                     f"PRK norm after {T} iterations = {norm!r}")
+        _require(len(r2.flushes) == 1 and r2.flushes[0]["cache"] == "hit",
+                 f"second solve: {[f['cache'] for f in r2.flushes]}")
+        _require(set(r1.kernel_paths()) == set(expect),
+                 f"PRK took {r1.kernel_paths()}, want {tuple(expect)}")
+        hits = r2.counters.get("dispatch.scalar.hit", 0)
+        puts = r2.counters.get("dispatch.scalar.put", 0)
+        _require(puts == 0 and hits >= iters,
+                 f"second solve: {hits} scalar operands found resident, "
+                 f"{puts} put; want at least {iters} and 0")
+        _require_sharded(rt, B, "PRK B")
+        del A, B
+    rec.require_clean(interpret_ok=interpret_ok)
+    return {"n": n, "iters": iters, "rungs": rec.rungs(),
+            "scalar_puts_first": r1.counters.get("dispatch.scalar.put", 0),
+            "scalar_hits_second": hits, "scalar_puts_second": puts,
+            "first_s": first, "second_s": second}
+
+
 def phase_axpy(rt, n_total, interpret_ok=False):
     """BASELINE config 4: ``random.normal`` fill, then ``Y += a*X`` in
     place, ``n_total`` elements in X and Y together."""
@@ -750,6 +800,8 @@ def main() -> int:
             rt, 8192, expected_stencil_paths(8192, ndev))),
         ("stencil sweeps 8192^2", lambda: phase_stencil_sweeps(
             rt, 8192, 5, 10, expected_stencil_paths(8192, ndev))),
+        ("prk scalars 8192^2", lambda: phase_prk_scalars(
+            rt, 8192, 10, expected_stencil_paths(8192, ndev))),
         ("axpy 1e9", lambda: phase_axpy(rt, 1_000_000_000)),
         ("broadcast 32768^2", lambda: phase_broadcast(rt, 32768)),
         ("stencil 30000^2", lambda: phase_stencil(

@@ -346,10 +346,12 @@ def maybe_prewarm(fp: str, program, leaf_vals, donate_key: tuple) -> None:
 
     avals = []
     for v in leaf_vals:
-        if hasattr(v, "shape") and hasattr(v, "dtype"):
+        if getattr(v, "shape", ()) and hasattr(v, "dtype"):
             avals.append(jax.ShapeDtypeStruct(v.shape, v.dtype))
         else:
-            avals.append(v)  # python scalar: pass through by value
+            # a scalar operand, a number or its resident array: pass
+            # through by value, weak type and all
+            avals.append(v)
 
     def warm():
         import jax.numpy as jnp

@@ -58,10 +58,16 @@ class Const(Expr):
 class Scalar(Expr):
     """Leaf holding a python scalar.
 
-    Passed into the jitted flush as a (weakly-typed) argument so that changing
-    the *value* of a scalar does not invalidate the compile cache — the analog
-    of the reference pickling op operands separately from the generated source
-    whose name is a hash of the code only (ramba.py:8260-8265,8286-8298).
+    An argument of the jitted flush, weakly typed where the number is, so
+    that changing the *value* of a scalar does not invalidate the compile
+    cache — the analog of the reference pickling op operands separately from
+    the generated source whose name is a hash of the code only
+    (ramba.py:8260-8265,8286-8298).  The leaf keeps the number; what the
+    compiled call receives is a device array of this leaf's aval, committed
+    and replicated over the mesh, from the fuser's table of the values seen
+    (``fuser._resident_scalars``): a value met again crosses to the device
+    no second time, and jit places no number itself.  Only a value this
+    class does not table (below) is still handed over as a number.
 
     The aval is ``jax.eval_shape(lambda: jnp.asarray(value))``.  For the
     Python and NumPy number types it depends on ``type(value)`` and the

@@ -43,6 +43,7 @@ import contextvars
 import hashlib
 import itertools
 import os
+import struct
 import threading
 import time
 import warnings
@@ -59,6 +60,7 @@ from ramba_tpu.core import layouts as _layouts
 from ramba_tpu.core import memo as _memo
 from ramba_tpu.core import plancache as _plancache
 from ramba_tpu.core.expr import (Const, Expr, Node, Scalar, OPS,
+                                 _scalar_key,
                                  semantic_fingerprint as _semantic_fingerprint)
 from ramba_tpu.observe import attrib as _attrib
 from ramba_tpu.observe import events as _events
@@ -1305,9 +1307,14 @@ def _run_host(program: _Program, leaf_vals, span: Optional[dict]):
     t0 = time.perf_counter()
     cpu = jax.devices("cpu")[0]
     host_vals = []
-    for v in leaf_vals:
+    for kind, v in zip(program.leaf_kinds, leaf_vals):
         if isinstance(v, jax.Array):
-            v = jax.device_put(np.asarray(v), cpu)
+            if kind == "S" and v.weak_type:
+                # a resident scalar, back to the number it stands for:
+                # NumPy's array would lose its weak type
+                v = v.item()
+            else:
+                v = jax.device_put(np.asarray(v), cpu)
         host_vals.append(v)
     with jax.default_device(cpu):
         outs = _build_callable(program)(*host_vals)
@@ -1621,6 +1628,101 @@ def _gather_leaf_vals(leaves):
         else:
             leaf_vals.append(leaf.value)
     return leaf_vals, leaf_bytes
+
+
+# Scalar operands resident on the device: (the value's type and the
+# semantic fingerprint, the value's bits) -> the committed array,
+# replicated over the mesh as it stands, that a compiled call takes in the
+# number's place.  A Python or NumPy number among a jit call's arguments
+# crosses to the device on every call, and where the computation has more
+# than one device jit leaves its C++ path for each of them
+# (``cpp_pjit_shard_arg_fallback``: PERF.md section 6, PR 31).  Shared by
+# every stream, emptied with the mesh, dropped whole when full.
+_device_scalars: dict = {}
+_DEVICE_SCALARS_MAX = 256
+_device_scalars_home = None  # (mesh epoch, the mesh's replicated sharding)
+_device_scalars_lock = threading.Lock()
+
+
+def _scalar_bits(value):
+    """What tells two values of one type apart: ``0.0`` from ``-0.0`` and
+    one NaN from another, which ``==`` does not."""
+    t = type(value)
+    if t is float:
+        return struct.pack("d", value)
+    if t is complex:
+        return struct.pack("dd", value.real, value.imag)
+    if t is int or t is bool:
+        return value
+    return value.tobytes()  # a NumPy scalar
+
+
+def _lives_on(v, home) -> bool:
+    """Whether a compiled call can take ``v`` beside an array committed to
+    the sharding ``home``: it is no device array, or committed nowhere,
+    or lives on the same devices."""
+    sharding = getattr(v, "sharding", None)
+    return (sharding is None
+            or getattr(sharding, "mesh", None) is home.mesh
+            or not getattr(v, "committed", True)
+            or sharding.device_set == home.device_set)
+
+
+def _resident_scalars(leaves, leaf_vals) -> list:
+    """``leaf_vals`` with each ``Scalar`` leaf's number replaced by its
+    array from ``_device_scalars``, for every rung of the ladder alike.
+    Eligible are exactly the values whose aval ``Scalar`` tables
+    (``expr._scalar_key``); the array has that very aval, ``weak_type``
+    included, so the program traced for it is the one traced for the
+    number.  A value seen before costs a dictionary lookup
+    (``dispatch.scalar.hit``), a new one the transfer it has always cost
+    (``dispatch.scalar.put``).  Left as they are: a value of any other
+    type or width, one whose array would not have the leaf's aval (a
+    leaf built under another x64 regime), and every scalar of a flush
+    one of whose arrays is committed to other devices than the mesh's
+    (an array that outlived a ``set_mesh``), which a scalar committed to
+    the mesh would set against it."""
+    global _device_scalars_home
+    if not any(isinstance(leaf, Scalar) for leaf in leaves):
+        return leaf_vals
+    vals = list(leaf_vals)
+    hits = puts = 0
+    with _device_scalars_lock:
+        home = _device_scalars_home
+        if home is None or home[0] != _mesh.mesh_epoch:
+            _device_scalars.clear()
+            # the sharding before the epoch: get_mesh may install the mesh
+            where = _mesh.replicated_sharding()
+            home = _device_scalars_home = (_mesh.mesh_epoch, where)
+        where = home[1]
+        if not all(_lives_on(v, where) for v in leaf_vals):
+            return leaf_vals
+        for i, leaf in enumerate(leaves):
+            if not isinstance(leaf, Scalar):
+                continue
+            kind = _scalar_key(leaf.value)
+            if kind is None:
+                continue
+            key = (kind, _scalar_bits(leaf.value))
+            arr = _device_scalars.get(key)
+            if arr is None:
+                arr = jax.device_put(leaf.value, where)  # host to mesh
+                aval = leaf.aval  # a ShapeDtypeStruct, as eval_shape gave it
+                if (arr.shape, arr.dtype, arr.weak_type) != (
+                        aval.shape, aval.dtype, aval.weak_type):
+                    continue
+                if len(_device_scalars) >= _DEVICE_SCALARS_MAX:
+                    _device_scalars.clear()
+                _device_scalars[key] = arr
+                puts += 1
+            else:
+                hits += 1
+            vals[i] = arr
+    if hits:
+        _registry.inc("dispatch.scalar.hit", hits)
+    if puts:
+        _registry.inc("dispatch.scalar.put", puts)
+    return vals
 
 
 def _donation_mask(leaves, leaf_vals) -> tuple:
@@ -2158,6 +2260,9 @@ def _flush_dispatch_traced(work: "_FlushWork", *, coalesced: int = 0) -> list:
                           ("compile", "dispatch", "device_execute"))
         t_ladder = time.perf_counter()
         with _profile.flush_annotation("run", span):
+            # from here on no scalar operand is a number: whatever rung
+            # runs hands its callable device arrays only
+            leaf_vals = _resident_scalars(work.leaves, leaf_vals)
             with warnings.catch_warnings():
                 warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
                 if hedge_s is not None:

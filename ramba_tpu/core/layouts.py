@@ -128,8 +128,9 @@ class RowMajorJit:
         self._by_signature = {}
 
     def _jit_for(self, args):
-        sig = tuple((v.shape, v.dtype) if hasattr(v, "shape")
-                    and hasattr(v, "dtype") else type(v) for v in args)
+        sig = tuple((v.shape, v.dtype, getattr(v, "weak_type", False))
+                    if hasattr(v, "shape") and hasattr(v, "dtype")
+                    else type(v) for v in args)
         fn = self._by_signature.get(sig)
         if fn is None:
             formats = result_formats(self._plain, args)

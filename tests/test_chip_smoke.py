@@ -73,6 +73,14 @@ def test_stencil_sweeps_phase(interpreting):
     assert facts["rungs"] == ["fused"]
 
 
+def test_prk_scalars_phase(interpreting):
+    facts = chip_smoke.phase_prk_scalars(rt, 136, 10, _paths(136),
+                                         interpret_ok=True)
+    assert facts["rungs"] == ["fused"]
+    assert facts["scalar_puts_second"] == 0
+    assert facts["scalar_hits_second"] >= 10
+
+
 def test_axpy_phase(interpreting):
     assert chip_smoke.phase_axpy(rt, 1 << 18, interpret_ok=True)[
         "rungs"] == ["fused"]
