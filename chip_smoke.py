@@ -632,6 +632,59 @@ def phase_prk_scalars(rt, n, iters, expect, interpret_ok=False):
             "first_s": first, "second_s": second}
 
 
+def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
+    """NAS MG's four operators over the pyramid of an n^3 grid
+    (``benchmark/programs/nas_mg.py``: ``iters`` V-cycles and the
+    residual, twice over): a flush too long for one program (a V-cycle at
+    512^3 is 668 instructions, a program at most 768) runs as chained
+    segments on
+    the fused rung, the second time from the executables of the first.
+    ``want`` is the norm to meet; the NumPy reference gives it where it is
+    not given (toy sizes: at 512^3 it takes minutes)."""
+    from benchmark.programs import nas_mg
+
+    cfg = {"n": n, "iterations": iters, "dtype": "float32",
+           "smoother": "B+", "norm": want,
+           "as_published": {"n": n, "iterations": iters},
+           "assumed": {"norm_rtol": 1e-4, "window_rtol": 2e-5}}
+    if want is None:
+        cfg["norm"] = nas_mg.mg_np(n, iters, numpy.float64, "B+")[0][-1]
+    prog = nas_mg.Program(rt, cfg, {"solve": [{"op": "mg"}]},
+                          numpy.random.default_rng(SEED), 1)
+    with Recorder(rt) as rec:
+        prog.setup()
+        with Recorder(rt) as r1:
+            out1, first = _timed(prog.solve)
+        with Recorder(rt) as r2:
+            out2, second = _timed(prog.solve)
+        for out in (out1, out2):
+            bad = prog.check(out)
+            _require(bad is None, bad)
+        _require(out1 == out2, f"two solves read {out1} and {out2}")
+        calls = r2.counters.get("fuser.segments", 0)
+        hits = r2.counters.get("fuser.segment.hit", 0)
+        _require(calls >= 2 and hits == calls
+                 and not r2.counters.get("fuser.segment.miss", 0),
+                 f"second solve: {calls} segment calls, {hits} hits")
+        _require([f["cache"] for f in r2.flushes] == ["hit"],
+                 f"second solve: {[f['cache'] for f in r2.flushes]}")
+        paths = sorted(k[len("stencil.path."):] for k, v in
+                       r2.counters.items()
+                       if k.startswith("stencil.path.") and v > 0)
+        if rt.get_mesh().devices.size == 1:
+            # on a mesh the layouts of the pyramid's nine sizes are
+            # GSPMD's to choose: nothing to hold them to
+            _require_sharded(rt, prog.u, "MG u")
+        prog.u = prog.r = prog.v = None
+    rec.require_clean(interpret_ok=interpret_ok)
+    return {"n": n, "norm": out2[0], "want": cfg["norm"],
+            "path": "+".join(paths), "rungs": rec.rungs(),
+            "instrs": r2.flushes[0]["instrs"], "segments": calls,
+            "segment_hits_second": hits,
+            "segment_misses_first": r1.counters.get("fuser.segment.miss", 0),
+            "first_s": first, "second_s": second}
+
+
 def phase_axpy(rt, n_total, interpret_ok=False):
     """BASELINE config 4: ``random.normal`` fill, then ``Y += a*X`` in
     place, ``n_total`` elements in X and Y together."""
@@ -802,6 +855,10 @@ def main() -> int:
             rt, 8192, 5, 10, expected_stencil_paths(8192, ndev))),
         ("prk scalars 8192^2", lambda: phase_prk_scalars(
             rt, 8192, 10, expected_stencil_paths(8192, ndev))),
+        # NumPy float64 reads 1.274675290838857e-04 after two iterations at
+        # class C, float32 1.2746751486658546e-04 (PERF.md, PR 32)
+        ("mg 512^3", lambda: phase_mg(rt, 512, 1.274675290838857e-04,
+                                      iters=2)),
         ("axpy 1e9", lambda: phase_axpy(rt, 1_000_000_000)),
         ("broadcast 32768^2", lambda: phase_broadcast(rt, 32768)),
         ("stencil 30000^2", lambda: phase_stencil(

@@ -83,12 +83,17 @@ max_pending_ops = _env_int("RAMBA_TPU_MAX_PENDING", 10_000)
 
 # Max instructions per compiled XLA program.  A flush whose linearized
 # program exceeds this is segmented into chained jit calls of at most this
-# many instructions each (fuser._run_segmented).  XLA compile time grows
-# superlinearly with instruction count (a single 3000-op elementwise chain
-# took >2 min to compile on CPU); segments of a few hundred compile in
-# seconds, and repeated-structure chains reuse ONE compiled segment.  Set to
-# 0 to disable segmentation.
-max_program_instrs = _env_int("RAMBA_TPU_MAX_PROGRAM_INSTRS", 384)
+# many instructions each (fuser._run_segmented), cut at the same places of
+# every repetition of a loop the script unrolled, so that a repeated
+# structure compiles ONE segment and reuses it.  XLA compile time grows
+# with instruction count (a single 3000-op elementwise chain took >2 min
+# to compile on CPU); what a cut costs is what crosses it, stored and read
+# back.  Read on the chip (NAS MG class C, 668 instructions an iteration,
+# 13,381 a solve; PERF.md section 6, PR 32): at 768 an iteration is one
+# segment, a solve 3603 ms, cold set-up 76 s; at 384 (the value until
+# then) an iteration is cut in two, 3890 ms and 72 s; at 192, 3943 ms and
+# 73 s.  Set to 0 to disable segmentation.
+max_program_instrs = _env_int("RAMBA_TPU_MAX_PROGRAM_INSTRS", 768)
 
 # How many mesh axes the default mesh is factored into (1..3).
 mesh_ndim = _env_int("RAMBA_TPU_MESH_NDIM", 2)

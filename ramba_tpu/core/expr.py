@@ -496,14 +496,18 @@ def decode_index(enc: tuple):
 
 @defop("getitem")
 def _op_getitem(static, x):
+    from ramba_tpu.core import slicing
+
     (enc,) = static
-    return x[decode_index(enc)]
+    return slicing.take(x, decode_index(enc))
 
 
 @defop("setitem")
 def _op_setitem(static, x, v):
+    from ramba_tpu.core import slicing
+
     (enc,) = static
-    return x.at[decode_index(enc)].set(v.astype(x.dtype))
+    return slicing.put(x, decode_index(enc), v.astype(x.dtype))
 
 
 @defop("getitem_adv")

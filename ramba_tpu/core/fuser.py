@@ -52,6 +52,7 @@ from contextlib import contextmanager
 from typing import Optional, Sequence
 
 import jax
+import numpy as np
 
 from ramba_tpu import common
 from ramba_tpu.compile import classes as _classes
@@ -917,29 +918,124 @@ def _live_grouped(program: _Program, leaf_avals,
                     live_cuts=cuts)
 
 
+def _repetition(program: _Program):
+    """``(start, period, count)`` of the unrolled loop that makes up most
+    of ``program``, or None: ``count`` whole repetitions of ``period``
+    instructions from ``start``, each instruction equal to the one a
+    period later in op, static and where its operands come from (a leaf
+    by its kind, a value by its distance).  A script that iterates (a
+    solver's sweeps, a chain of equal updates) linearizes to this."""
+    instrs, n_leaves, kinds = program.instrs, program.n_leaves, \
+        program.leaf_kinds
+    sig = np.empty(len(instrs), np.int64)
+    for i, (op, st, args) in enumerate(instrs):
+        rel = tuple(kinds[s] if s < n_leaves else n_leaves + i - s
+                    for s in args)
+        try:
+            sig[i] = hash((op, st, rel))
+        except TypeError:  # a static that carries a list or a dict
+            sig[i] = hash((op, rel))
+    n, mid = len(sig), len(sig) // 2
+    for period in np.flatnonzero(sig[mid + 1:] == sig[mid]) + 1:
+        if mid + 2 * period > n:
+            break
+        if not np.array_equal(sig[mid:mid + period],
+                              sig[mid + period:mid + 2 * period]):
+            continue
+        same = sig[:-period] == sig[period:]
+        breaks = np.flatnonzero(~same)
+        below, above = breaks[breaks < mid], breaks[breaks > mid]
+        start = int(below[-1]) + 1 if len(below) else 0
+        stop = (int(above[0]) if len(above) else len(same)) + period
+        if 2 * (stop - start) >= n:  # a local echo is not the loop
+            return start, int(period), (stop - start) // int(period)
+    return None
+
+
+def _segment_ends(program: _Program, seg_size: int) -> list:
+    """Where the count-bounded segments of ``program`` end.  A program
+    that repeats is cut at the same places of every repetition (whole
+    repetitions to a segment where several fit, else a repetition in
+    equal parts), so that one executable serves every repetition; what
+    stands before and after the loop, and a program with no loop, is cut
+    every ``seg_size`` instructions."""
+    ninstr = len(program.instrs)
+    loop = _repetition(program) if ninstr > seg_size else None
+    if loop is None:
+        return list(range(seg_size, ninstr, seg_size)) + [ninstr]
+    start, period, count = loop
+    ends = list(range(seg_size, start, seg_size))
+    if start:
+        ends.append(start)
+    if period <= seg_size:
+        step = period * (seg_size // period)
+        ends += list(range(start + step, start + period * count, step))
+    else:
+        parts = -(-period // seg_size)
+        ends += [start + k * period + (j + 1) * period // parts
+                 for k in range(count) for j in range(parts)]
+    last = start + period * count
+    if not ends or ends[-1] != last:
+        ends.append(last)
+    ends += list(range(last + seg_size, ninstr, seg_size))
+    if ends[-1] != ninstr:
+        ends.append(ninstr)
+    return ends
+
+
+#: a program's count-bounded segments, by (program, size): the script of
+#: an iterating solver flushes the same thousands of instructions every
+#: time, and finding its loop and cutting it is host time with the chip
+#: idle (50 to 90 ms of a 13,381-instruction flush; PERF.md section 6, PR
+#: 32).  Segments hold no arrays.  Dropped whole when full.
+_segments_cache: dict = {}
+_SEGMENTS_CACHE_MAX = 64
+
+
 def _iter_segments(program: _Program, last_use: dict,
                    seg_size: Optional[int] = None, *,
                    slot_bytes: Optional[dict] = None,
                    max_seg_bytes: Optional[int] = None):
     """Split ``program`` into sub-programs of at most ``seg_size``
-    (default ``common.max_program_instrs``) instructions — or, when
-    ``max_seg_bytes``/``slot_bytes`` are given (the ``chunked`` rung), of
-    bounded *estimated live bytes* per segment.  Yields
+    (default ``common.max_program_instrs``) instructions, cut where
+    ``_segment_ends`` says — or, when ``max_seg_bytes``/``slot_bytes`` are
+    given (the ``chunked`` rung), of bounded *estimated live bytes* per
+    segment.  Returns the list of
     ``(seg_prog, in_slots, out_here, top)`` where ``in_slots`` are the
     parent-program value slots the segment consumes, ``out_here`` the
     parent slots it must emit (used later or program outputs), and ``top``
     the first parent slot index past this segment."""
-    instrs, n_leaves = program.instrs, program.n_leaves
     if seg_size is None:
         seg_size = common.max_program_instrs
+    if max_seg_bytes and slot_bytes is not None:
+        return list(_cut_segments(program, last_use, seg_size, slot_bytes,
+                                  max_seg_bytes))
+    if program.key_hash == -1:  # unhashable: cut anew every time
+        return list(_cut_segments(program, last_use, seg_size))
+    key = (_plancache._HashedKey(program.key, program.key_hash), seg_size)
+    segments = _segments_cache.get(key)
+    if segments is None:
+        segments = tuple(_cut_segments(program, last_use, seg_size))
+        if len(_segments_cache) >= _SEGMENTS_CACHE_MAX:
+            _segments_cache.clear()
+        _segments_cache[key] = segments
+    return segments
+
+
+def _cut_segments(program: _Program, last_use: dict, seg_size: int,
+                  slot_bytes: Optional[dict] = None,
+                  max_seg_bytes: Optional[int] = None):
+    instrs, n_leaves = program.instrs, program.n_leaves
     ninstr = len(instrs)
+    by_bytes = slot_bytes is not None
+    ends = None if by_bytes else iter(_segment_ends(program, seg_size))
     start = 0
     while start < ninstr:
-        if max_seg_bytes and slot_bytes is not None:
+        if by_bytes:
             end = _byte_segment_end(instrs, n_leaves, start, slot_bytes,
                                     max_seg_bytes, seg_size)
         else:
-            end = min(start + seg_size, ninstr)
+            end = next(ends)
         base, top = n_leaves + start, n_leaves + end
         seg = instrs[start:end]
         in_slots = sorted(
@@ -960,7 +1056,7 @@ def _iter_segments(program: _Program, last_use: dict,
                   for s in in_slots),
             tuple(nin + (s - base) for s in out_here),
         )
-        yield seg_prog, in_slots, out_here, top
+        yield seg_prog, tuple(in_slots), tuple(out_here), top
         start = end
 
 
@@ -1011,6 +1107,8 @@ def _run_segmented(program: _Program, leaf_vals: list, donate_idx: tuple,
         with _stats_lock:
             stats["segments"] += 1
         _registry.inc("fuser.segments")
+        _registry.inc("fuser.segment.miss" if is_new
+                      else "fuser.segment.hit")
     return tuple(vals[s] for s in program.out_slots)
 
 

@@ -605,6 +605,47 @@ def _xla_estimate(program, avals) -> Optional[int]:
     return total if total > 0 else None
 
 
+def _segmented_estimate(program, avals, donate) -> Optional[int]:
+    """The same for a program the fused rung runs as chained segments
+    (``fuser._run_segmented``): the worst segment's own numbers plus the
+    values that are live past it and not its arguments (leaves the caller
+    keeps, values carried to a later segment).  Each distinct segment is
+    lowered once; the whole program never is, which is what segmenting
+    is for.  Values between segments carry shapes only, as in
+    ``fuser.analyze_pending``."""
+    import jax
+
+    from ramba_tpu.analyze.rules import _aval_nbytes
+    from ramba_tpu.core import fuser as _fuser
+
+    last_use = _fuser._last_use_map(program)
+    donated = set(donate)
+    live = dict(enumerate(avals))
+    nbytes = {s: _aval_nbytes(a) for s, a in live.items()}
+    seen: dict = {}
+    peak = 0
+    for seg, in_slots, out_here, top in _fuser._iter_segments(program,
+                                                              last_use):
+        seg_avals = [live[s] for s in in_slots]
+        key = _est_key(seg, seg_avals, ())
+        if key not in seen:
+            est = _xla_estimate(seg, seg_avals)
+            if est is None:
+                return None
+            seen[key] = est, jax.eval_shape(_fuser._build_callable(seg),
+                                            *seg_avals)
+        est, outs = seen[key]
+        peak = max(peak, est + sum(nbytes.values())
+                   - sum(nbytes[s] for s in in_slots))
+        for s in in_slots:
+            if last_use.get(s, 0) < top and (s >= program.n_leaves
+                                             or s in donated):
+                del live[s], nbytes[s]
+        for s, a in zip(out_here, outs):
+            live[s], nbytes[s] = a, _aval_nbytes(a)
+    return peak
+
+
 def _est_key(program, avals, donate) -> tuple:
     return (program.key, tuple(donate),
             tuple((tuple(a.shape), str(a.dtype)) for a in avals))
@@ -626,8 +667,12 @@ def estimate_program_bytes(program, leaf_vals, donate=()) -> int:
         return cached
     est: Optional[int] = None
     if os.environ.get("RAMBA_HBM_ESTIMATE", "") != "analytic":
+        cap = _common.max_program_instrs
         try:
-            est = _xla_estimate(program, avals)
+            if cap and len(program.instrs) > cap:
+                est = _segmented_estimate(program, avals, donate)
+            else:
+                est = _xla_estimate(program, avals)
         except Exception:
             est = None
     if est is None:

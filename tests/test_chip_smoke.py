@@ -81,6 +81,14 @@ def test_prk_scalars_phase(interpreting):
     assert facts["scalar_hits_second"] >= 10
 
 
+def test_mg_phase(interpreting):
+    facts = chip_smoke.phase_mg(rt, 16, iters=6, interpret_ok=True)
+    assert facts["rungs"] == ["fused"] and "xla" in facts["path"]
+    assert facts["segments"] >= 2
+    assert facts["segment_hits_second"] == facts["segments"]
+    assert abs(facts["norm"] - facts["want"]) < 1e-4 * facts["want"]
+
+
 def test_axpy_phase(interpreting):
     assert chip_smoke.phase_axpy(rt, 1 << 18, interpret_ok=True)[
         "rungs"] == ["fused"]

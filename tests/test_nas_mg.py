@@ -1,0 +1,260 @@
+"""NAS MG (``benchmark/programs/nas_mg.py``): the NumPy reference against
+NPB's published class-S value, the ``ramba_tpu`` port against the
+reference at small sizes, each operator alone with the periodic seam, and
+the flush that is too long for one program: cut at the same places of
+every iteration, so that one executable serves them all.
+"""
+
+import numpy as np
+import pytest
+
+import ramba_tpu as rt
+from benchmark.programs import nas_mg
+from ramba_tpu import common, diagnostics
+from ramba_tpu.core import fuser
+from ramba_tpu.resilience import memory
+
+#: NPB 3.x MG, class S: 32^3, 4 iterations (the published verification
+#: value; the norm after each iteration is this repo's own float64 run)
+CLASS_S = 0.5307707005734e-04
+CLASS_S_BY_ITERATION = [2.933796097632787e-03, 6.315001790622818e-04,
+                        1.7360856792372287e-04, 5.307707005734874e-05]
+
+
+def program(n, nit, smoother="SWA", resident=True):
+    cfg = {"n": n, "iterations": nit, "dtype": "float32",
+           "smoother": smoother, "norm": 1.0,
+           "as_published": {"n": 512, "iterations": 20},
+           "assumed": {"norm_rtol": 1e-4, "window_rtol": 2e-5}}
+    prog = nas_mg.Program(rt, cfg, {"solve": [{"op": "mg"}]},
+                          np.random.default_rng(3), 1)
+    if resident:
+        v, prog.charges = nas_mg.zran3(n, prog.dtype)
+        prog.v = rt.fromarray(nas_mg.wrap_ghosts(v))
+    return prog
+
+
+def inner(a):
+    return np.asarray(a)[1:-1, 1:-1, 1:-1]
+
+
+def moved(before, name):
+    return diagnostics.counters().get(name, 0) - before.get(name, 0)
+
+
+# -- the reference is the source's -------------------------------------------
+def test_mg_np_float64_gives_npbs_class_s_value():
+    norms, u, r = nas_mg.mg_np(32, 4, np.float64, "SWA")
+    assert abs(norms[-1] - CLASS_S) <= 1e-8 * CLASS_S
+    np.testing.assert_allclose(norms, CLASS_S_BY_ITERATION, rtol=1e-12)
+    assert u.dtype == r.dtype == np.float64
+
+
+def test_the_generator_and_the_charges():
+    x = nas_mg.randlc_stream(3) * 2.0 ** -46
+    np.testing.assert_allclose(x, [0.79452191, 0.86906527, 0.64763173],
+                               atol=5e-9)
+    # drawn in blocks or whole, the stream and its extremes are the same
+    whole = nas_mg.randlc_stream(40 ** 3)
+    v, charges = nas_mg.zran3(40, np.float32)
+    order = np.argsort(whole)
+    assert set(charges[:10]) == set(order[-10:])
+    assert set(charges[10:]) == set(order[:10])
+    assert v.sum() == 0 and np.abs(v).sum() == 20
+    assert (v.ravel()[charges[:10]] == 1).all()
+
+
+def test_zran3_block_by_block(monkeypatch):
+    want, _ = nas_mg.zran3(16, np.float32)
+    monkeypatch.setattr(nas_mg, "ZRAN_BLOCK", 1000)  # 4096 = 4 blocks and a bit
+    got, _ = nas_mg.zran3(16, np.float32)
+    np.testing.assert_array_equal(got, want)
+
+
+# -- the port against the reference ------------------------------------------
+@pytest.mark.parametrize("n,smoother", [(16, "SWA"), (16, "B+"),
+                                        (32, "SWA"), (32, "B+")])
+def test_a_solve_agrees_with_mg_np_float32(n, smoother):
+    prog = program(n, 4, smoother)
+    (norm,) = prog.solve()
+    norms, u, r = nas_mg.mg_np(n, 4, np.float32, smoother)
+    assert abs(norm - norms[-1]) <= 2e-5 * norms[-1]
+    # u is O(1) near a charge; r is what is left of +-1 after four cycles
+    np.testing.assert_allclose(inner(prog.u), u, rtol=0, atol=2e-6)
+    np.testing.assert_allclose(inner(prog.r), r, rtol=0,
+                               atol=2e-6 * max(1.0, float(np.abs(u).max())))
+    for a in (prog.u, prog.r):  # the ghost layers are the faces they copy
+        host = np.asarray(a)
+        np.testing.assert_array_equal(host, nas_mg.comm3(host.copy()))
+
+
+@pytest.mark.parametrize("op", ["resid", "psinv", "rprj3", "interp",
+                                "interp_onto_zero"])
+def test_an_operator_alone_against_its_numpy_form(op):
+    """On a random periodic field, every point compared: the seam (first
+    and last planes of every axis) is part of the array."""
+    n, f = 16, np.float32
+    prog = program(n, 1)
+    rng = np.random.default_rng(11)
+    a, b = (rng.standard_normal((n, n, n)).astype(f) for _ in range(2))
+    z = rng.standard_normal((n // 2,) * 3).astype(f)
+    put = lambda x: rt.fromarray(nas_mg.wrap_ghosts(x))  # noqa: E731
+    if op == "resid":
+        got, want = prog.resid(put(a), put(b)), nas_mg.resid_np(a, b)
+    elif op == "psinv":
+        got = prog.psinv(put(a), put(b))
+        want = nas_mg.psinv_np(a, b, prog.smoother)
+    elif op == "rprj3":
+        got, want = prog.rprj3(put(a)), nas_mg.rprj3_np(a)
+    elif op == "interp":
+        got, want = prog.interp(put(z), put(a)), nas_mg.interp_np(z, a)
+    else:
+        got = prog.interp(put(z), None)
+        want = nas_mg.interp_np(z, np.zeros_like(a))
+    host = np.asarray(got)
+    assert host.dtype == f and host.shape == tuple(s + 2 for s in want.shape)
+    np.testing.assert_allclose(host[1:-1, 1:-1, 1:-1], want, rtol=0,
+                               atol=2e-5)
+    np.testing.assert_array_equal(host, nas_mg.comm3(host.copy()))
+
+
+def test_the_byte_and_flop_conventions():
+    prog = program(512, 20, "B+", resident=False)
+    # NPB's own 58 flops a finest point and iteration
+    assert prog.algo_flops_per_solve() == 58 * 512 ** 3 * 20
+    # at the finest level an iteration is 2 resid + psinv (3 passes each),
+    # interp (2 + 1/8) and rprj3 (1 + 1/8): 12.25 passes; a level below has
+    # 10 of its own, an eighth the size each time; the first resid is 2
+    per_it = prog.algo_bytes_per_solve() / 20 / (512 ** 3 * 4)
+    assert abs(per_it - (12 + 10 / 7 + 2 / 20)) < 0.01
+    assert prog.stencil_bytes_per_solve() < prog.algo_bytes_per_solve()
+
+
+# -- a flush too long for one program ------------------------------------------
+def test_a_long_solve_runs_segmented_and_equals_the_unsegmented_one(
+        monkeypatch):
+    prog = program(16, 4)
+    monkeypatch.setattr(common, "max_program_instrs", 0)
+    (whole,) = prog.solve()
+    span = diagnostics.last_flushes()[-1]
+    assert span["segments"] == 0 and span["instrs"] > 384
+    u_whole, r_whole = np.asarray(prog.u), np.asarray(prog.r)
+
+    monkeypatch.setattr(common, "max_program_instrs", 384)
+    before = diagnostics.counters()
+    (cut,) = prog.solve()
+    span = diagnostics.last_flushes()[-1]
+    assert span.get("degraded") is None  # the fused rung
+    assert span["segments"] >= 2 and span["cache"] == "miss"
+    calls = moved(before, "fuser.segments")
+    assert calls == span["segments"] + 1 == len(span["calls"])
+    assert (moved(before, "fuser.segment.miss")
+            + moved(before, "fuser.segment.hit")) == calls
+    assert cut == whole
+    np.testing.assert_array_equal(np.asarray(prog.u), u_whole)
+    np.testing.assert_array_equal(np.asarray(prog.r), r_whole)
+
+    before = diagnostics.counters()
+    (again,) = prog.solve()
+    span = diagnostics.last_flushes()[-1]
+    assert again == cut and span["cache"] == "hit"
+    assert moved(before, "fuser.segment.hit") == calls
+    assert moved(before, "fuser.segment.miss") == 0
+    assert moved(before, "stencil.path.xla") >= 1
+
+
+def test_every_iteration_runs_the_same_executables():
+    """Twenty iterations linearize to twenty repetitions: cut at the same
+    places of each, all but the first share their segments."""
+    prog = program(8, 20)
+    before = diagnostics.counters()
+    prog.solve()
+    span = diagnostics.last_flushes()[-1]
+    calls = moved(before, "fuser.segments")
+    assert calls >= span["instrs"] // common.max_program_instrs >= 4
+    assert moved(before, "fuser.segment.miss") <= 4
+    assert moved(before, "fuser.segment.hit") >= calls - 4
+
+
+def test_a_program_is_cut_once(monkeypatch):
+    """Finding the loop hashes every instruction: the cut places are kept
+    by the program's key, for the run, admission's estimate and every
+    later flush of the same script."""
+    cuts = []
+    real = fuser._segment_ends
+    monkeypatch.setattr(fuser, "_segment_ends",
+                        lambda p, size: cuts.append(len(p.instrs))
+                        or real(p, size))
+    fuser._segments_cache.clear()
+    prog = program(8, 20)
+    first = prog.solve()
+    span = diagnostics.last_flushes()[-1]
+    assert span["segments"] >= 4 and cuts == [span["instrs"]]
+    assert prog.solve() == first and len(cuts) == 1
+    p = _chain(40)
+    last_use = fuser._last_use_map(p)
+    assert fuser._iter_segments(p, last_use, 16) is fuser._iter_segments(
+        _chain(40), last_use, 16)
+    assert fuser._iter_segments(p, last_use, 8) is not fuser._iter_segments(
+        p, last_use, 16)
+
+
+def _chain(n_ops, prefix=0, suffix=0):
+    x = rt.zeros(64, dtype="float32")
+    for i in range(prefix):
+        x = rt.sin(x) * float(i + 2)
+    for _ in range(n_ops):
+        x = rt.sqrt(x * x + 1.0) - x
+    for i in range(suffix):
+        x = rt.cos(x) + float(i + 2)
+    program, _leaves, _ = fuser._prepare_program([x.read_expr()])
+    return program
+
+
+def test_segment_ends_follow_the_loop():
+    p = _chain(400, prefix=7, suffix=3)
+    start, body, count = fuser._repetition(p)  # as rewritten: 4 or 5
+    assert 3 <= body <= 5 and count >= 399 and start <= 2 * 7 + 2
+    ends = fuser._segment_ends(p, 384)
+    assert ends[-1] == len(p.instrs) and ends == sorted(set(ends))
+    sizes = np.diff([0] + ends)
+    assert sizes.max() <= 384
+    # whole iterations to a segment, as many as fit
+    inside = [s for e, s in zip(ends, sizes)
+              if start < e <= start + body * count and s > 100]
+    assert inside and all(s == 384 // body * body for s in inside[1:-1])
+    # a loop longer than a segment is cut into equal parts
+    ends = fuser._segment_ends(_chain(400), 3)
+    assert set(np.diff(ends[2:-2])) <= {2, 3}
+    # no loop: every seg_size instructions, as before
+    x = rt.zeros(64, dtype="float32")
+    for i in np.random.default_rng(5).integers(0, 3, 60):
+        x = (x * 2.0, rt.sin(x), rt.cos(x) + x)[i]
+    plain, _l, _ = fuser._prepare_program([x.read_expr()])
+    n = len(plain.instrs)
+    assert fuser._segment_ends(plain, 8) == list(range(8, n, 8)) + [n]
+
+
+def test_admission_estimates_a_segmented_program_by_its_segments(
+        monkeypatch):
+    """The whole program is never lowered: each distinct segment once,
+    and the estimate is the worst segment's plus what is carried past
+    it."""
+    lowered = []
+
+    def fake(seg, avals):
+        lowered.append(len(seg.instrs))
+        return 1000 * len(seg.instrs)
+
+    monkeypatch.setattr(memory, "_xla_estimate", fake)
+    monkeypatch.delenv("RAMBA_HBM_ESTIMATE", raising=False)
+    memory._est_memo.clear()
+    p = _chain(400, prefix=7)
+    x0 = np.zeros(64, np.float32)
+    leaves = [x0 if k == "C" else 1.0 for k in p.leaf_kinds]
+    est = memory.estimate_program_bytes(p, leaves)
+    memory._est_memo.clear()
+    assert max(lowered) <= common.max_program_instrs
+    assert len(lowered) <= 4  # the distinct ones only
+    carried = sum(64 * 4 if k == "C" else 8 for k in p.leaf_kinds)
+    assert 1000 * max(lowered) <= est <= 1000 * max(lowered) + carried + 512

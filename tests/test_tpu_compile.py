@@ -2,9 +2,10 @@
 v5e with no chip attached (on-chip-measurement guide, section 2.3), and the
 fact about the chip's compiler that ``ramba_tpu/core/layouts.py`` answers:
 left alone it lays a (time, 721, 1440) cube out with TIME minor, so a walk
-along time costs a copy of the cube.  Nothing here runs on a TPU and nothing
-printed is a time.  The only tier-1 file that loads the TPU's compiler: keep
-such tests here."""
+along time costs a copy of the cube; and a strided index of a long row as
+``ramba_tpu/core/slicing.py`` lowers it.  Nothing here runs on a TPU and
+nothing printed is a time.  The only tier-1 file that loads the TPU's
+compiler: keep such tests here."""
 
 import os
 
@@ -17,7 +18,7 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from ramba_tpu import groupby  # noqa: F401  (registers the segment ops)
-from ramba_tpu.core import layouts
+from ramba_tpu.core import layouts, slicing
 from ramba_tpu.core.expr import OPS
 from ramba_tpu.parallel import mesh as rmesh
 
@@ -132,3 +133,23 @@ def test_the_flush_at_the_cells_size_stores_nothing_of_the_cube(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
     assert 14.6e9 < total(compiled) < WATERMARK
     assert compiled.as_text().count(" while(") == 2
+
+
+@pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
+def test_a_strided_long_row_is_work_that_follows_its_length(one_chip, write):
+    """``x[:, ::2]`` of a (64, 2^20) float32 array (268 MB), read and
+    written: the selection product in tiles is 1.4e11 flops and two copies
+    of the array in temporaries as compiled.  As ONE product over the row
+    it asked for a 2^20 x 2^19 matrix, a terabyte, and 3e14 flops."""
+    idx = (slice(None), slice(None, None, 2))
+    x = jax.ShapeDtypeStruct((64, 1 << 20), jnp.float32, sharding=one_chip)
+    assert slicing._lanes_through_mxu(x, slicing._axes(idx, x.shape))
+    if write:
+        v = jax.ShapeDtypeStruct((64, 1 << 19), jnp.float32,
+                                 sharding=one_chip)
+        c = jax.jit(lambda a, b: slicing.put(a, idx, b)).lower(x, v).compile()
+    else:
+        c = jax.jit(lambda a: slicing.take(a, idx)).lower(x).compile()
+    nbytes = 64 * (1 << 20) * 4
+    assert c.memory_analysis().temp_size_in_bytes < 3 * nbytes
+    assert c.cost_analysis()["flops"] < 2e11
