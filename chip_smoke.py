@@ -207,6 +207,27 @@ def jacobi_np(a):
     return o
 
 
+#: NAS MG's A by distance class (centre, face, edge, corner)
+_A27 = (-8 / 3, 0.0, 1 / 6, 1 / 12)
+
+
+def a27_np(a):
+    """The 27-point operator of weights ``_A27`` with sstencil's zero
+    border, plain NumPy."""
+    import itertools
+
+    o = numpy.zeros_like(a)
+    if min(a.shape) < 3:
+        return o
+    inner = 0
+    for d in itertools.product((-1, 0, 1), repeat=3):
+        c = a.dtype.type(_A27[sum(abs(v) for v in d)])
+        inner = inner + c * a[tuple(slice(1 + v, n - 1 + v)
+                                    for v, n in zip(d, a.shape))]
+    o[1:-1, 1:-1, 1:-1] = inner
+    return o
+
+
 def _check_stencil_bands(rt, x, y, sweep_np, radius, sweeps, what):
     """Compare rows of ``y`` (device) with ``sweeps`` NumPy sweeps over the
     rows of ``x`` (device) they depend on.  A band of output rows [a, b)
@@ -245,6 +266,17 @@ def expected_stencil_paths(n, ndev):
     if n % 128 == 0 and n >= 32:
         return ("pallas_fast",)
     return ("pallas_padded",)
+
+
+def expected_stencil_paths3(n, ndev):
+    """The path an n^3 f32 stencil takes: on one device the general
+    Pallas kernel, walking blocks of planes, where the last axis has two
+    whole lane tiles (``stencil_pallas._rank3_wins``), else XLA's fusion
+    of shifted slices; on several devices the sharded path, whose local
+    blocks of rank 3 are XLA's."""
+    if ndev > 1:
+        return ("sharded", "xla")
+    return ("pallas_padded",) if n >= 256 else ("xla",)
 
 
 # ---------------------------------------------------------------------------
@@ -525,6 +557,60 @@ def phase_stencil(rt, n, expect, interpret_ok=False):
     rec.require_clean(interpret_ok=interpret_ok)
     return {"n": n, "path": "+".join(paths), "rungs": rec.rungs(),
             "max_abs_err": worst, "first_s": first, "second_s": second}
+
+
+def phase_stencil3(rt, n, expect, interpret_ok=False):
+    """NAS MG's 27-point A (``benchmark/programs/nas_mg.py``) over an n^3
+    f32 array: one sweep on the path ``expect`` names, checked on bands of
+    planes against NumPy and everywhere against XLA's fusion of shifted
+    slices over the same device array."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.programs import nas_mg
+    from ramba_tpu import skeletons
+
+    op = nas_mg.stencil27(rt, _A27)
+    with Recorder(rt) as rec:
+        x = _random_f32(rt, (n, n, n))
+
+        def sweep():
+            y = rt.sstencil(op, x)
+            rt.sync()
+            return y
+
+        with Recorder(rt) as r1:
+            y, first = _timed(sweep)
+        paths = tuple(dict.fromkeys(r1.kernel_paths()))
+        _require(paths == tuple(expect),
+                 f"stencil {n}^3 took path {paths}, want {tuple(expect)}")
+        copies = r1.counters.get("stencil.operand_copy", 0)
+        _require(not copies,
+                 f"stencil {n}^3: {copies} operands travelled in a copy")
+        del y
+        with Recorder(rt) as r2:
+            y, second = _timed(sweep)
+        _require([f["cache"] for f in r2.flushes] == ["hit"],
+                 f"second sweep: {[f['cache'] for f in r2.flushes]}")
+        _require_sharded(rt, y, f"stencil {n}^3 output")
+        worst = _check_stencil_bands(rt, x, y, a27_np, 1, 1, f"A27 {n}^3")
+        slots = (("arr", 0),)
+        lo, hi, _ = op.neighborhood(slots)
+
+        @jax.jit
+        def off_xla(xv, yv):
+            ref = skeletons.stencil_interior(op.func, lo, hi, slots, [xv])
+            ref = jnp.zeros_like(xv).at[1:-1, 1:-1, 1:-1].set(ref)
+            return jnp.max(jnp.abs(yv - ref))
+
+        vs_xla = float(off_xla(x._value(), y._value()))
+        _require(vs_xla <= _tol(numpy.float32),
+                 f"A27 {n}^3 differs from the XLA path by {vs_xla:.3e}")
+        del x, y
+    rec.require_clean(interpret_ok=interpret_ok)
+    return {"n": n, "path": "+".join(paths), "rungs": rec.rungs(),
+            "max_abs_err": worst, "max_abs_vs_xla": vs_xla,
+            "first_s": first, "second_s": second}
 
 
 def phase_stencil_sweeps(rt, n, sweeps, jacobi_iters, expect,
@@ -855,6 +941,9 @@ def main() -> int:
             rt, 8192, 5, 10, expected_stencil_paths(8192, ndev))),
         ("prk scalars 8192^2", lambda: phase_prk_scalars(
             rt, 8192, 10, expected_stencil_paths(8192, ndev))),
+        # level 8 of mg-C's pyramid: the smallest the rank-3 kernel takes
+        ("stencil 258^3", lambda: phase_stencil3(
+            rt, 258, expected_stencil_paths3(258, ndev))),
         # NumPy float64 reads 1.274675290838857e-04 after two iterations at
         # class C, float32 1.2746751486658546e-04 (PERF.md, PR 32)
         ("mg 512^3", lambda: phase_mg(rt, 512, 1.274675290838857e-04,

@@ -733,24 +733,8 @@ class StencilKernel:
         cache_key = None if has_literals else tuple(kind for kind, _ in slots)
         if (has_literals or self._probe_cache is None
                 or self._probe_key != cache_key):
-            probes = []
-            call_args = []
-            for kind, payload in slots:
-                if kind == "arr":
-                    p = _ProbeProxy()
-                    probes.append(p)
-                    call_args.append(p)
-                else:
-                    call_args.append(payload.v)
-            try:
-                # branch enumeration visits every path, so a branching
-                # kernel's probe records the UNION of both sides' offsets
-                _explore_branches(lambda: self.func(*call_args))
-            except Exception as e:  # kernel must be offset-indexing only
-                raise ValueError(
-                    f"could not probe stencil kernel {self.func}: {e}"
-                ) from e
-            all_offs = [o for p in probes for o in p.offsets]
+            all_offs = [o for offs in stencil_offsets(self.func, slots)
+                        for o in offs]
             nd = len(all_offs[0]) if all_offs else 1
             lo = tuple(min(0, *(o[d] for o in all_offs)) if all_offs else 0
                        for d in range(nd))
@@ -776,6 +760,30 @@ class StencilKernel:
         return np.asarray(
             _eval_stencil((self.func, lo, hi, tuple(slots), taps), *operands)
         )
+
+
+def stencil_offsets(func, slots):
+    """The relative offsets ``func`` reads, one list per array slot in
+    slot order, every read counted: what ``neighborhood`` bounds and what
+    the rank-3 Pallas kernel stages its shifted copies from."""
+    probes = []
+    call_args = []
+    for kind, payload in slots:
+        if kind == "arr":
+            p = _ProbeProxy()
+            probes.append(p)
+            call_args.append(p)
+        else:
+            call_args.append(payload.v)
+    try:
+        # branch enumeration visits every path, so a branching kernel's
+        # probe records the UNION of both sides' offsets
+        _explore_branches(lambda: func(*call_args))
+    except Exception as e:  # kernel must be offset-indexing only
+        raise ValueError(
+            f"could not probe stencil kernel {func}: {e}"
+        ) from e
+    return [p.offsets for p in probes]
 
 
 def stencil(func=None, **kwargs):
@@ -871,7 +879,8 @@ def _eval_stencil(static, *arrs):
             return stencil_sharded.run(func, lo, hi, slots, arrs, taps)
         except Exception as e:  # trace-time failure: next path, loudly
             _stencil_degrade("sharded", "pallas/xla", e)
-    if len(arrs[0].shape) == 2:
+    if len(arrs[0].shape) in (2, 3):
+        # the Pallas family says which shapes of these ranks it takes
         from ramba_tpu.ops import pallas_backend
 
         fam = pallas_backend.family("stencil")

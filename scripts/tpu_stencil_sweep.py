@@ -13,9 +13,17 @@ kernel derives, which is what ships.  The configurations:
     15000^2, 13500^2  f32  pallas_padded, halo "edge"    derived, 32, 64
     15000^2, 13500^2  f32  pallas_padded, halo "strips"  derived, 32, 64
 
+    514^3, 258^3  f32   pallas_padded, rank 3   derived, planes x rows
+    130^3         f32   pallas_padded and xla   the threshold's two readings
+    514^3, 258^3  f32   xla                     what the kernel replaces
+
 (the padded shapes are what the benchmark's star cells hand the kernel per
 chip: the array's own edge on one chip, the block and its four received
-strips on four).  Each worker refuses to run off the TPU, checks from the
+strips on four; the cubes are levels 9, 8 and 7 of ``mg-C``'s pyramid,
+swept by NPB MG's 27-point operator ``op``: A, 21 taps, or P, 27).  A
+rank-3 candidate is ``[block_planes, rows]``, ``null`` for the derived
+one; a configuration's ``set`` tries another value of one of the kernel's
+own constants (``_CHUNK_VREGS``).  Each worker refuses to run off the TPU, checks from the
 kernel's own note that the stencil took the path and the halo its
 configuration names, at the height asked for, and compares one sweep with
 ``skeletons.stencil_interior`` (``equal``: to the bit).  ``to_beat_ms`` is
@@ -27,7 +35,9 @@ Run it through the chip tool, one call:
     python scripts/tpu_stencil_sweep.py
 
 Prints one JSON object, also written to chiprun_out/stencil_sweep.json;
-exits non-zero if any candidate failed.  ``kernel_ms`` is the device time
+every candidate's row is appended to chiprun_out/stencil_sweep_rows.jsonl
+as it is measured, so a call that is cut keeps what it read; exits
+non-zero if any candidate failed.  ``kernel_ms`` is the device time
 of the Pallas custom calls of one sweep, from a profiler trace of one
 chain; ``device_ms`` that of every op of the sweep (the tails XLA builds
 for the padded path with it); ``wall_ms`` the host's clock over the
@@ -67,10 +77,30 @@ def star2(a):
             + 0.125 * (a[0, 2] + a[0, -2] + a[2, 0] + a[-2, 0]))
 
 sn, dtype, want_path, sk = cfg["n"], cfg["dtype"], cfg["path"], 10
+rank = cfg.get("rank", 2)
+for name, value in cfg.get("set", {}).items():
+    # a candidate for one of the kernel's own constants
+    assert hasattr(stencil_pallas, name), name
+    setattr(stencil_pallas, name, value)
+if rank == 3:
+    import itertools
+    w = {"A": (-8 / 3, 0.0, 1 / 6, 1 / 12),
+         "P": (0.5, 0.25, 0.125, 0.0625)}[cfg["op"]]
+
+    @rt.stencil
+    def star2(a):  # NPB MG's 27-point operator, zero weights left out
+        acc = None
+        for d in itertools.product((-1, 0, 1), repeat=3):
+            c = w[sum(abs(v) for v in d)]
+            if c:
+                term = c * a[d]
+                acc = term if acc is None else acc + term
+        return acc
+
 slots = (("arr", 0),)
 lo, hi, taps = star2.neighborhood(slots)
 rs = np.random.RandomState(0)
-x = jnp.asarray(rs.rand(sn, sn), dtype=dtype)
+x = jnp.asarray(rs.rand(*(sn,) * rank), dtype=dtype)
 halos = None
 if cfg.get("halo") == "strips":
     # what four chips hand the kernel: the block and its received strips
@@ -81,7 +111,9 @@ if cfg.get("halo") == "strips":
 def reference(y):
     v = skeletons.stencil_interior
     if halos is None:
-        return jnp.zeros_like(y).at[2:-2, 2:-2].set(
+        inner = tuple(slice(-l, y.shape[d] - h)
+                      for d, (l, h) in enumerate(zip(lo, hi)))
+        return jnp.zeros_like(y).at[inner].set(
             v(star2.func, lo, hi, slots, [y]))
     w, e, n, s = halos[0]
     ext = jnp.concatenate(
@@ -105,11 +137,15 @@ def device_ns(tdir):
 def sweep(y, rows):
     if want_path == "xla":
         return reference(y)
+    planes = None
+    if rank == 3 and rows is not None:
+        planes, rows = rows
     return stencil_pallas.run(star2.func, lo, hi, slots, [y], taps,
-                              halos=halos, _block_rows=rows)
+                              halos=halos, _block_rows=rows,
+                              _block_planes=planes)
 
 for rows in cfg["rows"]:
-    row = {"block_rows_asked": rows}
+    row = {"config": cfg["name"], "block_rows_asked": rows}
     try:
         def chain(y, rows=rows):
             for _ in range(sk):
@@ -123,9 +159,11 @@ for rows in cfg["rows"]:
             assert {n["path"] for n in notes} == {want_path}, notes
             assert not any(n["interpret"] for n in notes), notes
             row.update({k: notes[0][k] for k in
-                        ("block_rows", "grid", "vmem_limit_bytes", "halo",
-                         "operand_copy") if k in notes[0]})
-            assert rows is None or row.get("block_rows", rows) == rows, row
+                        ("block_planes", "block_rows", "grid",
+                         "vmem_limit_bytes", "halo", "operand_copy")
+                        if k in notes[0]})
+            asked = rows if rank == 2 or rows is None else rows[1]
+            assert asked is None or row.get("block_rows", asked) == asked, row
             assert row.get("halo") == cfg.get("halo"), row
             got = jax.jit(lambda y, rows=rows: sweep(y, rows))(x)
             diff = jnp.abs(got.astype(jnp.float32)
@@ -133,7 +171,7 @@ for rows in cfg["rows"]:
             row["max_abs_diff"] = float(jnp.max(diff))
             row["equal"] = row["max_abs_diff"] == 0.0
             del got, diff
-            assert row["max_abs_diff"] < 1e-5, row
+            assert row["max_abs_diff"] < 1e-5 * (taps if rank == 3 else 1), row
         jax.block_until_ready(run(x))
         walls = []
         for _ in range(5):
@@ -149,18 +187,23 @@ for rows in cfg["rows"]:
         row.update({
             "wall_ms": sorted(walls)[2], "device_ms": every / sk / 1e6,
             "kernel_ms": kernel / sk / 1e6,
-            "gpoints_per_s": sn * sn / per / 1e9,
-            "gb_per_s": 2 * sn * sn * itemsize / per / 1e9,
+            "gpoints_per_s": sn ** rank / per / 1e9,
+            "gb_per_s": 2 * sn ** rank * itemsize / per / 1e9,
         })
     except Exception as e:
         lines = f"{type(e).__name__}: {e}".splitlines()
         row["error"] = " | ".join(lines)[:600]
+    with open(cfg["rows_file"], "a") as f:
+        f.write(json.dumps(row) + "\n")
     print(json.dumps(row), flush=True)
 print(json.dumps({"device_kind": dev.device_kind,
                   "device_count": len(jax.devices())}), flush=True)
 """
 
 _PADDED_ROWS = [None, 32, 64]
+#: rank 3: [block_planes, rows staged at once]; None is what ships
+_CUBE_BLOCKS = [None, [10, 64], [8, 128], [7, 176], [6, 256], [5, 264]]
+_CUBE_BLOCKS_8 = [None, [16, 64], [16, 136], [12, 264]]
 CONFIGS = [
     ("fast_8192", {"n": 8192, "dtype": "float32", "path": "pallas_fast",
                    "rows": [None, 16, 32]}),
@@ -184,11 +227,43 @@ CONFIGS = [
         "path": "pallas_padded", "rows": _PADDED_ROWS,
         "to_beat_ms": {"star2-x4: kernel 3.095 + pad 2.400 + "
                        "concatenate 5.056": 10.551}}),
+    # mg-C's levels 9 and 8, which the rank-3 kernel takes
+    ("cube_514_A", {"n": 514, "rank": 3, "op": "A", "dtype": "float32",
+                    "halo": "edge", "path": "pallas_padded",
+                    "rows": _CUBE_BLOCKS,
+                    "to_beat_ms": {"mg-C: the XLA stencil alone, PR 32":
+                                   22.2}}),
+    ("cube_514_A_v10", {"n": 514, "rank": 3, "op": "A", "dtype": "float32",
+                       "halo": "edge", "path": "pallas_padded",
+                       "set": {"_CHUNK_VREGS": 10},
+                       "rows": [[10, 64], [8, 128]]}),
+    ("cube_514_A_v40", {"n": 514, "rank": 3, "op": "A", "dtype": "float32",
+                       "halo": "edge", "path": "pallas_padded",
+                       "set": {"_CHUNK_VREGS": 40},
+                       "rows": [[7, 176], [6, 256]]}),
+    ("cube_514_P", {"n": 514, "rank": 3, "op": "P", "dtype": "float32",
+                    "halo": "edge", "path": "pallas_padded",
+                    "rows": [None]}),
+    ("cube_258_A", {"n": 258, "rank": 3, "op": "A", "dtype": "float32",
+                    "halo": "edge", "path": "pallas_padded",
+                    "rows": _CUBE_BLOCKS_8}),
+    # level 7, the threshold's two readings, and the path the kernel
+    # replaces at the levels above it
+    ("cube_130_A", {"n": 130, "rank": 3, "op": "A", "dtype": "float32",
+                    "halo": "edge", "path": "pallas_padded",
+                    "rows": [None, [8, 64]]}),
+    ("cube_130_A_xla", {"n": 130, "rank": 3, "op": "A", "dtype": "float32",
+                        "path": "xla", "rows": [None]}),
+    ("cube_258_A_xla", {"n": 258, "rank": 3, "op": "A", "dtype": "float32",
+                        "path": "xla", "rows": [None]}),
+    ("cube_514_A_xla", {"n": 514, "rank": 3, "op": "A", "dtype": "float32",
+                        "path": "xla", "rows": [None]}),
 ]
+ROWS_FILE = os.path.join(REPO, "chiprun_out", "stencil_sweep_rows.jsonl")
 
 
-def _run(cfg, timeout_s):
-    arg = json.dumps(dict(cfg, repo=REPO))
+def _run(name, cfg, timeout_s):
+    arg = json.dumps(dict(cfg, repo=REPO, name=name, rows_file=ROWS_FILE))
     try:
         r = subprocess.run([sys.executable, "-c", _WORKER_SRC, arg],
                            capture_output=True, text=True, timeout=timeout_s)
@@ -214,26 +289,28 @@ def main(argv) -> int:
         sys.exit(f"stencil sweep: no configuration named {sorted(unknown)}")
     out = {"configs": {},
            "recorded_at": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())}
+    os.makedirs(os.path.dirname(ROWS_FILE), exist_ok=True)
     for name, cfg in CONFIGS:
         if argv and name not in argv:
             continue
-        got = out["configs"][name] = dict(_run(cfg, per_cfg), **cfg)
+        got = out["configs"][name] = dict(_run(name, cfg, per_cfg), **cfg)
         print(f"{name}: {got}", file=sys.stderr, flush=True)
     out["failed"] = sorted(
         name for name, got in out["configs"].items()
         if "error" in got or any("error" in c for c in got["candidates"]))
-    os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
     with open(os.path.join(REPO, "chiprun_out", "stencil_sweep.json"),
               "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
-    print(f"{'configuration':<16}{'rows':>6}{'kernel_ms':>11}{'device_ms':>11}"
-          f"{'equal':>7}  to beat (ledger, PR 28)", file=sys.stderr)
+    print(f"{'configuration':<16}{'planes':>7}{'rows':>6}{'kernel_ms':>11}"
+          f"{'device_ms':>11}{'equal':>7}  to beat (ledger, PRs 28, 32)",
+          file=sys.stderr)
     for name, got in out["configs"].items():
         beat = "; ".join(f"{v} ms ({k})"
                          for k, v in got.get("to_beat_ms", {}).items())
         for c in got.get("candidates", ()):
-            print(f"{name:<16}{c.get('block_rows', '-'):>6}"
+            print(f"{name:<16}{c.get('block_planes', '-'):>7}"
+                  f"{c.get('block_rows', '-'):>6}"
                   f"{c.get('kernel_ms', float('nan')):>11.3f}"
                   f"{c.get('device_ms', float('nan')):>11.3f}"
                   f"{str(c.get('equal', '-')):>7}  {beat}", file=sys.stderr)

@@ -67,6 +67,49 @@ def test_stencil_phase(interpreting, n):
     assert facts["path"] == "+".join(_paths(n))
 
 
+@pytest.fixture
+def one_device():
+    """The mesh of one chip: one device."""
+    from jax.sharding import Mesh
+    import numpy as np
+
+    from ramba_tpu.parallel import mesh as rmesh
+
+    before = rmesh.get_mesh()
+    rmesh.set_mesh(Mesh(np.array(jax.devices()[:1]), ("d0",)))
+    yield
+    rmesh.set_mesh(before)
+
+
+def test_stencil3_phase(interpreting, monkeypatch):
+    """The 27-point sweep at toy size on the CPU mesh: the sharded path
+    and XLA's local blocks, as on four chips."""
+    n = 20
+    facts = chip_smoke.phase_stencil3(
+        rt, n, chip_smoke.expected_stencil_paths3(n, len(jax.devices())),
+        interpret_ok=True)
+    assert facts["rungs"] == ["fused"]
+    assert chip_smoke.expected_stencil_paths3(258, 1) == ("pallas_padded",)
+    assert chip_smoke.expected_stencil_paths3(130, 1) == ("xla",)
+    assert stencil_pallas._rank3_wins((258,) * 3, jax.numpy.dtype("float32"),
+                                      1)
+
+
+def test_stencil3_phase_on_one_device(interpreting, one_device, monkeypatch):
+    """On a mesh of one device, the kernel's threshold lowered to the
+    toy's last axis: the kernel, as on one chip; and a sweep that takes
+    another path than the one named fails its phase."""
+    monkeypatch.setattr(stencil_pallas, "_RANK3_MIN_LANES", 22)
+    # the placement check counts every device jax shows: seven of the CPU
+    # mesh's eight are outside this mesh
+    monkeypatch.setattr(chip_smoke, "_require_sharded", lambda *a, **k: None)
+    facts = chip_smoke.phase_stencil3(rt, 22, ("pallas_padded",),
+                                      interpret_ok=True)
+    assert facts["path"] == "pallas_padded" and facts["max_abs_vs_xla"] < 1e-5
+    with pytest.raises(chip_smoke.SmokeFailure, match="took path"):
+        chip_smoke.phase_stencil3(rt, 24, ("xla",), interpret_ok=True)
+
+
 def test_stencil_sweeps_phase(interpreting):
     facts = chip_smoke.phase_stencil_sweeps(rt, 128, 3, 4, _paths(128),
                                             interpret_ok=True)

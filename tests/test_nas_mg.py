@@ -25,6 +25,7 @@ def program(n, nit, smoother="SWA", resident=True):
     cfg = {"n": n, "iterations": nit, "dtype": "float32",
            "smoother": smoother, "norm": 1.0,
            "as_published": {"n": 512, "iterations": 20},
+           "stencil_paths": {"kernel": "pallas_padded", "fusion": "xla"},
            "assumed": {"norm_rtol": 1e-4, "window_rtol": 2e-5}}
     prog = nas_mg.Program(rt, cfg, {"solve": [{"op": "mg"}]},
                           np.random.default_rng(3), 1)
@@ -258,3 +259,67 @@ def test_admission_estimates_a_segmented_program_by_its_segments(
     assert len(lowered) <= 4  # the distinct ones only
     carried = sum(64 * 4 if k == "C" else 8 for k in p.leaf_kinds)
     assert 1000 * max(lowered) <= est <= 1000 * max(lowered) + carried + 512
+
+
+# -- the rank-3 Pallas kernel under the solve ---------------------------------
+def sweeps_by_level(lt, nit):
+    """27-point sweeps of one solve at each level: the finest has the
+    first ``resid`` and per iteration two ``resid``, ``psinv`` and
+    ``rprj3``'s P; a level between has ``rprj3``, ``resid``, ``psinv``;
+    the coarsest ``psinv`` alone."""
+    return {k: 4 * nit + 1 if k == lt else 3 * nit if k > 1 else nit
+            for k in range(1, lt + 1)}
+
+
+def test_the_finest_level_takes_the_kernel_and_the_counters_say_so(
+        monkeypatch):
+    """With the kernel interpreting and its threshold at the toy's finest
+    level (18^3), a solve's operators run on ``pallas_padded`` there and
+    on ``xla`` below, the norm is the XLA path's, and the two counters
+    move by the sweeps on each side of the predicate: when the flush
+    traces, when it hits, and when it runs as chained segments."""
+    from ramba_tpu.ops import stencil_pallas, stencil_sharded
+
+    n, nit = 16, 4
+    (xla_norm,) = program(n, nit).solve()
+    monkeypatch.setattr(stencil_pallas, "_INTERPRET", True)
+    monkeypatch.setattr(stencil_pallas, "_ENABLED", True)
+    monkeypatch.setattr(stencil_pallas, "_RANK3_MIN_LANES", n + 2)
+    monkeypatch.setattr(stencil_sharded, "eligible", lambda *a, **k: False)
+    by_level = sweeps_by_level(4, nit)
+    want = {"stencil.path.pallas_padded": by_level[4],
+            "stencil.path.xla": sum(by_level.values()) - by_level[4]}
+    assert want == {"stencil.path.pallas_padded": 17, "stencil.path.xla": 28}
+    # what mg-C's solve must read with 514^3 and 258^3 on the kernel
+    c = sweeps_by_level(9, 20)
+    assert (c[9] + c[8], sum(c.values()) - c[9] - c[8]) == (141, 380)
+
+    prog = program(n, nit)
+    assert prog.expected_paths(1) == ("pallas_padded", "xla")
+    # a script's first solve also traces each distinct operator once for
+    # its result's type (a miss of node inference): A, S, P at the finest
+    # level, the three at levels 3 and 2 and S at the coarsest
+    inferred = {"stencil.path.pallas_padded": 3, "stencil.path.xla": 7}
+    for segment_at, cache in ((0, "miss"), (0, "hit"), (384, "miss"),
+                              (384, "hit")):
+        monkeypatch.setattr(common, "max_program_instrs", segment_at)
+        before = diagnostics.counters()
+        (norm,) = prog.solve()
+        span = diagnostics.last_flushes()[-1]
+        assert span["cache"] == cache and span.get("degraded") is None
+        assert (span["segments"] > 0) == bool(segment_at)
+        first = moved(before, "dag.infer.n") > 0
+        assert first == ((segment_at, cache) == (0, "miss"))
+        assert {k: moved(before, k) - first * inferred[k]
+                for k in want} == want, (segment_at, cache)
+        assert not moved(before, "stencil.degraded")
+        assert not moved(before, "stencil.operand_copy")
+        assert abs(norm - xla_norm) <= prog.norm_rtol * xla_norm
+        if cache == "miss":
+            notes = [k for k in span["kernels"] if k["path"] == "pallas_padded"]
+            assert notes and all(
+                k["interpret"] and k["halo"] == "edge"
+                and k["grid"] == -(-(n + 2) // k["block_planes"])
+                for k in notes)
+    host = np.asarray(prog.r)
+    np.testing.assert_array_equal(host, nas_mg.comm3(host.copy()))

@@ -2,6 +2,8 @@
 real-TPU lowering of the same kernel is exercised by bench.py on hardware).
 """
 
+import itertools
+
 import numpy as np
 import pytest
 
@@ -127,7 +129,36 @@ def _box(a):
             + 9 * a[1, 1])
 
 
-_KERNELS = {"mix": _mix, "skew": _skew, "far": _far, "box": _box}
+def _op27(a):
+    # NPB MG's P: four distinct weights by distance class, all dyadic, so
+    # that the 27 products of whole numbers sum exactly in any order
+    acc = None
+    for d in itertools.product((-1, 0, 1), repeat=3):
+        term = (0.5, 0.25, 0.125, 0.0625)[sum(abs(v) for v in d)] * a[d]
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _skew3(a):
+    # another reach on each side of each axis
+    return a[-2, 0, 1] + 2 * a[0, 3, 0] - a[1, -1, -5]
+
+
+def _mix3(a, b):
+    return a[0, 0, 0] + 0.5 * (b[-1, 0, 0] + b[1, 0, 0]) + b[0, 1, -1]
+
+
+def _branch3(a):
+    if a[0, 0, 0] > 8:
+        return a[1, 0, 0] + a[0, -1, 0]
+    return 2 * a[0, 0, 1]
+
+
+_KERNELS = {"mix": _mix, "skew": _skew, "far": _far, "box": _box,
+            "op27": _op27, "skew3": _skew3, "mix3": _mix3,
+            "branch3": _branch3}
+#: kernels whose operand is whole numbers: their sums are exact
+_WHOLE = ("box", "op27", "branch3")
 
 
 def _kernel(which):
@@ -135,9 +166,10 @@ def _kernel(which):
 
 
 def _data(rs, shape, which):
-    """Random operand; whole numbers for the nine-tap box, whose sum is
-    then exact in any order (XLA and the interpreter contract differently)."""
-    return rs.randint(0, 16, shape) if which == "box" else rs.rand(*shape)
+    """Random operand; whole numbers for the nine-tap box and the 27-point
+    operator, whose sums are then exact in any order (XLA and the
+    interpreter contract differently)."""
+    return rs.randint(0, 16, shape) if which in _WHOLE else rs.rand(*shape)
 
 
 def _bordered(st, lo, hi, slots, arrs):
@@ -148,8 +180,18 @@ def _bordered(st, lo, hi, slots, arrs):
     interior = np.asarray(
         skeletons.stencil_interior(st.func, lo, hi, slots, arrs))
     want = np.zeros(shape, dtype=interior.dtype)
-    want[-lo[0]:shape[0] - hi[0], -lo[1]:shape[1] - hi[1]] = interior
+    want[tuple(slice(-l, n - h) for l, h, n in zip(lo, hi, shape))] = interior
     return want
+
+
+def _padded(st, lo, hi, slots, arrs, taps, interpret, block):
+    """``_run_padded`` at ``block``: rows a block at rank 2, (planes a
+    block, rows staged at once) at rank 3, ``None`` for the derived."""
+    planes = None
+    if block is not None and arrs[0].ndim == 3:
+        planes, block = block
+    return stencil_pallas._run_padded(st.func, lo, hi, slots, arrs, taps,
+                                      interpret, block, None, planes)
 
 
 #: (shape, dtype, kernel, candidate rows or None for the derived height,
@@ -173,7 +215,18 @@ _PADDED_CASES = {
     "halo-wider-than-a-tile": ((60, 400), "float32", "far", 8, 8),
     "no-whole-row-tile": ((5, 300), "float32", "box", None, 1),
     "box-two-blocks": ((44, 260), "float32", "box", 24, 2),
+    # rank 3: (planes a block, rows staged at once); the grid walks planes
+    "cube-grid1-derived": ((10, 66, 130), "float32", "op27", None, 1),
+    "cube-grid2": ((16, 24, 256), "float32", "op27", (8, 16), 2),
+    "cube-no-whole-lane-tile": ((18, 20, 34), "float32", "op27", (4, 8), 5),
+    "cube-short-last-block": ((34, 258, 130), "float32", "op27", (5, 64), 7),
+    "cube-one-plane-blocks": ((6, 40, 140), "float32", "op27", (1, 16), 6),
+    "cube-asymmetric": ((13, 45, 150), "float32", "skew3", (4, 16), 4),
+    "cube-two-inputs": ((9, 50, 200), "float32", "mix3", (3, 24), 3),
+    "cube-branching": ((7, 24, 136), "float32", "branch3", (4, 8), 2),
 }
+#: inputs a kernel takes
+_N_IN = {"mix": 2, "mix3": 2}
 
 
 class TestPaddedKernel:
@@ -189,31 +242,42 @@ class TestPaddedKernel:
         shape, dtype, which, rows, want_grid = _PADDED_CASES[case]
         st = _kernel(which)
         rs = np.random.RandomState(len(case))
-        n_in = 2 if which == "mix" else 1
+        n_in = _N_IN.get(which, 1)
         arrs = [jnp.asarray(_data(rs, shape, which), dtype=dtype)
                 for _ in range(n_in)]
         slots = tuple(("arr", k) for k in range(n_in))
         lo, hi, taps = st.neighborhood(slots)
         with registry.collect_kernel_notes() as notes:
-            got = stencil_pallas._run_padded(
-                st.func, lo, hi, slots, arrs, taps, True, rows)
+            got = _padded(st, lo, hi, slots, arrs, taps, True, rows)
         (note,) = notes
         assert note["path"] == "pallas_padded" and note["interpret"]
-        assert note["grid"] == want_grid == -(-shape[0] // note["block_rows"])
-        assert rows is None or note["block_rows"] == rows
+        # the blocked axis: rows at rank 2, planes at rank 3
+        walked = note["block_planes" if len(shape) == 3 else "block_rows"]
+        assert note["grid"] == want_grid == -(-shape[0] // walked)
+        assert rows is None or rows in (
+            note["block_rows"], (note.get("block_planes"), note["block_rows"]))
         assert 0 < note["vmem_limit_bytes"] <= stencil_pallas._vmem_cap()
         assert note["halo"] == "edge"
-        # only an operand with no whole tile travels in an XLA-made copy
-        whole = shape[0] >= 32 // arrs[0].dtype.itemsize and shape[1] >= 128
+        # at rank 2 an operand with no whole tile travels in an XLA-made
+        # copy; at rank 3 none does, the kernel fetches the ragged tiles
+        whole = len(shape) == 3 or (
+            shape[0] >= 32 // arrs[0].dtype.itemsize and shape[1] >= 128)
         assert note.get("operand_copy", 0) == (0 if whole else n_in)
         assert got.dtype == arrs[0].dtype and got.shape == shape
+        got = np.asarray(got)
         np.testing.assert_array_equal(
-            np.asarray(got), _bordered(st, lo, hi, slots, arrs))
+            got, _bordered(st, lo, hi, slots, arrs))
+        # the faces: every cell whose neighbourhood leaves the array
+        inner = tuple(slice(-l, n - h) for l, h, n in zip(lo, hi, shape))
+        face = np.ones(shape, bool)
+        face[inner] = False
+        assert (got[face] == 0).all() and got[inner].any()
 
     @pytest.mark.parametrize("case", [
         "toy-13500", "toy-15000", "last-block-shorter-than-halo",
         "last-block-one-row-grid6", "grid3-two-inputs",
-        "halo-wider-than-a-tile"])
+        "halo-wider-than-a-tile", "cube-grid2", "cube-no-whole-lane-tile",
+        "cube-short-last-block", "cube-asymmetric", "cube-two-inputs"])
     def test_stale_slab_never_reaches_the_result(self, case):
         """The TPU interpreter with every scratch buffer NaN to begin with
         (and reads out of bounds refused): slab cells that no copy wrote
@@ -223,14 +287,14 @@ class TestPaddedKernel:
 
         shape, dtype, which, rows, _ = _PADDED_CASES[case]
         st = _kernel(which)
-        n_in = 2 if which == "mix" else 1
+        n_in = _N_IN.get(which, 1)
         rs = np.random.RandomState(7)
-        arrs = [jnp.asarray(rs.rand(*shape), dtype=dtype)
+        arrs = [jnp.asarray(_data(rs, shape, which), dtype=dtype)
                 for _ in range(n_in)]
         slots = tuple(("arr", k) for k in range(n_in))
         lo, hi, taps = st.neighborhood(slots)
-        got = np.asarray(stencil_pallas._run_padded(
-            st.func, lo, hi, slots, arrs, taps,
+        got = np.asarray(_padded(
+            st, lo, hi, slots, arrs, taps,
             pltpu.InterpretParams(uninitialized_memory="nan"), rows))
         assert not np.isnan(got).any()
         np.testing.assert_array_equal(
@@ -478,3 +542,182 @@ class TestPallasFastPath:
         e = np.zeros_like(x)
         e[3:, :-5] = x[:-3, :-5] + x[3:, 5:]
         np.testing.assert_allclose(out, e)
+
+
+# -- rank 3: the plane-walking kernel ----------------------------------------
+_A27 = (-8 / 3, 0.0, 1 / 6, 1 / 12)
+
+
+def _a27(a):
+    # NPB MG's A: the face weight is zero and left out, as the cell does
+    acc = None
+    for d in itertools.product((-1, 0, 1), repeat=3):
+        c = _A27[sum(abs(v) for v in d)]
+        if c:
+            term = c * a[d]
+            acc = term if acc is None else acc + term
+    return acc
+
+
+def _a27_numpy(x):
+    out = np.zeros_like(x)
+    inner = 0
+    for d in itertools.product((-1, 0, 1), repeat=3):
+        c = np.float32(_A27[sum(abs(v) for v in d)])
+        inner = inner + c * x[tuple(slice(1 + v, n - 1 + v)
+                                    for v, n in zip(d, x.shape))]
+    out[1:-1, 1:-1, 1:-1] = inner
+    return out
+
+
+class TestRank3:
+    @pytest.mark.parametrize("shape", [(18, 20, 34), (10, 66, 130),
+                                       (12, 24, 258)], ids=str)
+    def test_27_points_through_sstencil_against_numpy(
+            self, shape, interpret_mode, monkeypatch):
+        """The cell's own operator on cubes and non-cubes with ragged last
+        lanes and rows, through ``rt.sstencil``: the predicate lets the
+        shape through once its threshold is low enough, and the counters
+        say which path ran."""
+        from ramba_tpu import diagnostics
+
+        monkeypatch.setattr(stencil_pallas, "_RANK3_MIN_LANES", 34)
+        x = np.random.RandomState(5).rand(*shape).astype(np.float32)
+        before = diagnostics.counters()
+        out = rt.sstencil(rt.stencil(_a27), rt.fromarray(x)).asarray()
+        after = diagnostics.counters()
+        assert after.get("stencil.path.pallas_padded", 0) > before.get(
+            "stencil.path.pallas_padded", 0)
+        assert after.get("stencil.path.xla", 0) == before.get(
+            "stencil.path.xla", 0)
+        np.testing.assert_allclose(out, _a27_numpy(x), rtol=0, atol=2e-6)
+        face = np.ones(shape, bool)
+        face[1:-1, 1:-1, 1:-1] = False
+        assert (out[face] == 0).all()
+
+    def test_a_small_cube_stays_on_the_xla_path(self, interpret_mode):
+        from ramba_tpu import diagnostics
+
+        # (a shape of its own: the fuser keeps a program by its function
+        # and shapes, and the test above lowered the threshold)
+        x = np.random.RandomState(6).rand(11, 66, 130).astype(np.float32)
+        before = diagnostics.counters()
+        out = rt.sstencil(rt.stencil(_a27), rt.fromarray(x)).asarray()
+        after = diagnostics.counters()
+        assert after.get("stencil.path.xla", 0) > before.get(
+            "stencil.path.xla", 0)
+        assert after.get("stencil.path.pallas_padded", 0) == before.get(
+            "stencil.path.pallas_padded", 0)
+        np.testing.assert_allclose(out, _a27_numpy(x), rtol=0, atol=2e-6)
+
+    @pytest.mark.parametrize("shape,dtype,n_in,takes", [
+        ((514, 514, 514), "float32", 1, True),
+        ((258, 258, 258), "float32", 1, True),
+        ((258, 258, 258), "float32", 2, True),
+        ((66, 66, 66), "float32", 1, False),     # no whole lane tile
+        ((34, 34, 34), "float32", 1, False),
+        ((4, 4, 4), "float32", 1, False),
+        ((64, 4, 512), "float32", 1, False),     # no whole row tile
+        ((514, 514, 514), "bfloat16", 1, False),
+        ((4, 15000, 15000), "float32", 1, False),  # a plane over VMEM
+        ((15000, 15000), "float32", 1, True),    # rank 2 as before
+        ((16, 16), "bfloat16", 1, True),
+        ((4, 4, 4, 512), "float32", 1, False),
+    ])
+    def test_the_predicate_reads_rank_shape_and_dtype(
+            self, shape, dtype, n_in, takes, monkeypatch):
+        import jax
+
+        monkeypatch.setattr(stencil_pallas, "_INTERPRET", True)
+        monkeypatch.setattr(stencil_pallas, "_ENABLED", True)
+        arrs = [jax.ShapeDtypeStruct(shape, dtype)] * n_in
+        assert stencil_pallas.available(arrs) == takes
+        assert stencil_pallas.available_local(arrs) == takes
+
+    def test_the_threshold_is_the_last_axis(self):
+        import jax.numpy as jnp
+
+        f32 = jnp.dtype("float32")
+        lanes = stencil_pallas._RANK3_MIN_LANES
+        assert stencil_pallas._rank3_wins((8, 8, lanes), f32, 1)
+        assert not stencil_pallas._rank3_wins((8, 8, lanes - 1), f32, 1)
+        assert 128 <= lanes <= 258  # mg-C's 258^3 and 514^3 take the kernel
+
+    @pytest.mark.parametrize("shape,taps", [
+        ((514, 514, 514), 27), ((514, 514, 514), 21), ((258, 258, 258), 27),
+        ((130, 130, 130), 27), ((1026, 1026, 1026), 27), ((8, 8, 128), 1)],
+        ids=str)
+    def test_block_never_asks_more_than_the_cap(self, shape, taps):
+        halo, margins = (1, 1), (8, 8, 128, 128)
+        (bp, rows), limit = stencil_pallas._padded_block3(
+            *shape, 4, halo, margins, [(2, 6)], taps)
+        assert 1 <= bp <= min(stencil_pallas._BLOCK_PLANES, shape[0])
+        assert rows % 8 == 0 and 8 <= rows <= stencil_pallas._BLOCK_ROWS3
+        assert stencil_pallas._padded_vmem_bytes3(
+            bp, rows, *shape[1:], 4, halo, margins, [(2, 6)],
+            taps) <= limit <= stencil_pallas._vmem_cap()
+        # a plane more would not fit, or the block is as tall as it may be
+        assert bp == min(stencil_pallas._BLOCK_PLANES, shape[0]) or (
+            stencil_pallas._padded_vmem_bytes3(
+                bp + 1, rows, *shape[1:], 4, halo, margins, [(2, 6)], taps)
+            > stencil_pallas._vmem_cap())
+
+    @pytest.mark.parametrize("tiles,most,want", [
+        (65, 33, 33),   # 514 rows: two parts of 264, eight rows done twice
+        (33, 33, 33),   # 258 rows: the plane whole
+        (33, 4, 3),     # its evaluation: eleven parts of three tiles
+        (17, 33, 17), (1, 4, 1), (64, 4, 4), (7, 4, 1), (5, 4, 1)])
+    def test_a_plane_is_walked_in_equal_parts(self, tiles, most, want):
+        t = stencil_pallas._part(tiles, most)
+        assert t == want <= max(1, min(tiles, most))
+        done = -(-tiles // t) * t
+        assert tiles <= done and tiles >= 0.97 * done
+
+    def test_a_plane_too_large_is_refused(self):
+        with pytest.raises(ValueError, match="VMEM"):
+            stencil_pallas._padded_block3(
+                4, 15000, 15000, 4, (1, 1), (8, 8, 128, 128), [(2, 6)], 27)
+
+    def test_the_stage_plan_shares_the_shifts_of_27_points(self):
+        st = rt.stencil(_op27)
+        slots = (("arr", 0),)
+        (plan,) = stencil_pallas._stage_plan(st.func, slots, 8)
+        lanes, subs = plan
+        assert lanes == (-1, 1)
+        assert sorted(subs) == [(di, dj) for di in (-1, 1)
+                                for dj in (-1, 0, 1)]
+        # offsets that are whole tiles stage nothing
+        whole = rt.stencil(lambda a: a[1, 8, 0] + a[0, 0, 128] + a[-1, 0, 0])
+        assert stencil_pallas._stage_plan(whole.func, slots, 8) == (
+            ((), ()),)
+
+    @pytest.mark.parametrize("shape", [(12, 72, 1200), (9, 20, 34)], ids=str)
+    def test_the_operand_is_the_kernels_only_input(self, shape):
+        """No XLA op stands between the array and the kernel: the program
+        of one sweep is the ``pallas_call`` on the operand itself, the
+        ragged tiles being blocks of it."""
+        import jax
+        import jax.numpy as jnp
+
+        st = rt.stencil(_op27)
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+        jaxpr = jax.make_jaxpr(lambda a: stencil_pallas._run_padded(
+            st.func, lo, hi, slots, [a], taps, True))(
+                jnp.zeros(shape, jnp.float32))
+        (outer,) = jaxpr.jaxpr.eqns
+        assert outer.params["name"] == "ramba_stencil"
+        (call,) = outer.params["jaxpr"].jaxpr.eqns
+        assert call.primitive.name == "pallas_call"
+        assert {v.aval.shape for v in call.invars} == {shape}
+
+    def test_halo_strips_are_rank_2_only(self):
+        import jax.numpy as jnp
+
+        st = rt.stencil(_op27)
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+        x = jnp.zeros((8, 16, 256), jnp.float32)
+        with pytest.raises(NotImplementedError):
+            stencil_pallas.run(st.func, lo, hi, slots, [x], taps,
+                               halos=[(x, x, x, x)])

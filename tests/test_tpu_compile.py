@@ -3,8 +3,9 @@ v5e with no chip attached (on-chip-measurement guide, section 2.3), and the
 fact about the chip's compiler that ``ramba_tpu/core/layouts.py`` answers:
 left alone it lays a (time, 721, 1440) cube out with TIME minor, so a walk
 along time costs a copy of the cube; and a strided index of a long row as
-``ramba_tpu/core/slicing.py`` lowers it.  Nothing here runs on a TPU and
-nothing printed is a time.  The only tier-1 file that loads the TPU's
+``ramba_tpu/core/slicing.py`` lowers it; and the rank-3 stencil kernel at
+``mg-C``'s two finest levels, as Mosaic takes it.  Nothing here runs on a
+TPU and nothing printed is a time.  The only tier-1 file that loads the TPU's
 compiler: keep such tests here."""
 
 import os
@@ -153,3 +154,40 @@ def test_a_strided_long_row_is_work_that_follows_its_length(one_chip, write):
     nbytes = 64 * (1 << 20) * 4
     assert c.memory_analysis().temp_size_in_bytes < 3 * nbytes
     assert c.cost_analysis()["flops"] < 2e11
+
+
+# -- the rank-3 stencil kernel as Mosaic takes it ----------------------------
+@pytest.mark.parametrize("n,weights", [
+    (514, (-8 / 3, 0.0, 1 / 6, 1 / 12)),        # A: 21 taps
+    (514, (0.5, 0.25, 0.125, 0.0625)),          # P: 27
+    (258, (-3 / 17, 1 / 33, -1 / 61, 0.0)),     # S: 19
+], ids=["A-514", "P-514", "S-258"])
+def test_the_rank_3_kernel_compiles_at_the_cells_sizes(one_chip, n, weights):
+    """NPB MG's operators over mg-C's (2^k + 2)^3 arrays: the block the
+    kernel derives fits the VMEM it asks for, every copy is one Mosaic
+    takes (whole tiles from the operand, the ragged ones as blocks of
+    it), and the compiled program is the custom call alone: no pad, slice
+    or fusion of the operand's size beside it.  In the x32 regime, the
+    chip's: Mosaic takes no 64-bit index, at rank 2 either."""
+    import ramba_tpu as rt
+    from benchmark.programs import nas_mg
+    from ramba_tpu.observe import registry
+    from ramba_tpu.ops import stencil_pallas
+
+    st = nas_mg.stencil27(rt, weights)
+    slots = (("arr", 0),)
+    lo, hi, taps = st.neighborhood(slots)
+    x = jax.ShapeDtypeStruct((n, n, n), jnp.float32, sharding=one_chip)
+    assert stencil_pallas._rank3_wins(x.shape, x.dtype, 1)
+    with registry.collect_kernel_notes() as notes, jax.enable_x64(False):
+        compiled = jax.jit(lambda a: stencil_pallas._run_padded(
+            st.func, lo, hi, slots, [a], taps, False)).lower(x).compile()
+    (note,) = notes
+    assert note["path"] == "pallas_padded" and not note["interpret"]
+    assert note["grid"] == -(-n // note["block_planes"]) >= 16
+    assert not note.get("operand_copy")
+    assert note["vmem_limit_bytes"] <= stencil_pallas._vmem_cap()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " fusion(" not in text and " pad(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
