@@ -16,6 +16,7 @@ plain Python functions over jax values; no source-string codegen, no eval().
 
 from __future__ import annotations
 
+import time
 import types
 import weakref
 from typing import Any, Callable, Sequence
@@ -30,6 +31,24 @@ from ramba_tpu.observe import registry as _registry
 # ---------------------------------------------------------------------------
 # Nodes
 # ---------------------------------------------------------------------------
+
+# A script builds thousands of nodes a flush, so what is counted per node
+# takes no lock: plain integers here (one thread builds a stream's DAG; an
+# increment lost under contention is a measurement's, not a result's) that
+# the registry folds in when it is read (``registry.add_source``).
+# ``dag.node.n`` nodes, ``dag.node.ns`` the time in their constructor,
+# which is ``infer_aval`` whole; ``dag.infer.hit`` the inferences served
+# from a memo or from ``Scalar``'s table.  The time adds up in float
+# seconds: two float operations a node cost less than two on integers
+# past 2**30.
+_node_n = _infer_hit = 0
+_node_s = 0.0
+_now = time.perf_counter
+
+_registry.add_source(lambda: {"dag.node.n": _node_n,
+                              "dag.node.ns": int(_node_s * 1e9),
+                              "dag.infer.hit": _infer_hit})
+
 
 class Expr:
     """Base class. ``aval`` is a jax.ShapeDtypeStruct-like with shape/dtype."""
@@ -82,6 +101,7 @@ class Scalar(Expr):
     __slots__ = ("value",)
 
     def __init__(self, value):
+        global _infer_hit
         self.value = value
         key = _scalar_key(value)
         aval = _scalar_avals.get(key) if key is not None else None
@@ -91,7 +111,7 @@ class Scalar(Expr):
             if key is not None:
                 _scalar_avals[key] = aval
         else:
-            _registry.inc("dag.infer.hit")
+            _infer_hit += 1
         self.aval = aval
 
 
@@ -101,12 +121,16 @@ class Node(Expr):
     __slots__ = ("op", "static", "args")
 
     def __init__(self, op: str, static: tuple, args: Sequence[Expr], aval=None):
+        global _node_n, _node_s
+        t0 = _now()
         self.op = op
         self.static = static
         self.args = tuple(args)
         if aval is None:
             aval = infer_aval(op, static, [a.aval for a in self.args])
         self.aval = aval
+        _node_n += 1
+        _node_s += _now() - t0
 
 
 def as_expr(x: Any) -> Expr:
@@ -210,7 +234,9 @@ def infer_aval(op: str, static: tuple, arg_avals: Sequence[Any]) -> Any:
     executable (``program.key`` hashes the function by identity too).
 
     A hit counts ``dag.infer.hit``; a miss ``dag.infer.n`` and its time
-    ``dag.infer.ns``."""
+    ``dag.infer.ns``.  Hit or miss, the caller's ``dag.node.ns`` holds the
+    whole of it: the key, the lookup and the evaluation."""
+    global _infer_hit
     fn = OPS[op]
     funcs: list = []
     try:
@@ -224,7 +250,7 @@ def infer_aval(op: str, static: tuple, arg_avals: Sequence[Any]) -> Any:
         key = None
     else:
         if hit is not None:
-            _registry.inc("dag.infer.hit")
+            _infer_hit += 1
             return hit
     with _profile.span("dag.infer"):
         out = jax.eval_shape(lambda *a: fn(static, *a), *arg_avals)

@@ -164,7 +164,7 @@ class FlushStream:
     __slots__ = ("stream_id", "name", "tenant", "max_pending_ops",
                  "quota_bytes", "on_threshold", "inflight", "stats",
                  "nodes_since_flush", "trace_id", "root_span",
-                 "deadline_ms", "priority",
+                 "deadline_ms", "priority", "_build",
                  "_pending", "_lock", "_flush_lock", "__weakref__")
 
     def __init__(self, name: Optional[str] = None,
@@ -195,6 +195,10 @@ class FlushStream:
         self.stats = {"flushes": 0, "nodes_flushed": 0, "quarantined": 0,
                       "enqueued": 0}
         self.nodes_since_flush = 0
+        # the build phase: open (``ramba.dag.build``, counters
+        # ``dag.build.n``, ``.ns``) from the first pending node to the
+        # flush that collects it
+        self._build: Optional[_profile.span] = None
         self._pending: dict[int, "weakref.ref"] = {}
         self._lock = threading.RLock()
         self._flush_lock = threading.RLock()
@@ -252,6 +256,7 @@ class FlushStream:
         references keep the arrays alive until write-back."""
         with self._lock:
             self.nodes_since_flush = 0
+            build, self._build = self._build, None
             roots = []
             for r in list(self._pending.values()):
                 a = r()
@@ -266,6 +271,10 @@ class FlushStream:
                 for a in roots:
                     if _arr_streams.get(id(a)) is self:
                         del _arr_streams[id(a)]
+        if build is not None:
+            # the build ends where the flush begins: the caller opens
+            # ``ramba.flush.prepare`` next
+            build.__exit__(None, None, None)
         return roots
 
     # -- thresholds --------------------------------------------------------
@@ -275,6 +284,8 @@ class FlushStream:
         stream, so one tenant's burst only flushes that tenant's work."""
         with self._lock:
             self.nodes_since_flush += 1
+            if self.nodes_since_flush == 1:
+                self._build = _profile.span("dag.build").__enter__()
             cap = self.max_pending_ops
             if cap is None:
                 cap = common.max_pending_ops
@@ -1224,6 +1235,11 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
         if _ledger.sync_timing():
             # RAMBA_PERF=sync: a second, device-synchronized sample.
             sync_dt = dt + fence_dt
+    # hashed once a call, and only for whoever reads it: the per-function
+    # timer, the ledger row, the span's call entry
+    timed = not is_new and common.timing_level > 0
+    label = (_program_label(program)
+             if timed or fp is not None or span is not None else None)
     if is_new:
         # jax.jit compiles lazily: the first call pays trace+lower+XLA
         # compile.  Attribute it separately so per-program execution times
@@ -1231,11 +1247,11 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
         _timing.add_time("trace_compile_first_call", dt)
     else:
         _timing.add_time("flush_execute", dt)
-        if common.timing_level > 0:  # label hashing is off the hot path
-            _timing.add_func_time(_program_label(program), dt)
+        if timed:
+            _timing.add_func_time(label, dt)
     if fp is not None:
         _ledger.record_execute(
-            fp, _program_label(program), len(program.instrs), rung, dt,
+            fp, label, len(program.instrs), rung, dt,
             is_new, bytes_in=bytes_in,
             bytes_out=sum(_nbytes(o) for o in outs),
             donated=donated, sync_seconds=sync_dt,
@@ -1251,7 +1267,7 @@ def _execute_compiled(fn, program: _Program, leaf_vals, is_new: bool,
         if fence_dt is not None:
             _attrib.add_stage(span, "device_execute", fence_dt)
         call = {
-            "label": _program_label(program),
+            "label": label,
             "cache": "miss" if is_new else "hit",
             "seconds": round(dt, 6),
         }

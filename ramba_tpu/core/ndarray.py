@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import builtins
 import itertools
+import time
 from typing import Optional
 
 import jax
@@ -32,6 +33,7 @@ from ramba_tpu.core import expr as E
 from ramba_tpu.core import fuser
 from ramba_tpu.core.expr import Const, Expr, Node, Scalar
 from ramba_tpu.observe import profile as _profile
+from ramba_tpu.observe import registry as _registry
 from ramba_tpu.parallel import mesh as _mesh
 
 _seq_counter = itertools.count()
@@ -731,7 +733,30 @@ def expand_ellipsis(idx: tuple, ndim: int) -> tuple:
     return idx
 
 
+# Index lowering, counted where every ``__getitem__`` and ``__setitem__``
+# passes: ``dag.index.n`` and ``dag.index.ns``, plain integers that the
+# registry folds in when it is read (as expr.py's per-node counts, the
+# time in float seconds).
+_index_n = 0
+_index_s = 0.0
+_now = time.perf_counter
+
+_registry.add_source(lambda: {"dag.index.n": _index_n,
+                              "dag.index.ns": int(_index_s * 1e9)})
+
+
 def _classify_index(idx, shape):
+    """:func:`_classify` under the index counters."""
+    global _index_n, _index_s
+    t0 = _now()
+    try:
+        return _classify(idx, shape)
+    finally:
+        _index_n += 1
+        _index_s += _now() - t0
+
+
+def _classify(idx, shape):
     """Split an index into basic / boolean-mask / advanced-integer cases.
 
     Reference analog: ndarray.__getitem__ dispatch between slicing views,

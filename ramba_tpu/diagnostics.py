@@ -62,7 +62,9 @@ def identity() -> dict:
 def counters() -> dict:
     """Copy of every named counter (see observe/registry.py for the
     naming convention)."""
-    return dict(_registry.counters)
+    with _registry.lock:
+        _registry.fold()
+        return dict(_registry.counters)
 
 
 def last_flushes(n: int = 10) -> list:

@@ -4,7 +4,11 @@ Every flush runs inside ``TraceAnnotation``s with stable names
 (``ramba.flush.prepare``, ``ramba.flush.run``, ``ramba.flush.fence``),
 each carrying the program's label and the span's trace id as arguments,
 and the work outside the flush span that :func:`span` wraps shows as
-``ramba.<name>``.  They engage under ANY profiler session: one the
+``ramba.<name>``: ``ramba.dag.build`` from a stream's first pending
+node to its flush (core/fuser.py ``FlushStream``), ``ramba.dag.infer``,
+``ramba.read``, ``ramba.observe.tail``, and ``ramba.host.gc`` for each
+pause of Python's collector (counted below, in whichever of the others
+it fell).  They engage under ANY profiler session: one the
 caller starts (``jax.profiler.start_trace``) or the whole-process one of
 ``RAMBA_PROFILE_DIR=<dir>``, which the first flush starts and atexit
 stops.  With no session a ``TraceAnnotation`` is one atomic load, so
@@ -17,7 +21,9 @@ stage metric, and a timeline row is matched back to its span by the
 from __future__ import annotations
 
 import atexit
+import gc
 import os
+import threading
 import time
 
 from jax.profiler import TraceAnnotation, start_trace, stop_trace
@@ -74,14 +80,18 @@ class span:
     """Host work outside the flush span, counted where it happens: for
     its duration ``ramba.<name>`` is open on the profiler's host line,
     and on exit the elapsed nanoseconds go to the registry counter
-    ``<name>.ns`` and 1 to ``<name>.n``.  No event is emitted."""
+    ``<name>.ns`` and 1 to ``<name>.n``.  No event is emitted.  One
+    entered and left by hand (a stream's build phase) may be left on
+    another thread than it was entered on: the profiler keeps a line a
+    thread, so the annotation is then dropped and the counters kept."""
 
-    __slots__ = ("name", "_ann", "_t0")
+    __slots__ = ("name", "_ann", "_t0", "_tid")
 
     def __init__(self, name: str):
         self.name = name
 
     def __enter__(self):
+        self._tid = threading.get_ident()
         self._ann = TraceAnnotation("ramba." + self.name)
         self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
@@ -89,7 +99,39 @@ class span:
 
     def __exit__(self, *exc):
         ns = time.perf_counter_ns() - self._t0
-        self._ann.__exit__(*exc)
+        if threading.get_ident() == self._tid:
+            self._ann.__exit__(*exc)
         registry.inc(self.name + ".ns", ns)
         registry.inc(self.name + ".n")
         return False
+
+
+# Python's collector: counters ``host.gc.n`` (collections), ``host.gc.ns``
+# (their pauses) and ``host.gc.gen2.n`` (the full ones), and
+# ``ramba.host.gc`` open for each pause.  One collection runs at a time
+# in a process, on the thread that tripped it, so plain integers do; the
+# registry folds them in when it is read.  Nothing runs between
+# collections, and no threshold is touched.
+_gc_n = _gc_ns = _gc_gen2_n = 0
+_gc_t0 = 0
+_gc_ann = None
+
+
+def _on_gc(phase, info):
+    global _gc_n, _gc_ns, _gc_gen2_n, _gc_t0, _gc_ann
+    if phase == "start":
+        _gc_ann = TraceAnnotation("ramba.host.gc",
+                                  generation=info["generation"])
+        _gc_ann.__enter__()
+        _gc_t0 = time.perf_counter_ns()
+    elif _gc_ann is not None:
+        _gc_ns += time.perf_counter_ns() - _gc_t0
+        _gc_n += 1
+        _gc_gen2_n += info["generation"] == 2
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+
+
+gc.callbacks.append(_on_gc)
+registry.add_source(lambda: {"host.gc.n": _gc_n, "host.gc.ns": _gc_ns,
+                             "host.gc.gen2.n": _gc_gen2_n})

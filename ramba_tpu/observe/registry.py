@@ -68,6 +68,32 @@ def inc(name: str, n: int = 1) -> None:
         counters[name] += n
 
 
+# Counts made thousands of times a flush (a lazy node, an index lowered)
+# do not take the lock: their owner adds to plain module integers and
+# registers a function that returns {name: total since import}.  fold()
+# brings the store up to those totals; every read of the store below, and
+# diagnostics.counters(), folds first.  ``counters`` read directly is as
+# of the last fold.
+_sources: list = []
+_folded: dict = {}
+
+
+def add_source(fn) -> None:
+    """Register ``fn() -> {name: total}`` as the owner of those names."""
+    with lock:
+        _sources.append(fn)
+
+
+def fold() -> None:
+    """Add to the store what each source has counted since the last
+    fold."""
+    with lock:
+        for fn in _sources:
+            for name, total in fn().items():
+                counters[name] += total - _folded.get(name, 0)
+                _folded[name] = total
+
+
 # Which lowering a hand-written kernel took is decided while jax traces
 # the program (from shapes, dtypes, mesh and backend), so it is recorded
 # then.  Two records with two lifetimes:
@@ -164,6 +190,7 @@ def gauge_names() -> set:
 
 
 def get(name: str) -> int:
+    fold()
     return counters.get(name, 0)
 
 
@@ -171,6 +198,7 @@ def prefixed(prefix: str) -> dict:
     """Counters under one subsystem prefix (e.g. ``prefixed("resilience.")``
     → every fault/retry/degradation counter)."""
     with lock:  # iteration would break under a concurrent inc of a new key
+        fold()
         return {k: v for k, v in counters.items() if k.startswith(prefix)}
 
 
@@ -178,6 +206,7 @@ def snapshot() -> dict:
     """Point-in-time copy of every store (JSON-serializable except
     sub_timers' tuple keys, which stringify as 'parent/name')."""
     with lock:
+        fold()
         return {
             "counters": dict(counters),
             "timers": {k: tuple(v) for k, v in timers.items()},
@@ -190,6 +219,7 @@ def snapshot() -> dict:
 
 def reset_counters() -> None:
     with lock:
+        fold()  # what the sources counted so far is cleared with the rest
         counters.clear()
         _gauge_names.clear()
 

@@ -134,8 +134,8 @@ def _dump_history() -> None:
         with open("ramba_tpu_flush_history.txt", "w") as f:
             for k, v in fuser.stats.items():
                 f.write(f"{k}: {v}\n")
-            for k in sorted(registry.counters):
-                f.write(f"{k}: {registry.counters[k]}\n")
+            for k, v in sorted(registry.prefixed("").items()):
+                f.write(f"{k}: {v}\n")
     except OSError:
         pass
 

@@ -13,10 +13,13 @@ Covers the ``ramba_tpu.observe`` package + ``ramba_tpu.diagnostics``:
   touched.
 """
 
+import gc
 import json
 import os
 import subprocess
 import sys
+import threading
+import time
 import warnings
 
 import numpy as np
@@ -299,6 +302,184 @@ def test_observe_tail_counts_flush_spans():
     assert moved["observe.tail.ns"] > 0
     # the tail starts where the span's wall clock stops
     assert all("wall_s" in s for s in spans)
+
+
+# ---------------------------------------------------------------------------
+# the lazy-DAG layer counted where its work happens: dag.node (every node,
+# hit or miss), dag.index, dag.build (first pending node to the flush),
+# host.gc (the collector's pauses)
+# ---------------------------------------------------------------------------
+
+
+def _moved(before, *names):
+    after = diagnostics.counters()
+    return {n: after.get(n, 0) - before.get(n, 0) for n in names}
+
+
+def _reachable_nodes(e, seen=None):
+    seen = {} if seen is None else seen
+    if isinstance(e, expr.Node) and id(e) not in seen:
+        seen[id(e)] = e
+        for a in e.args:
+            _reachable_nodes(a, seen)
+    return seen
+
+
+@pytest.mark.parametrize("k", [1, 7, 40])
+def test_dag_node_counts_every_node_hit_or_miss(k):
+    x = rt.arange(256, dtype=np.float32)
+    rt.sync()
+
+    def build():
+        a = x
+        for i in range(k):
+            a = a * 2.0 + x if i % 2 else rt.sin(a)
+        return a
+
+    names = ("dag.node.n", "dag.node.ns", "dag.infer.n", "dag.infer.hit")
+    before = diagnostics.counters()
+    first = build()
+    m1 = _moved(before, *names)
+    nodes = len(_reachable_nodes(first.read_expr()))
+    assert nodes >= k
+    before = diagnostics.counters()
+    second = build()
+    m2 = _moved(before, *names)
+    # the second build infers nothing and still counts every node and its
+    # constructor's time: the memo's key and lookup are inference's cost
+    assert m1["dag.node.n"] == m2["dag.node.n"] == nodes
+    assert m2["dag.infer.n"] == 0 and m2["dag.infer.hit"] >= nodes
+    assert m2["dag.node.ns"] > 0
+    assert float(rt.sum(first - second)) == 0.0
+
+
+def test_dag_index_counts_each_lowered_index():
+    a = rt.zeros(64, dtype=np.float32)
+    b = rt.arange(64, dtype=np.float32)
+    rt.sync()
+    before = diagnostics.counters()
+    a[1:-1] = b[2:]
+    moved = _moved(before, "dag.index.n", "dag.index.ns")
+    assert moved["dag.index.n"] == 2 and moved["dag.index.ns"] > 0
+    before = diagnostics.counters()
+    with pytest.raises(IndexError):
+        a[64]
+    assert _moved(before, "dag.index.n")["dag.index.n"] == 1
+    np.testing.assert_array_equal(
+        a.asarray(), np.r_[0.0, np.arange(2, 64), 0.0].astype(np.float32))
+
+
+def test_dag_build_is_the_host_time_from_first_node_to_flush():
+    fuser.flush()
+    x = rt.arange(300, dtype=np.float32)
+    rt.sync()
+    names = ("dag.build.n", "dag.build.ns")
+    before = diagnostics.counters()
+    fuser.flush()  # nothing pending: no phase, nothing counted
+    assert _moved(before, *names) == {"dag.build.n": 0, "dag.build.ns": 0}
+    t0 = time.perf_counter_ns()
+    y = x * 3.0
+    z = y + x
+    assert fuser.default_stream()._build is not None
+    time.sleep(0.002)
+    fuser.flush()
+    host = time.perf_counter_ns() - t0
+    moved = _moved(before, *names)
+    assert moved["dag.build.n"] == 1
+    assert 2_000_000 <= moved["dag.build.ns"] <= host
+    assert fuser.default_stream()._build is None
+    # a read of a view of a materialized array builds its node inside the
+    # flush, with nothing pending: no phase opens for it
+    float(z[5])
+    assert _moved(before, *names)["dag.build.n"] == 1
+    # ... and a flush per pending build counts one each
+    for _ in range(3):
+        float(rt.sum(z * 2.0))
+    assert _moved(before, *names)["dag.build.n"] == 4
+    del y
+
+
+def test_host_gc_counts_a_forced_collection():
+    names = ("host.gc.n", "host.gc.gen2.n", "host.gc.ns")
+    enabled = gc.isenabled()
+    gc.disable()
+    try:
+        before = diagnostics.counters()
+        assert _moved(before, *names) == dict.fromkeys(names, 0)
+        gc.collect()
+        moved = _moved(before, *names)
+        assert moved["host.gc.n"] == 1 and moved["host.gc.gen2.n"] == 1
+        assert moved["host.gc.ns"] > 0
+        before = diagnostics.counters()
+        gc.collect(0)
+        moved = _moved(before, *names)
+        assert moved["host.gc.n"] == 1 and moved["host.gc.gen2.n"] == 0
+    finally:
+        if enabled:
+            gc.enable()
+
+
+def test_a_build_phase_closed_on_another_thread_still_counts():
+    stream = fuser.FlushStream(name="built-here-flushed-there")
+    x = rt.arange(128, dtype=np.float32)
+    rt.sync()
+    before = diagnostics.counters()
+    with fuser.stream_scope(stream):
+        y = x + 5.0
+    assert stream._build is not None
+    errors = []
+
+    def flush():
+        try:
+            stream.flush()
+        except Exception as e:  # pragma: no cover - the assertion below
+            errors.append(e)
+
+    t = threading.Thread(target=flush)
+    t.start()
+    t.join()
+    assert not errors
+    moved = _moved(before, "dag.build.n", "dag.build.ns")
+    assert moved["dag.build.n"] == 1 and moved["dag.build.ns"] > 0
+    assert stream._build is None
+    np.testing.assert_array_equal(
+        y.asarray(), np.arange(128, dtype=np.float32) + 5.0)
+
+
+@pytest.mark.skipif(_MULTIPROC, reason="one profiler session per process")
+def test_build_phase_and_collector_on_the_profilers_host_line(tmp_path):
+    """``ramba.dag.build`` runs from the first pending node to where
+    ``ramba.flush.prepare`` begins, and a pause of the collector shows
+    inside whichever span it fell in."""
+    from tests.helpers import profiled_host_lines
+
+    x = rt.arange(515, dtype=np.float32)
+    rt.sync()
+    float(rt.sum(x * 2.0 + 1.0))  # compiled outside the session
+
+    def body():
+        with _jax.profiler.TraceAnnotation("test_outer"):
+            y = x * 2.0
+            gc.collect()
+            float(rt.sum(y + 1.0))
+
+    lines = profiled_host_lines(tmp_path, body)
+    mine = [evs for evs in lines.values()
+            if any(name == "test_outer" for name, *_ in evs)]
+    assert len(mine) == 1
+    by_name = {}
+    for name, a, b, stats in mine[0]:
+        by_name.setdefault(name, []).append((a, b, stats))
+    (outer,) = by_name["test_outer"]
+    (build,) = [e for e in by_name["ramba.dag.build"]
+                if outer[0] <= e[0] and e[1] <= outer[1]]
+    prepare = by_name["ramba.flush.prepare"][-1]
+    assert build[1] <= prepare[0]
+    assert prepare[0] - build[1] < 1_000_000  # nothing of ours between
+    pauses = [e for e in by_name["ramba.host.gc"]
+              if build[0] <= e[0] and e[1] <= build[1]]
+    assert pauses and any(p[2].get("generation") in (2, "2")
+                          for p in pauses)
 
 
 @pytest.mark.skipif(_MULTIPROC, reason="one profiler session per process")
