@@ -279,6 +279,19 @@ def expected_stencil_paths3(n, ndev):
     return ("pallas_padded",) if n >= 256 else ("xla",)
 
 
+def expected_face_walks(n, iters, ndev):
+    """How many of the ``(4 lt - 2) iters + 1`` ghost-layer refreshes of
+    ``phase_mg``'s float32 solve over an n^3 grid take the in-place walk
+    (``faces.path.wrap``): on one chip every refresh of a level whose
+    (2^k + 2)^3 array has a whole row tile of 8, four an iteration and
+    the first ``resid``'s at the finest; on several devices none."""
+    if ndev > 1:
+        return 0
+    lt = n.bit_length() - 1
+    return sum(4 * iters + (k == lt) for k in range(2, lt + 1)
+               if 2 ** k + 2 >= 8)
+
+
 # ---------------------------------------------------------------------------
 # phases: plain functions of a size; return a dict of facts, raise on failure
 # ---------------------------------------------------------------------------
@@ -722,7 +735,7 @@ def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
     """NAS MG's four operators over the pyramid of an n^3 grid
     (``benchmark/programs/nas_mg.py``: ``iters`` V-cycles and the
     residual, twice over): a flush too long for one program (a V-cycle at
-    512^3 is 668 instructions, a program at most 768) runs as chained
+    512^3 is 294 instructions, a program at most 768) runs as chained
     segments on
     the fused rung, the second time from the executables of the first.
     ``want`` is the norm to meet; the NumPy reference gives it where it is
@@ -757,6 +770,17 @@ def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
         paths = sorted(k[len("stencil.path."):] for k, v in
                        r2.counters.items()
                        if k.startswith("stencil.path.") and v > 0)
+        # every ``comm3`` is ONE node (4 lt - 2 an iteration and the first
+        # ``resid``'s); on one chip the levels with a whole row tile take
+        # the in-place walk (off the chip the kernel is not offered)
+        wraps = r2.counters.get("faces.path.wrap", 0)
+        writes = r2.counters.get("faces.path.dus", 0)
+        _require(wraps + writes == (4 * prog.lt - 2) * iters + 1,
+                 f"second solve: {wraps} + {writes} ghost-layer refreshes")
+        walks = 0 if interpret_ok else expected_face_walks(
+            n, iters, rt.get_mesh().devices.size)
+        _require(wraps == walks,
+                 f"faces.path.wrap moved by {wraps}, expected {walks}")
         if rt.get_mesh().devices.size == 1:
             # on a mesh the layouts of the pyramid's nine sizes are
             # GSPMD's to choose: nothing to hold them to
@@ -764,7 +788,8 @@ def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
         prog.u = prog.r = prog.v = None
     rec.require_clean(interpret_ok=interpret_ok)
     return {"n": n, "norm": out2[0], "want": cfg["norm"],
-            "path": "+".join(paths), "rungs": rec.rungs(),
+            "path": "+".join(paths), "faces": f"wrap:{wraps}+dus:{writes}",
+            "rungs": rec.rungs(),
             "instrs": r2.flushes[0]["instrs"], "segments": calls,
             "segment_hits_second": hits,
             "segment_misses_first": r1.counters.get("fuser.segment.miss", 0),
@@ -944,10 +969,12 @@ def main() -> int:
         # level 8 of mg-C's pyramid: the smallest the rank-3 kernel takes
         ("stencil 258^3", lambda: phase_stencil3(
             rt, 258, expected_stencil_paths3(258, ndev))),
-        # NumPy float64 reads 1.274675290838857e-04 after two iterations at
-        # class C, float32 1.2746751486658546e-04 (PERF.md, PR 32)
-        ("mg 512^3", lambda: phase_mg(rt, 512, 1.274675290838857e-04,
-                                      iters=2)),
+        # NumPy float32 reads 1.2746751486658546e-04 after two iterations at
+        # class C (float64 1.274675290838857e-04: PERF.md, PR 32) and
+        # 8.398651024955054e-05 after three (``mg_np``, off the chip, PR
+        # 35); two are one program since a refresh is one instruction
+        ("mg 512^3", lambda: phase_mg(rt, 512, 8.398651024955054e-05,
+                                      iters=3)),
         ("axpy 1e9", lambda: phase_axpy(rt, 1_000_000_000)),
         ("broadcast 32768^2", lambda: phase_broadcast(rt, 32768)),
         ("stencil 30000^2", lambda: phase_stencil(

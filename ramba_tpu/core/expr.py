@@ -536,6 +536,17 @@ def _op_setitem(static, x, v):
     return slicing.put(x, decode_index(enc), v.astype(x.dtype))
 
 
+@defop("remap_faces")
+def _op_remap_faces(static, x):
+    """A chain of whole-face copies ``x[.., d, ..] = x[.., s, ..]`` on one
+    array (``rewrite.fold_face_copy``): per axis the ``(d, s)`` pairs
+    in the script's order."""
+    from ramba_tpu.core import slicing
+
+    (maps,) = static
+    return slicing.remap(x, maps)
+
+
 @defop("getitem_adv")
 def _op_getitem_adv(static, x, *indexers):
     """Fancy-index gather.  The reference builds an all2all owner-lookup gather

@@ -965,11 +965,18 @@ def _repetition(program: _Program):
 
 def _segment_ends(program: _Program, seg_size: int) -> list:
     """Where the count-bounded segments of ``program`` end.  A program
-    that repeats is cut at the same places of every repetition (whole
-    repetitions to a segment where several fit, else a repetition in
-    equal parts), so that one executable serves every repetition; what
-    stands before and after the loop, and a program with no loop, is cut
-    every ``seg_size`` instructions."""
+    that repeats is cut at the same places of every repetition, so that
+    one executable serves every repetition: whole repetitions to a segment
+    where several fit, else a repetition in equal parts.  Of the numbers
+    that fit, the largest that divides the loop's count is taken if it is
+    at least half of what fits, so that the calls at most double; where
+    none is, as many as fit, and the repetitions left over are cut with
+    what stands after the loop.  Either way a remainder makes no program
+    of its own to trace, lower and compile (19 repetitions of 294
+    instructions, two to a segment of 768: a fourth executable and 10 s
+    of a 48 s set-up; PERF.md section 6, PR 35).  What stands before and
+    after the loop, and a program with no loop, is cut every ``seg_size``
+    instructions."""
     ninstr = len(program.instrs)
     loop = _repetition(program) if ninstr > seg_size else None
     if loop is None:
@@ -979,15 +986,16 @@ def _segment_ends(program: _Program, seg_size: int) -> list:
     if start:
         ends.append(start)
     if period <= seg_size:
-        step = period * (seg_size // period)
-        ends += list(range(start + step, start + period * count, step))
+        fit = min(seg_size // period, count)
+        reps = max((k for k in range(-(-fit // 2), fit + 1)
+                    if count % k == 0), default=fit)
+        last = start + period * reps * (count // reps)
+        ends += list(range(start + period * reps, last + 1, period * reps))
     else:
         parts = -(-period // seg_size)
         ends += [start + k * period + (j + 1) * period // parts
                  for k in range(count) for j in range(parts)]
-    last = start + period * count
-    if not ends or ends[-1] != last:
-        ends.append(last)
+        last = start + period * count
     ends += list(range(last + seg_size, ninstr, seg_size))
     if ends[-1] != ninstr:
         ends.append(ninstr)

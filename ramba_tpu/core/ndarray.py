@@ -31,6 +31,7 @@ import numpy as np
 from ramba_tpu import common
 from ramba_tpu.core import expr as E
 from ramba_tpu.core import fuser
+from ramba_tpu.core import rewrite as _rewrite
 from ramba_tpu.core.expr import Const, Expr, Node, Scalar
 from ramba_tpu.observe import profile as _profile
 from ramba_tpu.observe import registry as _registry
@@ -542,6 +543,15 @@ class ndarray:
 
     def __setitem__(self, idx, value):
         kind, payload = _classify_index(idx, self.shape)
+        if (kind == "basic" and common.rewrite_enabled
+                and isinstance(value, ndarray) and value._base is self
+                and self._base is None and type(value._view) is SliceView):
+            # a face of this array onto another: one node, no view read
+            folded = _rewrite.fold_face_copy(self._expr, payload,
+                                             value._view.enc)
+            if folded is not None:
+                self.write_expr(folded)
+                return
         vexpr = as_exprable(value)
         if kind == "basic":
             self.write_expr(Node("setitem", (payload,), [self.read_expr(), vexpr]))

@@ -23,7 +23,14 @@ with direct implementations:
 
 Rules run bottom-up once per flush (core/fuser.py); a rule returns a
 replacement Node or None.  All matching is defensive: any structural
-mismatch leaves the graph untouched.
+mismatch leaves the graph untouched.  One fold runs where the script
+writes, not at the flush:
+
+* ``fold_face_copy`` (counted as ``rewrite_face_copies``) — ``a[d] =
+  a[s]``, a whole hyperplane of an array onto another of the same array,
+  and every such copy after it, become ONE ``remap_faces`` node: a
+  ghost-layer refresh (NPB MG's ``comm3``, six copies) is one in-place
+  pass (``core/slicing.py`` ``remap``), not six writes.
 """
 
 from __future__ import annotations
@@ -412,6 +419,70 @@ def rewrite_reduce_group_broadcast(node: Node):
     return fuse_broadcast_reduce(node)
 
 
+def _face(enc, shape):
+    """``(axis, index, kind)`` of a basic index that selects ONE whole
+    hyperplane, an integer (kind ``"i"``) or a unit-stride slice of
+    length one (``"s"``) on one axis and full slices on the others, or
+    None."""
+    if len(enc) > len(shape):
+        return None
+    found = None
+    for ax, part in enumerate(enc):
+        n = shape[ax]
+        if part[0] == "i":
+            hit = (ax, part[1] + (n if part[1] < 0 else 0), "i")
+        elif part[0] == "s":
+            start, stop, step = slice(*part[1:]).indices(n)
+            if (start, stop, step) == (0, n, 1):
+                continue
+            if step != 1 or stop - start != 1:
+                return None
+            hit = (ax, start, "s")
+        else:  # a new axis, an ellipsis
+            return None
+        if found is not None:
+            return None
+        found = hit
+    return found
+
+
+def fold_face_copy(x: Expr, dst_enc, src_enc):
+    """The node of ``x[dst] = x[src]``, both indexes one whole hyperplane
+    of the same axis of ``x``, counted as a firing of
+    ``rewrite_face_copies``; else None.
+
+    The state after any number of such copies is ``y[i, j, ..] = x[w0(i),
+    w1(j), ..]``: a copy ``d <- s`` on axis ``ax`` sets ``w_ax(d)`` to the
+    CURRENT ``w_ax(s)``, so copies on one axis compose in the order given
+    and copies on different axes commute.  The node keeps each axis' pairs
+    in order, a copy onto a ``remap_faces`` node joining its pairs;
+    ``slicing.remap`` composes them.  Same array, same dtype, same shape
+    on both sides: nothing is cast or broadcast.
+
+    ``ndarray.__setitem__`` asks where the script writes the copy (a basic
+    view of an array assigned to a window of the same array), before a
+    ``getitem`` or a ``setitem`` node exists, and this is no entry of
+    ``RULES``: a rule at the flush has every node above a firing rebuilt
+    by ``rewrite_roots``, 17,142 nodes a ``mg-C`` solve with the
+    collector's full pause in prepare (PERF.md section 6, PR 35), and no
+    script reaches the flush with the pattern but through
+    ``__setitem__``."""
+    shape = tuple(x.aval.shape)
+    dst, src = _face(dst_enc, shape), _face(src_enc, shape)
+    if (dst is None or src is None or dst[0] != src[0] or dst[2] != src[2]
+            or dst[1] == src[1]):
+        return None
+    ax, aval = dst[0], x.aval
+    if isinstance(x, Node) and x.op == "remap_faces":
+        (maps,), (x,) = x.static, x.args
+    else:
+        maps = ((),) * len(shape)
+    maps = maps[:ax] + (maps[ax] + ((dst[1], src[1]),),) + maps[ax + 1:]
+    stats["rewrite_face_copies"] += 1
+    _registry.inc("rewrite.rewrite_face_copies")
+    return Node("remap_faces", (maps,), [x], aval=aval)
+
+
 RULES = [
     rewrite_arange_reshape,
     rewrite_stack_reduce_advindex,
@@ -424,6 +495,7 @@ RULES = [
 # xarray/pandas idiom actually took the rewritten path — cf. the reference's
 # DAG-rewrite debug prints, ramba.py:4567-4789).
 stats = {rule.__name__: 0 for rule in RULES}
+stats["rewrite_face_copies"] = 0  # fold_face_copy: fired at the build
 
 
 def rewrite_roots(roots):

@@ -1,7 +1,7 @@
 """How a basic index (slices and integers) reads and writes a device array.
 
 ``x[idx]`` and ``x.at[idx].set(v)`` are right everywhere and slow or
-wrong in three places this module answers (PERF.md section 6, PR 32):
+wrong in four places this module answers (PERF.md section 6, PRs 32, 35):
 
 * **A write.**  jax lowers ``x.at[idx].set(v)`` to a ``scatter``, also
   where the index is plain slices.  XLA's SPMD partitioner (jax 0.9.0)
@@ -39,6 +39,18 @@ wrong in three places this module answers (PERF.md section 6, PR 32):
   at any size, on any mesh, slices the major axes first and the last two
   after an ``optimization_barrier``: two programs XLA cannot fuse back
   into the one that fails.
+* **A whole face copied onto another of the same array.**  A ghost-layer
+  refresh (NPB MG's ``comm3``: ``a[0] = a[m]; a[m + 1] = a[1]`` on every
+  axis in turn) is six such writes.  XLA:TPU materialises a face of the
+  LANE axis as ``f32[D, H, 1]`` in (8, 128) tiles, one useful float in a
+  row of 128: 137 MB at 514^3 for 1 MB of face, written by a ``slice``
+  and read back by the ``dynamic-update-slice``: 3.69 ms a refresh for
+  0.4 % of the data.  ``core/rewrite.py`` folds a chain of such copies
+  into one ``remap_faces`` node and ``remap`` lowers it: on one device a
+  rank-3 array of four-byte elements that ``ops/faces_pallas.py`` takes
+  is its own result, and the kernel visits, ONCE, the blocks that hold a
+  ghost lane or row (0.96 ms); everything else is the six writes as they
+  were.
 """
 
 from __future__ import annotations
@@ -48,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ramba_tpu.observe import registry as _registry
 from ramba_tpu.parallel import mesh as _mesh
 
 #: below this a strided window stays with XLA's own slice: the launch, not
@@ -217,3 +230,62 @@ def put(x, idx, v):
         v = jnp.where(mine, v, lax.slice(
             x, starts, [s + e for s, e in zip(starts, extent)]))
     return lax.dynamic_update_slice(x, v, starts)
+
+
+def _composed(pairs):
+    """``{d: s}`` of a chain of copies ``a[d] = a[s]`` along one axis, each
+    reading the array as the copies before it left it: hyperplane ``d`` of
+    the result is hyperplane ``s`` of the operand."""
+    w = {}
+    for d, s in pairs:
+        w[d] = w.get(s, s)
+    return {d: s for d, s in w.items() if d != s}
+
+
+def _faces_through_kernel(x, composed) -> bool:
+    """Whether a remap takes the in-place walk: one device, an array the
+    kernel takes (``faces_pallas.available``: no size under which the six
+    writes won on the chip), a row or lane remapped at all (a plane alone
+    is XLA's in place), and no source that is itself a destination, so
+    that what a block reads never depends on which blocks were written
+    before it."""
+    from ramba_tpu.ops import faces_pallas
+
+    if (x.ndim != 3 or not (composed[1] or composed[2])
+            or any(s in w for w in composed for s in w.values())
+            or _mesh.get_mesh().devices.size != 1):
+        return False
+    return faces_pallas.available(x.shape, x.dtype)
+
+
+def remap(x, maps):
+    """``x`` after the copies ``x[.., d, ..] = x[.., s, ..]`` of ``maps``:
+    per axis the ``(d, s)`` pairs in the order the script wrote them, whole
+    hyperplanes.  The result is ``x[w0, w1, ...]`` with ``w`` the composed
+    pairs of each axis, so copies on different axes commute.  Notes
+    ``faces.path.wrap`` (the Pallas walk, its block on the note) or
+    ``faces.path.dus`` (the writes one by one, the HLO they had)."""
+    composed = [_composed(pairs) for pairs in maps]
+
+    def face(ax, i):
+        return (slice(None),) * ax + (i,)
+
+    if _faces_through_kernel(x, composed):
+        from ramba_tpu.ops import faces_pallas
+
+        interpret = faces_pallas.interpreting()
+        bp, brp, vmem_limit = faces_pallas.block(x.shape)
+        _registry.note_kernel("faces", "wrap", interpret,
+                              grid=-(-x.shape[0] // bp), block_planes=bp,
+                              row_block_planes=brp,
+                              vmem_limit_bytes=vmem_limit)
+        x = faces_pallas.wrap(x, sorted(composed[1].items()),
+                              sorted(composed[2].items()), interpret)
+        for d, s in composed[0].items():  # no source is written: in place
+            x = put(x, face(0, d), take(x, face(0, s)))
+        return x
+    _registry.note_kernel("faces", "dus")
+    for ax, pairs in enumerate(maps):
+        for d, s in pairs:
+            x = put(x, face(ax, d), take(x, face(ax, s)))
+    return x

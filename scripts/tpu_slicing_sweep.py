@@ -1,7 +1,11 @@
 """``core/slicing.py``'s constants on the chip: from what window a stride
 on the lane axis is worth a selection product on the MXU
 (``MXU_MIN_ELEMENTS``), up to what step (``MXU_MAX_STEP``), and in what
-tiles a long row is cut (``LANE_WHOLE``, ``LANE_TILE``).
+tiles a long row is cut (``LANE_WHOLE``, ``LANE_TILE``); and whether at any
+size a ghost-layer refresh (NPB MG's ``comm3``: six whole-face copies) is
+better left six writes than given to the in-place walk of
+``ops/faces_pallas.py`` (at none read: the walk has no threshold), in what
+blocks.
 
 One process, one chip.  Every candidate is ``slicing.take`` or
 ``slicing.put`` jitted alone over a resident operand of random BITS (every
@@ -11,9 +15,17 @@ a gather of the lane axis; a write: ``lax.pad`` over at most
 ``PAD_MAX_EXTENT`` elements, jax's scatter over more), ``whole`` one
 product over the whole lane axis, ``tile<T>`` tiles of T.  ``ships`` marks
 what the constants in the tree choose.  A product's result is held against
-NumPy's, byte for byte.
+NumPy's, byte for byte.  A refresh (``faces``) is ``slicing.remap`` jitted
+alone with its operand donated, as a flush hands it a temporary: ``dus`` the
+six writes one by one (the parent's HLO), ``edge`` the walk over the edge
+blocks as the module sizes them, ``edge<P>`` in lane blocks of P planes,
+``whole<P>`` a walk over whole blocks of P planes (every byte read and
+written once), ``pass`` XLA's ``a + 1`` (what a read and a write of the array
+cost); held against NumPy's ``comm3``.
 
-    python -u scripts/tpu_slicing_sweep.py
+    python -u scripts/tpu_slicing_sweep.py [reads] [writes] [faces[=18,10]]
+
+(no argument: all three; ``faces=`` with the cubes' sides: those alone).
 
 Prints one JSON object, and a table on stderr row by row; the rows so far
 are in chiprun_out/slicing_sweep.json after every candidate, so a call
@@ -65,6 +77,19 @@ WRITES = [
     ("8192^2 ::8", (8192, 8192), (S(None), S(None, None, 8)),
      ["xla", "tile256"]),
 ]
+#: refreshes in one program
+ROUNDS = 20
+#: side of the cube, candidates: the levels of mg-C's pyramid that have a
+#: whole row tile
+FACES = [
+    (514, ["pass", "dus", "edge", "edge1", "edge12", "whole3"]),
+    (258, ["pass", "dus", "edge", "edge2", "edge28", "whole10"]),
+    (130, ["pass", "dus", "edge", "edge4", "edge60", "whole30"]),
+    (66, ["pass", "dus", "edge", "edge7", "whole66"]),
+    (34, ["pass", "dus", "edge", "edge8", "whole34"]),
+    (18, ["pass", "dus", "edge", "whole18"]),
+    (10, ["pass", "dus", "edge", "whole10"]),
+]
 
 
 def main() -> int:
@@ -73,7 +98,12 @@ def main() -> int:
     import numpy as np
 
     from ramba_tpu.core import slicing
+    from ramba_tpu.ops import faces_pallas
 
+    wanted = {a.split("=")[0] for a in sys.argv[1:]} or {"reads", "writes",
+                                                         "faces"}
+    sides = [int(n) for a in sys.argv[1:] if a.startswith("faces=")
+             for n in a[6:].split(",")]
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"no TPU: {dev.platform}", file=sys.stderr)
@@ -81,6 +111,7 @@ def main() -> int:
     shipped = {k: getattr(slicing, k) for k in
                ("MXU_MIN_ELEMENTS", "MXU_MAX_STEP", "LANE_WHOLE", "LANE_TILE",
                 "PAD_MAX_EXTENT")}
+    through_kernel = slicing._faces_through_kernel
 
     def configure(cand):
         """The module's constants for one candidate."""
@@ -96,6 +127,7 @@ def main() -> int:
     def restore():
         for k, v in shipped.items():
             setattr(slicing, k, v)
+        slicing._faces_through_kernel = through_kernel
 
     def what_ships(x, idx):
         axes = slicing._axes(idx, x.shape)
@@ -130,6 +162,8 @@ def main() -> int:
             json.dump(out, f, indent=1)
 
     for kind, table in (("read", READS), ("write", WRITES)):
+        if kind + "s" not in wanted:
+            continue
         for name, shape, idx, cands in table:
             x = bits(shape, 1)
             want = np.asarray(x).view(np.uint32)
@@ -171,8 +205,145 @@ def main() -> int:
                          else row["error"]), file=sys.stderr, flush=True)
                 keep()
             del x, args
+
+    def refresh(n, cand):
+        """One ``comm3`` of an n^3 array as ``cand`` lowers it: ROUNDS of
+        them in ONE program over a donated operand, as a flush holds them,
+        so that what is read is the device's time and not the host's 0.1
+        ms a call (a single call and its wait read 0.5 ms more than the
+        device took, at every size).  ``pass`` is ``a + 1``: what one
+        read and one write of the array cost as XLA fuses them."""
+        m = n - 2
+        pairs = ((0, m), (m + 1, 1))
+        name = cand.rstrip("0123456789")
+        if cand == "pass":
+            def f(a):
+                return jax.lax.optimization_barrier(a + 1.0)
+        elif cand in ("dus", "edge"):  # as the module lowers the node
+            if cand == "dus":
+                slicing._faces_through_kernel = lambda x, composed: False
+
+            def f(a):
+                return slicing.remap(a, (pairs,) * 3)
+        else:  # a block of the sweep's, the plane faces XLA's as shipped
+            def walk(a, bp):
+                if name == "whole":
+                    return whole_pass(a, pairs, pairs, False, bp)
+                return faces_pallas._wrap_jit(
+                    pairs, pairs, False, *faces_pallas._sized(a.shape, bp))(a)
+
+            def f(a):
+                a = walk(a, int(cand[len(name):]))
+                for d, s in pairs:
+                    a = slicing.put(a, (d,), slicing.take(a, (s,)))
+                return a
+
+        def rounds(a):
+            for _ in range(ROUNDS):
+                a = f(a)
+            return a
+
+        try:
+            fn = jax.jit(rounds, donate_argnums=0)
+            x = bits((n, n, n), 3)
+            want = np.pad(np.asarray(x).view(np.uint32)[1:-1, 1:-1, 1:-1], 1,
+                          mode="wrap")
+            reps = 1 if n > 200 else 10
+            x = jax.block_until_ready(fn(x))
+            ms = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                for _ in range(reps):
+                    x = fn(x)
+                jax.block_until_ready(x)
+                ms.append(1e3 * (time.perf_counter() - t0) / reps / ROUNDS)
+            return (statistics.median(ms), cand == "pass" or bool(
+                (np.asarray(x).view(np.uint32) == want).all()))
+        finally:
+            restore()
+
+    for n, cands in FACES if "faces" in wanted else ():
+        if sides and n not in sides:
+            continue
+        ships = ("edge" if faces_pallas.available((n, n, n), jnp.float32)
+                 else "dus")
+        for cand in cands:
+            row = {"kind": "faces", "operand": f"cube-{n} comm3",
+                   "shape": [n, n, n], "candidate": cand,
+                   "ships": cand == ships}
+            if cand == "edge":
+                row["block_planes"] = faces_pallas.block((n, n, n))[:2]
+            try:
+                ms, same = refresh(n, cand)
+                row.update(ms=ms, exact=same, gbps=4.0 * n ** 3 / ms / 1e6)
+                bad += not same
+            except Exception as e:  # a candidate the chip refuses
+                row.update(error=f"{type(e).__name__}: {str(e)[:300]}")
+                bad += 1
+            rows.append(row)
+            print(f"faces cube-{n:<17d} {cand:8s}"
+                  f"{'*' if row['ships'] else ' '} "
+                  + (f"{row['ms']:10.4f} ms {row['gbps']:8.1f} GB/s "
+                     f"exact={row['exact']}" if "ms" in row
+                     else row["error"]), file=sys.stderr, flush=True)
+            keep()
     print(json.dumps(out))
     return 1 if bad else 0
+
+
+def whole_pass(x, rows, lanes, interpret, bp):
+    """The sweep's own candidate, not the library's: the remap of
+    ``faces_pallas.wrap`` as ONE walk over whole blocks of ``bp`` planes
+    that Pallas's pipeline fetches and writes back where they came from,
+    every byte of the array read and written once."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ramba_tpu.ops import faces_pallas
+
+    D = x.shape[0]
+    Ho, Wo = faces_pallas._tiled(x.shape)
+    by_lane = faces_pallas._by_tile(lanes, 128)
+    by_row = faces_pallas._by_tile(rows, 8)
+
+    taking = faces_pallas._taking
+
+    def kernel(in_ref, out_ref):
+        def plane(p):
+            for r0 in range(0, Ho, 64):
+                rws = pl.ds(r0, min(64, Ho - r0))
+
+                def lane_tile(c, rws=rws):
+                    return in_ref[p, rws, pl.ds(128 * c, 128)]
+
+                for c in range(Wo // 128):
+                    tile = lane_tile(c)
+                    if c in by_lane:
+                        tile = taking(tile, by_lane[c], lane_tile, 1, 128)
+                    out_ref[p, rws, pl.ds(128 * c, 128)] = tile
+
+            def row_tile(t):
+                return out_ref[p, pl.ds(8 * t, 8), :]
+
+            for t, takes in by_row.items():
+                out_ref[p, pl.ds(8 * t, 8), :] = taking(
+                    row_tile(t), takes, row_tile, 0, 8)
+
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(bp),
+                          lambda p, c: plane(p) or c, jnp.int32(0))
+
+    spec = pl.BlockSpec((bp, Ho, Wo), lambda i: (i, 0, 0),
+                        memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        kernel, grid=(-(-D // bp),),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        in_specs=[spec], out_specs=spec, input_output_aliases={0: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=16 * bp * Ho * Wo + (2 << 20)),
+        interpret=interpret, name="ramba_face_whole",
+    )(x)
 
 
 if __name__ == "__main__":

@@ -102,3 +102,36 @@ else:
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", X64)
 
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def interpreting_walk(monkeypatch):
+    """``ops/faces_pallas.py``'s in-place walk off the chip.  The
+    interpreter refuses a read past a ragged tile, which the chip's padded
+    tiles allow, and jax's pipeline asks the attached chip its generation
+    to choose a tiling: here the kernel walks a copy padded to whole tiles
+    and blocks and is told the v5e's.  So tier-1 never makes the chip's
+    ragged reads; ``scripts/tpu_slicing_sweep.py faces`` and the ``mg-C``
+    cell do.  On one device only: install ``one_device`` first."""
+    import jax.numpy as jnp
+    from jax._src.pallas.mosaic import pipeline
+
+    from ramba_tpu.ops import faces_pallas
+
+    real = faces_pallas._wrap_call
+
+    def padded(rows, lanes, interpret, bp, brp, vmem_limit, x):
+        whole = jnp.pad(x, [(0, -n % t)
+                            for n, t in zip(x.shape, (brp, 8, 128))])
+        out = real(rows, lanes, interpret, bp, brp, vmem_limit, whole)
+        return out[tuple(slice(0, n) for n in x.shape)]
+
+    monkeypatch.setattr(faces_pallas, "_wrap_call", padded)
+    monkeypatch.setattr(faces_pallas, "_INTERPRET", True)
+    monkeypatch.setattr(pipeline, "_get_tpu_generation", lambda: 5)
+    faces_pallas._wrap_jit.cache_clear()
+    yield
+    faces_pallas._wrap_jit.cache_clear()

@@ -125,7 +125,7 @@ def test_prk_scalars_phase(interpreting):
 
 
 def test_mg_phase(interpreting):
-    facts = chip_smoke.phase_mg(rt, 16, iters=6, interpret_ok=True)
+    facts = chip_smoke.phase_mg(rt, 16, iters=12, interpret_ok=True)
     assert facts["rungs"] == ["fused"] and "xla" in facts["path"]
     assert facts["segments"] >= 2
     assert facts["segment_hits_second"] == facts["segments"]
@@ -247,3 +247,13 @@ def test_compile_refusal_surfaces_from_the_fused_rung(monkeypatch):
     monkeypatch.undo()
     del a
     rt.sync()
+
+
+@pytest.mark.parametrize("n,iters,ndev,walks", [
+    (512, 20, 1, 561),  # mg-C: 81 at 514^3, 80 at each of 258^3 .. 10^3
+    (512, 3, 1, 85), (16, 3, 1, 25), (4, 5, 1, 0), (512, 3, 4, 0),
+    (16, 12, 8, 0)])
+def test_the_smoke_states_which_refreshes_walk(n, iters, ndev, walks):
+    """From the grid, the iterations and the devices alone: the levels
+    whose array has a whole row tile, on one chip."""
+    assert chip_smoke.expected_face_walks(n, iters, ndev) == walks

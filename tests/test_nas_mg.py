@@ -138,10 +138,10 @@ def test_a_long_solve_runs_segmented_and_equals_the_unsegmented_one(
     monkeypatch.setattr(common, "max_program_instrs", 0)
     (whole,) = prog.solve()
     span = diagnostics.last_flushes()[-1]
-    assert span["segments"] == 0 and span["instrs"] > 384
+    assert span["segments"] == 0 and span["instrs"] > 192
     u_whole, r_whole = np.asarray(prog.u), np.asarray(prog.r)
 
-    monkeypatch.setattr(common, "max_program_instrs", 384)
+    monkeypatch.setattr(common, "max_program_instrs", 192)
     before = diagnostics.counters()
     (cut,) = prog.solve()
     span = diagnostics.last_flushes()[-1]
@@ -165,9 +165,9 @@ def test_a_long_solve_runs_segmented_and_equals_the_unsegmented_one(
 
 
 def test_every_iteration_runs_the_same_executables():
-    """Twenty iterations linearize to twenty repetitions: cut at the same
+    """Forty iterations linearize to forty repetitions: cut at the same
     places of each, all but the first share their segments."""
-    prog = program(8, 20)
+    prog = program(8, 40)
     before = diagnostics.counters()
     prog.solve()
     span = diagnostics.last_flushes()[-1]
@@ -187,7 +187,7 @@ def test_a_program_is_cut_once(monkeypatch):
                         lambda p, size: cuts.append(len(p.instrs))
                         or real(p, size))
     fuser._segments_cache.clear()
-    prog = program(8, 20)
+    prog = program(8, 40)
     first = prog.solve()
     span = diagnostics.last_flushes()[-1]
     assert span["segments"] >= 4 and cuts == [span["instrs"]]
@@ -212,6 +212,14 @@ def _chain(n_ops, prefix=0, suffix=0):
     return program
 
 
+def _reps(fit, count):
+    """Repetitions to a segment: the largest divisor of the count that is
+    at least half of what fits, else what fits."""
+    fit = min(fit, count)
+    return max((k for k in range(1, fit + 1)
+                if count % k == 0 and 2 * k >= fit), default=fit)
+
+
 def test_segment_ends_follow_the_loop():
     p = _chain(400, prefix=7, suffix=3)
     start, body, count = fuser._repetition(p)  # as rewritten: 4 or 5
@@ -220,10 +228,13 @@ def test_segment_ends_follow_the_loop():
     assert ends[-1] == len(p.instrs) and ends == sorted(set(ends))
     sizes = np.diff([0] + ends)
     assert sizes.max() <= 384
-    # whole iterations to a segment, as many as fit
+    # whole iterations to a segment: as many as fit, or a divisor of the
+    # count that is at least half of that
     inside = [s for e, s in zip(ends, sizes)
               if start < e <= start + body * count and s > 100]
-    assert inside and all(s == 384 // body * body for s in inside[1:-1])
+    assert inside and all(s == _reps(384 // body, count) * body
+                          for s in inside[1:-1])
+    assert 2 * inside[1] >= 384 // body * body
     # a loop longer than a segment is cut into equal parts
     ends = fuser._segment_ends(_chain(400), 3)
     assert set(np.diff(ends[2:-2])) <= {2, 3}
@@ -234,6 +245,57 @@ def test_segment_ends_follow_the_loop():
     plain, _l, _ = fuser._prepare_program([x.read_expr()])
     n = len(plain.instrs)
     assert fuser._segment_ends(plain, 8) == list(range(8, n, 8)) + [n]
+
+
+@pytest.mark.parametrize("n_ops,fit", [(38, 3), (39, 3), (40, 3), (42, 3),
+                                       (47, 3), (98, 10), (102, 10)])
+def test_a_remainder_makes_no_program_of_its_own(n_ops, fit):
+    """``fit`` repetitions fit a segment.  Where a number from half of
+    that up divides the count, the loop is cut so many at a time; where
+    none does (a prime count), ``fit`` at a time, and the repetitions left
+    over are cut with what stands after the loop.  Either way every
+    segment inside the loop is the same program and the calls at most
+    double (``mg-C``: 19 repetitions of 294 instructions, two to a
+    segment, left a fourth executable to trace, lower and compile)."""
+    p = _chain(n_ops, suffix=2)
+    start, body, count = fuser._repetition(p)
+    ends = fuser._segment_ends(p, fit * body)
+    reps = _reps(fit, count)
+    whole = start + body * reps * (count // reps)
+    inside = [e for e in ends if start < e <= whole]
+    assert set(np.diff([start] + inside)) == {reps * body}
+    assert inside[-1] == whole and len(inside) <= 2 * -(-count // fit)
+    # the rest, left-over repetitions and all, every ``fit * body``
+    rest = [e for e in ends if e > whole]
+    assert rest == (list(range(whole + fit * body, len(p.instrs), fit * body))
+                    + [len(p.instrs)])
+    segments = fuser._iter_segments(p, fuser._last_use_map(p), fit * body)
+    in_loop = {seg.key for (seg, _in, _out, top), e in zip(segments, ends)
+               if start < e <= whole}
+    assert len(in_loop) <= 2  # the first may read leaves, the rest carry
+
+
+@pytest.mark.parametrize("start,period,count,tail,size,calls", [
+    (9, 294, 19, 295, 768, [9] + [294] * 19 + [295]),       # mg-C, PR 35
+    (9, 668, 19, 680, 768, [9] + [668] * 19 + [680]),       # mg-C, PR 34
+    (9, 294, 20, 1, 768, [9] + [588] * 10 + [1]),
+    (0, 10, 97, 5, 768, [760, 215]),      # a prime count: what fits
+    (0, 10, 96, 0, 768, [480, 480]),      # 48 divides, 76 fit
+    (3, 100, 20, 0, 768, [3] + [500] * 4),
+    (0, 100, 23, 900, 768, [700] * 3 + [768, 332]),
+    (5, 300, 3, 800, 1000, [5, 900, 800]),  # fewer repetitions than fit
+], ids=["mg-C", "mg-C-parent", "even", "prime", "divisor", "five-of-seven",
+        "left-over-and-tail", "short-loop"])
+def test_the_cut_by_its_numbers(start, period, count, tail, size, calls,
+                                monkeypatch):
+    """``_segment_ends`` from a loop's place, period and count alone."""
+    import types
+
+    n = start + period * count + tail
+    monkeypatch.setattr(fuser, "_repetition",
+                        lambda p: (start, period, count))
+    ends = fuser._segment_ends(types.SimpleNamespace(instrs=[None] * n), size)
+    assert list(np.diff([0] + ends)) == calls
 
 
 def test_admission_estimates_a_segmented_program_by_its_segments(
@@ -323,3 +385,91 @@ def test_the_finest_level_takes_the_kernel_and_the_counters_say_so(
                 for k in notes)
     host = np.asarray(prog.r)
     np.testing.assert_array_equal(host, nas_mg.comm3(host.copy()))
+
+
+# -- a ghost-layer refresh is one node ----------------------------------------
+def refreshes(lt, nit):
+    """``comm3`` calls of one solve: per iteration ``rprj3`` at lt - 1
+    levels, ``psinv`` at the coarsest, ``interp``, ``resid``, ``psinv`` at
+    the lt - 1 above it, and the closing ``resid``; the first ``resid``."""
+    return (4 * lt - 2) * nit + 1
+
+
+def test_mg_cs_refreshes_by_the_scripts_count():
+    assert refreshes(9, 20) == 681 and 6 * refreshes(9, 20) == 4086
+    # the walk wherever a plane has a whole row tile: 514^3 down to 10^3,
+    # not 6^3 (four an iteration) and 4^3 (two)
+    assert (4 * 20 + 1) + 6 * 4 * 20 == 561 and 681 - 561 == 6 * 20
+    # 12 of a refresh's 13,381 - 681 x 11 instructions are one
+    assert 13381 - 11 * refreshes(9, 20) == 5890
+
+
+@pytest.fixture
+def one_device():
+    import jax
+    from jax.sharding import Mesh
+
+    from ramba_tpu.parallel import mesh as mesh_mod
+
+    if jax.process_count() > 1:
+        pytest.skip("installs a local mesh")
+    fuser.flush()
+    old = mesh_mod.get_mesh()
+    mesh_mod.set_mesh(Mesh(np.array(jax.devices()[:1]), ("d0",)))
+    try:
+        yield
+    finally:
+        fuser.flush()
+        mesh_mod.set_mesh(old)
+
+
+@pytest.mark.parametrize("where", ["mesh", "walk"])
+@pytest.mark.parametrize("segment_at", [0, 128], ids=["whole", "segmented"])
+def test_the_refresh_as_one_node_changes_no_bit(segment_at, where,
+                                                monkeypatch, request):
+    """A toy solve with ``rewrite_face_copies`` against the same solve
+    with the rewriter off: the norm and both arrays to the last bit, on
+    the flush that traces and the one that hits, whole and in segments;
+    the two counters read what the script's count says.  ``walk``: one
+    device, the kernel interpreting wherever an array has a whole row
+    tile: the toy's 18^3 and 10^3, not its 6^3 and 4^3."""
+    n, nit, lt = 16, 3, 4
+    total, walks = refreshes(lt, nit), (4 * nit + 1) + 4 * nit
+    if where == "walk":
+        request.getfixturevalue("one_device")
+        request.getfixturevalue("interpreting_walk")
+    monkeypatch.setattr(common, "max_program_instrs", segment_at)
+    monkeypatch.setattr(common, "rewrite_enabled", False)
+    prog = program(n, nit)
+    before = diagnostics.counters()
+    (plain,) = prog.solve()
+    u, r = np.asarray(prog.u), np.asarray(prog.r)
+    instrs = diagnostics.last_flushes()[-1]["instrs"]
+    assert not moved(before, "faces.path.dus")
+    assert not moved(before, "faces.path.wrap")
+
+    monkeypatch.setattr(common, "rewrite_enabled", True)
+    prog = program(n, nit)
+    want = {"faces.path.wrap": walks if where == "walk" else 0,
+            "rewrite.rewrite_face_copies": 6 * total}
+    want["faces.path.dus"] = total - want["faces.path.wrap"]
+    for cache in ("miss", "hit"):
+        before = diagnostics.counters()
+        (norm,) = prog.solve()
+        span = diagnostics.last_flushes()[-1]
+        assert span["cache"] == cache and span.get("degraded") is None
+        assert (span["segments"] > 0) == bool(segment_at)
+        assert span["instrs"] == instrs - 11 * total
+        assert {k: moved(before, k) for k in want} == want, cache
+        assert norm == plain
+        np.testing.assert_array_equal(np.asarray(prog.u), u)
+        np.testing.assert_array_equal(np.asarray(prog.r), r)
+        if cache == "miss":
+            notes = [k for k in span["kernels"] if k["kernel"] == "faces"]
+            assert len(notes) == total
+            walked = [k for k in notes if k["path"] == "wrap"]
+            assert len(walked) == want["faces.path.wrap"] and all(
+                k["interpret"] and k["grid"] in (-(-18 // k["block_planes"]),
+                                                 -(-10 // k["block_planes"]))
+                and k["row_block_planes"] % k["block_planes"] == 0
+                for k in walked)

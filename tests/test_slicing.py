@@ -242,3 +242,360 @@ def test_a_strided_write_reads_back_through_the_public_api():
     np.testing.assert_array_equal(np.asarray(x), a)
     np.testing.assert_array_equal(np.asarray(x[::3, 1::4, ::5]),
                                   a[::3, 1::4, ::5])
+
+
+# -- whole faces copied onto faces of the same array: one node, one pass ------
+from ramba_tpu import common, diagnostics  # noqa: E402
+from ramba_tpu.core import rewrite  # noqa: E402
+from ramba_tpu.core.expr import Node  # noqa: E402
+
+
+def _faces(expr):
+    """The ``remap_faces`` nodes under ``expr`` and every other op."""
+    seen, nodes, others, stack = set(), [], [], [expr]
+    while stack:
+        e = stack.pop()
+        if id(e) in seen or not isinstance(e, Node):
+            continue
+        seen.add(id(e))
+        (nodes if e.op == "remap_faces" else others).append(e)
+        stack.extend(e.args)
+    return nodes, sorted(e.op for e in others)
+
+
+def _moved(before, name):
+    return diagnostics.counters().get(name, 0) - before.get(name, 0)
+
+
+def folded(script):
+    """The expression a script's copies leave, folded where it wrote them
+    (``ndarray.__setitem__``), as the flush's rules then leave it."""
+    expr = script().read_expr()
+    (root,) = rewrite.rewrite_roots([expr])
+    assert root is expr
+    return root
+
+
+def test_comm3_is_six_firings_and_one_node():
+    from benchmark.programs import nas_mg
+
+    m = 8
+    a = rt.fromarray(field(np.float32, (m + 2,) * 3))
+    fired = rewrite.stats["rewrite_face_copies"]
+    before = diagnostics.counters()
+    root = folded(lambda: nas_mg.comm3(a * 2.0))
+    assert rewrite.stats["rewrite_face_copies"] - fired == 6
+    assert _moved(before, "rewrite.rewrite_face_copies") == 6
+    assert root.op == "remap_faces" and root.args[0].op == "map"
+    assert root.static == ((((0, m), (m + 1, 1)),) * 3,)
+    assert root.aval.shape == (m + 2,) * 3
+    # no getitem and no setitem was ever built
+    assert _faces(root) == ([root], ["map"])
+
+
+def _copies(a, chain):
+    """``a[.., d, ..] = a[.., s, ..]`` for each (axis, d, s) of ``chain``,
+    on a NumPy or a ramba array."""
+    for ax, d, s in chain:
+        dst, src = [S(None)] * a.ndim, [S(None)] * a.ndim
+        dst[ax], src[ax] = d, s
+        a[tuple(dst)] = a[tuple(src)]
+    return a
+
+
+CHAINS = {
+    "there-and-back": [(0, 0, 1), (0, 1, 0)],
+    "a-swap-that-is-none": [(1, 0, 5), (1, 5, 0)],
+    "a-shift-down": [(0, 0, 1), (0, 1, 2), (0, 2, 3)],
+    "a-shift-up": [(2, 3, 2), (2, 2, 1), (2, 1, 0)],
+    "written-twice": [(1, 4, 0), (1, 4, 7), (1, 0, 4)],
+    "axes-interleaved": [(0, 0, 6), (2, 0, 9), (0, 7, 0), (1, 9, 1),
+                         (2, 10, 0), (0, 3, 7)],
+    "negative": [(0, -1, -2), (1, 0, -1), (2, -1, 1)],
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int32", "int16", "uint8"])
+@pytest.mark.parametrize("chain", sorted(CHAINS))
+def test_copies_on_one_axis_compose_in_order(chain, dtype):
+    a = field(jnp.dtype(dtype))
+    x = rt.fromarray(a) + 0
+    before = diagnostics.counters()
+    nodes, others = _faces(folded(lambda: _copies(x, CHAINS[chain])))
+    assert len(nodes) == 1 and others == ["map"]
+    assert _moved(before, "rewrite.rewrite_face_copies") == len(CHAINS[chain])
+    assert same_bits(x, _copies(a.copy(), CHAINS[chain]))
+    assert _moved(before, "faces.path.dus") == 1
+
+
+def test_a_length_one_slice_is_a_face_and_joins_the_integers_node():
+    a = field(np.float32)
+    x = rt.fromarray(a) + 0
+    x[:, 0:1] = x[:, 8:9]
+    x[:, 9] = x[:, 1]
+    a[:, 0:1] = a[:, 8:9]
+    a[:, 9] = a[:, 1]
+    root = folded(lambda: x)
+    assert root.op == "remap_faces"
+    assert root.static == (((), ((0, 8), (9, 1)), ()),)
+    assert same_bits(x, a)
+
+
+def _partial(x, y):
+    x[0, 1:] = x[1, 1:]
+
+
+def _broadcast(x, y):
+    x[:, 0] = x[:, 1, 0:1]
+
+
+def _cast(x, y):
+    x[0] = x[1].astype(np.float64)
+
+
+def _another_array(x, y):
+    x[0] = y[1]
+
+
+def _another_value(x, y):
+    x[2] = (x + 0)[3]
+
+
+def _strided(x, y):
+    x[::2] = x[1::2]
+
+
+def _negative_step(x, y):
+    x[0:1] = x[2:0:-1][0:1]
+
+
+def _onto_itself(x, y):
+    x[..., 3] = x[..., 3]
+
+
+def _slice_from_integer(x, y):
+    x[0:1] = x[1]
+
+
+def _across_axes(x, y):
+    x[1] = x[:, 1]  # a square array
+
+
+def _two_hyperplanes(x, y):
+    x[0:2] = x[2:4]
+
+
+def _new_axis(x, y):
+    x[None, 0] = x[None, 1]
+
+
+def _a_view_of_a_view(x, y):
+    x[1:-1][0] = x[1:-1][1]  # written through a view: another base
+
+
+NOT_FACE_COPIES = [_partial, _broadcast, _cast, _another_array,
+                   _another_value, _strided, _negative_step, _onto_itself,
+                   _slice_from_integer, _across_axes, _two_hyperplanes,
+                   _new_axis, _a_view_of_a_view]
+
+
+@pytest.mark.parametrize("write", NOT_FACE_COPIES,
+                         ids=lambda f: f.__name__.strip("_"))
+def test_anything_else_stays_a_setitem(write):
+    a = field(np.float32, (10, 10, 10))
+    b = field(np.float32, (10, 10, 10))[::-1].copy()
+    x, y = rt.fromarray(a), rt.fromarray(b)
+    fired = rewrite.stats["rewrite_face_copies"]
+    before = diagnostics.counters()
+    write(x, y)
+    nodes, others = _faces(folded(lambda: x))
+    assert not nodes and "setitem" in others
+    assert rewrite.stats["rewrite_face_copies"] == fired
+    write(a, b)
+    assert same_bits(x, a)
+    assert not _moved(before, "faces.path.dus")
+    assert not _moved(before, "rewrite.rewrite_face_copies")
+
+
+def test_with_the_rewriter_off_the_copies_are_the_writes_they_were(
+        monkeypatch):
+    from benchmark.programs import nas_mg
+
+    a = field(np.float32, (10, 10, 10))
+    monkeypatch.setattr(common, "rewrite_enabled", False)
+    before = diagnostics.counters()
+    got = np.asarray(nas_mg.comm3(rt.fromarray(a) * 1.0))
+    assert not _moved(before, "faces.path.dus")
+    assert same_bits(got, nas_mg.comm3(a.copy()))
+
+
+def _halo(shape, kind):
+    """The copies of a boundary refresh, axis by axis: ``periodic`` (NPB's
+    ``comm3``), ``reflecting``, or ``wide`` (two periodic layers a
+    side)."""
+    chain = []
+    for ax, n in enumerate(shape):
+        if kind == "periodic":
+            chain += [(ax, 0, n - 2), (ax, n - 1, 1)]
+        elif kind == "reflecting":
+            chain += [(ax, 0, 1), (ax, -1, -2)]
+        else:
+            chain += [(ax, 0, n - 4), (ax, 1, n - 3), (ax, n - 2, 2),
+                      (ax, n - 1, 3)]
+    return chain
+
+
+RANKS = {1: (12,), 2: (9, 11), 3: (9, 10, 11), 4: (6, 7, 8, 9)}
+
+
+@pytest.mark.parametrize("where", ["mesh", "one-device"])
+@pytest.mark.parametrize("dtype", ["float32", "int32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["periodic", "reflecting", "wide"])
+@pytest.mark.parametrize("rank", sorted(RANKS))
+def test_a_boundary_refresh_is_numpys_bit_for_bit(rank, kind, dtype, where,
+                                                  request):
+    if where == "one-device":
+        if _MULTIPROC:
+            pytest.skip("installs a local mesh")
+        request.getfixturevalue("one_device")
+    a = field(jnp.dtype(dtype), RANKS[rank])
+    chain = _halo(a.shape, kind)
+    before = diagnostics.counters()
+    got = _copies(rt.fromarray(a), chain)
+    assert same_bits(got, _copies(a.copy(), chain))
+    # small, so the writes one by one: the HLO they had
+    assert _moved(before, "faces.path.dus") == 1
+    assert not _moved(before, "faces.path.wrap")
+    assert _moved(before, "rewrite.rewrite_face_copies") == len(chain)
+
+
+def test_the_lowering_is_the_parents_hlo_off_the_kernel():
+    """On the suite's mesh, and on one device under the threshold, the
+    node lowers to the six ``dynamic_update_slice`` it replaced."""
+    x = jnp.zeros((10, 10, 10), jnp.float32)
+    maps = (((0, 8), (9, 1)),) * 3
+
+    def parent(v):
+        for ax in range(3):
+            for d, s in maps[ax]:
+                i = (S(None),) * ax
+                v = slicing.put(v, i + (d,), slicing.take(v, i + (s,)))
+        return v
+
+    assert (str(jax.make_jaxpr(lambda v: slicing.remap(v, maps))(x))
+            == str(jax.make_jaxpr(parent)(x)))
+
+
+WRAP_CASES = {
+    # shape, kind: ragged last tiles on both axes wherever the side is 2^k + 2
+    "cube-18-periodic": ((18, 18, 18), "periodic"),
+    "cube-34-periodic": ((34, 34, 34), "periodic"),
+    "lanes-130-periodic": ((5, 18, 130), "periodic"),
+    "lanes-258-periodic": ((3, 10, 258), "periodic"),
+    "lanes-258-reflecting": ((3, 10, 258), "reflecting"),
+    "lanes-130-wide": ((8, 18, 130), "wide"),
+    "cube-34-reflecting": ((34, 34, 34), "reflecting"),
+    "aligned": ((4, 16, 256), "periodic"),
+    "rows-across-tiles": ((8, 23, 140), "wide"),
+}
+
+
+@pytest.fixture
+def walking(one_device, interpreting_walk, monkeypatch):
+    """The in-place walk interpreting (``conftest.interpreting_walk``), on
+    one device, with Pallas on whatever the environment says."""
+    from ramba_tpu.ops import stencil_pallas
+
+    monkeypatch.setattr(stencil_pallas, "_ENABLED", True)
+
+
+@pytest.mark.skipif(_MULTIPROC, reason="installs a local mesh")
+@pytest.mark.parametrize("dtype", ["float32", "int32"])
+@pytest.mark.parametrize("case", sorted(WRAP_CASES))
+def test_the_walk_interpreting_is_numpys_bit_for_bit(case, dtype, walking):
+    from ramba_tpu.observe import registry
+
+    shape, kind = WRAP_CASES[case]
+    a = field(jnp.dtype(dtype), shape)
+    chain = _halo(shape, kind)
+    maps = tuple(tuple((d % n, s % n) for ax, d, s in chain if ax == k)
+                 for k, n in enumerate(shape))
+    with registry.collect_kernel_notes() as notes:
+        got = jax.jit(lambda v: slicing.remap(v, maps))(jnp.asarray(a))
+    (note,) = notes
+    assert (note["kernel"], note["path"]) == ("faces", "wrap")
+    assert note["interpret"]
+    assert note["grid"] == -(-shape[0] // note["block_planes"])
+    assert same_bits(got, _copies(a.copy(), chain))
+
+
+@pytest.mark.skipif(_MULTIPROC, reason="installs a local mesh")
+@pytest.mark.parametrize("planes", [1, 2, 5])
+@pytest.mark.parametrize("case", ["cube-18-periodic", "lanes-258-reflecting",
+                                  "rows-across-tiles", "lanes-130-wide"])
+def test_stale_vmem_never_reaches_the_walks_result(case, planes,
+                                                   interpreting_walk):
+    """The TPU interpreter with every buffer NaN to begin with and reads
+    out of bounds refused, blocks that divide the planes and blocks that
+    do not: nothing a pipeline did not fetch is ever selected into a cell
+    of the array, and what is not visited stays as it lay."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ramba_tpu.ops import faces_pallas
+
+    shape, kind = WRAP_CASES[case]
+    a = np.random.default_rng(5).standard_normal(shape).astype(np.float32)
+    chain = _halo(shape, kind)
+    rows, lanes = (sorted((d % shape[k], s % shape[k])
+                          for ax, d, s in chain if ax == k) for k in (1, 2))
+    got = np.asarray(faces_pallas._wrap_jit(
+        tuple(rows), tuple(lanes),
+        pltpu.InterpretParams(uninitialized_memory="nan"),
+        *faces_pallas._sized(shape, planes))(jnp.asarray(a)))
+    want = _copies(a.copy(), [c for c in chain if c[0]])
+    assert not np.isnan(got).any() and same_bits(got, want)
+
+
+@pytest.mark.skipif(_MULTIPROC, reason="installs a local mesh")
+def test_what_the_walk_takes_and_what_stays_six_writes(walking, monkeypatch):
+    from ramba_tpu.ops import faces_pallas
+
+    periodic = (((0, 8), (9, 1)),) * 3
+
+    def path(x, maps):
+        from ramba_tpu.observe import registry
+
+        with registry.collect_kernel_notes() as notes:
+            jax.eval_shape(lambda v: slicing.remap(v, maps), x)
+        return notes[0]["path"]
+
+    cube = jax.ShapeDtypeStruct((10, 10, 10), jnp.float32)
+    assert path(cube, periodic) == "wrap"
+    assert path(jax.ShapeDtypeStruct((10, 10, 10), jnp.int32),
+                periodic) == "wrap"
+    # planes alone are XLA's in place; a source that is written is a chain
+    assert path(cube, (((0, 8),), (), ())) == "dus"
+    assert path(cube, ((), ((0, 1), (1, 2)), ())) == "dus"
+    assert path(cube, ((), ((0, 1), (1, 0)), ())) == "wrap"  # composed: 1 <- 1
+    for other in (jax.ShapeDtypeStruct((10, 10, 10), jnp.bfloat16),
+                  jax.ShapeDtypeStruct((10, 10, 10), jnp.complex64),
+                  jax.ShapeDtypeStruct((10, 10), jnp.float32),
+                  jax.ShapeDtypeStruct((4, 10, 10, 10), jnp.float32),
+                  jax.ShapeDtypeStruct((10, 4, 10), jnp.float32)):
+        maps = (((0, 2), (3, 1)),) * len(other.shape)
+        assert path(other, maps) == "dus"
+    # off the chip the kernel is offered by the suite's switch alone
+    monkeypatch.setattr(faces_pallas, "_INTERPRET", False)
+    assert path(cube, periodic) == "dus"
+
+
+def test_on_the_mesh_the_walk_is_never_taken(monkeypatch):
+    if mesh_mod.get_mesh().devices.size == 1:
+        pytest.skip("needs the suite's mesh")
+    from ramba_tpu.ops import faces_pallas
+
+    monkeypatch.setattr(faces_pallas, "_INTERPRET", True)
+    x = jnp.zeros((10, 10, 10), jnp.float32)
+    assert faces_pallas.available(x.shape, x.dtype)
+    composed = [slicing._composed(p) for p in (((0, 8), (9, 1)),) * 3]
+    assert not slicing._faces_through_kernel(x, composed)

@@ -4,7 +4,8 @@ fact about the chip's compiler that ``ramba_tpu/core/layouts.py`` answers:
 left alone it lays a (time, 721, 1440) cube out with TIME minor, so a walk
 along time costs a copy of the cube; and a strided index of a long row as
 ``ramba_tpu/core/slicing.py`` lowers it; and the rank-3 stencil kernel at
-``mg-C``'s two finest levels, as Mosaic takes it.  Nothing here runs on a
+``mg-C``'s two finest levels, as Mosaic takes it, and a ghost-layer
+refresh there, in place.  Nothing here runs on a
 TPU and nothing printed is a time.  The only tier-1 file that loads the TPU's
 compiler: keep such tests here."""
 
@@ -191,3 +192,47 @@ def test_the_rank_3_kernel_compiles_at_the_cells_sizes(one_chip, n, weights):
     assert text.count("tpu_custom_call") == 1
     assert " fusion(" not in text and " pad(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+# -- a ghost-layer refresh in place -------------------------------------------
+@pytest.mark.parametrize("n", [514, 258, 130, 66, 34, 18, 10])
+def test_a_ghost_layer_refresh_compiles_in_place(one_chip, n, monkeypatch):
+    """NPB's ``comm3`` of a (2^k + 2)^3 array as ``slicing.remap`` lowers
+    it for one chip, at every level of ``mg-C``'s pyramid that has a whole
+    row tile: the custom call and the two plane writes, the operand
+    aliased to the result, no temporary; as six writes XLA holds a lane
+    face in (8, 128) tiles, one float in a row of 128."""
+    from jax._src.pallas.mosaic import pipeline
+
+    from ramba_tpu.observe import registry
+    from ramba_tpu.ops import faces_pallas, stencil_pallas
+
+    m = n - 2
+    maps = (((0, m), (m + 1, 1)),) * 3
+    x = jax.ShapeDtypeStruct((n, n, n), jnp.float32, sharding=one_chip)
+    tiled = n * -(-n // 8) * 8 * -(-n // 128) * 128 * 4
+
+    def compiled():
+        with registry.collect_kernel_notes() as notes, jax.enable_x64(False):
+            c = jax.jit(lambda a: slicing.remap(a, maps),
+                        donate_argnums=0).lower(x).compile()
+        return c, notes
+
+    parent, (note,) = compiled()
+    assert note["path"] == "dus"  # off the chip the kernel is not offered
+    face = n * -(-n // 8) * 8 * 128 * 4  # f32[n, n, 1] as tiled: 137 MB
+    assert n < 514 or parent.memory_analysis().temp_size_in_bytes >= face
+    assert n < 258 or parent.cost_analysis()["bytes accessed"] > 1.4 * tiled
+    # the chip's answers: there is no chip to ask here
+    monkeypatch.setattr(faces_pallas, "available", lambda *a: True)
+    monkeypatch.setattr(faces_pallas, "interpreting", lambda: False)
+    monkeypatch.setattr(pipeline, "_get_tpu_generation", lambda: 5)
+    walk, (note,) = compiled()
+    assert note["path"] == "wrap" and not note["interpret"]
+    assert note["vmem_limit_bytes"] <= stencil_pallas._vmem_cap()
+    ma = walk.memory_analysis()
+    assert ma.temp_size_in_bytes < 8 << 20
+    assert ma.alias_size_in_bytes == ma.argument_size_in_bytes == tiled
+    text = walk.as_text()
+    assert text.count("tpu_custom_call") == 1 and " copy(" not in text
+    assert f"f32[{n},{n},1]" not in text
