@@ -457,13 +457,16 @@ def _random_f32(rt, shape):
 def phase_groupby(rt, days, grid, groups=366, interpret_ok=False):
     """The Xarray day-of-year pattern over a small cube on the live
     mesh, against NumPy: the mean and the max of every day of the year
-    (the sorted chunked walk: on several devices each walks its own rows
-    and the partials are combined, which is where the scatter it replaced
-    miscompiled), and the RMS of the anomalies (one device: the walk
-    again, nothing stored; several: a take and a reduce, GSPMD's).  The
-    cube is a flush's result on a ragged grid, so on one chip it lies
+    (the sorted chunked walk: on several devices each walks its own block
+    inside ``shard_map`` and the partials are combined, which is where the
+    scatter it replaced miscompiled), and the RMS of the anomalies (the
+    walk again, nothing stored, on one device and on several).  The cube
+    is a flush's result on a ragged grid, so on one chip it lies
     row-major where the compiler would have put time last
-    (``core/layouts.py``).  Then the two corners of the walk no cell
+    (``core/layouts.py``); with ``days`` that the devices do not divide
+    (2922 on four) the default layout is the split that does divide, time
+    2 x longitude 2, and every device still holds its share.  Then the
+    two corners of the walk no cell
     stands on: twelve groups of thousands of narrow rows (each chunk one
     gather), and the minimum on the eager rung, where every op meets the
     pinned cube without a jit around it."""
@@ -486,6 +489,7 @@ def phase_groupby(rt, days, grid, groups=366, interpret_ok=False):
             return clim, g.max(), rms
 
         (clim, top, rms), first = _timed(run)
+        _require_sharded(rt, clim, "groupby climatology")
         got, got_top = _host(clim), _host(top)
         (_, _, again), second = _timed(run)
         by_month = _host(narrow.groupby(0, months, 12).sum())
@@ -522,10 +526,12 @@ def phase_groupby(rt, days, grid, groups=366, interpret_ok=False):
              f"rms {rms!r}, {again!r}, NumPy {want_rms!r}")
     paths = sorted(k[len("segment.path."):] for k, v in rec.counters.items()
                    if k.startswith("segment.path.") and v > 0)
-    _require("walk_reduce" in paths, f"segment paths {paths}")
+    _require(paths == ["walk_broadcast", "walk_reduce"],
+             f"segment paths {paths}")
     return {"flushes": len(rec.flushes), "rungs": rec.rungs(),
             "segment_paths": paths, "fetches": fetches, "max_abs_err": err,
             "rms": rms, "first_s": first, "second_s": second,
+            "spec": str(x._value().sharding.spec),
             "layout": str(getattr(x._value(), "format", None))}
 
 
@@ -959,6 +965,9 @@ def main() -> int:
                                     "peak_growth_bytes")),
         ("distributed", lambda: phase_distributed(rt)),
         ("groupby 366 days", lambda: phase_groupby(rt, 2928, (60, 380))),
+        # eight years whose days four chips do not divide: the layout that
+        # does divide, the partial sums combined across chips
+        ("groupby 2922 days", lambda: phase_groupby(rt, 2922, (60, 380))),
         ("chain+reductions", lambda: phase_chain(rt, 1_000_000_000)),
         ("stencil 8192^2", lambda: phase_stencil(
             rt, 8192, expected_stencil_paths(8192, ndev))),

@@ -703,7 +703,7 @@ def _device_put_default(x):
 
         _timing.note_transfer("host_to_device", x.nbytes)
     try:
-        return put_sharded(x, _mesh.default_sharding(x.shape))
+        return put_sharded(x, _mesh.upload_sharding(x.shape))
     except Exception:
         return jnp.asarray(x)
 

@@ -32,19 +32,34 @@ XLA fuses into one pass:
 
 No scatter anywhere (GSPMD miscompiled scatter-based segment reductions on
 sharded layouts, rounds 3 to 5) and nothing is left to GSPMD either: under
-a mesh the walk runs inside ``shard_map`` over each device's local rows
-and labels, and the per-device partials are combined across the devices
-that share the segment axis (psum/pmin/pmax).  ``K`` is chosen from what
-the code can observe (rows per group, the slab's bytes: ``_chunk_rows``,
-whose three constants were swept on the chip).
+a mesh both passes run inside ``shard_map`` over each device's block of the
+operand as the default layout cuts it (``_blocks``), with the labels of
+the block's own rows.  ``segment_reduce`` combines the per-device partials
+across the devices that share the segment axis (psum/pmin/pmax: every
+device then holds its block's own columns of the result, of which the
+default layout of a kept result is a slice, ``mesh._dividing_spec``); a
+mean's partial sums are divided by the group's size over all of them in
+the loop, once a group, so nothing follows the combination.
+``segment_mapreduce`` takes the per-group operands as the slabs of the
+block's own columns (the first pass's result as it stands) and combines
+its scalar over the mesh.
+One device is the mesh of one: the same walk, nothing to combine.  ``K`` is
+chosen from what the code can observe (rows per group, the slab's bytes:
+``_chunk_rows``, whose three constants were swept on the chip).
 
 The walk fetches slabs ``x[t]``, so it wants the segment axis slowest on
 the device.  XLA:TPU lays out arguments and results to pad least (a
 ``(T, 721, 1440)`` cube gets TIME minor); ``core/layouts.py`` keeps the
-flush's results of rank three or more row-major on one device, so the
-cube arrives as the walk wants it.  Where an operand arrives otherwise
-(under a mesh; an array this system did not make) XLA puts one transposed
-copy of it before the loops (PERF.md section 6, PR 30).
+flush's results of rank three or more row-major, on one device and on
+every device of a mesh (where the flush also places them: the default
+layout, ``mesh.held_spec``), so the block arrives as the walk wants it
+and ``segment_reduce``'s result leaves the flush in the default layout
+of its own shape.  Where an operand arrives otherwise (an array this
+system did not make) XLA puts one
+transposed copy of it before the loops (PERF.md section 6, PR 30).  A
+split that does not divide its extent is not used: the operand is whole
+along that dimension inside ``shard_map``, gathered on entry (the default
+layout leaves no such split where one that divides exists).
 """
 
 from __future__ import annotations
@@ -80,6 +95,7 @@ _COMB = {"sum": jnp.add, "prod": jnp.multiply,
 _RED = {"sum": jnp.sum, "prod": jnp.prod, "min": jnp.min, "max": jnp.max}
 _PCOMB = {"sum": lax.psum, "min": lax.pmin, "max": lax.pmax,
           "prod": lambda a, axes: jnp.prod(lax.all_gather(a, axes), axis=0)}
+_PNAME = {"sum": "psum", "min": "pmin", "max": "pmax", "prod": "all_gather"}
 
 
 def _reduce_identity(op, dtype):
@@ -170,20 +186,53 @@ def _fetch(x, rows, valid, dim):
     return [(jnp.take(x, rows, axis=dim), valid)]
 
 
-def _note(path, num_groups, n, k, sharded):
+def _note(path, num_groups, n, k, how, nbytes):
+    """The kernel note of a pass: what the walk chose, and under a mesh
+    (``how``: ``_blocks``' entries, the segment dimension, the
+    combination) how the operand is split and what a device hands to the
+    combination across chips."""
+    split = combine = None
+    if how is not None:
+        ents, dim, combine = how
+        split = {"segment": list(ents[dim]),
+                 "others": [a for d, e in enumerate(ents) if d != dim
+                            for a in e]}
     _registry.note_kernel(
         "segment", path, groups=num_groups, chunk_rows=k,
         chunks=n // k + num_groups,
-        fetch="slices" if k <= _UNROLL else "gather", sharded=bool(sharded))
+        fetch="slices" if k <= _UNROLL else "gather", sharded=how is not None,
+        split=split, local_rows=n, combine=combine or "none",
+        combine_bytes=nbytes if combine else 0)
 
 
-def _local_reduce(x, labels, num_groups, dim, op, pres, mean, sharded=False):
+def _blocks(shape):
+    """How a pass cuts an operand of ``shape``: ``(mesh, entries)``, the
+    mesh axes along each dimension in the operand's default layout, those
+    that divide their extent; ``entries`` is None on one device and for an
+    array too small to distribute, where the walk runs as it stands."""
+    from ramba_tpu.ops.stencil_sharded import _axis_entries
+
+    mesh = _mesh.get_mesh()
+    if mesh.devices.size == 1 or math.prod(shape) < common.dist_threshold:
+        return mesh, None
+    return mesh, [e if s % math.prod(mesh.shape[a] for a in e) == 0 else ()
+                  for e, s in zip(_axis_entries(mesh, shape), shape)]
+
+
+def _spec(entries):
+    return P(*((e[0] if len(e) == 1 else tuple(e)) if e else None
+               for e in entries))
+
+
+def _local_reduce(x, labels, num_groups, dim, op, pres, mean, how=None):
     """The walk over one device's rows: one accumulator per entry of
     ``pres``, folding ``pre(row)`` with ``op``.  With ``mean`` (and
     accumulators of an inexact type) each group's slab is divided by the
-    group's size at its last chunk, in the loop.  Returns the
-    accumulators (``dim`` of size ``num_groups``), the group sizes, and
-    whether the division was made."""
+    group's size at its last chunk, in the loop: the size over the WHOLE
+    segment axis (the sizes summed over ``how``'s devices along it), so
+    that the devices' slabs add up to the mean and no pass over the sums
+    follows their combination.  Returns the accumulators (``dim`` of size
+    ``num_groups``), the group sizes, and whether the division was made."""
     n, nd = x.shape[dim], x.ndim
     shape = x.shape[:dim] + (num_groups,) + x.shape[dim + 1:]
     row = jax.ShapeDtypeStruct((1,), x.dtype)
@@ -196,8 +245,12 @@ def _local_reduce(x, labels, num_groups, dim, op, pres, mean, sharded=False):
         return accs, jnp.zeros((num_groups,), jnp.int32), False
     k = _chunk_rows(n, num_groups,
                     math.prod(shape) // num_groups * x.dtype.itemsize)
-    _note("walk_reduce", num_groups, n, k, sharded)
+    _note("walk_reduce", num_groups, n, k, how,
+          sum(math.prod(shape) * jnp.dtype(dt).itemsize for dt in dtypes))
     t = _walk(labels, num_groups, k)
+    counts = t["counts"]
+    if how is not None and how[0][dim]:
+        counts = lax.psum(counts, how[0][dim])
 
     def body(c, accs):
         g = t["group"][c]
@@ -214,58 +267,41 @@ def _local_reduce(x, labels, num_groups, dim, op, pres, mean, sharded=False):
                 cur = _COMB[op](cur, v)
             if mean:
                 cur = jnp.where(t["last"][c],
-                                cur / t["counts"][g].astype(cur.dtype), cur)
+                                cur / counts[g].astype(cur.dtype), cur)
             out.append(lax.dynamic_update_slice_in_dim(acc, cur, g, axis=dim))
         return tuple(out)
 
-    return lax.fori_loop(0, t["total"], body, accs), t["counts"], mean
+    return lax.fori_loop(0, t["total"], body, accs), counts, mean
 
 
 def _segment_accumulate(x, labels, num_groups, dim, op, pres, mean):
     """``_local_reduce`` over the whole operand: as it stands on one
-    device; under a mesh inside ``shard_map`` over each device's rows and
-    labels (the operand's default layout, padded to divide), the
-    partials combined across the devices that share the segment axis
-    (whose groups only the caller can then divide by their sizes)."""
-    from ramba_tpu.ops.stencil_sharded import _axis_entries
-
-    mesh = _mesh.get_mesh()
-    ents = (_axis_entries(mesh, x.shape)
-            if mesh.devices.size > 1
-            and math.prod(x.shape) >= common.dist_threshold else [])
-    if not any(ents):
+    device; under a mesh inside ``shard_map`` over each device's block and
+    the labels of its rows, the partials combined across the devices that
+    share the segment axis and held by every one of them: the block's own
+    columns of the result, which a second pass takes as they are and of
+    which the default layout of a kept result is a device's own slice."""
+    mesh, ents = _blocks(x.shape)
+    if ents is None:
         return _local_reduce(x, labels, num_groups, dim, op, pres, mean)
-    shape = x.shape
-    split = [math.prod(mesh.shape[a] for a in e) if e else 1 for e in ents]
-    padded = tuple(-(-s // k) * k for s, k in zip(shape, split))
-    if padded != shape:
-        # rows past the end carry a label no group has
-        labels = jnp.pad(labels, (0, padded[dim] - shape[dim]),
-                         constant_values=num_groups)
-        x = jnp.pad(x, tuple((0, p - s) for p, s in zip(padded, shape)))
     seg = ents[dim]
+    combine = _PNAME[op] if seg else None
     divided = []
 
     def local(xb, lb):
         accs, counts, done = _local_reduce(
-            xb, lb, num_groups, dim, op, pres, mean and not seg, sharded=True)
+            xb, lb, num_groups, dim, op, pres, mean,
+            how=(ents, dim, combine))
         divided.append(done)
         if seg:
             accs = tuple(_PCOMB[op](a, seg) for a in accs)
-            counts = lax.psum(counts, seg)
         return accs, counts
 
-    def spec(entries):
-        return P(*((e[0] if len(e) == 1 else tuple(e)) if e else None
-                   for e in entries))
-
     accs, counts = jax.shard_map(
-        local, mesh=mesh, in_specs=(spec(ents), spec([seg])),
-        out_specs=((spec(ents[:dim] + [()] + ents[dim + 1:]),) * len(pres),
+        local, mesh=mesh, in_specs=(_spec(ents), _spec([seg])),
+        out_specs=((_spec(ents[:dim] + [()] + ents[dim + 1:]),) * len(pres),
                    P()), check_vma=False)(x, labels)
-    keep = tuple(slice(0, num_groups if d == dim else s)
-                 for d, s in enumerate(shape))
-    return tuple(a[keep] for a in accs), counts, divided[-1]
+    return accs, counts, divided[-1]
 
 
 # what a pass folds of a row: the row, its square, and their forms that
@@ -330,16 +366,10 @@ def _op_segment_reduce(static, x, labels):
     return out
 
 
-@defop("segment_mapreduce")
-def _op_segment_mapreduce(static, labels, *leaves):
-    """A full reduction of an elementwise expression whose operands are
-    arrays of one shape (``full``), per-group arrays broadcast to it by
-    label along ``dim`` (``group``) and scalars: the walk of
-    ``segment_reduce``, each chunk's rows evaluated against their
-    group's slab and reduced to one value.  Nothing of the operands' size
-    is stored.  ``instrs`` is the expression, post-order: ``(fname,
-    refs)`` with a ref ``("a", i)`` for leaf ``i`` or ``("t", j)`` for
-    instruction ``j``; the last one is what is reduced."""
+def _local_mapreduce(static, labels, leaves, how=None):
+    """``segment_mapreduce`` over one device's rows: the walk, each
+    chunk's rows evaluated against their group's slab and reduced to one
+    value (a sum, for ``mean``: the caller divides)."""
     kind, dim, num_groups, roles, instrs = static
     op = "sum" if kind == "mean" else kind
     full = [i for i, r in enumerate(roles) if r == "full"]
@@ -347,7 +377,6 @@ def _op_segment_mapreduce(static, labels, *leaves):
     n, nd = first.shape[dim], first.ndim
     k = _chunk_rows(n, num_groups, sum(
         leaves[i].size // max(n, 1) * leaves[i].dtype.itemsize for i in full))
-    _note("walk_broadcast", num_groups, n, k, False)
     t = _walk(labels, num_groups, k, clip=True)  # as ``take`` in mode clip
     others = tuple(a for a in range(nd) if a != dim)
 
@@ -372,8 +401,45 @@ def _op_segment_mapreduce(static, labels, *leaves):
         return total
 
     dt = jax.eval_shape(chunk, jax.ShapeDtypeStruct((), jnp.int32)).dtype
-    acc = lax.fori_loop(0, t["total"], lambda c, a: _COMB[op](a, chunk(c)),
-                        _reduce_identity(op, dt))
+    _note("walk_broadcast", num_groups, n, k, how, jnp.dtype(dt).itemsize)
+    return lax.fori_loop(0, t["total"], lambda c, a: _COMB[op](a, chunk(c)),
+                         _reduce_identity(op, dt))
+
+
+@defop("segment_mapreduce")
+def _op_segment_mapreduce(static, labels, *leaves):
+    """A full reduction of an elementwise expression whose operands are
+    arrays of one shape (``full``), per-group arrays broadcast to it by
+    label along ``dim`` (``group``) and scalars: the walk of
+    ``segment_reduce``, each chunk's rows evaluated against their
+    group's slab and reduced to one value.  Nothing of the operands' size
+    is stored.  ``instrs`` is the expression, post-order: ``(fname,
+    refs)`` with a ref ``("a", i)`` for leaf ``i`` or ``("t", j)`` for
+    instruction ``j``; the last one is what is reduced.  Under a mesh the
+    walk runs in ``shard_map``: the ``full`` operands as each device's
+    blocks, the ``group`` operands as the slabs of the block's own
+    columns, the value combined over every axis that splits the blocks;
+    ``mean`` divides by the size of the whole."""
+    kind, dim, _, roles, _ = static
+    op = "sum" if kind == "mean" else kind
+    first = leaves[roles.index("full")]
+    mesh, ents = _blocks(first.shape)
+    if ents is None:
+        acc = _local_mapreduce(static, labels, leaves)
+    else:
+        axes = tuple(a for e in ents for a in e)
+        rest = ents[:dim] + [()] + ents[dim + 1:]
+        how = (ents, dim, _PNAME[op] if axes else None)
+
+        def local(lb, *blocks):
+            acc = _local_mapreduce(static, lb, blocks, how)
+            return _PCOMB[op](acc, axes) if axes else acc
+
+        acc = jax.shard_map(
+            local, mesh=mesh, out_specs=P(), check_vma=False,
+            in_specs=(_spec([ents[dim]]),) + tuple(
+                {"full": _spec(ents), "group": _spec(rest),
+                 "scalar": P()}[r] for r in roles))(labels, *leaves)
     return acc / float(first.size) if kind == "mean" else acc
 
 
@@ -385,16 +451,14 @@ def fuse_broadcast_reduce(node: Node):
     the groups; a take that wraps or fills is left alone) ->
     ``segment_mapreduce``.  None where it does not apply: the take then
     materializes its operand, which is correct and, for an operand a
-    device can hold twice, all there is to say.  One device only: under a
-    mesh the take and the reduce are GSPMD's."""
+    device can hold twice, all there is to say."""
     kind, axis, keepdims, ddof = node.static
     body = node.args[0]
     shape = tuple(body.aval.shape)
     if (kind not in ("sum", "mean", "min", "max") or keepdims
             or ddof not in (None, 0) or not shape
             or (axis is not None and tuple(axis) != tuple(range(len(shape))))
-            or not jnp.issubdtype(body.aval.dtype, jnp.inexact)
-            or _mesh.get_mesh().devices.size != 1):
+            or not jnp.issubdtype(body.aval.dtype, jnp.inexact)):
         return None
     leaves, roles, instrs, ref = [], [], [], {}
     labels = dim = None

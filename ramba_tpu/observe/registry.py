@@ -110,17 +110,20 @@ _kernel_notes = threading.local()
 
 
 def _count_kernel(kernel: str, path: str, interpret: bool,
-                  operand_copy: int = 0) -> None:
+                  operand_copy: int = 0, combine_bytes: int = 0) -> None:
     inc(f"{kernel}.path.{path}")
     if interpret:
         inc(f"{kernel}.interpret")
     if operand_copy:
         inc(f"{kernel}.operand_copy", operand_copy)
+    if combine_bytes:
+        inc(f"{kernel}.combine_bytes", combine_bytes)
 
 
 def note_kernel(kernel: str, path: str, interpret: bool = False, *,
                 block_rows=None, grid=None, vmem_limit_bytes=None,
-                halo=None, operand_copy: int = 0, **chose) -> None:
+                halo=None, operand_copy: int = 0, combine_bytes: int = 0,
+                **chose) -> None:
     """Record that ``kernel`` (e.g. ``"stencil"``) lowered through
     ``path`` (``sharded`` / ``pallas_fast`` / ``pallas_padded`` / ``xla``
     / a Pallas family name), and whether a Pallas kernel on that path
@@ -132,17 +135,21 @@ def note_kernel(kernel: str, path: str, interpret: bool = False, *,
     kept on the note, not counted.  ``operand_copy`` is how many of its
     operands reach the kernel through an array-sized copy XLA makes:
     counted as ``<kernel>.operand_copy`` and kept on the note where it is
-    not 0, so that a replay counts it again.  Any further keyword is a
+    not 0, so that a replay counts it again.  ``combine_bytes`` is what a
+    device hands to the kernel's combination across chips (the segment
+    walk's partial sums): counted as ``<kernel>.combine_bytes`` and kept
+    on the note likewise.  Any further keyword is a
     plain value a lowering chose for itself (the segment walk's ``groups``,
-    ``chunk_rows``, ``chunks``, ``fetch``, ``sharded``): kept on the note
-    as given."""
-    _count_kernel(kernel, path, interpret, operand_copy)
+    ``chunk_rows``, ``chunks``, ``fetch``, ``sharded``, ``split``,
+    ``local_rows``, ``combine``): kept on the note as given."""
+    _count_kernel(kernel, path, interpret, operand_copy, combine_bytes)
     notes = getattr(_kernel_notes, "active", None)
     if notes is not None:
         note = {"kernel": kernel, "path": path, "interpret": bool(interpret)}
         sized = {"block_rows": block_rows, "grid": grid,
                  "vmem_limit_bytes": vmem_limit_bytes,
-                 "operand_copy": operand_copy or None}
+                 "operand_copy": operand_copy or None,
+                 "combine_bytes": combine_bytes or None}
         note.update((k, int(v)) for k, v in sized.items() if v is not None)
         if halo is not None:
             note["halo"] = halo
@@ -156,7 +163,8 @@ def replay_kernel_notes(notes) -> None:
     program that traced nothing: counters only, never a new note."""
     for note in notes:
         _count_kernel(note["kernel"], note["path"], note["interpret"],
-                      note.get("operand_copy", 0))
+                      note.get("operand_copy", 0),
+                      note.get("combine_bytes", 0))
 
 
 @contextlib.contextmanager

@@ -100,6 +100,26 @@ def balanced_factors(n: int, k: int) -> tuple:
     return tuple(sorted(factors, reverse=True))
 
 
+def _schedules(shape: tuple, n: int):
+    """Every assignment of ``n``'s prime factors to the dimensions of
+    ``shape`` as ``(cost, splits)``, in enumeration order: the cost is the
+    total cut surface, (splits - 1) cuts a dimension, each of area
+    prod(shape) / shape[d].  Splits never exceed the dimension size."""
+    ndim = len(shape)
+    primes = prime_factors(n)
+    total = math.prod(shape) if shape else 1
+    # n is small (the worker count, typically <= a few thousand; primes
+    # are few)
+    for assignment in itertools.product(range(ndim), repeat=len(primes)):
+        splits = [1] * ndim
+        for p, d in zip(primes, assignment):
+            splits[d] *= p
+        if any(s > max(1, shape[d]) for d, s in enumerate(splits)):
+            continue
+        yield sum((s - 1) * (total / shape[d])
+                  for d, s in enumerate(splits) if shape[d] > 0), tuple(splits)
+
+
 @lru_cache(maxsize=4096)
 def compute_regular_schedule(shape: tuple, n: int) -> tuple:
     """Choose per-dimension splits of ``n`` workers over ``shape`` minimizing
@@ -109,32 +129,13 @@ def compute_regular_schedule(shape: tuple, n: int) -> tuple:
     (/root/reference/ramba/common.py:287-680, modes ratio/surface/nodesurface):
     rather than materializing per-worker index ranges, the output here is just
     the split count per dimension; the actual layout is delegated to
-    NamedSharding.  Splits never exceed the dimension size.
+    NamedSharding.
     """
     ndim = len(shape)
     if ndim == 0 or n <= 1:
         return (1,) * ndim
-    best = None
-    best_cost = math.inf
-    primes = prime_factors(n)
-    # Enumerate assignments of prime factors to dimensions (n is small: the
-    # worker count, typically <= a few thousand; primes are few).
-    for assignment in itertools.product(range(ndim), repeat=len(primes)):
-        splits = [1] * ndim
-        for p, d in zip(primes, assignment):
-            splits[d] *= p
-        if any(s > max(1, shape[d]) for d, s in enumerate(splits)):
-            continue
-        # Cost = total cut surface: for each dim, (splits-1) cuts, each of area
-        # prod(shape)/shape[d].
-        total = math.prod(shape) if shape else 1
-        cost = sum(
-            (s - 1) * (total / shape[d]) for d, s in enumerate(splits) if shape[d] > 0
-        )
-        if cost < best_cost:
-            best_cost = cost
-            best = tuple(splits)
-    return best if best is not None else (1,) * ndim
+    best = min(_schedules(shape, n), key=lambda cs: cs[0], default=None)
+    return best[1] if best is not None else (1,) * ndim
 
 
 def _spec_parallelism(spec: P, mesh: Mesh) -> int:
@@ -147,6 +148,77 @@ def _spec_parallelism(spec: P, mesh: Mesh) -> int:
     return total
 
 
+def _holds(spec: P, shape: tuple, mesh: Mesh) -> bool:
+    """Whether every split of ``spec`` divides the extent it splits: jax
+    holds no other array."""
+    return all(e is None or shape[d] % _spec_parallelism(P(e), mesh) == 0
+               for d, e in enumerate(spec))
+
+
+def _natural_spec(shape: tuple, mesh: Mesh) -> P:
+    """The solver's choice realized on the mesh's axes; where the mesh's
+    factorization cannot realize it at full parallelism, the greedy
+    largest-dim assignment."""
+    n = mesh.devices.size
+    solved = spec_from_splits(compute_regular_schedule(shape, n), mesh)
+    if _spec_parallelism(solved, mesh) == n:
+        return solved
+    greedy = _greedy_spec(shape, mesh)
+    if _spec_parallelism(greedy, mesh) > _spec_parallelism(solved, mesh):
+        return greedy
+    return solved
+
+
+def _dividing_spec(shape: tuple, mesh: Mesh) -> Optional[P]:
+    """The least-surface split at full parallelism whose counts divide
+    the extents they split and that the mesh's axes realize, or None.
+    The axes are handed out from the last split dimension to the first,
+    so that the layout of a reduction's result refines its operand's: of
+    (T, H, W) over time x lon on 'd1' x 'd0', summed along time on every
+    device, the default layout of (G, H, W), lon on ('d0', 'd1'), is each
+    device's own slice (``groupby.py``)."""
+    n = mesh.devices.size
+    for _, splits in sorted(_schedules(shape, n), key=lambda cs: cs[0]):
+        if any(shape[d] % s for d, s in enumerate(splits)):
+            continue
+        entries = list(spec_from_splits(splits[::-1], mesh))
+        entries += [None] * (len(shape) - len(entries))
+        spec = P(*entries[::-1])
+        if _spec_parallelism(spec, mesh) == n:
+            return spec
+    return None
+
+
+@lru_cache(maxsize=4096)
+def _layout(shape: tuple, mesh: Mesh) -> P:
+    """The default layout of an array of ``shape`` large enough to
+    distribute: the solver's choice, or where that does not divide the
+    extents it splits, the split that does."""
+    natural = _natural_spec(shape, mesh)
+    if mesh.devices.size == 1 or _holds(natural, shape, mesh):
+        return natural
+    dividing = _dividing_spec(shape, mesh)
+    return natural if dividing is None else dividing
+
+
+def _distributed(shape: tuple) -> bool:
+    return len(shape) > 0 and math.prod(shape) >= common.dist_threshold
+
+
+def held_spec(shape: Sequence[int],
+              mesh: Optional[Mesh] = None) -> Optional[P]:
+    """``default_spec`` of an array large enough to distribute, where
+    every split divides the extent it splits, so that jax can hold the
+    array so; None for every other shape.  It is where a flush puts such
+    a result (``core/layouts.py``) and an upload such a host array."""
+    shape = tuple(int(s) for s in shape)
+    mesh = mesh or get_mesh()
+    if not _distributed(shape):
+        return None
+    spec = _layout(shape, mesh)
+    return spec if _holds(spec, shape, mesh) else None
+
+
 def default_spec(shape: Sequence[int], mesh: Optional[Mesh] = None) -> P:
     """Pick a PartitionSpec for a new array of ``shape``.
 
@@ -156,20 +228,15 @@ def default_spec(shape: Sequence[int], mesh: Optional[Mesh] = None) -> P:
     (the reference's compute_regular_schedule, common.py:287-680) and the
     splits are realized on mesh axes; when the mesh's factorization cannot
     realize the solver's choice at full parallelism, fall back to the
-    greedy largest-dim assignment.
+    greedy largest-dim assignment.  Where that choice does not divide the
+    extents it splits (10,958 days four ways), the least-surface split at
+    full parallelism that does divide is the layout (``_dividing_spec``);
+    where none divides, the choice stands as it is.
     """
-    mesh = mesh or get_mesh()
     shape = tuple(int(s) for s in shape)
-    if len(shape) == 0 or math.prod(shape) < common.dist_threshold:
+    if not _distributed(shape):
         return P()
-    n = mesh.devices.size
-    solved = spec_from_splits(compute_regular_schedule(shape, n), mesh)
-    if _spec_parallelism(solved, mesh) == n:
-        return solved
-    greedy = _greedy_spec(shape, mesh)
-    if _spec_parallelism(greedy, mesh) > _spec_parallelism(solved, mesh):
-        return greedy
-    return solved
+    return _layout(shape, mesh or get_mesh())
 
 
 def _greedy_spec(shape: tuple, mesh: Mesh) -> P:
@@ -254,8 +321,15 @@ def spec_from_splits(splits: Sequence[int], mesh: Optional[Mesh] = None) -> P:
     return P(*entries)
 
 
-def default_sharding(shape: Sequence[int]) -> NamedSharding:
-    return NamedSharding(get_mesh(), default_spec(shape))
+def upload_sharding(shape: Sequence[int]) -> NamedSharding:
+    """Where a host array of ``shape`` goes: its default layout where jax
+    can hold it; where no split divides (10,958 labels over four
+    devices), whole on every device of the mesh, never on one of them
+    alone: a leaf on one device cannot be lowered beside results pinned
+    to the mesh (``core/layouts.py``; PERF.md section 6, PR 36)."""
+    mesh = get_mesh()
+    spec = held_spec(shape, mesh)
+    return NamedSharding(mesh, P() if spec is None else spec)
 
 
 def replicated_sharding() -> NamedSharding:

@@ -422,6 +422,10 @@ class CompilePipeline:
             if not group:
                 continue
             self._dispatch_group(group)
+            # an idle worker holds nothing: left in this frame, the last
+            # group's tickets keep their work's leaf buffers and results
+            # alive (and counted as live bytes) until the next pop returns
+            del group
 
 
 _pipeline: Optional[CompilePipeline] = None

@@ -107,6 +107,20 @@ else:
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_dispatch_worker_outlives_its_file():
+    """A serving dispatch worker ends with the test file that started it.
+    What it is still running then (a warm-up compile nobody waited for)
+    would finish, and release its buffers, in the middle of the next
+    file's tests: ``tests/test_memory.py`` reads the ledger's live bytes
+    to the byte (PR 36: 256 of them died under it, once in four runs)."""
+    yield
+    from ramba_tpu.serve import pipeline
+
+    if pipeline.current_pipeline() is not None:
+        pipeline.shutdown()
+
+
 @pytest.fixture
 def interpreting_walk(monkeypatch):
     """``ops/faces_pallas.py``'s in-place walk off the chip.  The

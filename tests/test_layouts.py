@@ -162,3 +162,39 @@ def test_a_pinned_array_runs_on_the_eager_rung(one_device, monkeypatch):
     assert diagnostics.last_flushes(1)[0].get("degraded") == "eager"
     want = np.stack([x[labels == g].max(0) for g in range(3)])
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("shape,row_major", [
+    ((1462, 8, 128), True),    # whole tiles: the sharding and the layout
+    ((1462, 16, 64), False),   # 64 lanes of 128: the sharding alone
+    ((64, 128, 256), True),    # a shape the mesh divides: the same rule
+])
+def test_under_a_mesh_a_distributed_result_gets_the_default_layout(
+        shape, row_major):
+    """Several devices: the flush knows where a result of rank three
+    large enough to distribute goes (``mesh.held_spec``: the default
+    layout, whether the solver's own split divides the extents or another
+    had to), so it asks for that sharding and, with padding under an
+    eighth, for the row-major layout with it; rank two is left alone, and
+    so is a shape no split divides."""
+    if len(jax.devices()) == 1:
+        pytest.skip("tier-1's mesh has eight devices")
+    mesh = rmesh.get_mesh()
+    want = rmesh.held_spec(shape, mesh)
+    assert want == rmesh.default_spec(shape, mesh)
+    assert rmesh.held_spec((7, 11, 13), mesh) is None  # nothing divides
+    flat = layouts.RowMajorJit(lambda a: (a + 1.0,))
+    assert flat._jit_for((jnp.zeros((512, 384)),)) is flat._plain
+    fn = layouts.RowMajorJit(lambda a: (a + 1.0, a[:2, :2] * 2.0))
+    x = jnp.zeros(shape, jnp.float32)
+    # only a pinned LAYOUT keeps a program out of the caches
+    assert fn.pins(x) is row_major
+    assert layouts.pins([jax.ShapeDtypeStruct(shape, x.dtype)]) is row_major
+    assert fn._jit_for((x,)) is not fn._plain
+    out, small = fn(x)
+    assert out.sharding.spec == want
+    assert all(s.data.size * mesh.devices.size == out.size
+               for s in out.addressable_shards)
+    if row_major:
+        assert out.format.layout.major_to_minor == (0, 1, 2)
+    assert float(out.sum()) == out.size and float(small.sum()) == 0.0

@@ -214,6 +214,40 @@ def test_session_async_flush_ticket():
 
 
 @spmd_skip
+def test_an_idle_dispatch_worker_holds_nothing_of_its_last_flush():
+    """Once a ticket is resolved and its owner has dropped the arrays, the
+    ledger is back where it was: the worker's frame does not keep the
+    last group (tickets, their work, its leaf buffers and results) while
+    it waits for the next one.  It did, for the half second of its poll,
+    and bytes of one test died in the middle of another that was reading
+    the ledger (tests/test_memory.py, PR 36)."""
+    import gc
+    import time
+
+    from ramba_tpu.resilience import memory
+
+    rt.sync()
+    gc.collect()
+    base = memory.ledger.live_bytes
+    with serve.Session(tenant="idle") as s:
+        a = rt.fromarray(np.ones(64, np.float32))
+        b = a + 1.0
+        t = s.flush()
+        assert t.wait(timeout=60) == []
+        assert memory.ledger.live_bytes == base + 512
+        del a, b, t
+    del s
+    # the ticket and its work are a cycle; the worker needs a moment to
+    # return from the dispatch, far less than its poll of 0.5 s
+    until = time.monotonic() + 0.3
+    gc.collect()
+    while memory.ledger.live_bytes != base and time.monotonic() < until:
+        time.sleep(0.01)
+        gc.collect()
+    assert memory.ledger.live_bytes == base
+
+
+@spmd_skip
 def test_empty_flush_returns_finished_ticket():
     with serve.Session(tenant="empty") as s:
         t = s.flush()

@@ -329,8 +329,9 @@ class TestStripsKernel:
         assert sizes and max(sizes) <= n * n // 4 // 8, sizes
 
     def test_uneven_split_counts_its_copy(self, on_mesh):
-        """A shape the mesh does not divide is padded whole before the
-        shard_map: the one array-sized copy left, and it is counted."""
+        """A shape no split of the mesh divides is padded whole before the
+        shard_map: the one array-sized copy left, and it is counted (where
+        one split divides, the default layout is that split: no copy)."""
         from ramba_tpu.observe import registry
 
         on_mesh("2x2")
@@ -348,7 +349,9 @@ class TestStripsKernel:
         before = registry.get("stencil.operand_copy")
         assert copies((400, 600)) == 0
         assert registry.get("stencil.operand_copy") == before
-        assert copies((401, 600)) == 1
+        assert copies((401, 600)) == 0  # columns four ways
+        assert registry.get("stencil.operand_copy") == before
+        assert copies((401, 601)) == 1
         assert registry.get("stencil.operand_copy") == before + 1
 
 

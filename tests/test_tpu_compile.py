@@ -86,13 +86,19 @@ def test_which_results_stay_row_major(shape, dtype, keep):
         jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))) is keep
 
 
-def test_several_devices_leave_the_layout_to_the_compiler():
+def test_several_devices_leave_ranks_one_and_two_to_the_compiler():
+    """Under a mesh a result of rank three is the system's to place and
+    to lay out (PR 36), rank two GSPMD's and the compiler's as before."""
     if len(jax.devices()) == 1:
         pytest.skip("tier-1's mesh has eight devices")
     fn = layouts.RowMajorJit(lambda a: (a + 1.0,))
+    flat = jnp.zeros((512, 256), jnp.float32)
+    assert fn._jit_for((flat,)) is fn._plain
     x = jnp.zeros((4, 128, 256), jnp.float32)
-    assert fn._jit_for((x,)) is fn._plain
-    assert float(fn(x)[0].sum()) == 4 * 128 * 256
+    assert fn.pins(x)
+    out = fn(x)[0]
+    assert out.sharding.spec == rmesh.default_spec(x.shape)
+    assert float(out.sum()) == 4 * 128 * 256
 
 
 def test_left_alone_the_compiler_puts_time_last_and_copies_the_cube(one_chip):
@@ -135,6 +141,65 @@ def test_the_flush_at_the_cells_size_stores_nothing_of_the_cube(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
     assert 14.6e9 < total(compiled) < WATERMARK
     assert compiled.as_text().count(" while(") == 2
+
+
+@pytest.fixture
+def four_chips(topo):
+    """The program's mesh held to the described 2 x 2 host."""
+    before = rmesh.get_mesh()
+    rmesh.set_mesh(Mesh(np.array(topo.devices).reshape(2, 2), ("d0", "d1")))
+    yield rmesh.get_mesh()
+    rmesh.set_mesh(before)
+
+
+def test_the_thirty_year_flush_on_four_chips_fits_and_copies_nothing(
+        four_chips):
+    """One solve of ``doy-clim-30y-x4``: the cube of 10,958 days in the
+    default layout (time 2 x longitude 2, row-major on every device),
+    both walks inside ``shard_map``: under the watermark as compiled, the
+    climatology out in ITS default layout and row-major, ONE data-sized
+    collective (the all-reduce of a device's partial sums, 0.76 GB), and
+    no copy, transpose or pad of anything the size of a slab of the
+    climatology, let alone of the cube."""
+    import re
+
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    days = 10958
+    spec = rmesh.default_spec((days,) + GRID)
+    assert spec == PartitionSpec("d1", None, "d0")
+    cube = jax.ShapeDtypeStruct(
+        (days,) + GRID, jnp.float32, sharding=Format(
+            Layout(major_to_minor=(0, 1, 2)), NamedSharding(four_chips, spec)))
+    labels = jax.ShapeDtypeStruct(
+        (days,), jnp.int32, sharding=NamedSharding(four_chips,
+                                                   PartitionSpec()))
+    fn = layouts.RowMajorJit(flush)
+    assert fn.pins(cube, labels)
+    c = fn.lower(cube, labels).compile()
+    clim = c.output_formats[0]
+    assert clim.layout.major_to_minor == (0, 1, 2)
+    assert clim.sharding.spec == rmesh.default_spec((G,) + GRID)
+    assert 14.0e9 < total(c) < WATERMARK
+    text = c.as_text()
+    assert text.count(" while(") == 2
+    big = 4 * GRID[0] * GRID[1] // 4  # a quarter of one day's slab
+    moved, reduced = [], []
+    for m in re.finditer(r"= (\w+)\[([\d,]*)\]\S* "
+                         r"(copy|transpose|pad|all-reduce|all-gather|"
+                         r"reduce-scatter|all-to-all|collective-permute)"
+                         r"(?:-start)?\(", text):
+        dtype, dims, op = m.groups()
+        nbytes = int(np.prod([int(d) for d in dims.split(",") if d] or [1])
+                     ) * np.dtype({"f32": "float32", "s32": "int32",
+                                   "u32": "uint32", "pred": "bool"}.get(
+                                       dtype, "float32")).itemsize
+        if nbytes >= big:
+            (reduced if op.startswith(("all", "reduce", "coll"))
+             else moved).append((op, dims))
+    assert moved == []
+    assert reduced == [("all-reduce", "366,721,720")]
 
 
 @pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
