@@ -292,6 +292,15 @@ def expected_face_walks(n, iters, ndev):
                if 2 ** k + 2 >= 8)
 
 
+def expected_prolong_path(n, ndev):
+    """The path a prolongation onto an n^3 float32 array takes: on one
+    device the kernel (``ops/prolong_pallas.py``) from ``MIN_EXTENT`` up,
+    else the five writes through XLA."""
+    from ramba_tpu.ops import prolong_pallas
+
+    return "pallas" if ndev == 1 and n >= prolong_pallas.MIN_EXTENT else "xla"
+
+
 # ---------------------------------------------------------------------------
 # phases: plain functions of a size; return a dict of facts, raise on failure
 # ---------------------------------------------------------------------------
@@ -740,10 +749,10 @@ def phase_prk_scalars(rt, n, iters, expect, interpret_ok=False):
 def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
     """NAS MG's four operators over the pyramid of an n^3 grid
     (``benchmark/programs/nas_mg.py``: ``iters`` V-cycles and the
-    residual, twice over): a flush too long for one program (a V-cycle at
-    512^3 is 294 instructions, a program at most 768) runs as chained
-    segments on
-    the fused rung, the second time from the executables of the first.
+    residual, twice over): ONE flush on the fused rung, the second time a
+    hit; one too long for one program (a V-cycle at 512^3 is 110
+    instructions, a program at most 768) runs as chained segments, the
+    second time from the executables of the first.
     ``want`` is the norm to meet; the NumPy reference gives it where it is
     not given (toy sizes: at 512^3 it takes minutes)."""
     from benchmark.programs import nas_mg
@@ -768,7 +777,8 @@ def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
         _require(out1 == out2, f"two solves read {out1} and {out2}")
         calls = r2.counters.get("fuser.segments", 0)
         hits = r2.counters.get("fuser.segment.hit", 0)
-        _require(calls >= 2 and hits == calls
+        long = r2.flushes[0]["instrs"] > rt.common.max_program_instrs
+        _require(calls == hits and (calls >= 2) == long
                  and not r2.counters.get("fuser.segment.miss", 0),
                  f"second solve: {calls} segment calls, {hits} hits")
         _require([f["cache"] for f in r2.flushes] == ["hit"],
@@ -800,6 +810,54 @@ def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
             "segment_hits_second": hits,
             "segment_misses_first": r1.counters.get("fuser.segment.miss", 0),
             "first_s": first, "second_s": second}
+
+
+def phase_prolong(rt, n, expect, interpret_ok=False):
+    """NAS MG's ``interp`` onto a fresh n^3 float32 array
+    (``benchmark/programs/nas_mg.py`` ``prolong``): five writes folded into
+    ONE node (``rewrite.rewrite_prolong`` four times) on the path
+    ``expect`` names, counted once by a flush that hits (one that compiles
+    also counts admission's lowering), and every bit the five writes' as
+    the fold-off script makes them over the same device array."""
+    from benchmark.programs import nas_mg
+
+    with Recorder(rt) as rec:
+        z = _random_f32(rt, (n // 2 + 1,) * 3)
+
+        def interp():
+            f = nas_mg.prolong(z, rt.zeros((n,) * 3, dtype=numpy.float32))
+            rt.sync()
+            return f
+
+        with Recorder(rt) as r1:
+            f, first = _timed(interp)
+        folded = r1.counters.get("rewrite.rewrite_prolong", 0)
+        paths = tuple(dict.fromkeys(r1.kernel_paths("prolong")))
+        _require(folded == 4 and paths == (expect,),
+                 f"prolong {n}^3: {folded} writes folded, took {paths}, "
+                 f"want 4 and ({expect!r},)")
+        del f
+        with Recorder(rt) as r2:
+            f, second = _timed(interp)
+        counted = r2.counters.get(f"prolong.path.{expect}", 0)
+        caches = [fl["cache"] for fl in r2.flushes]
+        _require(counted == 1 and caches == ["hit"],
+                 f"prolong {n}^3 again: prolong.path.{expect} moved by "
+                 f"{counted}, flushes {caches}")
+        rt.common.rewrite_enabled = False
+        try:
+            plain = interp()
+        finally:
+            rt.common.rewrite_enabled = True
+        got, want = _host(f), _host(plain)
+        _require(numpy.array_equal(got.view(numpy.uint32),
+                                   want.view(numpy.uint32)),
+                 f"prolong {n}^3: {int((got != want).sum())} elements "
+                 f"differ from the five writes")
+        del z, f, plain
+    rec.require_clean(interpret_ok=interpret_ok)
+    return {"n": n, "path": expect, "rungs": rec.rungs(), "first_s": first,
+            "second_s": second}
 
 
 def phase_axpy(rt, n_total, interpret_ok=False):
@@ -984,6 +1042,11 @@ def main() -> int:
         # 35); two are one program since a refresh is one instruction
         ("mg 512^3", lambda: phase_mg(rt, 512, 8.398651024955054e-05,
                                       iters=3)),
+        # a prolongation over the kernel's bound and one under it
+        ("prolong 258^3", lambda: phase_prolong(
+            rt, 258, expected_prolong_path(258, ndev))),
+        ("prolong 10^3", lambda: phase_prolong(
+            rt, 10, expected_prolong_path(10, ndev))),
         ("axpy 1e9", lambda: phase_axpy(rt, 1_000_000_000)),
         ("broadcast 32768^2", lambda: phase_broadcast(rt, 32768)),
         ("stencil 30000^2", lambda: phase_stencil(

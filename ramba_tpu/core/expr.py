@@ -547,6 +547,19 @@ def _op_remap_faces(static, x):
     return slicing.remap(x, maps)
 
 
+@defop("prolong")
+def _op_prolong(static, z):
+    """The trilinear prolongation of ``z`` onto zeros
+    (``rewrite.fold_prolong``): ``f[0::2, 0::2, 0::2] = z[:-1, :-1, :-1]``,
+    then ``f[1:-1] = f[1:-1] + 0.5 * (f[2:] + f[:-2])`` along the first
+    ``axes`` axes in turn."""
+    from ramba_tpu.core import slicing
+
+    axes, spec = static
+    zeros = jnp.zeros(tuple(2 * n - 2 for n in z.shape), z.dtype)
+    return slicing.prolong(z, axes, _constrain(zeros, spec))
+
+
 @defop("getitem_adv")
 def _op_getitem_adv(static, x, *indexers):
     """Fancy-index gather.  The reference builds an all2all owner-lookup gather

@@ -967,16 +967,20 @@ def _segment_ends(program: _Program, seg_size: int) -> list:
     """Where the count-bounded segments of ``program`` end.  A program
     that repeats is cut at the same places of every repetition, so that
     one executable serves every repetition: whole repetitions to a segment
-    where several fit, else a repetition in equal parts.  Of the numbers
-    that fit, the largest that divides the loop's count is taken if it is
-    at least half of what fits, so that the calls at most double; where
-    none is, as many as fit, and the repetitions left over are cut with
-    what stands after the loop.  Either way a remainder makes no program
-    of its own to trace, lower and compile (19 repetitions of 294
-    instructions, two to a segment of 768: a fourth executable and 10 s
-    of a 48 s set-up; PERF.md section 6, PR 35).  What stands before and
-    after the loop, and a program with no loop, is cut every ``seg_size``
-    instructions."""
+    where a repetition is short, else a repetition in equal parts.  A
+    segment takes the fewest repetitions that reach an eighth of
+    ``seg_size``: packing more saves a call's fixed cost and costs compile
+    time that grows with the executable (six repetitions of 110
+    instructions to a call: 13 s more set-up for 12 ms a solve of
+    dispatch; PERF.md section 6, PR 37).  Of the numbers
+    up to that, the largest that divides the loop's count is taken if it
+    is at least half of it, so that the calls at most double; where none
+    is, that many, and the repetitions left over are cut with what stands
+    after the loop.  Either way a remainder makes no program of its own to
+    trace, lower and compile (19 repetitions of 294 instructions, two to a
+    segment of 768: a fourth executable and 10 s of a 48 s set-up; PERF.md
+    section 6, PR 35).  What stands before and after the loop, and a
+    program with no loop, is cut every ``seg_size`` instructions."""
     ninstr = len(program.instrs)
     loop = _repetition(program) if ninstr > seg_size else None
     if loop is None:
@@ -986,9 +990,10 @@ def _segment_ends(program: _Program, seg_size: int) -> list:
     if start:
         ends.append(start)
     if period <= seg_size:
-        fit = min(seg_size // period, count)
-        reps = max((k for k in range(-(-fit // 2), fit + 1)
-                    if count % k == 0), default=fit)
+        want = min(max(1, -(-(seg_size // 8) // period)), seg_size // period,
+                   count)
+        reps = max((k for k in range(-(-want // 2), want + 1)
+                    if count % k == 0), default=want)
         last = start + period * reps * (count // reps)
         ends += list(range(start + period * reps, last + 1, period * reps))
     else:

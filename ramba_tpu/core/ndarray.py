@@ -554,7 +554,12 @@ class ndarray:
                 return
         vexpr = as_exprable(value)
         if kind == "basic":
-            self.write_expr(Node("setitem", (payload,), [self.read_expr(), vexpr]))
+            folded = None
+            if common.rewrite_enabled and self._base is None:
+                # a write of a prolongation onto this array: one node
+                folded = _rewrite.fold_prolong(self._expr, payload, vexpr)
+            self.write_expr(folded if folded is not None else Node(
+                "setitem", (payload,), [self.read_expr(), vexpr]))
         elif kind == "mask":
             mexpr = as_exprable(payload)
             if np.dtype(vexpr.dtype) != self.dtype:

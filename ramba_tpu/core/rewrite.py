@@ -31,13 +31,17 @@ writes, not at the flush:
   and every such copy after it, become ONE ``remap_faces`` node: a
   ghost-layer refresh (NPB MG's ``comm3``, six copies) is one in-place
   pass (``core/slicing.py`` ``remap``), not six writes.
+* ``fold_prolong`` (counted as ``rewrite_prolong``) — the five writes of a
+  trilinear prolongation onto zeros (NPB MG's ``interp``) become ONE
+  ``prolong`` node, which one pass writes (``core/slicing.py``
+  ``prolong``).
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from ramba_tpu.core.expr import Const, Expr, Node
+from ramba_tpu.core.expr import Const, Expr, Node, Scalar
 from ramba_tpu.observe import registry as _registry
 from ramba_tpu.resilience import faults as _faults
 
@@ -483,6 +487,96 @@ def fold_face_copy(x: Expr, dst_enc, src_enc):
     return Node("remap_faces", (maps,), [x], aval=aval)
 
 
+def _window(enc, shape):
+    """``(start, stop, step)`` on every axis of ``shape`` of a basic index
+    of slices alone, or None."""
+    if len(enc) > len(shape) or any(part[0] != "s" for part in enc):
+        return None
+    return (tuple(slice(*part[1:]).indices(n) for part, n in zip(enc, shape))
+            + tuple((0, n, 1) for n in shape[len(enc):]))
+
+
+def _on_axis(shape, ax, start, stop):
+    """The window ``start:stop`` on axis ``ax`` of ``shape``, whole on the
+    others."""
+    return tuple((start, stop, 1) if a == ax else (0, n, 1)
+                 for a, n in enumerate(shape))
+
+
+def _reads(e, x, window) -> bool:
+    """Whether ``e`` is the read of ``window`` of ``x``."""
+    return (isinstance(e, Node) and e.op == "getitem" and e.args[0] is x
+            and _window(e.static[0], tuple(x.aval.shape)) == window)
+
+
+def _operands(e, fname):
+    """Both orders of the two operands of the elementwise ``fname`` node
+    ``e``, or nothing: the two orders give the same bits."""
+    if (isinstance(e, Node) and e.op == "map" and e.static == (fname,)
+            and len(e.args) == 2):
+        return (tuple(e.args), tuple(e.args[::-1]))
+    return ()
+
+
+def fold_prolong(x: Expr, dst_enc, v: Expr):
+    """The node of ``x[dst] = v``, a write of a trilinear prolongation onto
+    zeros, counted as a firing of ``rewrite_prolong``; else None.
+
+    The script (NPB MG's ``interp``): ``f[0::2, 0::2, 0::2] = z[:-1, :-1,
+    :-1]`` onto a ``zeros`` of twice ``z``'s extents less two, then along
+    each axis in turn ``f[1:-1] = f[1:-1] + 0.5 * (f[2:] + f[:-2])``.  The
+    first write makes a ``prolong`` node with no axis done; each pass along
+    the next axis joins it.  Rank 3, float32 on both sides (nothing is
+    cast), a zero that is +0, the scalar 0.5; any other write, axis order,
+    scalar or dtype builds the script's nodes.
+
+    As ``fold_face_copy``, ``ndarray.__setitem__`` asks where the script
+    writes, and this is no entry of ``RULES``: a rule at the flush would
+    rebuild every node above a firing (PERF.md section 6, PR 35)."""
+    if not (isinstance(x, Node) and x.op in ("full", "prolong")):
+        return None
+    shape = tuple(x.aval.shape)
+    if (len(shape) != 3 or x.aval.dtype != np.float32
+            or v.aval.dtype != np.float32):
+        return None
+    window = _window(dst_enc, shape)
+    if x.op == "full":
+        fill = x.args[0]
+        if not (isinstance(fill, Scalar) and fill.value == 0
+                and not np.signbit(np.real(fill.value))
+                and isinstance(v, Node) and v.op == "getitem"
+                and window == tuple((0, n, 2) for n in shape)):
+            return None
+        (z,) = v.args
+        coarse = tuple(z.aval.shape)
+        if (len(coarse) != 3
+                or any(n != 2 * c - 2 or c < 3 for n, c in zip(shape, coarse))
+                or not _reads(v, z, tuple((0, c - 1, 1) for c in coarse))):
+            return None
+        node = Node("prolong", (0, x.static[2]), [z], aval=x.aval)
+    else:
+        done, spec = x.static
+        if done == 3:
+            return None
+        n = shape[done]
+        mid, up, dn = (_on_axis(shape, done, a, b)
+                       for a, b in ((1, n - 1), (2, n), (0, n - 2)))
+
+        def half_sum(e):
+            return any(isinstance(s, Scalar) and s.value == 0.5
+                       and any(_reads(a, x, up) and _reads(b, x, dn)
+                               for a, b in _operands(t, "add"))
+                       for s, t in _operands(e, "multiply"))
+
+        if window != mid or not any(_reads(a, x, mid) and half_sum(b)
+                                    for a, b in _operands(v, "add")):
+            return None
+        node = Node("prolong", (done + 1, spec), x.args, aval=x.aval)
+    stats["rewrite_prolong"] += 1
+    _registry.inc("rewrite.rewrite_prolong")
+    return node
+
+
 RULES = [
     rewrite_arange_reshape,
     rewrite_stack_reduce_advindex,
@@ -496,6 +590,7 @@ RULES = [
 # DAG-rewrite debug prints, ramba.py:4567-4789).
 stats = {rule.__name__: 0 for rule in RULES}
 stats["rewrite_face_copies"] = 0  # fold_face_copy: fired at the build
+stats["rewrite_prolong"] = 0  # fold_prolong: fired at the build
 
 
 def rewrite_roots(roots):

@@ -51,6 +51,14 @@ wrong in four places this module answers (PERF.md section 6, PRs 32, 35):
   is its own result, and the kernel visits, ONCE, the blocks that hold a
   ghost lane or row (0.96 ms); everything else is the six writes as they
   were.
+* **A prolongation onto zeros.**  NPB MG's ``interp`` is five writes onto
+  a fresh array (``f[0::2, 0::2, 0::2] = z[:-1, :-1, :-1]``, then ``f[1:-1]
+  = f[1:-1] + 0.5 * (f[2:] + f[:-2])`` along each axis), each a pass over
+  the fine array with pads and slices between (PERF.md section 5).
+  ``core/rewrite.py`` folds them into one ``prolong`` node and ``prolong``
+  lowers it: on one device a float32 array that ``ops/prolong_pallas.py``
+  takes is written once, by the kernel; everything else is the five writes
+  as they were.
 """
 
 from __future__ import annotations
@@ -289,3 +297,40 @@ def remap(x, maps):
         for d, s in pairs:
             x = put(x, face(ax, d), take(x, face(ax, s)))
     return x
+
+
+def _prolong_through_kernel(f) -> bool:
+    """Whether a prolongation onto ``f`` takes the kernel: one device and
+    an array ``prolong_pallas.available`` takes."""
+    from ramba_tpu.ops import prolong_pallas
+
+    return (_mesh.get_mesh().devices.size == 1
+            and prolong_pallas.available(f.shape, f.dtype))
+
+
+def prolong(z, axes, f):
+    """``f``, zeros of twice ``z``'s extents less two, after
+    ``f[0::2, 0::2, 0::2] = z[:-1, :-1, :-1]`` and ``f[1:-1] = f[1:-1] +
+    0.5 * (f[2:] + f[:-2])`` along the first ``axes`` axes in turn.  Notes
+    ``prolong.path.pallas`` (the kernel, its block on the note) or
+    ``prolong.path.xla`` (the writes one by one, the HLO they had)."""
+    if axes == 3 and _prolong_through_kernel(f):
+        from ramba_tpu.ops import prolong_pallas
+
+        interpret = prolong_pallas.interpreting()
+        _registry.note_kernel(
+            "prolong", "pallas", interpret, grid=z.shape[0] - 1,
+            block_planes=2,
+            vmem_limit_bytes=prolong_pallas.vmem_bytes(z.shape))
+        return prolong_pallas.prolong(z, interpret)
+    _registry.note_kernel("prolong", "xla")
+    f = put(f, (slice(0, None, 2),) * f.ndim,
+            take(z, (slice(None, -1),) * z.ndim))
+    for ax in range(axes):
+        def on(start, stop):
+            return (slice(None),) * ax + (slice(start, stop),)
+
+        mid = on(1, -1)
+        f = put(f, mid, take(f, mid) + 0.5 * (take(f, on(2, None))
+                                              + take(f, on(None, -2))))
+    return f

@@ -5,7 +5,9 @@ tiles a long row is cut (``LANE_WHOLE``, ``LANE_TILE``); and whether at any
 size a ghost-layer refresh (NPB MG's ``comm3``: six whole-face copies) is
 better left six writes than given to the in-place walk of
 ``ops/faces_pallas.py`` (at none read: the walk has no threshold), in what
-blocks.
+blocks; and from what fine extent a trilinear prolongation (NPB MG's
+``interp``: five writes onto zeros) is given to ``ops/prolong_pallas.py``
+(``MIN_EXTENT``).
 
 One process, one chip.  Every candidate is ``slicing.take`` or
 ``slicing.put`` jitted alone over a resident operand of random BITS (every
@@ -21,11 +23,20 @@ six writes one by one (the parent's HLO), ``edge`` the walk over the edge
 blocks as the module sizes them, ``edge<P>`` in lane blocks of P planes,
 ``whole<P>`` a walk over whole blocks of P planes (every byte read and
 written once), ``pass`` XLA's ``a + 1`` (what a read and a write of the array
-cost); held against NumPy's ``comm3``.
+cost); held against NumPy's ``comm3``.  A prolongation (``prolong``) onto
+an n^3 array is ``slicing.prolong`` jitted alone, ``writes`` the five
+writes one by one (the parent's HLO), ``kernel`` the kernel, ``pass`` XLA's
+``a + 1`` of the fine array; several coarse operands a call where they are
+small, so that the device's time shows; ``compile_s`` is the host's time to
+lower and compile the call, what a shape adds to set-up.  The kernel is
+held against the writes bit for bit (NaNs, infinities and -0 among the
+operand's values) and both against NumPy's five writes (NaN for NaN).
 
     python -u scripts/tpu_slicing_sweep.py [reads] [writes] [faces[=18,10]]
+        [prolong[=514,258]]
 
-(no argument: all three; ``faces=`` with the cubes' sides: those alone).
+(no argument: all four; ``faces=``, ``prolong=`` with the cubes' sides:
+those alone).
 
 Prints one JSON object, and a table on stderr row by row; the rows so far
 are in chiprun_out/slicing_sweep.json after every candidate, so a call
@@ -90,6 +101,8 @@ FACES = [
     (18, ["pass", "dus", "edge", "whole18"]),
     (10, ["pass", "dus", "edge", "whole10"]),
 ]
+#: fine sides of mg-C's prolongations
+PROLONG = [514, 258, 130, 66, 34, 18, 10, 6]
 
 
 def main() -> int:
@@ -100,10 +113,12 @@ def main() -> int:
     from ramba_tpu.core import slicing
     from ramba_tpu.ops import faces_pallas
 
-    wanted = {a.split("=")[0] for a in sys.argv[1:]} or {"reads", "writes",
-                                                         "faces"}
+    wanted = {a.split("=")[0] for a in sys.argv[1:]} or {
+        "reads", "writes", "faces", "prolong"}
     sides = [int(n) for a in sys.argv[1:] if a.startswith("faces=")
              for n in a[6:].split(",")]
+    fine_sides = [int(n) for a in sys.argv[1:] if a.startswith("prolong=")
+                  for n in a[8:].split(",")]
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"no TPU: {dev.platform}", file=sys.stderr)
@@ -112,6 +127,7 @@ def main() -> int:
                ("MXU_MIN_ELEMENTS", "MXU_MAX_STEP", "LANE_WHOLE", "LANE_TILE",
                 "PAD_MAX_EXTENT")}
     through_kernel = slicing._faces_through_kernel
+    prolong_kernel = slicing._prolong_through_kernel
 
     def configure(cand):
         """The module's constants for one candidate."""
@@ -128,6 +144,7 @@ def main() -> int:
         for k, v in shipped.items():
             setattr(slicing, k, v)
         slicing._faces_through_kernel = through_kernel
+        slicing._prolong_through_kernel = prolong_kernel
 
     def what_ships(x, idx):
         axes = slicing._axes(idx, x.shape)
@@ -287,8 +304,102 @@ def main() -> int:
                      f"exact={row['exact']}" if "ms" in row
                      else row["error"]), file=sys.stderr, flush=True)
             keep()
+    for n in PROLONG if "prolong" in wanted else ():
+        if fine_sides and n not in fine_sides:
+            continue
+        row = {"kind": "prolong", "operand": f"cube-{n} interp",
+               "shape": [n, n, n], "ships": "kernel" if prolong_kernel(
+                   jax.ShapeDtypeStruct((n,) * 3, jnp.float32)) else "writes"}
+        try:
+            row.update(prolong_row(n, bits))
+            bad += not (row["exact"] and row["numpy"])
+        except Exception as e:  # a candidate the chip refuses
+            row.update(error=f"{type(e).__name__}: {str(e)[:300]}")
+            bad += 1
+        finally:
+            restore()
+        rows.append(row)
+        print(f"prolong cube-{n:<15d} " + (" ".join(
+            f"{k}={row[k]:.4f}" for k in ("pass_ms", "writes_ms", "kernel_ms",
+                                          "writes_compile_s",
+                                          "kernel_compile_s"))
+            + f" exact={row['exact']} numpy={row['numpy']}"
+            if "exact" in row else row["error"]), file=sys.stderr, flush=True)
+        keep()
     print(json.dumps(out))
     return 1 if bad else 0
+
+
+def prolong_row(n, bits):
+    """Times of ``pass``, ``writes`` and ``kernel`` for one fine side ``n``
+    (ms a prolongation, median of 5 after a warm-up), the compile of each
+    call, and whether the kernel's bits are the writes' and both NumPy's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ramba_tpu.core import slicing
+
+    c = n // 2 + 1
+    many = max(1, min(20, (64 << 20) // (4 * n ** 3)))
+    zs = []
+    for k in range(many):
+        z = np.asarray(bits((c, c, c), 10 + k)).view(np.uint32)
+        z = (z >> 9 | 0x3F800000).view(np.float32) - 1.5  # [-0.5, 0.5)
+        z.flat[::97] = -0.0
+        z.flat[5::101] = np.inf
+        z.flat[7::103] = np.nan
+        zs.append(z)
+    args = [jax.device_put(z) for z in zs]
+    out = {"operands_a_call": many}
+    got = {}
+    for cand in ("pass", "writes", "kernel"):
+        slicing._prolong_through_kernel = lambda f, k=cand: k == "kernel"
+
+        def f(*zz):
+            if cand == "pass":
+                return [jnp.zeros((n,) * 3, jnp.float32) + z[0, 0, 0]
+                        for z in zz]
+            return [slicing.prolong(z, 3, jnp.zeros((n,) * 3, jnp.float32))
+                    for z in zz]
+
+        t0 = time.perf_counter()
+        fn = jax.jit(f).lower(*args).compile()
+        out[f"{cand}_compile_s"] = time.perf_counter() - t0
+        jax.block_until_ready(fn(*args))
+        ms = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            r = jax.block_until_ready(fn(*args))
+            ms.append(1e3 * (time.perf_counter() - t0) / many)
+        out[f"{cand}_ms"] = statistics.median(ms)
+        got[cand] = [np.asarray(x) for x in r]
+    out["exact"] = all(np.array_equal(a.view(np.uint32), b.view(np.uint32))
+                       for a, b in zip(got["kernel"], got["writes"]))
+    # where the bits differ: a zero's sign, a NaN's payload, or a value
+    out["differ"] = {k: int(sum(
+        (f(a, b) & (a.view(np.uint32) != b.view(np.uint32))).sum()
+        for a, b in zip(got["kernel"], got["writes"]))) for k, f in (
+            ("zero_sign", lambda a, b: (a == 0) & (b == 0)),
+            ("nan", lambda a, b: np.isnan(a) & np.isnan(b)),
+            ("value", lambda a, b: ~((a == 0) & (b == 0))
+             & ~(np.isnan(a) & np.isnan(b))))}
+    want = [nas_writes(z, n) for z in zs]
+    out["numpy"] = all(
+        np.array_equal(a, w, equal_nan=True) and np.array_equal(
+            np.signbit(a)[~np.isnan(w)], np.signbit(w)[~np.isnan(w)])
+        for a, w in zip(got["writes"], want))
+    out["gbps"] = 4.0 * n ** 3 / out["kernel_ms"] / 1e6
+    return out
+
+
+def nas_writes(z, n):
+    """NumPy's five writes of ``benchmark/programs/nas_mg.py`` ``prolong``."""
+    import numpy as np
+
+    from benchmark.programs import nas_mg
+
+    return nas_mg.prolong(z, np.zeros((n,) * 3, np.float32))
 
 
 def whole_pass(x, rows, lanes, interpret, bp):

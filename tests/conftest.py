@@ -149,3 +149,53 @@ def interpreting_walk(monkeypatch):
     faces_pallas._wrap_jit.cache_clear()
     yield
     faces_pallas._wrap_jit.cache_clear()
+
+
+@pytest.fixture
+def interpreting_prolong(monkeypatch):
+    """``ops/prolong_pallas.py``'s kernel off the chip.  Its blocks are
+    whole tiles that reach past the array, which the chip's padded tiles
+    allow and the interpreter refuses: here the kernel reads a copy padded
+    to whole blocks and writes a result of whole blocks, of which the
+    array is cut.  So tier-1 never makes the chip's ragged blocks;
+    ``scripts/tpu_slicing_sweep.py prolong`` and the ``mg-C`` cell do.  On
+    one device only: install ``one_device`` first."""
+    import jax.numpy as jnp
+
+    from ramba_tpu.ops import prolong_pallas
+
+    real = prolong_pallas._prolong_call
+
+    def padded(shape, fine, interpret, z):
+        nt, ncol, fr, fl = prolong_pallas._tiles(shape)
+        whole = jnp.pad(z, [(0, 0), (0, 8 * nt - shape[1]),
+                            (0, 128 * ncol - shape[2])])
+        out = real(shape, (fine[0], fr, fl), interpret, whole)
+        return out[tuple(slice(0, n) for n in fine)]
+
+    monkeypatch.setattr(prolong_pallas, "_prolong_call", padded)
+    monkeypatch.setattr(prolong_pallas, "_INTERPRET", True)
+    prolong_pallas._prolong_jit.cache_clear()
+    yield
+    prolong_pallas._prolong_jit.cache_clear()
+
+
+@pytest.fixture
+def one_device():
+    """The program's mesh held to one device for the test."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ramba_tpu.core import fuser
+    from ramba_tpu.parallel import mesh as mesh_mod
+
+    if jax.process_count() > 1:
+        pytest.skip("installs a local mesh")
+    fuser.flush()
+    old = mesh_mod.get_mesh()
+    mesh_mod.set_mesh(Mesh(np.array(jax.devices()[:1]), ("d0",)))
+    try:
+        yield
+    finally:
+        fuser.flush()
+        mesh_mod.set_mesh(old)

@@ -112,6 +112,32 @@ def test_stencil3_phase_on_one_device(interpreting, one_device, monkeypatch):
         chip_smoke.phase_stencil3(rt, 24, ("xla",), interpret_ok=True)
 
 
+def test_prolong_phase(interpreting):
+    """On the CPU mesh the five writes through XLA, as on four chips."""
+    n = 10
+    facts = chip_smoke.phase_prolong(
+        rt, n, chip_smoke.expected_prolong_path(n, len(jax.devices())),
+        interpret_ok=True)
+    assert facts["path"] == "xla" and facts["rungs"] == ["fused"]
+    assert chip_smoke.expected_prolong_path(258, 1) == "pallas"
+    assert chip_smoke.expected_prolong_path(10, 1) == "xla"
+
+
+def test_prolong_phase_on_one_device(interpreting, one_device,
+                                     interpreting_prolong):
+    """On a mesh of one device, over the kernel's bound: the kernel,
+    interpreted, bit for bit the five writes; and a prolongation that
+    takes another path than the one named fails its phase."""
+    from ramba_tpu.ops import prolong_pallas
+
+    n = prolong_pallas.MIN_EXTENT
+    n = min(2 ** k + 2 for k in range(2, 12) if 2 ** k + 2 >= n)
+    assert chip_smoke.phase_prolong(rt, n, "pallas", interpret_ok=True)[
+        "path"] == "pallas"
+    with pytest.raises(chip_smoke.SmokeFailure, match="took"):
+        chip_smoke.phase_prolong(rt, 10, "pallas", interpret_ok=True)
+
+
 def test_stencil_sweeps_phase(interpreting):
     facts = chip_smoke.phase_stencil_sweeps(rt, 128, 3, 4, _paths(128),
                                             interpret_ok=True)
@@ -127,7 +153,7 @@ def test_prk_scalars_phase(interpreting):
 
 
 def test_mg_phase(interpreting):
-    facts = chip_smoke.phase_mg(rt, 16, iters=12, interpret_ok=True)
+    facts = chip_smoke.phase_mg(rt, 16, iters=20, interpret_ok=True)
     assert facts["rungs"] == ["fused"] and "xla" in facts["path"]
     assert facts["segments"] >= 2
     assert facts["segment_hits_second"] == facts["segments"]

@@ -138,10 +138,10 @@ def test_a_long_solve_runs_segmented_and_equals_the_unsegmented_one(
     monkeypatch.setattr(common, "max_program_instrs", 0)
     (whole,) = prog.solve()
     span = diagnostics.last_flushes()[-1]
-    assert span["segments"] == 0 and span["instrs"] > 192
+    assert span["segments"] == 0 and span["instrs"] > 64
     u_whole, r_whole = np.asarray(prog.u), np.asarray(prog.r)
 
-    monkeypatch.setattr(common, "max_program_instrs", 192)
+    monkeypatch.setattr(common, "max_program_instrs", 64)
     before = diagnostics.counters()
     (cut,) = prog.solve()
     span = diagnostics.last_flushes()[-1]
@@ -164,9 +164,10 @@ def test_a_long_solve_runs_segmented_and_equals_the_unsegmented_one(
     assert moved(before, "stencil.path.xla") >= 1
 
 
-def test_every_iteration_runs_the_same_executables():
+def test_every_iteration_runs_the_same_executables(monkeypatch):
     """Forty iterations linearize to forty repetitions: cut at the same
     places of each, all but the first share their segments."""
+    monkeypatch.setattr(common, "max_program_instrs", 192)
     prog = program(8, 40)
     before = diagnostics.counters()
     prog.solve()
@@ -212,12 +213,13 @@ def _chain(n_ops, prefix=0, suffix=0):
     return program
 
 
-def _reps(fit, count):
-    """Repetitions to a segment: the largest divisor of the count that is
-    at least half of what fits, else what fits."""
-    fit = min(fit, count)
-    return max((k for k in range(1, fit + 1)
-                if count % k == 0 and 2 * k >= fit), default=fit)
+def _reps(period, count, size):
+    """Repetitions to a segment of ``size``: the fewest that reach an
+    eighth of it (at most what fits and the count), or the largest divisor
+    of the count from half of that up."""
+    want = min(max(1, -(-(size // 8) // period)), size // period, count)
+    return max((k for k in range(1, want + 1)
+                if count % k == 0 and 2 * k >= want), default=want)
 
 
 def test_segment_ends_follow_the_loop():
@@ -228,13 +230,14 @@ def test_segment_ends_follow_the_loop():
     assert ends[-1] == len(p.instrs) and ends == sorted(set(ends))
     sizes = np.diff([0] + ends)
     assert sizes.max() <= 384
-    # whole iterations to a segment: as many as fit, or a divisor of the
-    # count that is at least half of that
+    # whole iterations to a segment: the fewest that reach an eighth of
+    # it, or a divisor of the count that is at least half of that
+    reps = _reps(body, count, 384)
+    assert reps >= 5  # short repetitions are still packed
     inside = [s for e, s in zip(ends, sizes)
-              if start < e <= start + body * count and s > 100]
-    assert inside and all(s == _reps(384 // body, count) * body
-                          for s in inside[1:-1])
-    assert 2 * inside[1] >= 384 // body * body
+              if start < e <= start + body * count and s >= 5 * body]
+    assert inside and all(s == reps * body for s in inside[1:-1])
+    assert 2 * inside[1] >= -(-48 // body) * body
     # a loop longer than a segment is cut into equal parts
     ends = fuser._segment_ends(_chain(400), 3)
     assert set(np.diff(ends[2:-2])) <= {2, 3}
@@ -247,48 +250,65 @@ def test_segment_ends_follow_the_loop():
     assert fuser._segment_ends(plain, 8) == list(range(8, n, 8)) + [n]
 
 
+def test_short_repetitions_are_still_packed():
+    """At the segment size the flush runs with, a loop of three to five
+    instructions a repetition still packs a tenth of a segment and more
+    to a call: the fixed cost of a call stays paid once for many."""
+    p = _chain(400)
+    start, body, count = fuser._repetition(p)
+    size = common.max_program_instrs
+    ends = fuser._segment_ends(p, size)
+    inside = [b - a for a, b in zip([0] + ends, ends)
+              if start < b <= start + body * count]
+    assert len(inside) >= 3 and inside[1] == _reps(body, count, size) * body
+    assert inside[1] >= size // 16 and len(ends) <= 2 * count // 10
+
+
 @pytest.mark.parametrize("n_ops,fit", [(38, 3), (39, 3), (40, 3), (42, 3),
                                        (47, 3), (98, 10), (102, 10)])
 def test_a_remainder_makes_no_program_of_its_own(n_ops, fit):
-    """``fit`` repetitions fit a segment.  Where a number from half of
-    that up divides the count, the loop is cut so many at a time; where
-    none does (a prime count), ``fit`` at a time, and the repetitions left
-    over are cut with what stands after the loop.  Either way every
-    segment inside the loop is the same program and the calls at most
-    double (``mg-C``: 19 repetitions of 294 instructions, two to a
+    """``fit`` repetitions reach an eighth of a segment.  Where a number
+    from half of that up divides the count, the loop is cut so many at a
+    time; where none does (a prime count), ``fit`` at a time, and the
+    repetitions left over are cut with what stands after the loop.  Either
+    way every segment inside the loop is the same program and the calls at
+    most double (``mg-C``: 19 repetitions of 294 instructions, two to a
     segment, left a fourth executable to trace, lower and compile)."""
     p = _chain(n_ops, suffix=2)
     start, body, count = fuser._repetition(p)
-    ends = fuser._segment_ends(p, fit * body)
-    reps = _reps(fit, count)
+    size = 8 * fit * body
+    ends = fuser._segment_ends(p, size)
+    reps = _reps(body, count, size)
     whole = start + body * reps * (count // reps)
     inside = [e for e in ends if start < e <= whole]
     assert set(np.diff([start] + inside)) == {reps * body}
     assert inside[-1] == whole and len(inside) <= 2 * -(-count // fit)
-    # the rest, left-over repetitions and all, every ``fit * body``
+    # the rest, left-over repetitions and all, every ``size``
     rest = [e for e in ends if e > whole]
-    assert rest == (list(range(whole + fit * body, len(p.instrs), fit * body))
-                    + [len(p.instrs)])
-    segments = fuser._iter_segments(p, fuser._last_use_map(p), fit * body)
+    assert rest == list(range(whole + size, len(p.instrs), size)) + [
+        len(p.instrs)]
+    segments = fuser._iter_segments(p, fuser._last_use_map(p), size)
     in_loop = {seg.key for (seg, _in, _out, top), e in zip(segments, ends)
                if start < e <= whole}
     assert len(in_loop) <= 2  # the first may read leaves, the rest carry
 
 
 @pytest.mark.parametrize("start,period,count,tail,size,calls", [
+    (9, 110, 19, 111, 768, [9] + [110] * 19 + [111]),       # mg-C, PR 38
     (9, 294, 19, 295, 768, [9] + [294] * 19 + [295]),       # mg-C, PR 35
     (9, 668, 19, 680, 768, [9] + [668] * 19 + [680]),       # mg-C, PR 34
-    (9, 294, 20, 1, 768, [9] + [588] * 10 + [1]),
-    (0, 10, 97, 5, 768, [760, 215]),      # a prime count: what fits
-    (0, 10, 96, 0, 768, [480, 480]),      # 48 divides, 76 fit
-    (3, 100, 20, 0, 768, [3] + [500] * 4),
-    (0, 100, 23, 900, 768, [700] * 3 + [768, 332]),
-    (5, 300, 3, 800, 1000, [5, 900, 800]),  # fewer repetitions than fit
-], ids=["mg-C", "mg-C-parent", "even", "prime", "divisor", "five-of-seven",
-        "left-over-and-tail", "short-loop"])
+    (9, 294, 20, 1, 768, [9] + [294] * 20 + [1]),
+    (0, 10, 97, 5, 768, [100] * 9 + [75]),  # a prime count: ten at a time
+    (0, 10, 96, 0, 768, [80] * 12),       # 8 divides, 10 reach 96
+    (3, 20, 42, 0, 768, [3] + [60] * 14),  # 3 divides, 5 reach 96
+    (0, 10, 23, 900, 768, [100] * 2 + [768, 162]),
+    (5, 30, 3, 1000, 1000, [5, 90, 1000]),  # fewer repetitions than reach
+], ids=["mg-C", "mg-C-PR35", "mg-C-PR34", "even", "prime", "divisor",
+        "three-of-five", "left-over-and-tail", "short-loop"])
 def test_the_cut_by_its_numbers(start, period, count, tail, size, calls,
                                 monkeypatch):
-    """``_segment_ends`` from a loop's place, period and count alone."""
+    """``_segment_ends`` from a loop's place, period and count alone: a
+    repetition of an eighth of a segment or more is a call of its own."""
     import types
 
     n = start + period * count + tail
@@ -362,8 +382,8 @@ def test_the_finest_level_takes_the_kernel_and_the_counters_say_so(
     # its result's type (a miss of node inference): A, S, P at the finest
     # level, the three at levels 3 and 2 and S at the coarsest
     inferred = {"stencil.path.pallas_padded": 3, "stencil.path.xla": 7}
-    for segment_at, cache in ((0, "miss"), (0, "hit"), (384, "miss"),
-                              (384, "hit")):
+    for segment_at, cache in ((0, "miss"), (0, "hit"), (64, "miss"),
+                              (64, "hit")):
         monkeypatch.setattr(common, "max_program_instrs", segment_at)
         before = diagnostics.counters()
         (norm,) = prog.solve()
@@ -400,27 +420,10 @@ def test_mg_cs_refreshes_by_the_scripts_count():
     # the walk wherever a plane has a whole row tile: 514^3 down to 10^3,
     # not 6^3 (four an iteration) and 4^3 (two)
     assert (4 * 20 + 1) + 6 * 4 * 20 == 561 and 681 - 561 == 6 * 20
-    # 12 of a refresh's 13,381 - 681 x 11 instructions are one
+    # 12 of a refresh's 13,381 - 681 x 11 instructions are one; then
+    # 24 of each of the 160 prolongations' (PR 38): 19 repetitions of 110
     assert 13381 - 11 * refreshes(9, 20) == 5890
-
-
-@pytest.fixture
-def one_device():
-    import jax
-    from jax.sharding import Mesh
-
-    from ramba_tpu.parallel import mesh as mesh_mod
-
-    if jax.process_count() > 1:
-        pytest.skip("installs a local mesh")
-    fuser.flush()
-    old = mesh_mod.get_mesh()
-    mesh_mod.set_mesh(Mesh(np.array(jax.devices()[:1]), ("d0",)))
-    try:
-        yield
-    finally:
-        fuser.flush()
-        mesh_mod.set_mesh(old)
+    assert 5890 - 23 * 8 * 20 == 2210 and 294 - 23 * 8 == 110
 
 
 @pytest.mark.parametrize("where", ["mesh", "walk"])
@@ -459,7 +462,8 @@ def test_the_refresh_as_one_node_changes_no_bit(segment_at, where,
         span = diagnostics.last_flushes()[-1]
         assert span["cache"] == cache and span.get("degraded") is None
         assert (span["segments"] > 0) == bool(segment_at)
-        assert span["instrs"] == instrs - 11 * total
+        # a refresh is one instruction of twelve, a prolongation of 24
+        assert span["instrs"] == instrs - 11 * total - 23 * (lt - 1) * nit
         assert {k: moved(before, k) for k in want} == want, cache
         assert norm == plain
         np.testing.assert_array_equal(np.asarray(prog.u), u)

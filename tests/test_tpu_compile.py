@@ -301,3 +301,37 @@ def test_a_ghost_layer_refresh_compiles_in_place(one_chip, n, monkeypatch):
     text = walk.as_text()
     assert text.count("tpu_custom_call") == 1 and " copy(" not in text
     assert f"f32[{n},{n},1]" not in text
+
+
+# -- a prolongation written once -----------------------------------------------
+@pytest.mark.parametrize("n", [514, 258, 130])
+def test_a_prolongation_compiles_to_one_pass(one_chip, n, monkeypatch):
+    """NPB's ``interp`` onto a (2^k + 2)^3 array of ``mg-C``'s pyramid as
+    ``slicing.prolong`` lowers it for one chip: the custom call alone,
+    whose blocks Mosaic takes, in the VMEM it asks for, and no
+    temporary; as five writes XLA makes a pass over the fine array each."""
+    from ramba_tpu.observe import registry
+    from ramba_tpu.ops import prolong_pallas, stencil_pallas
+
+    z = jax.ShapeDtypeStruct((n // 2 + 1,) * 3, jnp.float32,
+                             sharding=one_chip)
+
+    def compiled():
+        with registry.collect_kernel_notes() as notes, jax.enable_x64(False):
+            c = jax.jit(lambda a: slicing.prolong(
+                a, 3, jnp.zeros((n,) * 3, a.dtype))).lower(z).compile()
+        return c, notes
+
+    writes, (note,) = compiled()
+    assert note["path"] == "xla"  # off the chip the kernel is not offered
+    tiled = n * -(-n // 8) * 8 * -(-n // 128) * 128 * 4
+    assert writes.cost_analysis()["bytes accessed"] > 3 * tiled
+    # the chip's answers: there is no chip to ask here
+    monkeypatch.setattr(prolong_pallas, "available", lambda *a: True)
+    monkeypatch.setattr(prolong_pallas, "interpreting", lambda: False)
+    kernel, (note,) = compiled()
+    assert note["path"] == "pallas" and not note["interpret"]
+    assert note["vmem_limit_bytes"] <= stencil_pallas._vmem_cap()
+    assert kernel.memory_analysis().temp_size_in_bytes == 0
+    text = kernel.as_text()
+    assert text.count("tpu_custom_call") == 1 and " fusion(" not in text
