@@ -25,6 +25,7 @@ can run them at toy sizes on the CPU mesh; ``main()`` has no CPU mode.
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
 import time
@@ -860,6 +861,70 @@ def phase_prolong(rt, n, expect, interpret_ok=False):
             "second_s": second}
 
 
+def expected_transpose_path(n, ndev):
+    """The path ``ops/transpose_sharded.py`` names for an n x n float32
+    transpose under the default mesh of ``ndev`` devices: the swap of
+    blocks on a square grid whose blocks are whole lane tiles, the local
+    transpose on one device, GSPMD's otherwise."""
+    p = math.isqrt(ndev)
+    if ndev == 1:
+        return "local"
+    return "swap" if p * p == ndev and n % (128 * p) == 0 else "xla"
+
+
+def phase_transpose(rt, n, expect, iters=3, interpret_ok=False):
+    """PRK Transpose's ``B += A.T; A += 1`` (``benchmark/programs/
+    prk_transpose.py``) on an n x n float32 matrix made as PRK makes it:
+    ``iters`` iterations a flush, each folded into ONE node
+    (``rewrite.rewrite_add_transposed``), on the path ``expect`` names,
+    counted once an iteration by a flush that hits (one that compiles
+    also counts admission's lowering), ``A`` and ``B`` in the default
+    layout where they swap and every bit NumPy float32's."""
+    f32 = numpy.float32
+    with Recorder(rt) as rec:
+        i = rt.arange(n, dtype=f32)
+        A = i[:, None] * n + i[None, :]
+        B = rt.zeros((n, n), dtype=f32)
+        rt.sync()
+        An = numpy.arange(n, dtype=f32)[:, None] * f32(n) + numpy.arange(
+            n, dtype=f32)[None, :]
+        Bn = numpy.zeros((n, n), f32)
+
+        def iterate():
+            nonlocal A, B, An, Bn
+            for _ in range(iters):
+                B += A.T
+                A += 1.0
+                Bn += An.T
+                An += f32(1)
+            rt.sync()
+
+        with Recorder(rt) as r1:
+            _, first = _timed(iterate)
+        folded = r1.counters.get("rewrite.rewrite_add_transposed", 0)
+        paths = tuple(dict.fromkeys(r1.kernel_paths("transpose")))
+        _require(folded == iters, f"transpose {n}^2: {folded} of {iters} "
+                                  f"updates folded")
+        with Recorder(rt) as r2:
+            _, second = _timed(iterate)
+        counted = r2.counters.get(f"transpose.path.{expect}", 0)
+        caches = [fl["cache"] for fl in r2.flushes]
+        _require(counted == iters and paths == (expect,) and caches == ["hit"],
+                 f"transpose {n}^2 again: took {paths}, transpose.path."
+                 f"{expect} moved by {counted}, flushes {caches}")
+        for name, x, want in (("A", A, An), ("B", B, Bn)):
+            # GSPMD's transpose leaves the layout GSPMD's
+            _require_sharded(rt, x, f"transpose {name}",
+                             default_layout=expect == "swap")
+            _require(numpy.array_equal(_host(x), want),
+                     f"transpose {n}^2: {name} differs from NumPy")
+        sent = r2.counters.get("transpose.exchange_bytes", 0)
+        del A, B
+    rec.require_clean(interpret_ok=interpret_ok)
+    return {"n": n, "path": expect, "rungs": rec.rungs(),
+            "exchange_bytes": sent, "first_s": first, "second_s": second}
+
+
 def phase_axpy(rt, n_total, interpret_ok=False):
     """BASELINE config 4: ``random.normal`` fill, then ``Y += a*X`` in
     place, ``n_total`` elements in X and Y together."""
@@ -1047,6 +1112,9 @@ def main() -> int:
             rt, 258, expected_prolong_path(258, ndev))),
         ("prolong 10^3", lambda: phase_prolong(
             rt, 10, expected_prolong_path(10, ndev))),
+        # on four chips the swap of the off-diagonal blocks
+        ("transpose 4096^2", lambda: phase_transpose(
+            rt, 4096, expected_transpose_path(4096, ndev))),
         ("axpy 1e9", lambda: phase_axpy(rt, 1_000_000_000)),
         ("broadcast 32768^2", lambda: phase_broadcast(rt, 32768)),
         ("stencil 30000^2", lambda: phase_stencil(

@@ -596,8 +596,26 @@ def _op_masked_fill(static, x, mask, v):
 
 @defop("permute")
 def _op_permute(static, x):
+    from ramba_tpu.ops import transpose_sharded
+
     (axes,) = static
-    return jnp.transpose(x, axes)
+    return transpose_sharded.transpose(x, axes)
+
+
+@defop("add_transposed")
+def _op_add_transposed(static, b, a):
+    """``b + a.T`` of two rank-2 arrays of one dtype
+    (``rewrite.fold_add_transposed``)."""
+    from ramba_tpu.ops import transpose_sharded
+
+    return transpose_sharded.add_transposed(b, a)
+
+
+@defop("after")
+def _op_after(static, x, y):
+    """``x``, once ``y`` exists: both through one
+    ``optimization_barrier`` (``ndarray._hold_behind``)."""
+    return jax.lax.optimization_barrier((x, y))[0]
 
 
 @defop("reshape")

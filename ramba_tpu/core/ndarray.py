@@ -40,6 +40,29 @@ from ramba_tpu.parallel import mesh as _mesh
 _seq_counter = itertools.count()
 
 
+def _make_map(fname, operands):
+    """``E.make_map``, with ``B + A.T`` folded into one node where the
+    script writes it (``rewrite.fold_add_transposed``)."""
+    if fname == "add" and len(operands) == 2 and common.rewrite_enabled:
+        folded = _rewrite.fold_add_transposed(*operands)
+        if folded is not None:
+            return folded
+    return E.make_map(fname, operands)
+
+
+def _hold_behind(a, b_expr):
+    """After ``B += A.T``: ``A``'s pending value held behind one barrier
+    with the updated ``B`` (node ``after``), so that what is computed
+    from ``A`` next, the block the next exchange sends, waits for that
+    update and is not made iterations ahead: in a flush of ten ``B +=
+    A.T; A += 1`` one block of A and one received block are live, not
+    ten, and not three (an ``A`` made while the one before it is sent).
+    The value is ``A``'s own; an ``A`` that is a materialized leaf or a
+    view is left as it is."""
+    if a is not None and a._base is None and isinstance(a._expr, Node):
+        a._set_expr(Node("after", (), [a._expr, b_expr], aval=a._expr.aval))
+
+
 # ---------------------------------------------------------------------------
 # View ops — reversible transforms between a parent array and a derived view.
 # ---------------------------------------------------------------------------
@@ -403,13 +426,15 @@ class ndarray:
         operands = [self.read_expr()] + args
         if reverse:
             operands = operands[::-1]
-        return ndarray(E.make_map(fname, operands))
+        return ndarray(_make_map(fname, operands))
 
     def _inplace_map(self, fname, other):
-        val = E.make_map(fname, [self.read_expr(), as_exprable(other)])
+        val = _make_map(fname, [self.read_expr(), as_exprable(other)])
         if np.dtype(val.dtype) != self.dtype:
             val = Node("cast", (str(self.dtype),), [val])
         self.write_expr(val)
+        if isinstance(val, Node) and val.op == "add_transposed":
+            _hold_behind(other._base, val)
         return self
 
     def astype(self, dtype, copy=True):

@@ -23,7 +23,7 @@ with direct implementations:
 
 Rules run bottom-up once per flush (core/fuser.py); a rule returns a
 replacement Node or None.  All matching is defensive: any structural
-mismatch leaves the graph untouched.  One fold runs where the script
+mismatch leaves the graph untouched.  Three folds run where the script
 writes, not at the flush:
 
 * ``fold_face_copy`` (counted as ``rewrite_face_copies``) — ``a[d] =
@@ -35,6 +35,10 @@ writes, not at the flush:
   trilinear prolongation onto zeros (NPB MG's ``interp``) become ONE
   ``prolong`` node, which one pass writes (``core/slicing.py``
   ``prolong``).
+* ``fold_add_transposed`` (counted as ``rewrite_add_transposed``) —
+  ``B += A.T`` of rank-2 arrays becomes ONE ``add_transposed`` node: on a
+  square grid of devices one block exchange, read transposed by the
+  addition and ordered after ``B`` (``ops/transpose_sharded.py``).
 """
 
 from __future__ import annotations
@@ -577,6 +581,24 @@ def fold_prolong(x: Expr, dst_enc, v: Expr):
     return node
 
 
+def fold_add_transposed(x: Expr, y: Expr):
+    """The node of ``x + y`` where ``y`` is the transpose of a rank-2
+    array of ``x``'s shape and dtype (``B += A.T``), counted as a firing
+    of ``rewrite_add_transposed``; else None.  The transpose is still
+    made, once per firing: ``ops/transpose_sharded.py`` reads the
+    exchanged block transposed in the addition and orders the exchange
+    after ``x``.  ``ndarray`` asks where the script writes the addition,
+    as for ``fold_face_copy``."""
+    if not (isinstance(y, Node) and y.op == "permute"
+            and y.static == ((1, 0),) and len(x.aval.shape) == 2
+            and tuple(x.aval.shape) == tuple(y.aval.shape)
+            and x.aval.dtype == y.aval.dtype):
+        return None
+    stats["rewrite_add_transposed"] += 1
+    _registry.inc("rewrite.rewrite_add_transposed")
+    return Node("add_transposed", (), [x, y.args[0]], aval=y.aval)
+
+
 RULES = [
     rewrite_arange_reshape,
     rewrite_stack_reduce_advindex,
@@ -591,6 +613,7 @@ RULES = [
 stats = {rule.__name__: 0 for rule in RULES}
 stats["rewrite_face_copies"] = 0  # fold_face_copy: fired at the build
 stats["rewrite_prolong"] = 0  # fold_prolong: fired at the build
+stats["rewrite_add_transposed"] = 0  # fold_add_transposed: likewise
 
 
 def rewrite_roots(roots):

@@ -108,22 +108,25 @@ def fold() -> None:
 #   flush itself traced (fuser._execute_compiled collects them).
 _kernel_notes = threading.local()
 
+#: what a note counts besides its path, as ``<kernel>.<name>``; kept on
+#: the note where it is not 0, so that a replay counts it again
+_TALLIES = ("operand_copy", "combine_bytes", "exchange_bytes")
+
 
 def _count_kernel(kernel: str, path: str, interpret: bool,
-                  operand_copy: int = 0, combine_bytes: int = 0) -> None:
+                  tallies: dict) -> None:
     inc(f"{kernel}.path.{path}")
     if interpret:
         inc(f"{kernel}.interpret")
-    if operand_copy:
-        inc(f"{kernel}.operand_copy", operand_copy)
-    if combine_bytes:
-        inc(f"{kernel}.combine_bytes", combine_bytes)
+    for name, n in tallies.items():
+        if n:
+            inc(f"{kernel}.{name}", n)
 
 
 def note_kernel(kernel: str, path: str, interpret: bool = False, *,
                 block_rows=None, grid=None, vmem_limit_bytes=None,
                 halo=None, operand_copy: int = 0, combine_bytes: int = 0,
-                **chose) -> None:
+                exchange_bytes: int = 0, **chose) -> None:
     """Record that ``kernel`` (e.g. ``"stencil"``) lowered through
     ``path`` (``sharded`` / ``pallas_fast`` / ``pallas_padded`` / ``xla``
     / a Pallas family name), and whether a Pallas kernel on that path
@@ -138,18 +141,21 @@ def note_kernel(kernel: str, path: str, interpret: bool = False, *,
     not 0, so that a replay counts it again.  ``combine_bytes`` is what a
     device hands to the kernel's combination across chips (the segment
     walk's partial sums): counted as ``<kernel>.combine_bytes`` and kept
-    on the note likewise.  Any further keyword is a
+    on the note likewise; ``exchange_bytes`` the most bytes one device
+    sends to another (the transpose's swap), likewise.  Any further
+    keyword is a
     plain value a lowering chose for itself (the segment walk's ``groups``,
     ``chunk_rows``, ``chunks``, ``fetch``, ``sharded``, ``split``,
     ``local_rows``, ``combine``): kept on the note as given."""
-    _count_kernel(kernel, path, interpret, operand_copy, combine_bytes)
+    tallies = dict(zip(_TALLIES, (operand_copy, combine_bytes,
+                                  exchange_bytes)))
+    _count_kernel(kernel, path, interpret, tallies)
     notes = getattr(_kernel_notes, "active", None)
     if notes is not None:
         note = {"kernel": kernel, "path": path, "interpret": bool(interpret)}
         sized = {"block_rows": block_rows, "grid": grid,
                  "vmem_limit_bytes": vmem_limit_bytes,
-                 "operand_copy": operand_copy or None,
-                 "combine_bytes": combine_bytes or None}
+                 **{k: n or None for k, n in tallies.items()}}
         note.update((k, int(v)) for k, v in sized.items() if v is not None)
         if halo is not None:
             note["halo"] = halo
@@ -163,8 +169,7 @@ def replay_kernel_notes(notes) -> None:
     program that traced nothing: counters only, never a new note."""
     for note in notes:
         _count_kernel(note["kernel"], note["path"], note["interpret"],
-                      note.get("operand_copy", 0),
-                      note.get("combine_bytes", 0))
+                      {k: note.get(k, 0) for k in _TALLIES})
 
 
 @contextlib.contextmanager

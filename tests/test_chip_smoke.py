@@ -285,3 +285,50 @@ def test_the_smoke_states_which_refreshes_walk(n, iters, ndev, walks):
     """From the grid, the iterations and the devices alone: the levels
     whose array has a whole row tile, on one chip."""
     assert chip_smoke.expected_face_walks(n, iters, ndev) == walks
+
+
+@pytest.fixture
+def grid_2x2():
+    """The mesh of the four-chip host: 2 x 2, of four of the devices."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from ramba_tpu.parallel import mesh as rmesh
+
+    before = rmesh.get_mesh()
+    rmesh.set_mesh(Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                        ("d0", "d1")))
+    yield
+    rmesh.set_mesh(before)
+
+
+@pytest.mark.parametrize("n,ndev,path", [(512, 4, "swap"), (4096, 4, "swap"),
+                                         (512, 8, "xla"), (384, 4, "xla"),
+                                         (512, 1, "local")])
+def test_the_smoke_states_which_transposes_swap(n, ndev, path):
+    assert chip_smoke.expected_transpose_path(n, ndev) == path
+
+
+def test_transpose_phase_on_a_2x2_grid(interpreting, grid_2x2, monkeypatch):
+    """The four-chip host's leg at toy size: the swap, the kernel
+    interpreted, one block a device an iteration; and a transpose that
+    takes another path than the one named fails its phase."""
+    from ramba_tpu.ops import transpose_sharded
+
+    monkeypatch.setattr(transpose_sharded, "_INTERPRET", True)
+    # the placement check counts every device jax shows: four of the CPU
+    # mesh's eight are outside this mesh
+    monkeypatch.setattr(chip_smoke, "_require_sharded", lambda *a, **k: None)
+    facts = chip_smoke.phase_transpose(rt, 512, "swap", interpret_ok=True)
+    assert facts["path"] == "swap" and facts["rungs"] == ["fused"]
+    assert facts["exchange_bytes"] == 3 * 256 * 256 * 4
+    with pytest.raises(chip_smoke.SmokeFailure, match="took"):
+        chip_smoke.phase_transpose(rt, 384, "swap", interpret_ok=True)
+
+
+def test_transpose_phase_on_the_suites_mesh(interpreting):
+    n = 512
+    facts = chip_smoke.phase_transpose(
+        rt, n, chip_smoke.expected_transpose_path(n, len(jax.devices())),
+        interpret_ok=True)
+    assert facts["path"] == "xla" and facts["exchange_bytes"] == 0

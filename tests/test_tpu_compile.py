@@ -335,3 +335,83 @@ def test_a_prolongation_compiles_to_one_pass(one_chip, n, monkeypatch):
     assert kernel.memory_analysis().temp_size_in_bytes == 0
     text = kernel.as_text()
     assert text.count("tpu_custom_call") == 1 and " fusion(" not in text
+
+
+# -- the transpose's swap of blocks on four chips -------------------------------
+def test_the_transpose_flush_at_the_cells_size_fits_one_exchange_in_flight(
+        topo, monkeypatch):
+    """One solve of ``transpose-x4`` as the fuser hands it to admission
+    (ten of PRK's ``B += A.T; A += 1`` and the norm, captured at toy size
+    on a 2 x 2 grid of CPU devices), compiled at 49,152^2 for the
+    described 2 x 2 host without donation, as admission estimates it:
+    under the watermark, ten ``collective-permute``s (one swap of blocks
+    an iteration) and ten calls of the kernel that reads the received
+    block transposed, no ``all-to-all`` or ``all-gather``, and two blocks
+    of temporaries: one exchange in flight.  Without the hold on A the
+    next block is made while one is sent (16.9 GB); left to XLA, the
+    update asks as much (``ops/transpose_sharded.py``)."""
+    import re
+
+    from jax.sharding import NamedSharding
+
+    import ramba_tpu as rt
+    from ramba_tpu.core import fuser
+    from ramba_tpu.ops import pallas_backend
+
+    if len(jax.devices()) < 4:
+        pytest.skip("captures on a 2 x 2 grid of the suite's devices")
+    before = rmesh.get_mesh()
+    fuser.flush()
+    rmesh.set_mesh(Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                        ("d0", "d1")))
+    captured = []
+
+    class Captured(Exception):
+        pass
+
+    def capture(program, leaf_vals, donate_key, span=None, **kw):
+        captured.append((program, leaf_vals))
+        raise Captured()
+
+    try:
+        n = 512
+        i = rt.arange(n, dtype=np.float32)
+        a = rt.zeros((n, n), dtype=np.float32) + (i[:, None] * n + i[None, :])
+        b = rt.zeros((n, n), dtype=np.float32)
+        rt.sync()
+        monkeypatch.setattr(fuser._memory, "admit", capture)
+        for _ in range(10):
+            b += a.T
+            a += 1.0
+        with pytest.raises(Captured):
+            float(rt.sum(abs(b)))
+    finally:
+        monkeypatch.undo()
+        rmesh.set_mesh(before)
+    program, leaf_vals = captured[0]
+    assert [op for op, _, _ in program.instrs].count("add_transposed") == 10
+    four = Mesh(np.array(topo.devices).reshape(2, 2), ("d0", "d1"))
+    big = 49152
+    rmesh.set_mesh(four)
+    monkeypatch.setattr(pallas_backend, "interpret_mode", lambda: False)
+    try:
+        avals = [jax.ShapeDtypeStruct(
+            (big, big), v.dtype,
+            sharding=NamedSharding(four, rmesh.default_spec((big, big))))
+            if np.ndim(v) == 2 else jax.ShapeDtypeStruct((), np.float32,
+                                                         weak_type=True)
+            for v in leaf_vals]
+        with jax.enable_x64(False):
+            c = layouts.RowMajorJit(fuser._build_callable(program)).lower(
+                *avals).compile()
+    finally:
+        rmesh.set_mesh(before)
+    block = (big // 2) ** 2 * 4
+    assert total(c) < WATERMARK
+    assert c.memory_analysis().temp_size_in_bytes <= 2 * block + (64 << 20)
+    text = c.as_text()
+    count = {op: len(re.findall(r"\b" + op + r"(?:-start)?\(", text))
+             for op in ("collective-permute", "all-to-all", "all-gather")}
+    assert count == {"collective-permute": 10, "all-to-all": 0,
+                     "all-gather": 0}
+    assert text.count("ramba_add_transposed") >= 10
