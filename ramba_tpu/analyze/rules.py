@@ -392,9 +392,11 @@ def check_sharding(view: "ProgramView") -> List[Finding]:
                         "but combines per-shard partials; the result may "
                         "depend on the shard split",
                     ))
-        elif node.op in ("stencil", "stencil_iter"):
-            lo, hi = node.static[1], node.static[2]
-            bad = _halo_exceeds(lo, hi, [a.aval for a in node.args], mesh)
+        elif node.op in ("stencil", "stencil_iter", "stencil_update"):
+            # a stencil_update's static and operands lead with its epilogue
+            k = node.op == "stencil_update"
+            lo, hi = node.static[1 + k], node.static[2 + k]
+            bad = _halo_exceeds(lo, hi, [a.aval for a in node.args[k:]], mesh)
             if bad is not None:
                 d, halo, width = bad
                 fs.append(Finding(

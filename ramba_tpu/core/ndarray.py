@@ -41,10 +41,14 @@ _seq_counter = itertools.count()
 
 
 def _make_map(fname, operands):
-    """``E.make_map``, with ``B + A.T`` folded into one node where the
-    script writes it (``rewrite.fold_add_transposed``)."""
-    if fname == "add" and len(operands) == 2 and common.rewrite_enabled:
-        folded = _rewrite.fold_add_transposed(*operands)
+    """``E.make_map``, with ``B + A.T`` and ``v - sstencil(...)`` folded
+    into one node where the script writes them
+    (``rewrite.fold_add_transposed``, ``rewrite.fold_stencil_update``)."""
+    if (fname in ("add", "subtract") and len(operands) == 2
+            and common.rewrite_enabled):
+        folded = _rewrite.fold_stencil_update(fname, operands)
+        if folded is None and fname == "add":
+            folded = _rewrite.fold_add_transposed(*operands)
         if folded is not None:
             return folded
     return E.make_map(fname, operands)

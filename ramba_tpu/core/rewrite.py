@@ -23,7 +23,7 @@ with direct implementations:
 
 Rules run bottom-up once per flush (core/fuser.py); a rule returns a
 replacement Node or None.  All matching is defensive: any structural
-mismatch leaves the graph untouched.  Three folds run where the script
+mismatch leaves the graph untouched.  Four folds run where the script
 writes, not at the flush:
 
 * ``fold_face_copy`` (counted as ``rewrite_face_copies``) — ``a[d] =
@@ -39,6 +39,10 @@ writes, not at the flush:
   ``B += A.T`` of rank-2 arrays becomes ONE ``add_transposed`` node: on a
   square grid of devices one block exchange, read transposed by the
   addition and ordered after ``B`` (``ops/transpose_sharded.py``).
+* ``fold_stencil_update`` (counted as ``rewrite_stencil_update``) —
+  ``v - sstencil(A, u)`` and ``u + sstencil(S, r)`` of float32 rank-3
+  arrays become ONE ``stencil_update`` node, whose update the stencil
+  kernel's own store writes (``ops/stencil_pallas.py``).
 """
 
 from __future__ import annotations
@@ -599,6 +603,37 @@ def fold_add_transposed(x: Expr, y: Expr):
     return Node("add_transposed", (), [x, y.args[0]], aval=y.aval)
 
 
+def fold_stencil_update(fname: str, operands):
+    """The node of ``base - s``, ``base + s`` or ``s + base`` where ``s``
+    is a rank-3 ``stencil`` node and ``base`` has its shape and dtype,
+    float32 both, nothing broadcast or cast (NPB MG's ``resid`` and
+    ``psinv``), counted as a firing of ``rewrite_stencil_update``; else
+    None.  The ``stencil_update`` node (``skeletons``) takes the stencil's
+    static and operands after the epilogue ``(fname, at)``, ``at`` the
+    base's place: on one chip the rank-3 kernel's own store writes the
+    update, one pass over HBM fewer.  Any other operand, rank, dtype or
+    stencil (``stencil_iter``, a scalar in between) builds the script's
+    nodes.  A script that also reads ``s`` elsewhere gets the stencil
+    computed once more there.  ``ndarray`` asks where the script writes
+    the operation, as for ``fold_face_copy``: no entry of ``RULES``."""
+    if fname not in ("subtract", "add") or len(operands) != 2:
+        return None
+    for at in (0, 1) if fname == "add" else (0,):
+        base, s = operands[at], operands[1 - at]
+        if (isinstance(s, Node) and s.op == "stencil"
+                and len(s.aval.shape) == 3
+                and s.aval.dtype == np.float32
+                and base.aval.dtype == np.float32
+                and tuple(base.aval.shape) == tuple(s.aval.shape)
+                and not getattr(s.aval, "weak_type", False)
+                and not getattr(base.aval, "weak_type", False)):
+            stats["rewrite_stencil_update"] += 1
+            _registry.inc("rewrite.rewrite_stencil_update")
+            return Node("stencil_update", ((fname, at), *s.static),
+                        [base, *s.args], aval=s.aval)
+    return None
+
+
 RULES = [
     rewrite_arange_reshape,
     rewrite_stack_reduce_advindex,
@@ -614,6 +649,7 @@ stats = {rule.__name__: 0 for rule in RULES}
 stats["rewrite_face_copies"] = 0  # fold_face_copy: fired at the build
 stats["rewrite_prolong"] = 0  # fold_prolong: fired at the build
 stats["rewrite_add_transposed"] = 0  # fold_add_transposed: likewise
+stats["rewrite_stencil_update"] = 0  # fold_stencil_update: likewise
 
 
 def rewrite_roots(roots):

@@ -114,19 +114,22 @@ _TALLIES = ("operand_copy", "combine_bytes", "exchange_bytes")
 
 
 def _count_kernel(kernel: str, path: str, interpret: bool,
-                  tallies: dict) -> None:
+                  tallies: dict, epilogue=None, epilogue_fused=False) -> None:
     inc(f"{kernel}.path.{path}")
     if interpret:
         inc(f"{kernel}.interpret")
     for name, n in tallies.items():
         if n:
             inc(f"{kernel}.{name}", n)
+    if epilogue not in (None, "none"):
+        inc(f"{kernel}.epilogue.{'fused' if epilogue_fused else 'unfused'}")
 
 
 def note_kernel(kernel: str, path: str, interpret: bool = False, *,
                 block_rows=None, grid=None, vmem_limit_bytes=None,
                 halo=None, operand_copy: int = 0, combine_bytes: int = 0,
-                exchange_bytes: int = 0, **chose) -> None:
+                exchange_bytes: int = 0, epilogue=None,
+                epilogue_fused: bool = False, **chose) -> None:
     """Record that ``kernel`` (e.g. ``"stencil"``) lowered through
     ``path`` (``sharded`` / ``pallas_fast`` / ``pallas_padded`` / ``xla``
     / a Pallas family name), and whether a Pallas kernel on that path
@@ -142,14 +145,19 @@ def note_kernel(kernel: str, path: str, interpret: bool = False, *,
     device hands to the kernel's combination across chips (the segment
     walk's partial sums): counted as ``<kernel>.combine_bytes`` and kept
     on the note likewise; ``exchange_bytes`` the most bytes one device
-    sends to another (the transpose's swap), likewise.  Any further
-    keyword is a
+    sends to another (the transpose's swap), likewise.  ``epilogue`` is
+    the elementwise update that follows the kernel's result (the stencil's
+    ``subtract`` or ``add``, ``none``) and ``epilogue_fused`` whether the
+    kernel's own store wrote it: an update counts
+    ``<kernel>.epilogue.fused`` or ``.unfused``, both kept on the note so
+    that a replay counts it again.  Any further keyword is a
     plain value a lowering chose for itself (the segment walk's ``groups``,
     ``chunk_rows``, ``chunks``, ``fetch``, ``sharded``, ``split``,
     ``local_rows``, ``combine``): kept on the note as given."""
     tallies = dict(zip(_TALLIES, (operand_copy, combine_bytes,
                                   exchange_bytes)))
-    _count_kernel(kernel, path, interpret, tallies)
+    epilogue_fused = bool(epilogue_fused) and epilogue not in (None, "none")
+    _count_kernel(kernel, path, interpret, tallies, epilogue, epilogue_fused)
     notes = getattr(_kernel_notes, "active", None)
     if notes is not None:
         note = {"kernel": kernel, "path": path, "interpret": bool(interpret)}
@@ -159,6 +167,10 @@ def note_kernel(kernel: str, path: str, interpret: bool = False, *,
         note.update((k, int(v)) for k, v in sized.items() if v is not None)
         if halo is not None:
             note["halo"] = halo
+        if epilogue is not None:
+            note["epilogue"] = epilogue
+            if epilogue != "none":
+                note["epilogue_fused"] = epilogue_fused
         note.update(chose)
         notes.append(note)
 
@@ -169,7 +181,8 @@ def replay_kernel_notes(notes) -> None:
     program that traced nothing: counters only, never a new note."""
     for note in notes:
         _count_kernel(note["kernel"], note["path"], note["interpret"],
-                      {k: note.get(k, 0) for k in _TALLIES})
+                      {k: note.get(k, 0) for k in _TALLIES},
+                      note.get("epilogue"), note.get("epilogue_fused", False))
 
 
 @contextlib.contextmanager

@@ -45,6 +45,11 @@ Design (pallas_guide.md patterns):
 * Output blocks are plain VMEM BlockSpecs; the stencil border is zeroed
   in the kernel by a select, to match sstencil's semantics (the reference
   writes only indices whose full neighborhood is in range).
+* At rank 3 the store may write an elementwise update of the result
+  (``v - s``, ``u + s``: a ``stencil_update`` node, which
+  ``rewrite.fold_stencil_update`` makes where the script writes it), its
+  other operand a block of the output's walk: one pass over HBM fewer
+  than the kernel's result and XLA's separate subtraction.
 
 Multi-chip stencils run through ops/stencil_sharded.py (shard_map +
 explicit ppermute halo exchange), which calls back into this kernel on
@@ -176,8 +181,8 @@ def _fast_eligible(lo, hi, arrs) -> bool:
     )
 
 
-def run(func, lo, hi, slots, arrs, taps=8, *, halos=None, _block_rows=None,
-        _block_planes=None):
+def run(func, lo, hi, slots, arrs, taps=8, *, halos=None, epilogue=None,
+        base=None, _block_rows=None, _block_planes=None):
     """Evaluate the stencil with a Pallas kernel.  Returns the full-shape
     result with border cells zeroed (sstencil semantics); with ``halos``
     (per input the ``(west, east, north, south)`` strips its neighbours
@@ -185,9 +190,15 @@ def run(func, lo, hi, slots, arrs, taps=8, *, halos=None, _block_rows=None,
     the kernel automatically falls back to ``interpret=True`` (rather
     than raising from an impossible Mosaic compile), so the CPU suite —
     and the autotune parity tests — exercise the same code path.  Rank 3
-    takes the general path only.  ``_block_rows`` and ``_block_planes``
+    takes the general path only.  ``epilogue`` is ``(fname, at)``, the
+    elementwise update ``base fname s`` (``at`` 0) or ``s fname base``
+    (``at`` 1) that follows a rank-3 result ``s``: with ``base`` the
+    kernel's own store writes it and returns the update, without it the
+    note only names it.  ``_block_rows`` and ``_block_planes``
     are scripts/tpu_stencil_sweep.py's: a candidate block in place of the
     derived one."""
+    if base is not None and len(arrs[0].shape) != 3:
+        raise NotImplementedError("the epilogue is the rank-3 kernel's")
     interpret = _INTERPRET or _pallas_backend.interpret_mode()
     if (len(arrs[0].shape) == 2 and halos is None
             and _fast_eligible(lo, hi, arrs)):
@@ -195,7 +206,7 @@ def run(func, lo, hi, slots, arrs, taps=8, *, halos=None, _block_rows=None,
         return _run_fast(func, lo, hi, slots, arrs, taps, interpret,
                          _block_rows)
     return _run_padded(func, lo, hi, slots, arrs, taps, interpret,
-                       _block_rows, halos, _block_planes)
+                       _block_rows, halos, _block_planes, epilogue, base)
 
 
 def _run_fast(func, lo, hi, slots, arrs, taps, interpret=_INTERPRET,
@@ -471,14 +482,16 @@ def _tail_operands(x, strips, lo, hi, margins, sub):
 
 
 def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET,
-                block_rows=None, halos=None, block_planes=None):
+                block_rows=None, halos=None, block_planes=None,
+                epilogue=None, base=None):
     """General-shape path: walk row blocks (rank 2) or blocks of planes
     (rank 3), the fetch of block i+1 under the compute of block i, every
     fetch straight from the arrays that hold the data: no padded copy of
     an operand is made (``_padded_call``, ``_padded_call3``).  Sizes the
     block, notes what it chose, and calls the kernel through one jitted
-    function per (kernel function, neighbourhood, block): a program that
-    runs the same stencil ten times traces and lowers it once."""
+    function per (kernel function, neighbourhood, block, epilogue): a
+    program that runs the same stencil ten times traces and lowers it
+    once.  ``epilogue`` and ``base`` as ``run`` takes them."""
     x = arrs[0]
     H, W = x.shape[-2:]
     itemsize = np.dtype(x.dtype).itemsize
@@ -487,12 +500,13 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET,
         if halos:
             raise NotImplementedError(
                 "the rank-3 kernel reads its halo from the array's own edge")
+        fused = base is not None
         plan = _stage_plan(func, slots, sub)
         assert len(plan) == len(arrs), (len(plan), len(arrs))
         block, vmem_limit = _padded_block3(
             *x.shape, itemsize, (-lo[0], hi[0]),
             _margins(lo[1:], hi[1:], itemsize),
-            [(len(lanes), len(subs)) for lanes, subs in plan], taps)
+            [(len(lanes), len(subs)) for lanes, subs in plan], taps, fused)
         if block_rows or block_planes:
             # the sweep's candidate, under the cap itself
             rows = min(_round_up(block_rows or block[1], sub),
@@ -503,10 +517,13 @@ def _run_padded(func, lo, hi, slots, arrs, taps=8, interpret=_INTERPRET,
                               block_rows=block[1], grid=-(-x.shape[0]
                                                           // block[0]),
                               vmem_limit_bytes=vmem_limit, halo="edge",
-                              block_planes=block[0])
+                              block_planes=block[0],
+                              epilogue=epilogue[0] if epilogue else "none",
+                              epilogue_fused=fused)
         call = _padded_jit(func, tuple(lo), tuple(hi), tuple(slots),
-                           interpret, block, vmem_limit, plan)
-        return call(list(arrs), None)
+                           interpret, block, vmem_limit, plan,
+                           epilogue if fused else None)
+        return call(list(arrs), base)
     if block_rows:
         # the sweep's candidate, under the cap itself
         bh, vmem_limit = _round_up(block_rows, sub), _vmem_cap()
@@ -531,11 +548,12 @@ def _padded_jit(*static):
     """``_padded_call`` (rank 3: ``_padded_call3``) under these statics,
     jitted: jax traces it once per operand shapes and lowers it once per
     program, however many times the program calls it (PRK's ten
-    iterations; PERF.md section 6, PR 29)."""
-    def ramba_stencil(arrs, halos):
+    iterations; PERF.md section 6, PR 29).  The second argument is the
+    halo strips at rank 2 and the epilogue's base at rank 3."""
+    def ramba_stencil(arrs, extra):
         if len(static[1]) == 3:
-            return _padded_call3(*static, arrs)
-        return _padded_call(*static, arrs, halos)
+            return _padded_call3(*static, arrs, extra)
+        return _padded_call(*static, arrs, extra)
 
     return jax.jit(ramba_stencil)
 
@@ -790,19 +808,20 @@ def _chunk_rows(n, Wo, sub):
 
 
 def _padded_vmem_bytes3(bp, rows, H, W, itemsize, halo, margins, staged,
-                        taps):
+                        taps, base=False):
     """What a block of ``bp`` planes, staged ``rows`` rows at a time, asks
     of VMEM: per input two slabs of the block's planes and their halo (the
     whole plane and its margins) and two of its tail blocks, its
     lane-shifted copies (``rows`` and the row margins) and its row-shifted
     ones (``staged``: how many of each), the output block Pallas
-    double-buffers, and on Mosaic's stack the temporaries of one chunk and
-    of one staged copy."""
+    double-buffers and, with an epilogue's ``base``, its block likewise,
+    and on Mosaic's stack the temporaries of one chunk and of one staged
+    copy."""
     mt, mb, ml, mr = margins
     sub = 32 // itemsize
     Ho, Wo = _round_up(max(H, sub), sub), _round_up(max(W, 128), 128)
     planes = bp + sum(halo)
-    words = 2 * bp * Ho * Wo
+    words = (4 if base else 2) * bp * Ho * Wo
     for n_lane, n_sub in staged:
         words += planes * (2 * (mt + Ho + mb) * (ml + Wo + mr)
                            + 2 * (Ho * 128 + sub * Wo)
@@ -813,9 +832,11 @@ def _padded_vmem_bytes3(bp, rows, H, W, itemsize, halo, margins, staged,
     return itemsize * words + _VMEM_SLACK
 
 
-def _padded_block3(D, H, W, itemsize, halo, margins, staged, taps):
+def _padded_block3(D, H, W, itemsize, halo, margins, staged, taps,
+                   base=False):
     """((planes per block, rows staged at once), vmem_limit_bytes) of the
-    rank-3 kernel over a ``(D, H, W)`` array.  The plane's rows in equal
+    rank-3 kernel over a ``(D, H, W)`` array, with an epilogue's ``base``
+    block or without.  The plane's rows in equal
     parts of at most _BLOCK_ROWS3 (``_part``; a shifted copy of n rows
     reads n and the row margins); as many planes as VMEM allows up to
     _BLOCK_PLANES, since every block fetches and stages its halo planes
@@ -827,7 +848,7 @@ def _padded_block3(D, H, W, itemsize, halo, margins, staged, taps):
 
     def need(bp):
         return _padded_vmem_bytes3(bp, rows, H, W, itemsize, halo, margins,
-                                   staged, taps)
+                                   staged, taps, base)
 
     bp = min(_BLOCK_PLANES, D)
     while bp > 1 and need(bp) > cap:
@@ -840,9 +861,11 @@ def _padded_block3(D, H, W, itemsize, halo, margins, staged, taps):
 
 
 def _padded_call3(func, lo, hi, slots, interpret, block, vmem_limit, plan,
-                  arrs):
+                  epilogue, arrs, base):
     """The padded kernel over rank-3 ``arrs``, ``block`` = (planes a
-    block, rows staged at once).
+    block, rows staged at once); with ``epilogue`` = ``(fname, at)`` the
+    update ``base fname s`` (``at`` 0) or ``s fname base`` (``at`` 1) of
+    its result ``s``.
 
     The grid walks blocks of planes.  The leading axis is untiled, so a
     slab holds exactly the block's planes and their halo, each plane
@@ -866,7 +889,12 @@ def _padded_call3(func, lo, hi, slots, interpret, block, vmem_limit, plan,
     an aligned load of the slab or of a staged copy, a plane offset being
     another plane of the same buffer.  Cells whose neighbourhood leaves
     the array on any of the six faces are zeroed by the select: slab cells
-    that no copy wrote hold stale VMEM and are read by no other cell."""
+    that no copy wrote hold stale VMEM and are read by no other cell.
+
+    The epilogue's ``base`` arrives as blocks of the output's own walk,
+    fetched ahead by Pallas, and the store writes ``fname`` of the base
+    and the selected value, in the script's order: every cell the bits
+    of the two passes it replaces, the border's too (``v - +0``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -918,6 +946,12 @@ def _padded_call3(func, lo, hi, slots, interpret, block, vmem_limit, plan,
             operands += [a] * len(specs)
             in_specs += specs
         where.append(idx)
+    if epilogue:
+        base_at = len(operands)
+        operands.append(base)
+        in_specs.append(pl.BlockSpec((bp, Ho, Wo), lambda i: (i, 0, 0),
+                                     memory_space=pltpu.VMEM))
+        update = {"subtract": jnp.subtract, "add": jnp.add}[epilogue[0]]
     n_ops = len(operands)
 
     # blocks whose slab reaches above plane 0 or below the last one
@@ -1089,8 +1123,12 @@ def _padded_call3(func, lo, hi, slots, interpret, block, vmem_limit, plan,
             valid = ((gr >= -lo[1]) & (gr < H - hi[1])
                      & (gc >= -lo[2]) & (gc < W - hi[2])
                      & (g >= top) & (g < D - bottom))
-            out_ref[p, pl.ds(tile(r0 + c0), m), :] = jnp.where(
-                valid, val, jnp.zeros((), dtype))
+            rws = pl.ds(tile(r0 + c0), m)
+            val = jnp.where(valid, val, jnp.zeros((), dtype))
+            if epilogue:
+                b = refs[base_at][p, rws, :]
+                val = update(*((b, val) if epilogue[1] == 0 else (val, b)))
+            out_ref[p, rws, :] = val
 
         staged = any(lanes or subs for lanes, subs in plan)
 

@@ -148,9 +148,10 @@ def _halo_strips(b, ents, counts, los, his):
     return west, east, north, south
 
 
-def run(func, lo, hi, slots, arrs, taps):
+def run(func, lo, hi, slots, arrs, taps, epilogue=None):
     """Evaluate the stencil over the mesh with explicit halo exchange
-    (any rank).  Returns the full-shape result with border cells zeroed."""
+    (any rank).  Returns the full-shape result with border cells zeroed.
+    ``epilogue``: the update that follows it, named on the note."""
     mesh = _mesh.get_mesh()
     x = arrs[0]
     shape = x.shape
@@ -169,7 +170,8 @@ def run(func, lo, hi, slots, arrs, taps):
                          for d in range(nd))
     _registry.note_kernel(
         "stencil", "sharded",
-        operand_copy=len(arrs) if padded_shape != shape else 0)
+        operand_copy=len(arrs) if padded_shape != shape else 0,
+        epilogue=epilogue)
     if padded_shape != shape:
         pads = tuple((0, p - s) for p, s in zip(padded_shape, shape))
         arrs = [jnp.pad(a, pads) for a in arrs]

@@ -39,7 +39,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ramba_tpu import common
-from ramba_tpu.core.expr import Const, Node, defop
+from ramba_tpu.core.expr import OPS, Const, Node, defop
 from ramba_tpu.core.fuser import sync as _sync
 from ramba_tpu.core.ndarray import ndarray
 from ramba_tpu.observe import events as _events
@@ -870,13 +870,18 @@ def _stencil_degrade(frm: str, to: str, e: Exception) -> None:
         )
 
 
-def _eval_stencil(static, *arrs):
+def _eval_stencil(static, *arrs, epilogue=None):
+    """The stencil on the first path that takes it: sharded, Pallas, XLA's
+    shifted slices.  ``epilogue``, ``(fname, at)``: the update that
+    follows the result unfused (``stencil_update``), named on the note
+    of the path taken."""
     func, lo, hi, slots, taps = static
     from ramba_tpu.ops import stencil_sharded
 
     if stencil_sharded.eligible(lo, hi, arrs):
         try:
-            return stencil_sharded.run(func, lo, hi, slots, arrs, taps)
+            return stencil_sharded.run(func, lo, hi, slots, arrs, taps,
+                                       epilogue and epilogue[0])
         except Exception as e:  # trace-time failure: next path, loudly
             _stencil_degrade("sharded", "pallas/xla", e)
     if len(arrs[0].shape) in (2, 3):
@@ -886,10 +891,11 @@ def _eval_stencil(static, *arrs):
         fam = pallas_backend.family("stencil")
         if fam is not None and fam.available(arrs):
             try:
-                return fam.run(func, lo, hi, slots, arrs, taps)
+                return fam.run(func, lo, hi, slots, arrs, taps,
+                               epilogue=epilogue)
             except Exception as e:  # trace-time failure: XLA path, loudly
                 _stencil_degrade("pallas", "xla", e)
-    _registry.note_kernel("stencil", "xla")
+    _registry.note_kernel("stencil", "xla", epilogue=epilogue and epilogue[0])
     shape = arrs[0].shape
     interior = tuple(
         s - (h - l) for s, l, h in zip(shape, lo, hi)
@@ -901,6 +907,32 @@ def _eval_stencil(static, *arrs):
 
 
 defop("stencil")(_eval_stencil)
+
+
+@defop("stencil_update")
+def _eval_stencil_update(static, base, *arrs):
+    """``base - s``, ``base + s`` or ``s + base``, ``s`` the stencil of
+    ``static[1:]`` over ``arrs`` and ``static[0]`` the epilogue ``(fname,
+    at)``, ``at`` the base's place among the two operands
+    (``rewrite.fold_stencil_update``).  Where the one-chip rank-3 Pallas
+    kernel takes the operands, its own store writes the update; elsewhere
+    the stencil as ``_eval_stencil`` evaluates it, then the ``map`` node's
+    own lowering: the program of the script's two nodes."""
+    epilogue, st = static[0], static[1:]
+    func, lo, hi, slots, taps = st
+    from ramba_tpu.ops import pallas_backend, stencil_sharded
+
+    fam = pallas_backend.family("stencil")
+    if (len(arrs[0].shape) == 3 and not stencil_sharded.eligible(lo, hi, arrs)
+            and fam is not None and fam.available(arrs)):
+        try:
+            return fam.run(func, lo, hi, slots, arrs, taps, epilogue=epilogue,
+                           base=base)
+        except Exception as e:  # trace-time failure: unfused, loudly
+            _stencil_degrade("pallas epilogue", "stencil and map", e)
+    s = _eval_stencil(st, *arrs, epilogue=epilogue)
+    fname, at = epilogue
+    return OPS["map"]((fname,), *((base, s) if at == 0 else (s, base)))
 
 
 def _eval_stencil_iter(static, *arrs):

@@ -16,6 +16,8 @@ kernel derives, which is what ships.  The configurations:
     514^3, 258^3  f32   pallas_padded, rank 3   derived, planes x rows
     130^3         f32   pallas_padded and xla   the threshold's two readings
     514^3, 258^3  f32   xla                     what the kernel replaces
+    514^3, 258^3  f32   pallas_padded, b - A x  the update in the kernel's
+                                                store, and after it in XLA
 
 (the padded shapes are what the benchmark's star cells hand the kernel per
 chip: the array's own edge on one chip, the block and its four received
@@ -23,7 +25,11 @@ strips on four; the cubes are levels 9, 8 and 7 of ``mg-C``'s pyramid,
 swept by NPB MG's 27-point operator ``op``: A, 21 taps, or P, 27).  A
 rank-3 candidate is ``[block_planes, rows]``, ``null`` for the derived
 one; a configuration's ``set`` tries another value of one of the kernel's
-own constants (``_CHUNK_VREGS``).  Each worker refuses to run off the TPU, checks from the
+own constants (``_CHUNK_VREGS``); its ``update`` sweeps ``b - A x`` for a
+second operand ``b``: ``subtract``, the kernel's own store writing it
+(the epilogue, its ``block_planes`` sized with ``b``'s block), or
+``unfused``, the kernel's result and then XLA's subtraction, which
+``device_ms`` counts.  Each worker refuses to run off the TPU, checks from the
 kernel's own note that the stencil took the path and the halo its
 configuration names, at the height asked for, and compares one sweep with
 ``skeletons.stencil_interior`` (``equal``: to the bit).  ``to_beat_ms`` is
@@ -101,6 +107,9 @@ slots = (("arr", 0),)
 lo, hi, taps = star2.neighborhood(slots)
 rs = np.random.RandomState(0)
 x = jnp.asarray(rs.rand(*(sn,) * rank), dtype=dtype)
+# b - A x: "subtract" in the kernel's store, "unfused" after it in XLA
+update = cfg.get("update")
+base = jnp.asarray(rs.rand(*(sn,) * rank), dtype=dtype) if update else None
 halos = None
 if cfg.get("halo") == "strips":
     # what four chips hand the kernel: the block and its received strips
@@ -108,13 +117,14 @@ if cfg.get("halo") == "strips":
     halos = [tuple(jnp.asarray(rs.rand(*shp), dtype=dtype) for shp in
                    ((sn, l), (sn, r), (t, l + sn + r), (b, l + sn + r)))]
 
-def reference(y):
+def reference(y, b=None):
     v = skeletons.stencil_interior
     if halos is None:
         inner = tuple(slice(-l, y.shape[d] - h)
                       for d, (l, h) in enumerate(zip(lo, hi)))
-        return jnp.zeros_like(y).at[inner].set(
+        s = jnp.zeros_like(y).at[inner].set(
             v(star2.func, lo, hi, slots, [y]))
+        return s if b is None else b - s
     w, e, n, s = halos[0]
     ext = jnp.concatenate(
         [n, jnp.concatenate([w, y, e], axis=1), s], axis=0)
@@ -134,53 +144,58 @@ def device_ns(tdir):
                             kernel += e.duration_ns
     return every, kernel
 
-def sweep(y, rows):
+def sweep(y, b, rows):
     if want_path == "xla":
-        return reference(y)
+        return reference(y, b)
     planes = None
     if rank == 3 and rows is not None:
         planes, rows = rows
-    return stencil_pallas.run(star2.func, lo, hi, slots, [y], taps,
-                              halos=halos, _block_rows=rows,
-                              _block_planes=planes)
+    fused = update == "subtract"
+    s = stencil_pallas.run(star2.func, lo, hi, slots, [y], taps,
+                           halos=halos, epilogue=("subtract", 0) if fused
+                           else None, base=b if fused else None,
+                           _block_rows=rows, _block_planes=planes)
+    return b - s if update == "unfused" else s
 
 for rows in cfg["rows"]:
     row = {"config": cfg["name"], "block_rows_asked": rows}
     try:
-        def chain(y, rows=rows):
+        def chain(y, b, rows=rows):
             for _ in range(sk):
-                y = sweep(y, rows)
+                y = sweep(y, b, rows)
             return y
         t0 = time.perf_counter()
         with registry.collect_kernel_notes() as notes:
-            run = jax.jit(chain).lower(x).compile()
+            run = jax.jit(chain).lower(x, base).compile()
         row["compile_s"] = time.perf_counter() - t0
         if want_path != "xla":
             assert {n["path"] for n in notes} == {want_path}, notes
             assert not any(n["interpret"] for n in notes), notes
             row.update({k: notes[0][k] for k in
                         ("block_planes", "block_rows", "grid",
-                         "vmem_limit_bytes", "halo", "operand_copy")
-                        if k in notes[0]})
+                         "vmem_limit_bytes", "halo", "operand_copy",
+                         "epilogue") if k in notes[0]})
             asked = rows if rank == 2 or rows is None else rows[1]
             assert asked is None or row.get("block_rows", asked) == asked, row
             assert row.get("halo") == cfg.get("halo"), row
-            got = jax.jit(lambda y, rows=rows: sweep(y, rows))(x)
+            assert row.get("epilogue", "none") == (
+                "subtract" if update == "subtract" else "none"), row
+            got = jax.jit(lambda y, b, rows=rows: sweep(y, b, rows))(x, base)
             diff = jnp.abs(got.astype(jnp.float32)
-                           - jax.jit(reference)(x).astype(jnp.float32))
+                           - jax.jit(reference)(x, base).astype(jnp.float32))
             row["max_abs_diff"] = float(jnp.max(diff))
             row["equal"] = row["max_abs_diff"] == 0.0
             del got, diff
             assert row["max_abs_diff"] < 1e-5 * (taps if rank == 3 else 1), row
-        jax.block_until_ready(run(x))
+        jax.block_until_ready(run(x, base))
         walls = []
         for _ in range(5):
             t0 = time.perf_counter()
-            jax.block_until_ready(run(x))
+            jax.block_until_ready(run(x, base))
             walls.append((time.perf_counter() - t0) / sk * 1e3)
         with tempfile.TemporaryDirectory() as tdir:
             with jax.profiler.trace(tdir):
-                jax.block_until_ready(run(x))
+                jax.block_until_ready(run(x, base))
             every, kernel = device_ns(tdir)
         itemsize = np.dtype(dtype).itemsize
         per = (kernel or every) / sk / 1e9
@@ -188,7 +203,9 @@ for rows in cfg["rows"]:
             "wall_ms": sorted(walls)[2], "device_ms": every / sk / 1e6,
             "kernel_ms": kernel / sk / 1e6,
             "gpoints_per_s": sn ** rank / per / 1e9,
-            "gb_per_s": 2 * sn ** rank * itemsize / per / 1e9,
+            # the kernel's passes: with the update in its store, three
+            "gb_per_s": (3 if update == "subtract" else 2) * sn ** rank
+            * itemsize / per / 1e9,
         })
     except Exception as e:
         lines = f"{type(e).__name__}: {e}".splitlines()
@@ -258,6 +275,22 @@ CONFIGS = [
                         "path": "xla", "rows": [None]}),
     ("cube_514_A_xla", {"n": 514, "rank": 3, "op": "A", "dtype": "float32",
                         "path": "xla", "rows": [None]}),
+    # b - A x (mg-C's resid): the update in the kernel's store, at the
+    # derived block and a plane fewer, against the kernel and XLA's pass
+    ("cube_514_A_sub", {"n": 514, "rank": 3, "op": "A", "dtype": "float32",
+                        "halo": "edge", "path": "pallas_padded",
+                        "update": "subtract", "rows": [None, [3, 264]]}),
+    ("cube_514_A_unfused", {"n": 514, "rank": 3, "op": "A",
+                            "dtype": "float32", "halo": "edge",
+                            "path": "pallas_padded", "update": "unfused",
+                            "rows": [None, [4, 264]]}),
+    ("cube_258_A_sub", {"n": 258, "rank": 3, "op": "A", "dtype": "float32",
+                        "halo": "edge", "path": "pallas_padded",
+                        "update": "subtract", "rows": [None, [11, 264]]}),
+    ("cube_258_A_unfused", {"n": 258, "rank": 3, "op": "A",
+                            "dtype": "float32", "halo": "edge",
+                            "path": "pallas_padded", "update": "unfused",
+                            "rows": [None, [13, 264]]}),
 ]
 ROWS_FILE = os.path.join(REPO, "chiprun_out", "stencil_sweep_rows.jsonl")
 

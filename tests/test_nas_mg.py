@@ -407,6 +407,75 @@ def test_the_finest_level_takes_the_kernel_and_the_counters_say_so(
     np.testing.assert_array_equal(host, nas_mg.comm3(host.copy()))
 
 
+def updates(lt, nit):
+    """``v - A u`` and ``u + S r`` of one solve, the stencil folded into
+    each (``rewrite.rewrite_stencil_update``): per iteration ``resid`` and
+    ``psinv`` at the lt - 1 levels above the coarsest and the closing
+    ``resid``; the first ``resid``."""
+    return (2 * (lt - 1) + 1) * nit + 1
+
+
+def test_mg_cs_updates_by_the_scripts_count():
+    assert updates(9, 20) == 341
+    # on the kernel at 514^3 and 258^3: three an iteration at the finest
+    # and the first resid, two an iteration at 258^3; XLA's the rest
+    fused = 3 * 20 + 1 + 2 * 20
+    assert (fused, updates(9, 20) - fused) == (101, 240)
+    # a folded update is one instruction of the two: 17 an iteration
+    assert 110 - (updates(9, 20) - 1) // 20 == 93
+
+
+def test_the_update_in_the_kernels_store_changes_no_bit(monkeypatch):
+    """A toy solve with the kernel interpreting at its finest level
+    (18^3) and ``rewrite_stencil_update`` on, against the same solve with
+    the rewriter off: the norm and both arrays to the last bit, on the
+    flush that traces and on the one that hits; every firing is written
+    by the kernel's store at the finest level and by XLA's map below."""
+    from ramba_tpu.ops import stencil_pallas, stencil_sharded
+
+    n, nit, lt = 16, 3, 4
+    monkeypatch.setattr(stencil_pallas, "_INTERPRET", True)
+    monkeypatch.setattr(stencil_pallas, "_ENABLED", True)
+    monkeypatch.setattr(stencil_pallas, "_RANK3_MIN_LANES", n + 2)
+    monkeypatch.setattr(stencil_sharded, "eligible", lambda *a, **k: False)
+    monkeypatch.setattr(common, "rewrite_enabled", False)
+    before = diagnostics.counters()
+    prog = program(n, nit)
+    (plain,) = prog.solve()
+    u, r = np.asarray(prog.u), np.asarray(prog.r)
+    assert not moved(before, "rewrite.rewrite_stencil_update")
+
+    monkeypatch.setattr(common, "rewrite_enabled", True)
+    prog = program(n, nit)
+    fused = 3 * nit + 1
+    want = {"rewrite.rewrite_stencil_update": updates(lt, nit),
+            "stencil.epilogue.fused": fused,
+            "stencil.epilogue.unfused": updates(lt, nit) - fused}
+    assert want["stencil.epilogue.unfused"] == 2 * (lt - 2) * nit
+    for cache in ("miss", "hit"):
+        before = diagnostics.counters()
+        (norm,) = prog.solve()
+        span = diagnostics.last_flushes()[-1]
+        assert span["cache"] == cache and span.get("degraded") is None
+        got = {k: moved(before, k) for k in want}
+        assert got == want, cache
+        assert (got["stencil.epilogue.fused"]
+                + got["stencil.epilogue.unfused"] == updates(lt, nit))
+        assert not moved(before, "stencil.degraded")
+        assert norm == plain
+        np.testing.assert_array_equal(np.asarray(prog.u), u)
+        np.testing.assert_array_equal(np.asarray(prog.r), r)
+        if cache == "miss":
+            notes = [k for k in span["kernels"] if k["kernel"] == "stencil"
+                     and k.get("epilogue", "none") != "none"]
+            assert len(notes) == updates(lt, nit)
+            assert {(k["path"], k["epilogue"], k["epilogue_fused"])
+                    for k in notes} == {
+                ("pallas_padded", "subtract", True),
+                ("pallas_padded", "add", True),
+                ("xla", "subtract", False), ("xla", "add", False)}
+
+
 # -- a ghost-layer refresh is one node ----------------------------------------
 def refreshes(lt, nit):
     """``comm3`` calls of one solve: per iteration ``rprj3`` at lt - 1
@@ -427,7 +496,7 @@ def test_mg_cs_refreshes_by_the_scripts_count():
 
 
 @pytest.mark.parametrize("where", ["mesh", "walk"])
-@pytest.mark.parametrize("segment_at", [0, 128], ids=["whole", "segmented"])
+@pytest.mark.parametrize("segment_at", [0, 64], ids=["whole", "segmented"])
 def test_the_refresh_as_one_node_changes_no_bit(segment_at, where,
                                                 monkeypatch, request):
     """A toy solve with ``rewrite_face_copies`` against the same solve
@@ -454,7 +523,8 @@ def test_the_refresh_as_one_node_changes_no_bit(segment_at, where,
     monkeypatch.setattr(common, "rewrite_enabled", True)
     prog = program(n, nit)
     want = {"faces.path.wrap": walks if where == "walk" else 0,
-            "rewrite.rewrite_face_copies": 6 * total}
+            "rewrite.rewrite_face_copies": 6 * total,
+            "rewrite.rewrite_stencil_update": updates(lt, nit)}
     want["faces.path.dus"] = total - want["faces.path.wrap"]
     for cache in ("miss", "hit"):
         before = diagnostics.counters()
@@ -462,8 +532,10 @@ def test_the_refresh_as_one_node_changes_no_bit(segment_at, where,
         span = diagnostics.last_flushes()[-1]
         assert span["cache"] == cache and span.get("degraded") is None
         assert (span["segments"] > 0) == bool(segment_at)
-        # a refresh is one instruction of twelve, a prolongation of 24
-        assert span["instrs"] == instrs - 11 * total - 23 * (lt - 1) * nit
+        # a refresh is one instruction of twelve, a prolongation of 24, an
+        # update one of two
+        assert span["instrs"] == (instrs - 11 * total - 23 * (lt - 1) * nit
+                                  - updates(lt, nit))
         assert {k: moved(before, k) for k in want} == want, cache
         assert norm == plain
         np.testing.assert_array_equal(np.asarray(prog.u), u)

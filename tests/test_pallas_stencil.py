@@ -721,3 +721,101 @@ class TestRank3:
         with pytest.raises(NotImplementedError):
             stencil_pallas.run(st.func, lo, hi, slots, [x], taps,
                                halos=[(x, x, x, x)])
+
+
+# -- rank 3: the update the kernel's own store writes -------------------------
+_UPDATES = {"sub": lambda v, s: v - s, "add": lambda v, s: v + s,
+            "radd": lambda v, s: s + v}
+
+
+class TestEpilogue:
+    """``v - sstencil(A, u)`` and ``u + sstencil(S, r)`` folded into one
+    ``stencil_update`` node against the script's two nodes (rewrites
+    off), bit for bit: where the kernel takes the operand its store
+    writes the update, under 256 lanes XLA's stencil and map do."""
+
+    @pytest.mark.parametrize("which", sorted(_UPDATES))
+    @pytest.mark.parametrize("shape,fused", [
+        ((6, 13, 260), True),     # ragged lanes and rows, one block
+        ((20, 16, 256), True),    # two blocks, the last a short one
+        ((9, 24, 300), True),
+        ((6, 16, 20), False),     # under a lane tile: the XLA fallback
+    ], ids=str)
+    def test_folded_equals_unfolded_bit_for_bit(self, shape, fused, which,
+                                                interpret_mode, monkeypatch):
+        from ramba_tpu import common, diagnostics
+
+        rs = np.random.RandomState(sum(shape))
+        u = rs.standard_normal(shape).astype(np.float32)
+        v = rs.standard_normal(shape).astype(np.float32)
+        # signed zeros on the border cells, where the stencil reads +0
+        v[0] = -0.0
+        v[:, :, -1] = -0.0
+        v[:, 0, ::2] = 0.0
+        st = rt.stencil(_a27)
+        got = {}
+        for rewrite in (False, True):
+            monkeypatch.setattr(common, "rewrite_enabled", rewrite)
+            before = diagnostics.counters()
+            out = _UPDATES[which](rt.fromarray(v),
+                                  rt.sstencil(st, rt.fromarray(u)))
+            assert out.read_expr().op == (
+                "stencil_update" if rewrite else "map")
+            got[rewrite] = np.asarray(out)
+            after = diagnostics.counters()
+            moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
+                "stencil.epilogue.fused", "stencil.epilogue.unfused",
+                "rewrite.rewrite_stencil_update")}
+            assert moved == {
+                "stencil.epilogue.fused": int(rewrite and fused),
+                "stencil.epilogue.unfused": int(rewrite and not fused),
+                "rewrite.rewrite_stencil_update": int(rewrite)}
+        assert got[True].dtype == np.float32
+        np.testing.assert_array_equal(got[True].view(np.uint32),
+                                      got[False].view(np.uint32))
+        # the border is the base's own, -0 included, but where +0 is added
+        face = np.ones(shape, bool)
+        face[1:-1, 1:-1, 1:-1] = False
+        want = v + np.float32(0) if which != "sub" else v
+        np.testing.assert_array_equal(got[True][face].view(np.uint32),
+                                      want[face].view(np.uint32))
+
+    @pytest.mark.parametrize("which", ["sub", "radd"])
+    def test_stale_slab_never_reaches_the_update(self, which):
+        """Blocks of two planes (the last a short one), every scratch
+        buffer NaN to begin with: the base is read where the output is
+        written and nowhere else."""
+        import jax.numpy as jnp
+        from jax.experimental.pallas import tpu as pltpu
+
+        st = rt.stencil(_a27)
+        slots = (("arr", 0),)
+        lo, hi, taps = st.neighborhood(slots)
+        rs = np.random.RandomState(9)
+        shape = (7, 13, 260)
+        u, v = (jnp.asarray(rs.standard_normal(shape), jnp.float32)
+                for _ in range(2))
+        fname, at = {"sub": ("subtract", 0), "radd": ("add", 1)}[which]
+        got = np.asarray(stencil_pallas._run_padded(
+            st.func, lo, hi, slots, [u], taps,
+            pltpu.InterpretParams(uninitialized_memory="nan"), 8, None, 2,
+            (fname, at), v))
+        s = np.asarray(stencil_pallas._run_padded(
+            st.func, lo, hi, slots, [u], taps, True, 8, None, 2))
+        want = _UPDATES[which](np.asarray(v), s)
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      want.view(np.uint32))
+
+    def test_the_base_block_is_counted_in_vmem(self):
+        """At 514^3 the base's double-buffered block costs a plane of the
+        block: five planes without it, four with it (258^3: 14, 12)."""
+        got = {}
+        for n in (514, 258):
+            for base in (False, True):
+                (bp, _), limit = stencil_pallas._padded_block3(
+                    n, n, n, 4, (1, 1), (8, 8, 128, 128), [(2, 6)], 27,
+                    base)
+                assert limit <= stencil_pallas._vmem_cap()
+                got[n, base] = bp
+        assert got == {(514, False): 5, (514, True): 4,
+                       (258, False): 14, (258, True): 12}

@@ -259,6 +259,43 @@ def test_the_rank_3_kernel_compiles_at_the_cells_sizes(one_chip, n, weights):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+@pytest.mark.parametrize("n,weights,epilogue,planes", [
+    (514, (-8 / 3, 0.0, 1 / 6, 1 / 12), ("subtract", 0), 4),   # resid
+    (514, (-3 / 17, 1 / 33, -1 / 61, 0.0), ("add", 0), 4),     # psinv
+    (258, (-8 / 3, 0.0, 1 / 6, 1 / 12), ("subtract", 0), 12),
+    (258, (-3 / 17, 1 / 33, -1 / 61, 0.0), ("add", 1), 12),
+], ids=["A-514", "S-514", "A-258", "S-258"])
+def test_the_update_compiles_into_the_kernels_store(one_chip, n, weights,
+                                                    epilogue, planes):
+    """``v - A u`` and ``u + S r`` at mg-C's two finest levels: the base a
+    block of the output's walk, sized into the VMEM the kernel asks for (a
+    plane fewer at 514^3, two at 258^3), and the program is the custom
+    call alone: no subtraction or addition of the operand's size beside
+    it."""
+    import ramba_tpu as rt
+    from benchmark.programs import nas_mg
+    from ramba_tpu.observe import registry
+    from ramba_tpu.ops import stencil_pallas
+
+    st = nas_mg.stencil27(rt, weights)
+    slots = (("arr", 0),)
+    lo, hi, taps = st.neighborhood(slots)
+    x = jax.ShapeDtypeStruct((n, n, n), jnp.float32, sharding=one_chip)
+    with registry.collect_kernel_notes() as notes, jax.enable_x64(False):
+        compiled = jax.jit(lambda a, b: stencil_pallas._run_padded(
+            st.func, lo, hi, slots, [a], taps, False, epilogue=epilogue,
+            base=b)).lower(x, x).compile()
+    (note,) = notes
+    assert note["path"] == "pallas_padded" and not note["interpret"]
+    assert (note["epilogue"], note["epilogue_fused"]) == (epilogue[0], True)
+    assert note["block_planes"] == planes
+    assert note["vmem_limit_bytes"] <= stencil_pallas._vmem_cap()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " fusion(" not in text and " subtract(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 # -- a ghost-layer refresh in place -------------------------------------------
 @pytest.mark.parametrize("n", [514, 258, 130, 66, 34, 18, 10])
 def test_a_ghost_layer_refresh_compiles_in_place(one_chip, n, monkeypatch):
