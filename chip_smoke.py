@@ -753,7 +753,10 @@ def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
     residual, twice over): ONE flush on the fused rung, the second time a
     hit; one too long for one program (a V-cycle at 512^3 is 110
     instructions, a program at most 768) runs as chained segments, the
-    second time from the executables of the first.
+    second time from the executables of the first.  The second solve's
+    DAG is the first's served from the lazy layer's memos: its indexes
+    from ``ndarray._index_memo`` (``dag.index.hit``), its inferences by
+    keys found unwalked (``dag.key.hit``), each at least 95 %.
     ``want`` is the norm to meet; the NumPy reference gives it where it is
     not given (toy sizes: at 512^3 it takes minutes)."""
     from benchmark.programs import nas_mg
@@ -784,6 +787,12 @@ def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
                  f"second solve: {calls} segment calls, {hits} hits")
         _require([f["cache"] for f in r2.flushes] == ["hit"],
                  f"second solve: {[f['cache'] for f in r2.flushes]}")
+        c = r2.counters
+        index_hits = c.get("dag.index.hit", 0) / max(c.get("dag.index.n", 0), 1)
+        key_hits = c.get("dag.key.hit", 0) / max(c.get("dag.infer.hit", 0), 1)
+        _require(index_hits >= 0.95 and key_hits >= 0.95,
+                 f"second solve: {index_hits:.3f} of the indexes and "
+                 f"{key_hits:.3f} of the inference hits from the memos")
         paths = sorted(k[len("stencil.path."):] for k, v in
                        r2.counters.items()
                        if k.startswith("stencil.path.") and v > 0)
@@ -810,6 +819,8 @@ def phase_mg(rt, n, want=None, iters=1, interpret_ok=False):
             "instrs": r2.flushes[0]["instrs"], "segments": calls,
             "segment_hits_second": hits,
             "segment_misses_first": r1.counters.get("fuser.segment.miss", 0),
+            "index_hit_share_second": index_hits,
+            "key_hit_share_second": key_hits,
             "first_s": first, "second_s": second}
 
 

@@ -38,16 +38,18 @@ from ramba_tpu.observe import registry as _registry
 # the registry folds in when it is read (``registry.add_source``).
 # ``dag.node.n`` nodes, ``dag.node.ns`` the time in their constructor,
 # which is ``infer_aval`` whole; ``dag.infer.hit`` the inferences served
-# from a memo or from ``Scalar``'s table.  The time adds up in float
-# seconds: two float operations a node cost less than two on integers
-# past 2**30.
-_node_n = _infer_hit = 0
+# from a memo or from ``Scalar``'s table, and ``dag.key.hit`` those of
+# them whose key was found by one lookup, no static walked (``Scalar``'s
+# key holds no static).  The time adds up in float seconds: two float
+# operations a node cost less than two on integers past 2**30.
+_node_n = _infer_hit = _key_hit = 0
 _node_s = 0.0
 _now = time.perf_counter
 
 _registry.add_source(lambda: {"dag.node.n": _node_n,
                               "dag.node.ns": int(_node_s * 1e9),
-                              "dag.infer.hit": _infer_hit})
+                              "dag.infer.hit": _infer_hit,
+                              "dag.key.hit": _key_hit})
 
 
 class Expr:
@@ -101,7 +103,7 @@ class Scalar(Expr):
     __slots__ = ("value",)
 
     def __init__(self, value):
-        global _infer_hit
+        global _infer_hit, _key_hit
         self.value = value
         key = _scalar_key(value)
         aval = _scalar_avals.get(key) if key is not None else None
@@ -112,6 +114,7 @@ class Scalar(Expr):
                 _scalar_avals[key] = aval
         else:
             _infer_hit += 1
+            _key_hit += 1
         self.aval = aval
 
 
@@ -177,7 +180,7 @@ def _scalar_key(value):
 # Value-keyed entries: (op, static, arg avals, fingerprint) -> aval.
 _aval_memo: dict = {}
 # Function-keyed entries: first function of the static (weakly) ->
-# {(op, static with a weak reference in each function's place, arg avals,
+# {(op, static with a ``_FnRef`` in each function's place, arg avals,
 # fingerprint): aval}.  An inner key holds no function, so an entry dies
 # with its first function; a later function at a recycled address is
 # another key (a dead reference equals only itself).
@@ -192,10 +195,25 @@ class _NoKey(Exception):
     """The static holds an object the memo may neither key on nor keep."""
 
 
+class _FnRef(weakref.ref):
+    """A function's place in a memo key: a weak reference that also
+    equals the live function it refers to, so that a static holding the
+    function finds its entry as it stands, unwalked.  Between references
+    ``weakref.ref``'s equality holds: a dead one equals only itself."""
+
+    __slots__ = ()
+    __hash__ = weakref.ref.__hash__
+
+    def __eq__(self, other):
+        if type(other) is types.FunctionType:
+            return self() is other
+        return weakref.ref.__eq__(self, other)
+
+
 def _static_key(x, funcs: list):
     """``x`` as a memo key component: value-hashable members as they are,
-    each Python function replaced by a weak reference to it and appended
-    to ``funcs``.  Raises ``_NoKey`` for anything else: an identity-hashed
+    each Python function replaced by a ``_FnRef`` to it and appended to
+    ``funcs``.  Raises ``_NoKey`` for anything else: an identity-hashed
     object (a ``_Lit``, a callable instance) can never hit and would be
     pinned by its own key."""
     if isinstance(x, _MEMO_SAFE_TYPES):
@@ -204,7 +222,7 @@ def _static_key(x, funcs: list):
         return tuple([_static_key(e, funcs) for e in x])
     if type(x) is types.FunctionType:
         funcs.append(x)
-        return weakref.ref(x)
+        return _FnRef(x)
     if isinstance(x, frozenset) and all(
             isinstance(e, _MEMO_SAFE_TYPES) for e in x):
         return x
@@ -233,17 +251,43 @@ def infer_aval(op: str, static: tuple, arg_avals: Sequence[Any]) -> Any:
     the old aval, exactly as the compile cache already serves it the old
     executable (``program.key`` hashes the function by identity too).
 
-    A hit counts ``dag.infer.hit``; a miss ``dag.infer.n`` and its time
-    ``dag.infer.ns``.  Hit or miss, the caller's ``dag.node.ns`` holds the
-    whole of it: the key, the lookup and the evaluation."""
-    global _infer_hit
+    A static seen before is looked up as it stands, in one lookup: a
+    key holds a value-hashable static's members as they are, and a
+    function's ``_FnRef`` equals the live function.  Where the static's
+    first member is a function (a skeleton's kernel) the entry is sought
+    under it, elsewhere among the value-keyed entries; no stored key holds
+    an identity-hashed member, so a static with one finds nothing there.
+    Only where that misses is the static walked (``_static_key``) into the
+    key that is stored, under the first function the walk meets.  So a
+    static whose first function is not its first member is stored where
+    the unwalked lookup does not seek it: each of its hits is found by the
+    walk (``dag.infer.hit`` without ``dag.key.hit``).
+
+    A hit counts ``dag.infer.hit``, and ``dag.key.hit`` too where it was
+    found unwalked; a miss ``dag.infer.n`` and its time ``dag.infer.ns``.
+    Hit or miss, the caller's ``dag.node.ns`` holds the whole of it: the
+    key, the lookup and the evaluation."""
+    global _infer_hit, _key_hit
+    avals = tuple([
+        (tuple(a.shape), a.dtype, bool(getattr(a, "weak_type", False)))
+        for a in arg_avals])
+    fp = semantic_fingerprint()
+    first = static[0] if type(static) is tuple and static else None
+    memo = (_fn_aval_memo.get(first) if type(first) is types.FunctionType
+            else _aval_memo)
+    if memo:
+        try:
+            hit = memo.get((op, static, avals, fp))
+        except TypeError:  # a member that does not hash
+            hit = None
+        if hit is not None:
+            _infer_hit += 1
+            _key_hit += 1
+            return hit
     fn = OPS[op]
     funcs: list = []
     try:
-        key = (op, _static_key(static, funcs), tuple(
-            (tuple(a.shape), a.dtype, bool(getattr(a, "weak_type", False)))
-            for a in arg_avals
-        ), semantic_fingerprint())
+        key = (op, _static_key(static, funcs), avals, fp)
         memo = _fn_aval_memo.setdefault(funcs[0], {}) if funcs else _aval_memo
         hit = memo.get(key)
     except (TypeError, _NoKey):  # TypeError: a member that does not hash

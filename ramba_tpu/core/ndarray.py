@@ -778,23 +778,77 @@ def expand_ellipsis(idx: tuple, ndim: int) -> tuple:
 
 
 # Index lowering, counted where every ``__getitem__`` and ``__setitem__``
-# passes: ``dag.index.n`` and ``dag.index.ns``, plain integers that the
+# passes: ``dag.index.n`` and ``dag.index.ns``, and ``dag.index.hit`` the
+# classifications served from ``_index_memo``; plain integers that the
 # registry folds in when it is read (as expr.py's per-node counts, the
 # time in float seconds).
-_index_n = 0
+_index_n = _index_hit = 0
 _index_s = 0.0
 _now = time.perf_counter
 
 _registry.add_source(lambda: {"dag.index.n": _index_n,
-                              "dag.index.ns": int(_index_s * 1e9)})
+                              "dag.index.ns": int(_index_s * 1e9),
+                              "dag.index.hit": _index_hit})
+
+# A basic index classified before -> the ("basic", enc) it gave, the same
+# ``enc`` object on every hit: a node's static holding it then meets the
+# inference memo's stored key with the very tuples it was stored under,
+# and their comparison ends at each member's identity (the static is
+# still hashed whole, by value: ``expr.infer_aval``).  Cleared when it
+# holds ``_INDEX_MEMO_MAX`` entries, as that memo is.
+_index_memo: dict = {}
+_INDEX_MEMO_MAX = 8192
+# What a slice member or an item of a basic index may be: ``None`` and
+# the integers (``bool`` too), by exact type
+_INDEX_INT_TYPES = frozenset({type(None), int, bool, *(
+    t for t in set(np.sctypeDict.values()) if issubclass(t, np.integer))})
+_INDEX_ITEM_TYPES = _INDEX_INT_TYPES | {type(Ellipsis)}
+
+
+def _index_key(idx, shape):
+    """``_index_memo``'s key of a basic index: the shape, then each item
+    with its type, a slice as its members with their types, so that
+    ``1``, ``True`` and ``np.int64(1)`` are different keys; None for any
+    other index (an array, a list, a mask, a float anywhere).  A slice
+    is the marker ``slice`` (which no item equals), its three members and
+    their three types, any other item the item and its type, so the flat
+    key reads back one way.  The key holds no slice object: a slice
+    hashes only from Python 3.12 on."""
+    key = [shape]
+    for it in (idx if type(idx) is tuple else (idx,)):
+        t = type(it)
+        if t is slice:
+            a, b, c = it.start, it.stop, it.step
+            ta, tb, tc = type(a), type(b), type(c)
+            if not (ta in _INDEX_INT_TYPES and tb in _INDEX_INT_TYPES
+                    and tc in _INDEX_INT_TYPES):
+                return None
+            key += (slice, a, b, c, ta, tb, tc)
+        elif t in _INDEX_ITEM_TYPES:
+            key += (it, t)
+        else:
+            return None
+    return tuple(key)
 
 
 def _classify_index(idx, shape):
-    """:func:`_classify` under the index counters."""
-    global _index_n, _index_s
+    """:func:`_classify` under the index counters, a basic index served
+    from ``_index_memo`` once it has been classified.  Only a success is
+    kept: an index out of bounds raises on every call."""
+    global _index_n, _index_s, _index_hit
     t0 = _now()
     try:
-        return _classify(idx, shape)
+        key = _index_key(idx, shape)
+        got = _index_memo.get(key) if key is not None else None
+        if got is not None:
+            _index_hit += 1
+            return got
+        got = _classify(idx, shape)
+        if key is not None and got[0] == "basic":
+            if len(_index_memo) >= _INDEX_MEMO_MAX:
+                _index_memo.clear()
+            _index_memo[key] = got
+        return got
     finally:
         _index_n += 1
         _index_s += _now() - t0

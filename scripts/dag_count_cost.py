@@ -10,19 +10,22 @@ flushing it, and drops it.  Four variants of ``expr.Node.__init__``,
 source so that they cannot drift:
 
 in      as they stand: ``dag.node.n``, ``dag.node.ns``, ``dag.infer.hit``,
-        ``dag.index.n``, ``dag.index.ns`` on plain module integers
-node    the three counts of a node in, the index's two out
+        ``dag.key.hit``, ``dag.index.n``, ``dag.index.ns``,
+        ``dag.index.hit`` on plain module integers
+node    the counts of a node in, the index's three out
 out     the counting statements and their clock reads removed
 locked  ``out``, with the one locked ``registry.inc("dag.infer.hit")`` a
         hit of the memo paid before the counts left the lock
 
 The variants take turns, the collector is off while a build is timed
 (its pauses are ``host.gc.*``'s to count, not this script's), and each
-reads the median of its builds: microseconds a node.  The difference of
-two builds of 100 ms stands inside the host's noise (a quarter of a
-microsecond a node), so ``statements`` reads the same statements alone, a
-million times in a loop: a node's three counts against one locked
-increment.  A host timing: never a device number.
+reads the median of its builds: microseconds a node.  ``node_us`` and
+``index_us`` are what ``in``'s counters read: the time in a node's
+constructor and in an index's classification, each counted once.  The
+difference of two builds of 100 ms stands inside the host's noise (a
+quarter of a microsecond a node), so ``statements`` reads the same
+statements alone, a million times in a loop: a node's three counts
+against one locked increment.  A host timing: never a device number.
 """
 
 from __future__ import annotations
@@ -51,9 +54,16 @@ from ramba_tpu.observe import registry  # noqa: E402
 #: hit: what ``out`` removes ({statement: replacement, None to drop it})
 NODE_OUT = dict.fromkeys(("global _node_n, _node_s", "t0 = _now()",
                           "_node_n += 1", "_node_s += _now() - t0"))
-HIT_OUT = dict.fromkeys(("global _infer_hit", "_infer_hit += 1"))
+HIT_OUT = dict.fromkeys(("global _infer_hit, _key_hit", "_infer_hit += 1",
+                         "_key_hit += 1"))
 HIT_LOCKED = dict(HIT_OUT,
                   **{"_infer_hit += 1": '_registry.inc("dag.infer.hit")'})
+#: the counting statements of ``_classify_index`` (its ``finally`` keeps
+#: a ``pass``)
+INDEX_OUT = dict(dict.fromkeys(("global _index_n, _index_s, _index_hit",
+                                "t0 = _now()", "_index_hit += 1",
+                                "_index_s += _now() - t0")),
+                 **{"_index_n += 1": "pass"})
 
 
 def variant(fn, swap):
@@ -113,13 +123,14 @@ def statements(number=1_000_000):
 
 
 def variants():
-    node, infer = expr.Node.__init__, expr.infer_aval
-    bare = variant(node, NODE_OUT)
+    node, infer, index = expr.Node.__init__, expr.infer_aval, \
+        nd._classify_index
+    bare, index_bare = variant(node, NODE_OUT), variant(index, INDEX_OUT)
     return {
-        "in": (node, infer, nd._classify_index),
-        "node": (node, infer, nd._classify),
-        "out": (bare, variant(infer, HIT_OUT), nd._classify),
-        "locked": (bare, variant(infer, HIT_LOCKED), nd._classify),
+        "in": (node, infer, index),
+        "node": (node, infer, index_bare),
+        "out": (bare, variant(infer, HIT_OUT), index_bare),
+        "locked": (bare, variant(infer, HIT_LOCKED), index_bare),
     }
 
 
@@ -176,18 +187,24 @@ def main(argv):
                 if rep:
                     times[kind].append(dt)
                     counted[kind] = {
-                        k: c1[k] - c0.get(k, 0) for k in
-                        ("dag.node.n", "dag.index.n", "dag.infer.hit",
-                         "dag.infer.n")}
+                        k: c1.get(k, 0) - c0.get(k, 0) for k in
+                        ("dag.node.n", "dag.node.ns", "dag.index.n",
+                         "dag.index.ns", "dag.index.hit", "dag.infer.hit",
+                         "dag.key.hit", "dag.infer.n")}
     finally:
         install(kinds["in"])
         gc.enable()
     nodes = counted["in"]["dag.node.n"]
+    indexes = counted["in"]["dag.index.n"]
     med = {k: statistics.median(v) for k, v in times.items()}
     out = {
         "n": n, "repeats": repeats, "nodes": nodes,
-        "indexes": counted["in"]["dag.index.n"],
+        "indexes": indexes,
         "counted": counted,
+        # in's counters: the constructor's time a node, the
+        # classification's an index (the last round's build)
+        "node_us": 1e-3 * counted["in"]["dag.node.ns"] / nodes,
+        "index_us": 1e-3 * counted["in"]["dag.index.ns"] / indexes,
         "build_ms": {k: 1e3 * v for k, v in med.items()},
         "build_ms_all": {k: [round(1e3 * x, 3) for x in v]
                          for k, v in times.items()},
@@ -199,7 +216,7 @@ def main(argv):
         "locked_hit_us_per_node": 1e6 * (med["locked"] - med["out"]) / nodes,
         # dag.index.n and dag.index.ns, an index
         "index_counting_us_per_index":
-            1e6 * (med["in"] - med["node"]) / counted["in"]["dag.index.n"],
+            1e6 * (med["in"] - med["node"]) / indexes,
         "statements": statements(),
     }
     print(json.dumps(out, indent=1))

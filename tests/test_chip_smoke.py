@@ -158,6 +158,9 @@ def test_mg_phase(interpreting):
     assert facts["segments"] >= 2
     assert facts["segment_hits_second"] == facts["segments"]
     assert abs(facts["norm"] - facts["want"]) < 1e-4 * facts["want"]
+    # the second solve's DAG is the first's, every index and key a hit
+    assert facts["index_hit_share_second"] == 1.0
+    assert facts["key_hit_share_second"] == 1.0
 
 
 def test_axpy_phase(interpreting):
