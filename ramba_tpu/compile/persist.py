@@ -56,8 +56,7 @@ stats = {
     "hits": 0,
     "misses": 0,
     # hits whose entry was written by a DIFFERENT process (the writer
-    # identity rides the payload) — the cross-replica warm-start signal
-    # the fleet suite leg asserts on (fleet/artifacts.py)
+    # identity rides the payload): a warm start from another process
     "cross_hits": 0,
     "corrupt": 0,
     "stores": 0,
@@ -277,7 +276,7 @@ def lookup(fp: str, leaf_vals: Sequence, program, donate_key):
         if not isinstance(e, _integrity.IntegrityError):
             # unwrap already classified digest failures; anything that
             # passed the digest but failed to deserialize is its own
-            # integrity incident (fleet health must see corruption)
+            # integrity incident (the suspect window must count it)
             _integrity.failure("aot:blob", "deserialize",
                                detail=repr(e)[:200], fp=fp)
         try:

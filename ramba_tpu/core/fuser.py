@@ -65,7 +65,6 @@ from ramba_tpu.core.expr import (Const, Expr, Node, Scalar, OPS,
                                  semantic_fingerprint as _semantic_fingerprint)
 from ramba_tpu.observe import attrib as _attrib
 from ramba_tpu.observe import events as _events
-from ramba_tpu.observe import fleet as _fleet
 from ramba_tpu.observe import ledger as _ledger
 from ramba_tpu.observe import observer as _observer
 from ramba_tpu.observe import profile as _profile
@@ -1699,7 +1698,7 @@ class _FlushWork:
                  "label", "fingerprint", "skip_fused", "pins", "flight",
                  "t_flush", "detached", "enqueued_at", "memo_plan",
                  "memo_hit", "deadline", "is_abandoned", "class_plan",
-                 "plan_cert", "plan_cache")
+                 "plan_cert")
 
     def __init__(self, stream, roots, extra_n):
         self.stream = stream
@@ -1732,10 +1731,8 @@ class _FlushWork:
         # shape-bucket compile class (compile/classes.py); None = exact
         self.class_plan = None
         # plan-certificate cache (core/plancache.py): the certificate
-        # this flush ran under (redeemed or newly minted), and the hit
-        # tier ("hit" | "shared") — None on the miss/disabled path
+        # this flush ran under (redeemed or newly minted)
         self.plan_cert = None
-        self.plan_cache = None
 
 
 def _gather_leaf_vals(leaves):
@@ -1899,7 +1896,7 @@ def _quarantine(work: "_FlushWork", e: Exception) -> None:
     if work.stream.tenant is not None:
         ev["tenant"] = work.stream.tenant
     # Under coherent recovery the error that reached quarantine was
-    # fleet-agreed (ladder terminal decisions are agreement rounds), so
+    # agreed by every rank (ladder terminal decisions are agreement rounds), so
     # every rank quarantines the same program on the same epoch; stamping
     # the epoch lets merge-ranks pair the quarantines without guessing.
     epoch = _coherence.last_epoch("flush:rung")
@@ -2022,7 +2019,6 @@ def _flush_prepare_body(stream: FlushStream, roots: list,
         span["mem_live_bytes"] = _memory.ledger.live_bytes
         _profile.ensure_started()
         _telemetry.ensure_started()
-        _fleet.ensure_started()
         # In-flight leaves are never spill candidates: admission-
         # triggered (or oom-triggered) eviction during THIS flush must
         # not pull a buffer the program is about to read.
@@ -2080,7 +2076,7 @@ def _flush_prepare_body(stream: FlushStream, roots: list,
                     fingerprint=work.fingerprint,
                     compile_class=(class_plan.token
                                    if class_plan is not None else None))
-                pev["plan_cache"] = plan_hit.tier
+                pev["plan_cache"] = "hit"
                 if cert.chash is not None:
                     pev["chash"] = cert.chash
                 if "trace_id" in span:
@@ -2088,7 +2084,7 @@ def _flush_prepare_body(stream: FlushStream, roots: list,
                     pev.setdefault("parent_span", span["span_id"])
                 _events.emit(pev)
             # The memo plan is rebuilt, not re-certified: only the input
-            # version tokens and shared content key are live state.
+            # version tokens are live state.
             work.memo_plan = None
             if cert.memo_ok:
                 try:
@@ -2098,8 +2094,7 @@ def _flush_prepare_body(stream: FlushStream, roots: list,
                 except Exception:
                     work.memo_plan = None
             work.plan_cert = cert
-            work.plan_cache = plan_hit.tier
-            span["plan_cache"] = plan_hit.tier
+            span["plan_cache"] = "hit"
             if cert.chash is not None:
                 span["chash"] = cert.chash
             if cert.finding_counts:

@@ -100,12 +100,6 @@ class MemoPlan:
     leaf_order: Tuple[int, ...]
     key: Optional[Tuple[Any, ...]]
     effects: Optional[_effects.EffectReport]
-    #: content-addressed key for the fleet's shared memo tier
-    #: (``fleet/artifacts.py``) — unlike ``key``, which binds inputs by
-    #: buffer identity, this binds them by bytes and so survives a
-    #: process boundary.  None when the tier is disarmed, the process is
-    #: multi-controller, or the inputs exceed the shared-lane byte cap.
-    shared_key: Optional[str] = None
 
 
 # ---------------------------------------------------------------------------
@@ -274,25 +268,6 @@ def _release_entry(e: _Entry) -> None:
 cache = ResultCache()
 
 
-def _shared_tier():
-    """``fleet.artifacts`` when the cross-process shared memo lane is
-    armed for THIS process, else None.  Cheap env probe first so the
-    common (disarmed) case costs one dict lookup; single-controller
-    only — under SPMD a rank serving a shared-tier hit while its peers
-    execute would desync the collective schedule."""
-    if not os.environ.get("RAMBA_ARTIFACTS"):
-        return None
-    if _events._rank_info()[1] != 1:
-        return None
-    try:
-        from ramba_tpu.fleet import artifacts as _artifacts
-    except Exception:  # noqa: BLE001 — the tier must never break memo
-        return None
-    if not _artifacts.memo_shared_enabled():
-        return None
-    return _artifacts
-
-
 def evict(plan: Optional["MemoPlan"]) -> bool:
     """Drop one plan's cached entry (and its census refs).  The
     integrity plane calls this when a shadow audit disagreed with the
@@ -354,7 +329,6 @@ def plan_for(program: Any, donate_key: Tuple[int, ...], leaves: List[Any],
         _registry.inc("memo.not_canonical")
         return None
     tokens: List[Any] = []
-    parts: List[Any] = []  # content-hashable form, canonical leaf order
     for slot in form.leaf_order:
         leaf = leaves[slot]
         if isinstance(leaf, Scalar):
@@ -364,21 +338,15 @@ def plan_for(program: Any, donate_key: Tuple[int, ...], leaves: List[Any],
                 hash(tokens[-1])
             except TypeError:
                 return None
-            parts.append(tokens[-1])
         else:
             tok = value_token(leaf_vals[slot])
             if tok is None:
                 return None
             tokens.append(tok)
-            parts.append(leaf_vals[slot])
     from ramba_tpu.core import fuser as _fuser
 
     fingerprint = _fuser._semantic_fingerprint()
     key = (form.chash, tuple(tokens), fingerprint)
-    shared_key = None
-    tier = _shared_tier()
-    if tier is not None and rep.memoizable:
-        shared_key = tier.content_key(form.chash, parts, fingerprint)
     return MemoPlan(
         memoizable=True,
         certified=rep.memoizable,
@@ -388,7 +356,6 @@ def plan_for(program: Any, donate_key: Tuple[int, ...], leaves: List[Any],
         leaf_order=form.leaf_order,
         key=key,
         effects=rep,
-        shared_key=shared_key,
     )
 
 
@@ -401,16 +368,15 @@ def plan_from_cert(chash: Optional[str], form: Optional[str],
     (``analyze/plancert.py``) without re-running effect classification
     or canonicalization — the certificate already vouches for both, and
     its invalidation signature proves the verdicts still hold.  Only the
-    live state binds per flush: the per-input version tokens and the
-    shared-tier content key.  Returns None when memoization is disarmed,
-    the certificate carried no canonical hash, or an input cannot be
-    version-tracked (same bail-outs as :func:`plan_for`)."""
+    live state binds per flush: the per-input version tokens.  Returns
+    None when memoization is disarmed, the certificate carried no
+    canonical hash, or an input cannot be version-tracked (same
+    bail-outs as :func:`plan_for`)."""
     if not enabled() or chash is None:
         return None
     from ramba_tpu.core.expr import Scalar
 
     tokens: List[Any] = []
-    parts: List[Any] = []  # content-hashable form, canonical leaf order
     for slot in leaf_order:
         if slot >= len(leaves):
             return None
@@ -422,21 +388,15 @@ def plan_from_cert(chash: Optional[str], form: Optional[str],
                 hash(tokens[-1])
             except TypeError:
                 return None
-            parts.append(tokens[-1])
         else:
             tok = value_token(leaf_vals[slot])
             if tok is None:
                 return None
             tokens.append(tok)
-            parts.append(leaf_vals[slot])
     from ramba_tpu.core import fuser as _fuser
 
     fingerprint = _fuser._semantic_fingerprint()
     key = (chash, tuple(tokens), fingerprint)
-    shared_key = None
-    tier = _shared_tier()
-    if tier is not None:
-        shared_key = tier.content_key(chash, parts, fingerprint)
     return MemoPlan(
         memoizable=True,
         certified=True,
@@ -446,7 +406,6 @@ def plan_from_cert(chash: Optional[str], form: Optional[str],
         leaf_order=tuple(leaf_order),
         key=key,
         effects=effects,
-        shared_key=shared_key,
     )
 
 
@@ -457,39 +416,11 @@ def lookup(plan: Optional[MemoPlan]) -> Optional[List[Any]]:
         return None
     vals = cache.lookup(plan.key)
     if vals is None:
-        vals = _shared_lookup(plan)
-        if vals is None:
-            _registry.inc("memo.miss")
-            return None
-        return vals
+        _registry.inc("memo.miss")
+        return None
     _registry.inc("memo.hit")
     _events.emit({
         "type": "memo_hit", "chash": plan.chash, "n_outs": len(vals),
-    })
-    return vals
-
-
-def _shared_lookup(plan: MemoPlan) -> Optional[List[Any]]:
-    """Probe the fleet's shared memo tier on a local miss.  A hit is
-    promoted into the local cache (Const-wrapped, census-registered)
-    so the next lookup never touches disk."""
-    if plan.shared_key is None:
-        return None
-    tier = _shared_tier()
-    if tier is None:
-        return None
-    arrays = tier.memo_load(plan.shared_key)
-    if arrays is None:
-        return None
-    import jax.numpy as jnp
-
-    vals: List[Any] = [jnp.asarray(a) for a in arrays]
-    cache.insert(plan.key, vals)
-    _registry.inc("memo.hit")
-    _registry.inc("memo.shared_hit")
-    _events.emit({
-        "type": "memo_hit", "chash": plan.chash, "n_outs": len(vals),
-        "tier": "shared",
     })
     return vals
 
@@ -514,10 +445,4 @@ def insert(plan: Optional[MemoPlan], outs: List[Any]) -> bool:
             return False
     cache.insert(plan.key, list(outs))
     _registry.inc("memo.insert")
-    if plan.shared_key is not None and plan.certified:
-        tier = _shared_tier()
-        if tier is not None:
-            # best-effort fleet publish: one replica's result becomes
-            # every replica's shared-tier hit
-            tier.memo_store(plan.shared_key, outs)
     return True

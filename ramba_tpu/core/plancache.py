@@ -29,12 +29,6 @@ Design points:
   rejection path (raise) and warn mode's silent re-analysis are testable
   end-to-end.
 
-* **Shared tier.**  Certified verdicts are portable by chash: with the
-  fleet artifact tier armed (PR 17), ``publish`` writes a JSON blob to
-  ``<artifacts>/plancert/<chash>.json`` and a local miss may adopt a
-  peer replica's certificate (paying only canonicalization), so one
-  replica's analysis warms the fleet.
-
 * **Batched coherence.**  Multi-controller ranks agree on hit counts via
   one ``agree()`` round per RAMBA_PLANCERT_AGREE hits (default 16), not
   per flush; a divergent round clears the local cache so ranks
@@ -45,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import json
 import os
 import threading
 from collections import OrderedDict
@@ -57,7 +50,6 @@ from ramba_tpu.observe import events as _events
 from ramba_tpu.observe import registry as _registry
 from ramba_tpu.resilience import coherence as _coherence
 from ramba_tpu.resilience import faults as _faults
-from ramba_tpu.resilience import integrity as _integrity
 
 _OFF = ("", "0", "off", "false", "no")
 
@@ -164,13 +156,11 @@ def _forgery_armed() -> bool:
 
 @dataclasses.dataclass(frozen=True)
 class Hit:
-    """One redeemed certificate.  ``tier`` is ``"hit"`` (local) or
-    ``"shared"`` (adopted from the fleet artifact tier).  ``forged``
-    marks a ``plan:stale`` fault-forged staleness verdict — the fuser
-    rejects it under strict and silently re-analyzes under warn."""
+    """One redeemed certificate.  ``forged`` marks a ``plan:stale``
+    fault-forged staleness verdict — the fuser rejects it under strict
+    and silently re-analyzes under warn."""
 
     cert: _plancert.PlanCertificate
-    tier: str
     forged: bool
     causes: Tuple[str, ...]
 
@@ -224,7 +214,6 @@ def lookup(program: Any, leaf_vals: Sequence[Any],
     key = _key(program, leaf_vals, donate_key)
     if key is None:
         return None
-    tier = "hit"
     try:
         with _lock:
             _bump("lookups")
@@ -233,9 +222,6 @@ def lookup(program: Any, leaf_vals: Sequence[Any],
                 _store.move_to_end(key)
     except TypeError:       # unhashable program key — never certifiable
         return None
-    if entry is None:
-        entry = _adopt_shared(program, leaf_vals, donate_key, key)
-        tier = "shared"
     if entry is None:
         with _lock:
             _bump("misses")
@@ -252,7 +238,7 @@ def lookup(program: Any, leaf_vals: Sequence[Any],
             _bump("forged_stale")
         _registry.inc("plancache.forged_stale")
         _emit_stale(label, cert, causes, forged=True)
-        return Hit(cert=cert, tier=tier, forged=True, causes=causes)
+        return Hit(cert=cert, forged=True, causes=causes)
     # Fast path: live ambient probe equals the probe this entry last
     # validated under, and the leaf shardings still match — every other
     # signature field is a pure function of the probe, so the
@@ -297,13 +283,12 @@ def lookup(program: Any, leaf_vals: Sequence[Any],
         _emit_stale(label, cert, causes, forged=False)
         return None
     with _lock:
-        _bump("hits" if tier == "hit" else "shared_hits")
-    _registry.inc("plancache.hit" if tier == "hit"
-                  else "plancache.shared_hit")
+        _bump("hits")
+    _registry.inc("plancache.hit")
     _note_hit()
     hit = entry.hit
-    if hit is None or hit.tier != tier:
-        hit = Hit(cert=cert, tier=tier, forged=False, causes=())
+    if hit is None:
+        hit = Hit(cert=cert, forged=False, causes=())
         entry.hit = hit
     return hit
 
@@ -498,128 +483,6 @@ def class_plan_from(cert: _plancert.PlanCertificate) -> Optional[Any]:
 
 
 # ---------------------------------------------------------------------------
-# shared artifact tier (fleet/artifacts.py)
-# ---------------------------------------------------------------------------
-
-
-def _shared_tier() -> Optional[Any]:
-    """``fleet.artifacts`` when the cross-process certificate lane is
-    armed for THIS process, else None.  Single-controller only (same
-    reasoning as the shared memo lane: under SPMD one rank adopting a
-    verdict its peers re-derive would still agree — but the adoption
-    probe's filesystem traffic is per-rank waste, and a half-warmed
-    artifact dir must not split the ranks' hit/miss decisions)."""
-    if not os.environ.get("RAMBA_ARTIFACTS"):
-        return None
-    if (os.environ.get("RAMBA_PLANCERT_SHARED") or "1").strip().lower() \
-            in _OFF:
-        return None
-    if _events._rank_info()[1] != 1:
-        return None
-    try:
-        from ramba_tpu.fleet import artifacts as _artifacts
-    except Exception:  # noqa: BLE001 — the tier must never break flushes
-        return None
-    if not _artifacts.armed():
-        return None
-    return _artifacts
-
-
-#: integrity-envelope schema tag for shared certificate blobs
-CERT_SCHEMA = "plancert.json"
-
-
-def _cert_path(tier: Any, chash: str) -> str:
-    return os.path.join(tier.artifacts_dir(), "plancert",
-                        f"{chash}.json")
-
-
-def publish(cert: Optional[_plancert.PlanCertificate]) -> bool:
-    """Write a certificate to the shared artifact tier (keyed by chash)
-    so peer replicas can adopt it.  Serving-plane call site
-    (``serve/pipeline.py``); best-effort, never raises."""
-    if cert is None or cert.chash is None or not enabled():
-        return False
-    tier = _shared_tier()
-    if tier is None:
-        return False
-    try:
-        data = json.dumps(_plancert.to_payload(cert),
-                          sort_keys=True).encode()
-    except (TypeError, ValueError):
-        return False
-    if not tier.store_blob(_cert_path(tier, cert.chash),
-                           _integrity.wrap(data, CERT_SCHEMA)):
-        return False
-    with _lock:
-        _bump("publishes")
-    _registry.inc("plancache.publish")
-    return True
-
-
-def _adopt_shared(program: Any, leaf_vals: Sequence[Any],
-                  donate_key: Tuple[int, ...],
-                  key: Tuple[Any, ...]) -> Optional["_Entry"]:
-    """On a local miss, probe the shared tier by chash and adopt a peer's
-    certificate when its per-flush inputs match ours exactly.  Pays one
-    canonicalization — still far cheaper than the full pipeline — and
-    installs the adopted certificate locally so repeats are plain hits."""
-    tier = _shared_tier()
-    if tier is None:
-        return None
-    from ramba_tpu.analyze import canon as _canon
-
-    try:
-        cf = _canon.try_canonicalize(program)
-    except Exception:  # noqa: BLE001
-        return None
-    if cf is None:
-        return None
-    raw = tier.load_blob(_cert_path(tier, cf.chash))
-    if raw is None:
-        return None
-    try:
-        payload = _integrity.unwrap(raw, CERT_SCHEMA, site="plancert:blob")
-    except _integrity.IntegrityError:
-        # digest mismatch or unstamped pre-plane blob: evict, re-derive
-        tier.evict(_cert_path(tier, cf.chash))
-        return None
-    try:
-        obj = json.loads(payload.decode())
-    except (ValueError, UnicodeDecodeError) as e:
-        _integrity.failure("plancert:blob", "deserialize",
-                           detail=repr(e)[:200], chash=cf.chash)
-        tier.evict(_cert_path(tier, cf.chash))
-        return None
-    cert = _plancert.from_payload(obj)
-    if cert is None:
-        tier.evict(_cert_path(tier, cf.chash))
-        return None
-    # per-flush inputs must match exactly; ambient state is checked by
-    # the caller's signature comparison like any local hit
-    if cert.aval_sig != key[1] or cert.donate_key != tuple(donate_key):
-        return None
-    if cert.versions != _plancert.component_versions():
-        return None
-    # probe=None: the home process's ambient raw values are unknowable,
-    # so the first redemption validates through the full signature
-    # comparison and self-heals the fast path.
-    entry = _Entry(cert, None)
-    try:
-        with _lock:
-            _store[key] = entry
-            _store.move_to_end(key)
-            cap = _max_entries()
-            while len(_store) > cap:
-                _store.popitem(last=False)
-            _bump("adopted")
-    except TypeError:       # unhashable program key — never certifiable
-        return None
-    _registry.inc("plancache.adopted")
-    return entry
-
-
-# ---------------------------------------------------------------------------
 # batched coherence (ROADMAP 2b, hits only)
 # ---------------------------------------------------------------------------
 
@@ -672,14 +535,14 @@ def flush_agree() -> None:
 
 def snapshot() -> Dict[str, Any]:
     """Point-in-time view for diagnostics/bench: counters, stale causes,
-    and the derived hit rate (hits + shared hits over lookups)."""
+    and the derived hit rate (hits over lookups)."""
     with _lock:
         s = dict(_stats)
         causes = dict(_stale_causes)
         size = len(_store)
         pending = _pending_hits
     lookups = s.get("lookups", 0)
-    hits = s.get("hits", 0) + s.get("shared_hits", 0)
+    hits = s.get("hits", 0)
     return {
         "enabled": enabled(),
         "entries": size,

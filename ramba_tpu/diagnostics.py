@@ -25,21 +25,18 @@ from ramba_tpu.observe import events as _events, registry as _registry
 
 #: Version of the :func:`snapshot` JSON contract.  Bump on any change
 #: that breaks a consumer of the dump (key renamed/removed, semantics
-#: changed) — additive keys do NOT bump it.  The fleet collector
-#: (observe/fleet.py) refuses to aggregate snapshots whose major version
-#: differs from its own, so a mixed-version fleet degrades to "replica
-#: skipped, reason=schema" instead of silently mis-merging counters.
+#: changed) — additive keys do NOT bump it.
 SCHEMA_VERSION = 1
 
 
 def identity() -> dict:
     """The process-identity block: who produced this snapshot.
 
-    ``(host, pid, rank)`` names the replica; ``start_time_wall`` (plus
+    ``(host, pid, rank)`` names the process; ``start_time_wall`` (plus
     its monotonic twin) distinguishes incarnations of a recycled pid;
     ``schema_version`` versions the contract the rest of the snapshot
-    follows.  Stamped onto every snapshot, flight-recorder dump, and
-    fleet spool file so federated tooling can join/dedup replicas."""
+    follows.  Stamped onto every snapshot and flight-recorder dump so
+    tooling can join and dedup processes."""
     # never force the jax import from the observability plane
     jax = sys.modules.get("jax")
     try:
@@ -425,14 +422,11 @@ def report(file=None) -> None:
         print(
             f"  entries={plan['entries']}"
             f" hits={plan.get('hits', 0)}"
-            f"+{plan.get('shared_hits', 0)}shared"
             f" misses={plan.get('misses', 0)}"
             f" hit_rate={plan['hit_rate']:.1%}"
             f" stores={plan.get('stores', 0)}"
             f" stale={plan.get('stale', 0)}"
-            f" forged={plan.get('forged_stale', 0)}"
-            f" adopted={plan.get('adopted', 0)}"
-            f" published={plan.get('publishes', 0)}",
+            f" forged={plan.get('forged_stale', 0)}",
             file=file,
         )
         if plan["stale_causes"]:
@@ -534,7 +528,7 @@ def reset() -> None:
 def main(argv=None) -> int:
     """``python -m ramba_tpu.diagnostics`` — the machine-readable dump
     entrypoint.  ``--json`` writes one :func:`snapshot` object (the
-    versioned contract external tooling and the fleet collector consume)
+    versioned contract external tooling consumes)
     to stdout or ``-o <path>``; without it, the human summary of
     :func:`report` goes to stdout."""
     import argparse

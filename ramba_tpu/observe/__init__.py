@@ -36,12 +36,6 @@ Environment variables:
 * ``RAMBA_TRACE_BUFFER=<n>`` — pending-line bound of the buffered trace
   writer (default 2048); overflow drops lines and counts
   ``events.write_dropped`` instead of blocking the flush path.
-* ``RAMBA_FLEET_DIR`` — fleet snapshot spool: publish an atomic versioned
-  ``diagnostics.snapshot()`` document to ``<dir>/<host>-<pid>-<rank>.json``
-  every ``RAMBA_FLEET_INTERVAL_S`` seconds (default 5); the collector in
-  ``fleet``/``scripts/fleet_collector.py`` classifies each replica
-  healthy/degraded/stale/dead (``RAMBA_FLEET_STALE_X`` /
-  ``RAMBA_FLEET_DEAD_X`` x interval age thresholds, defaults 1.5 / 2.0).
 
 Every observability code path self-accounts its own wall time in
 ``observer`` (the observer-tax ledger): exported as
@@ -49,8 +43,8 @@ Every observability code path self-accounts its own wall time in
 ``ramba_observer_tax_frac``.
 
 Public read API lives in ``ramba_tpu.diagnostics`` (``perf_report()`` for
-the ledger, including the ``attribution`` section); the fleet-level read
-API is ``ramba_tpu.observe.fleet`` (``health()`` / ``rollup()``).
+the ledger, including the ``attribution`` section).
 """
 
-from ramba_tpu.observe import attrib, events, fleet, health, ledger, observer, profile, registry  # noqa: F401
+from ramba_tpu.observe import (  # noqa: F401
+    attrib, events, health, ledger, observer, profile, registry)

@@ -214,8 +214,8 @@ def _rank_info():
 
 
 def rank_info() -> tuple:
-    """Public ``(rank, nprocs)`` — the identity block of the fleet spool
-    and the exporter's ``.rank<i>`` textfile suffixing both key on this.
+    """Public ``(rank, nprocs)`` — the diagnostics identity block and
+    the exporter's ``.rank<i>`` textfile suffixing both key on this.
     Same caching discipline as the emit path (see :func:`_rank_info`)."""
     return _rank_info()
 

@@ -19,10 +19,9 @@ Components (what each window covers):
                   ``record_flush_wall``; the ``compile`` event's emit
                   self-accounts under ``events``).
 * ``telemetry`` — one Prometheus ``render()``.
-* ``fleet``     — one fleet snapshot ``publish()``.
 * ``flight``    — one flight-recorder dump.
 
-Windows may nest (an emit inside a publish bills both components), so
+Windows may nest (an emit inside a dump bills both components), so
 the total is a slight over-count: the bound errs against us, never
 for us.
 

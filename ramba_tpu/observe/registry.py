@@ -25,10 +25,10 @@ import time
 from collections import defaultdict
 
 # Process birth stamps, frozen at first import of the observability plane
-# (one pair per process lifetime).  The fleet spool and the
-# ``ramba_process_info`` exporter series use these to distinguish "same
-# pid, new incarnation" — a restarted replica publishes a NEW start_wall,
-# so a federated collector never merges two lives of one pid into one
+# (one pair per process lifetime).  The diagnostics identity block and
+# the ``ramba_process_info`` exporter series use these to distinguish
+# "same pid, new incarnation" — a restarted process publishes a NEW
+# start_wall, so a collector never merges two lives of one pid into one
 # counter history.
 START_WALL: float = round(time.time(), 6)
 START_MONO: float = round(time.monotonic(), 6)

@@ -260,46 +260,6 @@ def tenant_latency(tenant: Optional[str]) -> dict:
         return out
 
 
-def merge_summaries(summaries: list) -> dict:
-    """Merge N :meth:`Histogram.summary` dicts (e.g. one per fleet
-    replica) into one summary with re-derived p50/p95/p99.
-
-    This is why the histograms are fixed-bucket: merging is cumulative-
-    count addition per ``le`` bound, exact — no resampling, no quantile
-    sketch error beyond the single-histogram bucket-width bound.  The
-    fleet collector (observe/fleet.py) calls this on per-replica snapshot
-    JSON, so it must tolerate summaries whose bucket lists came from a
-    different process (lists from JSON, tuples from live snapshots)."""
-    h = Histogram()
-    for s in summaries:
-        if not s:
-            continue
-        buckets = s.get("buckets") or []
-        prev = 0
-        for i, pair in enumerate(buckets):
-            try:
-                ub, cum = float(pair[0]), int(pair[1])
-            except (TypeError, ValueError, IndexError):
-                continue
-            # cumulative -> per-bucket; align by position when the bound
-            # matches the canonical table, else drop into the landing slot
-            n = cum - prev
-            prev = cum
-            if n <= 0:
-                continue
-            slot = len(BUCKETS_S)
-            for j, b in enumerate(BUCKETS_S):
-                if ub <= b:
-                    slot = j
-                    break
-            h.counts[slot] += n
-        total = int(s.get("count") or 0)
-        h.counts[-1] += max(0, total - prev)  # +Inf tail beyond last bound
-        h.count += total
-        h.sum += float(s.get("sum_s") or 0.0)
-    return h.summary()
-
-
 def breached_tenants() -> list:
     with _lock:
         return sorted(_breached)

@@ -587,9 +587,9 @@ def render() -> str:
 def textfile_path(path: str) -> str:
     """The actual path one process rewrites: ``<path>.rank<i>`` under
     multi-controller SPMD (same suffixing as events.py's trace JSONL).
-    Two ranks handed the same ``RAMBA_TELEMETRY``/``RAMBA_METRICS_FILE``
-    path would otherwise take turns clobbering each other's atomic
-    rewrites — each scrape would see whichever rank replaced last."""
+    Two ranks handed the same ``RAMBA_METRICS_FILE`` path would
+    otherwise take turns clobbering each other's atomic rewrites — each
+    scrape would see whichever rank replaced last."""
     rank, nprocs = _events._rank_info()
     return path if nprocs <= 1 else f"{path}.rank{rank}"
 

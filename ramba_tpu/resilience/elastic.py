@@ -928,9 +928,9 @@ def report() -> dict:
     return {
         "watchdog_s": watchdog_seconds(),
         "heartbeat_running": heartbeat_running(),
-        # the interval rides along so a fleet collector reading this
-        # block out of a spool snapshot can judge last_beat_age_s against
-        # the beacon cadence the replica was actually configured with
+        # the interval rides along so a reader of this block out of a
+        # snapshot can judge last_beat_age_s against the beacon cadence
+        # the process was actually configured with
         "heartbeat_interval_s": round(_heartbeat_interval(), 3),
         "heartbeats": int(_registry.get("elastic.heartbeats")),
         "last_beat_age_s": (round(last_beat_age(), 4)

@@ -44,11 +44,10 @@ tests seed a stall without flaky timing.
   ``RAMBA_FAULTS_SEED`` + site + call number.  Like ``delay``/``hang``
   it takes an optional one-shot ``after=<k>`` payload (checks 1..k
   pass untouched, check ``k+1`` flips) and composes with ``rank=<i>``
-  for rank-skewed corruption.  The wired sites are ``memo:blob``,
-  ``aot:blob``, ``checkpoint:leaf``, ``migrate:payload`` and
-  ``audit:shadow`` (resilience/integrity.py) —
-  ``RAMBA_FAULTS='memo:blob:flip:bytes=2:rank=1'`` flips two bytes of
-  every shared-memo blob rank 1 reads, the seeded corruption the
+  for rank-skewed corruption.  The wired sites are ``aot:blob``,
+  ``checkpoint:leaf`` and ``audit:shadow`` (resilience/integrity.py) —
+  ``RAMBA_FAULTS='aot:blob:flip:bytes=2:rank=1'`` flips two bytes of
+  every persisted executable rank 1 reads, the seeded corruption the
   digest-verification path must catch.
 
 Every spec additionally accepts a ``rank=<i>`` *payload* (composes with

@@ -1,14 +1,11 @@
 """End-to-end data integrity plane: digests, audits, suspect quarantine.
 
-Every byte-carrying seam in the fleet — shared memo blobs
-(``fleet/artifacts.py``), persistent AOT executables
-(``compile/persist.py``), plan-certificate blobs (``core/plancache.py``),
-checkpoint leaves and manifests (``checkpoint.py``,
-``resilience/elastic.py``), migration handoff payloads
-(``fleet/migrate.py``) — trusts that bytes read back are the bytes
-written.  Before this module, corruption was only caught when
-deserialization *happened* to throw; a bit-flip that still parses was
-served to users as a wrong answer.  This module makes silent data
+Every byte-carrying seam — persistent AOT executables and program
+records (``compile/persist.py``), checkpoint leaves and manifests
+(``checkpoint.py``, ``resilience/elastic.py``) — trusts that bytes read
+back are the bytes written.  Before this module, corruption was only
+caught when deserialization *happened* to throw; a bit-flip that still
+parses was served to users as a wrong answer.  This module makes silent data
 corruption a first-class, classified fault, in four legs:
 
 **Content digests.**  :func:`wrap` stamps a payload with an envelope —
@@ -30,18 +27,17 @@ cross-rank via ``coherence.agree("integrity:audit", reduce="max")`` so
 a mismatch on one rank evicts coherently everywhere.  The *primary*
 result is always the one served (on a mismatch nobody can say which
 side flipped — serving the primary keeps audit-on runs byte-identical
-to audit-off runs); the memo insert is suppressed and any shared blob
-for the plan is evicted so the suspect bytes cannot propagate.
+to audit-off runs); the memo insert is suppressed and the plan's memo
+entry is evicted so the suspect bytes cannot be served again.
 
 **Suspect quarantine.**  A process accumulating
 ``RAMBA_INTEGRITY_THRESHOLD`` digest/audit failures (default 3) inside
 a sliding ``RAMBA_INTEGRITY_WINDOW_S`` window (default 300 s) flips a
-``suspect`` health signal that rides the fleet snapshot spool
-(``observe/fleet.py``) — ``fleet.poll()`` and the serving router then
-classify the replica degraded and route tenants away from it.
+``suspect`` signal: the ``integrity`` event carries it, the
+``integrity.suspect`` gauge reads 1, and ``snapshot()`` reports it.
 
-**Offline verification.**  ``scripts/ramba_fsck.py`` walks the artifact
-tier, the AOT cache and checkpoint digest sidecars, re-verifying every
+**Offline verification.**  ``scripts/ramba_fsck.py`` walks the AOT
+cache and checkpoint digest sidecars, re-verifying every
 envelope with :func:`verify_blob` (which never emits — an offline scan
 must not strike the live suspect window).
 
@@ -52,8 +48,8 @@ uses to reproduce the wrong-answer serve this plane exists to prevent.
 
 Fault site ``audit:shadow`` (``RAMBA_FAULTS='audit:shadow:flip:...'``)
 flips the shadow's bytes so audit mismatch handling can be driven
-deterministically; the digest seams wire ``memo:blob``, ``aot:blob``,
-``checkpoint:leaf`` and ``migrate:payload`` the same way.
+deterministically; the digest seams wire ``aot:blob`` and
+``checkpoint:leaf`` the same way.
 """
 
 from __future__ import annotations
@@ -264,7 +260,7 @@ def unwrap(data: bytes, schema: str, *, site: str,
 
 def file_digest(path: str, chunk: int = 1 << 20) -> str:
     """Streamed sha256 over a file's raw bytes (checkpoint sidecars,
-    handoff payload verification, ramba-fsck)."""
+    ramba-fsck)."""
     h = hashlib.sha256()
     with open(path, "rb") as f:
         while True:
@@ -332,8 +328,7 @@ def failure_count(now: Optional[float] = None) -> int:
 
 
 def suspect(now: Optional[float] = None) -> bool:
-    """Whether this process has crossed the quarantine threshold — the
-    health signal ``observe/fleet.py`` publishes into the spool."""
+    """Whether this process has crossed the quarantine threshold."""
     return failure_count(now) >= suspect_threshold()
 
 
@@ -405,7 +400,7 @@ def _classify_divergence(outs: Sequence[Any], primary: List[bytes],
 def shadow_audit(label: str, outs: Sequence[Any],
                  rerun: Callable[[], Sequence[Any]], *,
                  plan: Any = None, span: Optional[dict] = None) -> bool:
-    """Maybe shadow-audit one flush; returns True iff the fleet agreed
+    """Maybe shadow-audit one flush; returns True iff the ranks agreed
     the audit found a mismatch (caller must then suppress the memo
     insert — the primary ``outs`` are still the ones served).
 
@@ -476,8 +471,7 @@ def shadow_audit(label: str, outs: Sequence[Any],
 
 def _evict_plan_blobs(plan: Any) -> None:
     """A mismatched audit means the flush's bytes are suspect: evict the
-    plan's local memo entry and its shared-tier blob so they cannot be
-    served to a peer."""
+    plan's memo entry so they cannot be served again."""
     if plan is None:
         return
     try:
@@ -486,15 +480,6 @@ def _evict_plan_blobs(plan: Any) -> None:
         _memo.evict(plan)
     except Exception:  # noqa: BLE001 — eviction is best-effort
         pass
-    key = getattr(plan, "shared_key", None)
-    if key:
-        try:
-            from ramba_tpu.fleet import artifacts as _artifacts
-
-            if _artifacts.armed():
-                _artifacts.evict(_artifacts._memo_path(key))
-        except Exception:  # noqa: BLE001
-            pass
 
 
 # ---------------------------------------------------------------------------

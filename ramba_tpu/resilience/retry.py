@@ -98,22 +98,10 @@ def classify(exc: BaseException) -> str:
     place), ``"degrade"`` (re-attempting identically is pointless — move
     down the ladder), ``"oom"`` (device memory exhaustion, real or
     injected: degrade-worthy, but recoverable by evicting HBM first —
-    the ladder runs ``memory.evict_for_oom`` before the rung drop),
-    ``"redirect"`` (retryable *elsewhere*, not here: a fleet replica
-    refused or died, so re-attempting on the same target is pointless
-    but another replica can serve the identical request — the router's
-    rung, never produced by in-process failures), or ``"fatal"``
-    (propagate unchanged)."""
+    the ladder runs ``memory.evict_for_oom`` before the rung drop), or
+    ``"fatal"`` (propagate unchanged)."""
     if isinstance(exc, RetryBudgetExhausted):
         return "degrade"
-    # Fleet-level refusals/unavailability (fleet/router.py) carry their
-    # routing duck-typed like stalls and sheds below: the work is valid
-    # but THIS replica cannot serve it.  Checked before the shed branch
-    # — a replica's CircuitOpenError/QueueFullError arrives wrapped in a
-    # redirect-classified error, and redirect must win: shed semantics
-    # ("never re-attempt") apply within a replica, not across the fleet.
-    if getattr(exc, "redirect_classification", None) is not None:
-        return "redirect"
     # Coherent aborts (coherence.CoherentAbort) carry the fleet-agreed
     # class: a peer's failure consumed here must route exactly as the
     # original did on its rank.

@@ -573,7 +573,7 @@ def admit_submit(*, tenant: Optional[str], priority: bool = False,
 # ---------------------------------------------------------------------------
 
 #: agreement codes for the ``serve:shed`` site (severity max; any shed
-#: proposal beats ADMIT fleet-wide)
+#: proposal beats ADMIT on every rank)
 ADMIT = 0
 SHED_DEADLINE = 1
 SHED_SOJOURN = 2
@@ -607,7 +607,7 @@ def dispatch_verdict(*, deadline: Optional["Deadline"],
     on the same epoch — the PR-10 lesson applied to the front door."""
     engaged = _coherence.engaged()
     if not _active(deadline):
-        # nothing fleet-decidable; still honor a local red brownout
+        # nothing for the ranks to decide; still honor a local red brownout
         # (single-controller only: a local signal must not desync ranks)
         if not engaged and _brownout.state == RED and not priority:
             _shed_event("brownout", "dispatch", tenant=tenant, label=label)
@@ -824,58 +824,6 @@ def run_hedged(execute: Callable[[dict], tuple], threshold_s: float, *,
 # ---------------------------------------------------------------------------
 # reporting / reset
 # ---------------------------------------------------------------------------
-
-
-def health_signals() -> dict:
-    """Compact liveness-relevant slice of the overload plane — what the
-    fleet snapshot spool publishes every interval and the collector's
-    replica health model (observe/fleet.py) classifies on.  Deliberately
-    tiny and always present (unlike the quiet-when-idle ``overload``
-    section of ``diagnostics.snapshot()``): a router polling fleet
-    health must see ``brownout == "green"`` as a positive signal, not
-    infer it from an absent key."""
-    with _brownout.lock:
-        state = _brownout.state
-    with _breaker_lock:
-        snaps = {t: b.snapshot() for t, b in _breakers.items()}
-    return {
-        "brownout": state,
-        "open_breakers": sorted(t for t, s in snaps.items()
-                                if s["state"] == "open"),
-        "breaker_trips": sum(s["trips"] for s in snaps.values()),
-        "shed_total": _registry.get("serve.shed"),
-    }
-
-
-def admission_verdict(tenant: Optional[str] = None) -> dict:
-    """Would a submit for ``tenant`` be admitted right now?  The
-    replica server (``fleet/replica.py``) answers router pings with
-    this so the router can redirect *before* sending work, not just
-    after a refusal.  Read-only: unlike :func:`admit_submit` it never
-    transitions a breaker to half-open or burns its probe slot —
-    routing probes must not perturb the admission state they observe."""
-    with _brownout.lock:
-        brown = _brownout.state
-    with _breaker_lock:
-        snaps = {t: b.snapshot() for t, b in _breakers.items()}
-    reasons = []
-    if brown == RED:
-        reasons.append("brownout_red")
-    breaker = None
-    if tenant is not None:
-        snap = snaps.get(tenant)
-        breaker = snap["state"] if snap else CLOSED
-        if breaker == OPEN:
-            reasons.append("breaker_open")
-    open_breakers = sorted(t for t, s in snaps.items()
-                           if s["state"] == OPEN)
-    return {
-        "accepting": not reasons,
-        "reasons": reasons,
-        "brownout": brown,
-        "breaker": breaker,
-        "open_breakers": open_breakers,
-    }
 
 
 def report() -> dict:

@@ -233,14 +233,6 @@ class CompilePipeline:
                                          detached=True)
         if work is None:
             return FlushTicket(stream)
-        if work.plan_cert is not None and work.plan_cache is None:
-            # a freshly certified plan (miss path) is fleet property:
-            # publish it to the shared artifact tier by chash so one
-            # replica's analysis warms its peers (core/plancache.py is
-            # a no-op when the tier is disarmed)
-            from ramba_tpu.core import plancache as _plancache
-
-            _plancache.publish(work.plan_cert)
         work.enqueued_at = time.perf_counter()
         ticket = FlushTicket(stream, work)
         # late-completion probe: dispatch checks this before write-back

@@ -144,26 +144,6 @@ class Session:
         if not self._tokens:
             self.close(drain=exc_type is None)
 
-    # -- non-scoped activation (replica servers) ---------------------------
-
-    def acquire(self) -> "Session":
-        """Activate this session's stream on the calling thread WITHOUT
-        closing on deactivation — the long-lived form of ``__enter__``
-        for servers that resume one session across many requests
-        (``fleet/replica.py``).  Pair every acquire with a
-        :meth:`release`; the session stays open until :meth:`close` or
-        :meth:`handoff`."""
-        if self.closed:
-            raise RuntimeError("session is closed")
-        self._tokens.append(_fuser.activate_stream(self.stream))
-        return self
-
-    def release(self) -> None:
-        """Deactivate the stream on the calling thread (undo one
-        :meth:`acquire`) without closing the session."""
-        if self._tokens:
-            _fuser.deactivate_stream(self._tokens.pop())
-
     # -- flushing ----------------------------------------------------------
 
     def flush(self, wait: bool = False) -> "_pipeline.FlushTicket":
@@ -197,24 +177,6 @@ class Session:
         else:
             self.stream.drain()
             self.stream.on_threshold = None
-
-    def handoff(self) -> dict:
-        """Drain and close this session for migration to another
-        process (``fleet/migrate.py``): a final :meth:`sync` lands every
-        pending flush so the arrays the caller is about to checkpoint
-        are complete, then the session closes.  Returns the identity
-        meta the migration manifest records (tenant, trace root) so the
-        adopting replica's new session can chain the same distributed
-        trace."""
-        meta = {"tenant": self.tenant, "trace_id": self.trace_id,
-                "root_span": self.root_span, "stream": self.stream.name}
-        self.close(drain=True)
-        ev = {"type": "migrate", "action": "handoff",
-              "trace_id": self.trace_id, "stream": self.stream.name}
-        if self.tenant is not None:
-            ev["tenant"] = self.tenant
-        _events.emit(ev)
-        return meta
 
     # -- introspection -----------------------------------------------------
 

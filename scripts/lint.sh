@@ -44,37 +44,42 @@ JAX_PLATFORMS=cpu python -m ramba_tpu.analyze --plan-audit "${traces[@]}" || rc=
 
 echo "== lint.sh: ramba-fsck smoke (seed, verify, flip, repair) =="
 ftd="$(mktemp -d)"
-if JAX_PLATFORMS=cpu RAMBA_ARTIFACTS="$ftd" python - <<'EOF'
+# a jax cache of its own: an AOT store is skipped where jax's cache served
+# the compile (compile/persist.py), which a shared cache would after one run
+if JAX_PLATFORMS=cpu RAMBA_CACHE="$ftd/ramba" JAX_COMPILATION_CACHE_DIR="$ftd/jax" \
+        python - <<'EOF'
 import os
 import sys
 
 import numpy as np
 
-from ramba_tpu.fleet import artifacts
+import ramba_tpu as rt
+from ramba_tpu.compile import persist
 
 sys.path.insert(0, os.path.join(os.getcwd(), "scripts"))
 import ramba_fsck  # noqa: E402
 
-artifacts.configure()
-assert artifacts.memo_store("fscksmoke0" * 3 + "ab", [np.arange(16.0)])
-assert artifacts.memo_store("fscksmoke1" * 3 + "cd", [np.ones(4)])
-root = os.environ["RAMBA_ARTIFACTS"]
+np.asarray(rt.array(np.arange(16.0)) * 3.0 + 1.0)
+assert persist.save_topk(4)["stored"] >= 1
+root = os.environ["RAMBA_CACHE"]
 
-r = ramba_fsck.scan(artifacts=root)
+r = ramba_fsck.scan(cache=root)
 assert r["status"] == 0 and r["scanned"] >= 2, r
 
-blob = os.path.join(root, "memo", sorted(os.listdir(os.path.join(root, "memo")))[0])
+aot = os.path.join(root, "aot")
+blob = os.path.join(
+    aot, sorted(n for n in os.listdir(aot) if n.endswith(".aot"))[0])
 b = bytearray(open(blob, "rb").read())
 b[len(b) // 2] ^= 0xFF
 open(blob, "wb").write(bytes(b))
 
-r = ramba_fsck.scan(artifacts=root)
+r = ramba_fsck.scan(cache=root)
 assert r["status"] == 1 and r["corrupt"] == 1, r
 
-r = ramba_fsck.scan(artifacts=root, repair=True)
+r = ramba_fsck.scan(cache=root, repair=True)
 assert r["status"] == 1 and os.path.isdir(os.path.join(root, "quarantine")), r
 
-r = ramba_fsck.scan(artifacts=root)
+r = ramba_fsck.scan(cache=root)
 assert r["status"] == 0, r
 print("fsck smoke: detect + quarantine + clean rescan OK")
 EOF

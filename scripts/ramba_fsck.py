@@ -5,12 +5,8 @@ came back from a crash, or from cron as a corruption tripwire.
 
 What gets scanned (each an independent leg; a leg with nothing to scan
 is skipped, and scanning *nothing at all* is its own exit code so a
-misconfigured cron job cannot masquerade as a clean fleet):
+misconfigured cron job cannot masquerade as a clean scan):
 
-* the shared artifact tier (``--artifacts`` / ``RAMBA_ARTIFACTS``):
-  memo blobs (``memo/*.npz``), plan certificates (``plancert/*.json``),
-  migration handoffs (``handoff/*.manifest.json`` + each checkpoint's
-  payload byte census + digest sidecar);
 * the persistent executable cache (``--cache`` / ``RAMBA_CACHE``):
   AOT entries (``aot/*.aot``) and program skeletons
   (``programs/*.pkl``);
@@ -29,8 +25,8 @@ recomputes/recompiles on the resulting miss; a quarantined checkpoint
 leaf makes the checkpoint refuse restore loudly instead of serving
 silently corrupt state).
 
-Exit status (the contract scripts/lint.sh and cron wrappers consume,
-mirroring scripts/fleet_collector.py): ``0`` everything verified,
+Exit status (the contract scripts/lint.sh and cron wrappers consume):
+``0`` everything verified,
 ``1`` corruption found (fix or re-run with ``--repair``), ``4``
 nothing to scan anywhere.
 """
@@ -55,8 +51,6 @@ from ramba_tpu.resilience import integrity as _integrity  # noqa: E402
 
 #: schema tag per scanned blob shape (import-light: the tags are data,
 #: re-declared here so fsck never imports jax through the cache modules)
-_MEMO_SCHEMA = "memo.npz"
-_CERT_SCHEMA = "plancert.json"
 _AOT_SCHEMA = "aot.pkl"
 _PROGRAM_SCHEMA = "program.pkl"
 _DIGESTS_SCHEMA = "ckpt.digests.json"
@@ -118,22 +112,6 @@ def _scan_blob_dir(report: dict, root: str, sub: str, suffix: str,
             _bad(report, root, path, schema, reason, repair)
 
 
-def _payload_census(ckpt_dir: str) -> tuple:
-    """(total_bytes, sorted file list) over one checkpoint directory —
-    the same census fleet/migrate.py records as ``payload_bytes``."""
-    files: List[str] = []
-    total = 0
-    for r, _dirs, names in os.walk(ckpt_dir):
-        for name in names:
-            full = os.path.join(r, name)
-            files.append(full)
-            try:
-                total += os.path.getsize(full)
-            except OSError:
-                pass
-    return total, sorted(files)
-
-
 def _scan_sidecar(report: dict, root: str, side: str, repair: bool) -> None:
     """Verify one checkpoint digest sidecar: the sidecar's own envelope,
     then every file it stamps, byte-for-byte."""
@@ -166,36 +144,6 @@ def _scan_sidecar(report: dict, root: str, side: str, repair: bool) -> None:
             _bad(report, root, full, "checkpoint:leaf", "digest", repair)
 
 
-def _scan_handoffs(report: dict, root: str, repair: bool) -> None:
-    d = os.path.join(root, "handoff")
-    try:
-        names = sorted(os.listdir(d))
-    except OSError:
-        return
-    for name in names:
-        if not name.endswith(".manifest.json"):
-            continue
-        mpath = os.path.join(d, name)
-        report["scanned"] += 1
-        try:
-            man = json.loads(_read(mpath) or b"")
-        except ValueError:
-            _bad(report, root, mpath, "handoff.manifest", "deserialize",
-                 repair)
-            continue
-        sid = name[:-len(".manifest.json")]
-        ckpt = os.path.join(d, sid)
-        want = man.get("payload_bytes")
-        if want is not None and os.path.isdir(ckpt):
-            got, _files = _payload_census(ckpt)
-            if got != want:
-                _bad(report, root, mpath, "handoff.manifest",
-                     f"payload_bytes:{got}!={want}", repair)
-        side = ckpt + _DIGESTS_SUFFIX
-        if os.path.exists(side):
-            _scan_sidecar(report, root, side, repair)
-
-
 def _scan_manifest_selfdigest(report: dict, root: str, mpath: str,
                               repair: bool) -> None:
     import hashlib
@@ -214,14 +162,6 @@ def _scan_manifest_selfdigest(report: dict, root: str, mpath: str,
         json.dumps(body, sort_keys=True).encode()).hexdigest()
     if got != want:
         _bad(report, root, mpath, "elastic.manifest", "digest", repair)
-
-
-def scan_artifacts(root: str, repair: bool = False) -> dict:
-    report = _new_report(root, "artifacts")
-    _scan_blob_dir(report, root, "memo", ".npz", _MEMO_SCHEMA, repair)
-    _scan_blob_dir(report, root, "plancert", ".json", _CERT_SCHEMA, repair)
-    _scan_handoffs(report, root, repair)
-    return report
 
 
 def scan_cache(root: str, repair: bool = False) -> dict:
@@ -256,15 +196,12 @@ def _new_report(root: str, kind: str) -> dict:
             "quarantined": [], "repair_errors": []}
 
 
-def scan(artifacts: Optional[str] = None, cache: Optional[str] = None,
-         checkpoints: Optional[List[str]] = None,
+def scan(cache: Optional[str] = None, checkpoints: Optional[List[str]] = None,
          repair: bool = False) -> dict:
     """Importable entry point (bench.py times it; tests drive it).
     Returns ``{"legs": [...], "scanned": n, "corrupt": n, "status": s}``
     with ``status`` matching the CLI exit code."""
     legs = []
-    if artifacts and os.path.isdir(artifacts):
-        legs.append(scan_artifacts(artifacts, repair))
     if cache and os.path.isdir(cache):
         legs.append(scan_cache(cache, repair))
     for c in checkpoints or []:
@@ -282,10 +219,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="ramba-fsck",
         description="offline integrity verification of ramba_tpu's "
-                    "persisted artifacts, caches and checkpoints")
-    ap.add_argument("--artifacts", default=os.environ.get("RAMBA_ARTIFACTS"),
-                    help="shared artifact tier dir (default: "
-                         "RAMBA_ARTIFACTS)")
+                    "persisted caches and checkpoints")
     ap.add_argument("--cache", default=os.environ.get("RAMBA_CACHE"),
                     help="persistent executable cache dir (default: "
                          "RAMBA_CACHE)")
@@ -299,8 +233,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="emit the full report as JSON on stdout")
     args = ap.parse_args(argv)
 
-    result = scan(artifacts=args.artifacts, cache=args.cache,
-                  checkpoints=args.checkpoint, repair=args.repair)
+    result = scan(cache=args.cache, checkpoints=args.checkpoint,
+                  repair=args.repair)
     if args.as_json:
         json.dump(result, sys.stdout, indent=1, sort_keys=True)
         sys.stdout.write("\n")
@@ -314,8 +248,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                 print(f"  CORRUPT {c['path']} [{c['schema']}] "
                       f"{c['reason']}")
         if not result["legs"]:
-            print("ramba-fsck: nothing to scan (set RAMBA_ARTIFACTS / "
-                  "RAMBA_CACHE or pass --checkpoint)", file=sys.stderr)
+            print("ramba-fsck: nothing to scan (set RAMBA_CACHE or "
+                  "pass --checkpoint)", file=sys.stderr)
     if result["status"] == EXIT_CORRUPT and args.repair and all(
             not leg["repair_errors"] and
             len(leg["quarantined"]) >= len(leg["corrupt"])

@@ -36,11 +36,7 @@ echo "=== leg 15: compile classes + persistent warm start (2-rank lockstep bucke
 python scripts/two_process_suite.py --warmstart-leg
 echo "=== leg 16: critical-path attribution (2-rank lockstep stage waterfalls) ==="
 python scripts/two_process_suite.py --attrib-leg
-echo "=== leg 17: fleet observability federation (3 publishers + collector, kill-mid-soak) ==="
-python scripts/two_process_suite.py --fleet-leg
-echo "=== leg 18: fleet serving plane (router + replicas, shared artifact tier, kill-mid-soak failover) ==="
-python scripts/two_process_suite.py --router-leg
-echo "=== leg 19: data integrity plane (2-rank agreed audit verdict; RAMBA_INTEGRITY=0 wrong-answer repro) ==="
+echo "=== leg 17: data integrity plane (2-rank agreed audit verdict) ==="
 python scripts/two_process_suite.py --integrity-leg
-echo "=== leg 20: self-metering observability (head-sampled trace retention under rank skew) ==="
+echo "=== leg 18: self-metering observability (head-sampled trace retention under rank skew) ==="
 python scripts/two_process_suite.py --sampling-leg

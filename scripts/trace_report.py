@@ -65,12 +65,12 @@ def _stage_sig(flush: dict) -> str:
 
 def _discover(path: str) -> list:
     """The file itself, or its .rank* siblings (multi-controller runs).
-    A DIRECTORY discovers every trace JSONL beneath it — the fleet
-    layout, where each replica process wrote its own trace dir/file."""
+    A DIRECTORY discovers every trace JSONL beneath it, one stream per
+    process that wrote its own trace dir/file."""
     import os
 
     if os.path.isdir(path):
-        return _walk_fleet_dir(path)
+        return _walk_trace_dir(path)
     files = []
     if os.path.exists(path):
         files.append(path)
@@ -78,7 +78,7 @@ def _discover(path: str) -> list:
     return files
 
 
-def _walk_fleet_dir(root: str) -> list:
+def _walk_trace_dir(root: str) -> list:
     """Every ``*.jsonl`` / ``*.jsonl.rank<i>`` file under ``root``,
     sorted — one entry per per-process trace stream."""
     import os
@@ -93,21 +93,22 @@ def _walk_fleet_dir(root: str) -> list:
 
 def _rname(r) -> str:
     """Display name of one stream key: SPMD ranks are ints (``r0``),
-    fleet replicas are path-derived string labels used verbatim."""
+    the processes of a directory are path-derived string labels used
+    verbatim."""
     return f"r{r}" if isinstance(r, int) else str(r)
 
 
 def _load_streams(path: str):
     """``{stream_key: [events]}`` for one input.  A plain file keys its
     ``.rank<i>`` siblings by integer rank; a directory keys each
-    discovered file by its relative path (the replica label), so two
-    replicas that each called themselves rank 0 stay distinct streams.
+    discovered file by its relative path (the process label), so two
+    processes that each called themselves rank 0 stay distinct streams.
     Returns None when nothing was found."""
     import os
 
     if os.path.isdir(path):
         streams: dict = {}
-        for f in _walk_fleet_dir(path):
+        for f in _walk_trace_dir(path):
             label = os.path.relpath(f, path).replace(os.sep, "/")
             label = label.replace(".jsonl", "") or label
             streams.setdefault(label, []).extend(_load(f))
@@ -217,12 +218,8 @@ def report(path: str, events: list, top: int = 10, file=None) -> None:
     plan_hits = sum(1 for f in flushes if f.get("plan_cache"))
     plan_stale = [e for e in events if e.get("type") == "plan_stale"]
     if plan_hits or plan_stale:
-        shared = sum(1 for f in flushes
-                     if f.get("plan_cache") == "shared")
         line = (f"plan cache: {plan_hits}/{len(flushes)} flushes on the "
                 f"fast path ({100.0 * plan_hits / len(flushes):.0f}%)")
-        if shared:
-            line += f"  adopted from shared tier: {shared}"
         if plan_stale:
             causes = defaultdict(int)
             forged = 0
@@ -664,33 +661,6 @@ def _merge_line(e: dict) -> str:
                 f" queue={e.get('queue_ratio', '?')}"
                 f" mem={e.get('memory_frac', '?')}"
                 f" slo_breached={e.get('slo_breached', '?')}")
-    if t == "redirect":
-        sid = str(e.get("sid") or "?")
-        return (f"redirect  {e.get('reason', '?')}"
-                f" sid={sid[:8]}"
-                f" {e.get('from', '?')}->{e.get('to') or '(reroute)'}"
-                f" class={e.get('classification', '?')}"
-                + (f" tenant={e['tenant']}" if e.get("tenant") else ""))
-    if t == "heal":
-        sid = str(e.get("sid") or "?")
-        return (f"heal      {e.get('how', '?')} sid={sid[:8]}"
-                f" {e.get('from', '?')}->{e.get('to', '?')}"
-                f" replayed={e.get('steps_replayed', '?')}"
-                f" wall={e.get('wall_ms', '?')}ms"
-                + (f" tenant={e['tenant']}" if e.get("tenant") else ""))
-    if t == "migrate":
-        sid = str(e.get("sid") or "?")
-        line = f"migrate   {e.get('action', '?')} sid={sid[:8]}"
-        if e.get("from") or e.get("to"):
-            line += f" {e.get('from', '?')}->{e.get('to', '?')}"
-        if e.get("wall_ms") is not None:
-            line += f" wall={e['wall_ms']}ms"
-        if e.get("tenant"):
-            line += f" tenant={e['tenant']}"
-        return line
-    if t == "replica":
-        return (f"replica   {e.get('action', '?')}"
-                f" {e.get('endpoint', '?')}")
     return t
 
 
@@ -698,8 +668,8 @@ def merge_report(path: str, per_rank: dict, file=None, cap: int = 80) -> None:
     """Cross-rank merged timeline + rank-divergence analysis.
 
     ``per_rank`` maps rank -> event list; keys are integer SPMD ranks
-    for file inputs and replica path labels for directory (fleet)
-    inputs — the analysis is identical.  Per-rank clock skew is
+    for file inputs and process path labels for directory inputs — the
+    analysis is identical.  Per-rank clock skew is
     estimated from the bring-up anchor (see ``_anchor_ts``) and
     subtracted, then all ranks' noteworthy events are interleaved by
     adjusted timestamp (seq breaks ties within a rank).  Divergence
@@ -763,8 +733,7 @@ def merge_report(path: str, per_rank: dict, file=None, cap: int = 80) -> None:
         if t in ("fault", "degrade", "cache_evict",
                  "flush_error", "health", "serve_coalesce", "stall",
                  "lifecycle", "coherence", "reshard", "shed", "breaker",
-                 "hedge", "brownout", "redirect", "heal", "migrate",
-                 "replica", "plan_stale", "plan_divergence"):
+                 "hedge", "brownout", "plan_stale", "plan_divergence"):
             return True
         if t == "memory":
             return not (e.get("action") == "admit" and e.get("ok"))
@@ -949,7 +918,7 @@ def trace_chain(trace_id: str, per_rank: dict, file=None) -> int:
 
     Every event stamped with ``trace_id`` (directly, or via the
     ``trace_ids`` list on a coalesced-batch event) is collected from all
-    input streams (SPMD ranks, or fleet replicas when the input was a
+    input streams (SPMD ranks, or processes when the input was a
     directory) and re-threaded by span parentage: the ``serve_session``
     root, then each flush span in time order, with that span's child
     events (degrade rungs, stalls, memory admissions, barrier spans)

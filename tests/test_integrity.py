@@ -3,8 +3,7 @@
 The contract under test, in order of importance:
 
 * **Never serve suspect bytes** — a flipped blob at any stamped seam
-  (shared memo, AOT executable, plan certificate, checkpoint leaf,
-  migration payload) is detected by digest, evicted, and recomputed /
+  (AOT executable, checkpoint leaf) is detected by digest, evicted, and
   recompiled / rejected; the caller observes the *correct* answer or a
   classified error, never silence and never a crash.
 * **Byte identity under audit** — ``RAMBA_AUDIT`` shadow re-execution
@@ -12,13 +11,12 @@ The contract under test, in order of importance:
   same seeded chain are byte-identical.
 * **Visibility** — every detection is an ``integrity`` event, a
   counter, and (past ``RAMBA_INTEGRITY_THRESHOLD`` in the window) a
-  ``suspect`` health signal the fleet plane classifies as degraded.
+  ``suspect`` verdict on the event and the ``integrity.suspect`` gauge.
 * **Offline scrub** — ``ramba-fsck`` finds at-rest corruption with the
   runtime not even loaded, and ``--repair`` quarantines rather than
   deletes.
 
-The SPMD analog (rank-skewed shadow flips agreed via coherence, plus
-the wrong-answer repro with the plane disabled) is
+The SPMD analog (rank-skewed shadow flips agreed via coherence) is
 ``scripts/two_process_suite.py --integrity-leg``.
 """
 
@@ -31,9 +29,9 @@ import pytest
 
 import ramba_tpu as rt
 from ramba_tpu import diagnostics
-from ramba_tpu.core import fuser, memo, plancache
-from ramba_tpu.fleet import artifacts, migrate
-from ramba_tpu.observe import events, fleet, registry, telemetry
+from ramba_tpu.compile import persist
+from ramba_tpu.core import fuser, memo
+from ramba_tpu.observe import events, registry, telemetry
 from ramba_tpu.resilience import faults, integrity
 
 _SCRIPTS = os.path.join(
@@ -46,25 +44,22 @@ import ramba_fsck  # noqa: E402
 @pytest.fixture(autouse=True)
 def _isolate(monkeypatch):
     """Empty pending set, integrity plane at defaults (stamping on,
-    audits off), zeroed counters, no faults, no shared tier."""
+    audits off), zeroed counters, no faults."""
     fuser.flush()
     faults.configure(None)
     for k in ("RAMBA_INTEGRITY", "RAMBA_AUDIT", "RAMBA_INTEGRITY_THRESHOLD",
-              "RAMBA_INTEGRITY_WINDOW_S", "RAMBA_MEMO", "RAMBA_ARTIFACTS",
-              "RAMBA_FAULTS", "RAMBA_VERIFY", "RAMBA_PLANCERT"):
+              "RAMBA_INTEGRITY_WINDOW_S", "RAMBA_MEMO", "RAMBA_FAULTS",
+              "RAMBA_VERIFY", "RAMBA_PLANCERT"):
         monkeypatch.delenv(k, raising=False)
     integrity.reset()
     memo.reset()
-    artifacts.reset()
     yield
     faults.reset()
     fuser.flush()
     integrity.reset()
     memo.reset()
-    for k in ("RAMBA_ARTIFACTS", "RAMBA_MEMO", "RAMBA_AUDIT",
-              "RAMBA_INTEGRITY"):
+    for k in ("RAMBA_MEMO", "RAMBA_AUDIT", "RAMBA_INTEGRITY"):
         os.environ.pop(k, None)
-    artifacts.reset()
 
 
 # ---------------------------------------------------------------------------
@@ -181,158 +176,97 @@ class TestEnvelope:
 
 
 # ---------------------------------------------------------------------------
-# seam: shared memo blobs (memo:blob)
-# ---------------------------------------------------------------------------
-
-
-class TestMemoBlobSeam:
-    def _tier(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("RAMBA_ARTIFACTS", str(tmp_path))
-        artifacts.configure(str(tmp_path))
-
-    def test_flip_detected_evicted_recomputed(self, tmp_path, monkeypatch):
-        self._tier(tmp_path, monkeypatch)
-        key = "deadbeef" * 5
-        ref = np.arange(64.0)
-        assert artifacts.memo_store(key, [ref])
-        got = artifacts.memo_load(key)
-        np.testing.assert_array_equal(got[0], ref)
-        faults.configure("memo:blob:flip:bytes=1")
-        c0 = integrity.stats["failures"]
-        assert artifacts.memo_load(key) is None      # never served
-        assert artifacts.snapshot()["memo_corrupt"] >= 1
-        assert integrity.stats["failures"] == c0 + 1
-        assert not os.path.exists(artifacts._memo_path(key))  # evicted
-        ev = events.last(6, type="integrity")
-        assert any(e["site"] == "memo:blob" for e in ev), ev
-        # recompute + republish heals the lane
-        faults.configure(None)
-        assert artifacts.memo_store(key, [ref])
-        np.testing.assert_array_equal(artifacts.memo_load(key)[0], ref)
-
-    def test_unstamped_preplane_blob_evicted_once(self, tmp_path,
-                                                  monkeypatch):
-        import io
-
-        self._tier(tmp_path, monkeypatch)
-        key = "cafebabe" * 5
-        buf = io.BytesIO()
-        np.savez(buf, out0=np.ones(8))
-        path = artifacts._memo_path(key)
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        with open(path, "wb") as f:
-            f.write(buf.getvalue())               # raw, pre-plane entry
-        assert artifacts.memo_load(key) is None
-        assert not os.path.exists(path)
-        assert integrity.stats["unstamped_evictions"] >= 1
-
-    def test_valid_envelope_bad_payload_is_deserialize(self, tmp_path,
-                                                       monkeypatch):
-        # a stamped-but-unparseable blob (schema drift / pre-stamp torn
-        # write) still classifies as an integrity incident  (satellite:
-        # existing corrupt paths emit integrity events too)
-        self._tier(tmp_path, monkeypatch)
-        key = "0badf00d" * 5
-        path = artifacts._memo_path(key)
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        with open(path, "wb") as f:
-            f.write(integrity.wrap(b"not an npz archive", "memo.npz"))
-        assert artifacts.memo_load(key) is None
-        assert not os.path.exists(path)
-        ev = events.last(6, type="integrity")
-        assert any(e["site"] == "memo:blob" and e["reason"] == "deserialize"
-                   for e in ev), ev
-
-
-# ---------------------------------------------------------------------------
 # seam: persistent AOT executables (aot:blob)
 # ---------------------------------------------------------------------------
 
 
-class TestAotBlobSeam:
-    def test_flip_evicts_recompiles_correct_answer(self, tmp_path):
-        from ramba_tpu.compile import persist
+def _unstamp(path):
+    """Rewrite a stamped blob as its bare payload: a pre-plane entry."""
+    with open(path, "rb") as f:
+        raw = f.read()
+    payload = integrity.unwrap(raw, persist.AOT_SCHEMA, site="test",
+                               record=False)
+    with open(path, "wb") as f:
+        f.write(payload)
 
-        saved = {k: os.environ.get(k) for k in ("RAMBA_CACHE", "RAMBA_AOT")}
-        os.environ["RAMBA_CACHE"] = str(tmp_path / "cache")
+
+def _bad_payload(path):
+    """A valid envelope around bytes that do not deserialize."""
+    with open(path, "wb") as f:
+        f.write(integrity.wrap(b"not a pickle", persist.AOT_SCHEMA))
+
+
+class _CacheDir:
+    """Arm the AOT lane on ``root`` for the duration of a ``with``."""
+
+    def __init__(self, root):
+        self.root = root
+
+    def __enter__(self):
+        self.saved = {k: os.environ.get(k)
+                      for k in ("RAMBA_CACHE", "RAMBA_AOT")}
+        os.environ["RAMBA_CACHE"] = self.root
         os.environ.pop("RAMBA_AOT", None)
-        try:
-            persist.reconfigure()
-            assert persist.armed(), persist.snapshot()
-            with fuser._cache_lock:
-                fuser._compile_cache.clear()
+        persist.reconfigure()
+        assert persist.armed(), persist.snapshot()
+        with fuser._cache_lock:
+            fuser._compile_cache.clear()
+        return self
+
+    def __exit__(self, *exc):
+        faults.configure(None)
+        for k, v in self.saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+        persist.reset()
+
+
+def _seed_cache(root, base):
+    """Compile ``base * 5 - 2`` with the AOT lane on ``root`` and store
+    its executable; returns the stored ``.aot`` paths."""
+    np.asarray(rt.array(base) * 5.0 - 2.0)
+    assert persist.save_topk(4)["stored"] >= 1
+    aot_dir = os.path.join(root, "aot")
+    return [os.path.join(aot_dir, n) for n in sorted(os.listdir(aot_dir))
+            if n.endswith(".aot")]
+
+
+class TestAotBlobSeam:
+    @pytest.mark.parametrize("corruption",
+                             ["flip", "unstamped", "deserialize"])
+    def test_flip_evicts_recompiles_correct_answer(self, tmp_path,
+                                                   corruption):
+        root = str(tmp_path / "cache")
+        with _CacheDir(root):
             base = np.arange(40, dtype=np.float32).reshape(5, 8)
-            np.asarray(rt.array(base) * 5.0 - 2.0)
-            assert persist.save_topk(4)["stored"] >= 1
+            blobs = _seed_cache(root, base)
+            assert blobs
             with fuser._cache_lock:
                 fuser._compile_cache.clear()
             c0 = persist.snapshot()["corrupt"]
             i0 = integrity.stats["failures"]
-            faults.configure("aot:blob:flip:bytes=2")
+            u0 = integrity.stats["unstamped_evictions"]
+            if corruption == "flip":
+                faults.configure("aot:blob:flip:bytes=2")
+            else:
+                for p in blobs:
+                    (_unstamp if corruption == "unstamped"
+                     else _bad_payload)(p)
             out = np.asarray(rt.array(base) * 5.0 - 2.0)  # must NOT raise
             np.testing.assert_array_equal(out, base * 5.0 - 2.0)
             snap = persist.snapshot()
             assert snap["corrupt"] >= c0 + 1, snap
             assert integrity.stats["failures"] >= i0 + 1
             assert registry.get("compile.persist_corrupt") >= 1
-        finally:
-            faults.configure(None)
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
-            persist.reset()
-
-
-# ---------------------------------------------------------------------------
-# seam: shared plan certificates (plancert:blob)
-# ---------------------------------------------------------------------------
-
-
-class TestPlancertBlobSeam:
-    def test_flipped_cert_evicted_rederived(self, tmp_path, monkeypatch):
-        from ramba_tpu.analyze import plancert
-
-        monkeypatch.setenv("RAMBA_PLANCERT", "1")
-        monkeypatch.setenv("RAMBA_VERIFY", "strict")
-        monkeypatch.setenv("RAMBA_ARTIFACTS", str(tmp_path))
-        artifacts.configure(str(tmp_path))
-        plancache.reset()
-        plancert.reset_caches()
-        try:
-            def _workload():
-                a = rt.fromarray(np.arange(256.0).reshape(16, 16))
-                b = rt.fromarray(np.ones((16, 16)))
-                return np.asarray((a + b) * 2.0 - 0.5)
-
-            first = _workload()
-            certs = [e.cert for e in plancache._store.values()]
-            assert certs and all(c.chash for c in certs)
-            for c in certs:
-                assert plancache.publish(c)
-            cert_dir = os.path.join(str(tmp_path), "plancert")
-            blobs = sorted(os.listdir(cert_dir))
-            assert blobs
-            # at-rest bit rot: flip one byte of every published cert
-            for name in blobs:
-                p = os.path.join(cert_dir, name)
-                raw = bytearray(open(p, "rb").read())
-                raw[len(raw) // 2] ^= 0xFF
-                open(p, "wb").write(bytes(raw))
-            plancache.reset()          # model a fresh process
-            i0 = integrity.stats["failures"]
-            second = _workload()       # adoption must fail silently
-            assert first.tobytes() == second.tobytes()
-            assert plancache.snapshot().get("adopted", 0) == 0
-            assert integrity.stats["failures"] >= i0 + 1
-            # the poisoned blobs were evicted, not left to re-trip
-            left = [n for n in blobs
-                    if os.path.exists(os.path.join(cert_dir, n))]
-            assert len(left) < len(blobs)
-        finally:
-            plancache.reset()
-            plancert.reset_caches()
+            assert any(not os.path.exists(p) for p in blobs)  # evicted
+            ev = events.last(8, type="integrity")
+            assert any(e["site"] == "aot:blob" for e in ev), ev
+            if corruption == "unstamped":
+                assert integrity.stats["unstamped_evictions"] >= u0 + 1
+            if corruption == "deserialize":
+                assert any(e["reason"] == "deserialize" for e in ev), ev
 
 
 # ---------------------------------------------------------------------------
@@ -465,75 +399,6 @@ class TestElasticManifestDigest:
 
 
 # ---------------------------------------------------------------------------
-# seam: migration payloads (migrate:payload)
-# ---------------------------------------------------------------------------
-
-
-class TestMigratePayloadSeam:
-    def _tier(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("RAMBA_ARTIFACTS", str(tmp_path))
-        artifacts.configure(str(tmp_path))
-
-    def test_manifest_records_payload_bytes(self, tmp_path, monkeypatch):
-        self._tier(tmp_path, monkeypatch)
-        path = migrate.export_session("sid-a", {"seq": 1},
-                                      {"x": rt.full([64], 2.0)})
-        man = migrate.load_manifest("sid-a")
-        assert man["payload_bytes"] == migrate._payload_bytes(path)
-        assert man["payload_bytes"] > 0
-
-    def test_truncated_payload_rejected(self, tmp_path, monkeypatch):
-        # satellite: handoff whose on-disk byte-length disagrees with the
-        # manifest must be rejected before restore is even attempted
-        self._tier(tmp_path, monkeypatch)
-        path = migrate.export_session("sid-b", {"seq": 1},
-                                      {"x": rt.full([64], 2.0)})
-        files = migrate._payload_files(path)
-        victim = max(files, key=os.path.getsize)
-        with open(victim, "rb+") as f:
-            f.truncate(max(0, os.path.getsize(victim) - 7))
-        i0 = integrity.stats["failures"]
-        with pytest.raises(migrate.MigrateError):
-            migrate.adopt_session("sid-b")
-        assert integrity.stats["failures"] > i0
-        ev = events.last(6, type="integrity")
-        assert any(e["site"] == "migrate:payload" for e in ev), ev
-
-    def test_flip_seam_rejected(self, tmp_path, monkeypatch):
-        self._tier(tmp_path, monkeypatch)
-        migrate.export_session("sid-c", {"seq": 1},
-                               {"x": rt.full([64], 2.0)})
-        faults.configure("migrate:payload:flip:bytes=2")
-        with pytest.raises(migrate.MigrateError):
-            migrate.adopt_session("sid-c")
-
-    def test_legacy_manifest_without_census_adopts(self, tmp_path,
-                                                   monkeypatch):
-        self._tier(tmp_path, monkeypatch)
-        migrate.export_session("sid-d", {"seq": 1},
-                               {"x": rt.full([16], 3.0)})
-        mp = migrate._manifest_path("sid-d", None)
-        man = json.loads(open(mp, "rb").read())
-        man.pop("payload_bytes")
-        with open(mp, "w") as f:
-            json.dump(man, f)
-        manifest, adopted = migrate.adopt_session("sid-d")
-        np.testing.assert_array_equal(np.asarray(adopted["x"].asarray()),
-                                      np.full(16, 3.0))
-
-    def test_discard_removes_sidecar(self, tmp_path, monkeypatch):
-        from ramba_tpu import checkpoint
-
-        self._tier(tmp_path, monkeypatch)
-        path = migrate.export_session("sid-e", {"seq": 1},
-                                      {"x": rt.full([8], 1.0)})
-        side = checkpoint.digests_path(path)
-        if os.path.exists(side):              # stamped export
-            migrate.discard("sid-e")
-            assert not os.path.exists(side)
-
-
-# ---------------------------------------------------------------------------
 # shadow recompute audits (audit:shadow)
 # ---------------------------------------------------------------------------
 
@@ -609,22 +474,28 @@ class TestShadowAudit:
 
 
 # ---------------------------------------------------------------------------
-# suspect quarantine + fleet visibility
+# suspect quarantine
 # ---------------------------------------------------------------------------
 
 
 class TestSuspectQuarantine:
-    def test_threshold_trips_suspect_and_fleet_signal(self, monkeypatch):
+    def test_threshold_trips_suspect(self, monkeypatch):
         monkeypatch.setenv("RAMBA_INTEGRITY_THRESHOLD", "2")
+        registry.gauge("integrity.suspect", 0)
+        f0 = registry.get("integrity.failures")
         assert not integrity.suspect()
-        integrity.failure("memo:blob", "digest", detail="t1")
+        integrity.failure("aot:blob", "digest", detail="t1")
         assert integrity.failure_count() == 1
         assert not integrity.suspect()
+        assert events.last(1, type="integrity")[-1]["suspect"] is False
+        assert registry.get("integrity.suspect") == 0
         integrity.failure("aot:blob", "digest", detail="t2")
         assert integrity.suspect()
-        sig = fleet._signals()
-        assert sig["integrity_suspect"] is True
-        assert sig["integrity_failures"] >= 2
+        ev = events.last(1, type="integrity")[-1]
+        assert ev["suspect"] is True and ev["failures_in_window"] == 2
+        assert registry.get("integrity.suspect") == 1
+        assert registry.get("integrity.failures") == f0 + 2
+        assert integrity.snapshot()["suspect"] is True
 
     def test_window_expires_strikes(self, monkeypatch):
         monkeypatch.setenv("RAMBA_INTEGRITY_THRESHOLD", "1")
@@ -632,15 +503,6 @@ class TestSuspectQuarantine:
         now = __import__("time").time()
         assert integrity.suspect(now)
         assert not integrity.suspect(now + integrity.suspect_window_s() + 1)
-
-    def test_fleet_classifies_suspect_replica_degraded(self):
-        doc = {"schema_version": diagnostics.SCHEMA_VERSION,
-               "interval_s": 30.0, "published_at": 1000.0,
-               "signals": {"integrity_suspect": True,
-                           "integrity_failures": 3}}
-        state, reason = fleet.classify({"doc": doc}, now=1010.0)
-        assert state == fleet.DEGRADED
-        assert "integrity suspect" in reason and "3" in reason
 
     def test_integrity_is_a_flight_trigger(self):
         assert "integrity" in telemetry.FLIGHT_TRIGGERS
@@ -660,37 +522,34 @@ class TestSuspectQuarantine:
 
 class TestFsck:
     def test_empty_tier_is_exit_empty(self, tmp_path):
-        r = ramba_fsck.scan(artifacts=str(tmp_path))
+        r = ramba_fsck.scan(cache=str(tmp_path))
         assert r["status"] == ramba_fsck.EXIT_EMPTY and r["scanned"] == 0
 
-    def test_detect_repair_quarantine_rescan(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("RAMBA_ARTIFACTS", str(tmp_path))
-        artifacts.configure(str(tmp_path))
-        assert artifacts.memo_store("fsck0" * 8, [np.arange(16.0)])
-        assert artifacts.memo_store("fsck1" * 8, [np.ones(4)])
-        root = str(tmp_path)
-        r = ramba_fsck.scan(artifacts=root)
+    def test_detect_repair_quarantine_rescan(self, tmp_path):
+        root = str(tmp_path / "cache")
+        with _CacheDir(root):
+            _seed_cache(root, np.arange(16, dtype=np.float32))
+        r = ramba_fsck.scan(cache=root)
         assert r["status"] == ramba_fsck.EXIT_CLEAN and r["scanned"] >= 2
-        memo_dir = os.path.join(root, "memo")
-        victim = os.path.join(memo_dir, sorted(os.listdir(memo_dir))[0])
+        aot_dir = os.path.join(root, "aot")
+        victim = os.path.join(aot_dir, sorted(os.listdir(aot_dir))[0])
         raw = bytearray(open(victim, "rb").read())
         raw[len(raw) // 2] ^= 0xFF
         open(victim, "wb").write(bytes(raw))
-        r = ramba_fsck.scan(artifacts=root)
+        r = ramba_fsck.scan(cache=root)
         assert r["status"] == ramba_fsck.EXIT_CORRUPT and r["corrupt"] == 1
-        r = ramba_fsck.scan(artifacts=root, repair=True)
+        r = ramba_fsck.scan(cache=root, repair=True)
         assert r["status"] == ramba_fsck.EXIT_CORRUPT
         qdir = os.path.join(root, "quarantine")
         assert os.path.isdir(qdir)
         assert not os.path.exists(victim)     # moved, not deleted
-        r = ramba_fsck.scan(artifacts=root)
+        r = ramba_fsck.scan(cache=root)
         assert r["status"] == ramba_fsck.EXIT_CLEAN, r
 
-    def test_cli_exit_codes(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("RAMBA_ARTIFACTS", str(tmp_path))
-        artifacts.configure(str(tmp_path))
-        assert artifacts.memo_store("fsck2" * 8, [np.arange(8.0)])
-        assert ramba_fsck.main(["--artifacts", str(tmp_path)]) \
-            == ramba_fsck.EXIT_CLEAN
-        assert ramba_fsck.main(["--artifacts", str(tmp_path / "nope")]) \
+    def test_cli_exit_codes(self, tmp_path):
+        root = str(tmp_path / "cache")
+        with _CacheDir(root):
+            _seed_cache(root, np.arange(8, dtype=np.float32))
+        assert ramba_fsck.main(["--cache", root]) == ramba_fsck.EXIT_CLEAN
+        assert ramba_fsck.main(["--cache", str(tmp_path / "nope")]) \
             == ramba_fsck.EXIT_EMPTY

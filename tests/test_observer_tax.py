@@ -224,9 +224,9 @@ def test_observer_tax_frac_denominator_is_flush_wall():
 
 def test_observer_surfaces_in_diagnostics_and_telemetry():
     observer.reset()
-    observer.add("fleet", 0.001)
+    observer.add("flight", 0.001)
     rep = diagnostics.observer_report()
-    assert rep["components"]["fleet"]["seconds"] > 0
+    assert rep["components"]["flight"]["seconds"] > 0
     assert "observer" in diagnostics.snapshot()
     import io
     buf = io.StringIO()
@@ -235,7 +235,7 @@ def test_observer_surfaces_in_diagnostics_and_telemetry():
     prom = telemetry.render()
     line = next(ln for ln in prom.splitlines()
                 if ln.startswith("ramba_observer_seconds_total{"))
-    assert 'component="fleet"' in line  # (rank label rides along)
+    assert 'component="flight"' in line  # (rank label rides along)
     observer.reset()
 
 

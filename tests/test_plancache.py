@@ -15,9 +15,6 @@ The contract under test, in order of importance:
 * **Strict-mode rejection** — a ``plan:stale``-forged staleness verdict
   raises ``ProgramVerificationError`` under strict and silently
   re-analyzes (byte-identical) under warn.
-* **Shared tier** — a published certificate is adoptable by chash from
-  the fleet artifact tier, and the adopted copy redeems like a local
-  hit.
 * **Batched coherence** — hit agreement runs per batch, and a divergent
   round clears the local store.
 
@@ -52,7 +49,7 @@ def _isolate(monkeypatch):
     monkeypatch.setenv("RAMBA_PLANCERT", "1")
     monkeypatch.setenv("RAMBA_VERIFY", "strict")
     for k in ("RAMBA_VERIFY_RULES", "RAMBA_VERIFY_SKIP",
-              "RAMBA_HBM_BUDGET", "RAMBA_ARTIFACTS", "RAMBA_MEMO"):
+              "RAMBA_HBM_BUDGET", "RAMBA_MEMO"):
         monkeypatch.delenv(k, raising=False)
     plancache.reset()
     plancert.reset_caches()
@@ -209,34 +206,6 @@ def test_certificate_roundtrips_through_payload():
     assert back.sig_fields == cert.sig_fields
     assert back.findings_digest == cert.findings_digest
     assert back.aval_sig == cert.aval_sig
-
-
-def test_shared_tier_adoption(tmp_path, monkeypatch):
-    from ramba_tpu.fleet import artifacts
-
-    monkeypatch.setenv("RAMBA_ARTIFACTS", str(tmp_path))
-    artifacts.configure(str(tmp_path))
-    try:
-        _workload()
-        certs = [e.cert for e in plancache._store.values()]
-        assert certs and all(c.chash for c in certs)
-        for c in certs:
-            assert plancache.publish(c)
-        # a fresh process is modeled by dropping the local store: the
-        # next flush misses locally, adopts by chash, and redeems
-        plancache.reset()
-        first = _workload()
-        snap = plancache.snapshot()
-        assert snap.get("adopted", 0) >= 1
-        assert snap.get("shared_hits", 0) >= 1
-        span = events.last(1, type="flush")[-1]
-        assert span.get("plan_cache") == "shared"
-        # and the adopted copy is now a plain local hit
-        second = _workload()
-        assert plancache.snapshot().get("hits", 0) >= 1
-        assert first.tobytes() == second.tobytes()
-    finally:
-        artifacts.reset()
 
 
 def test_batched_agree_divergence_clears(monkeypatch):
